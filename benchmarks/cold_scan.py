@@ -42,7 +42,7 @@ def main():
     args = ap.parse_args()
 
     from greptimedb_tpu.common.jax_cache import enable_compile_cache
-    enable_compile_cache("/tmp/coldscan-xla-cache")
+    enable_compile_cache()
     from greptimedb_tpu.datanode.instance import (
         DatanodeInstance, DatanodeOptions)
     from greptimedb_tpu.frontend.instance import FrontendInstance

@@ -1,7 +1,7 @@
-"""BASELINE.md measurement suite: configs 1-5 on real hardware.
+"""BASELINE.json measurement suite: configs 1-5 on real hardware.
 
 Run on the TPU host:  python benchmarks/suite.py [--rows-scale 1.0]
-Prints one JSON line per config; paste results into BASELINE.md.
+Prints one JSON line per config.
 
 Config map (BASELINE.json):
   1 README monitor smoke — end-to-end standalone SQL latency
@@ -11,7 +11,7 @@ Config map (BASELINE.json):
   5 compaction + 1s→1m downsample over a multi-SST region
 
 CPU denominators are same-machine pandas columnar equivalents (the
-reference publishes no numbers; see BASELINE.md).
+reference publishes no numbers).
 """
 
 import argparse

@@ -11,7 +11,7 @@ iamazy/greptimedb, surveyed in SURVEY.md), designed TPU-first:
 - SQL and PromQL front ends, HTTP/MySQL/gRPC protocol servers
 - standalone-to-distributed frontend/datanode/meta architecture
 
-The compute path is JAX (jit/pallas); the host path (WAL, catalog, routing,
+The compute path is JAX (jit/XLA); the host path (WAL, catalog, routing,
 object-store I/O) is Python/C++ and never touches the accelerator.
 """
 

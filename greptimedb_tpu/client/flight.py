@@ -16,6 +16,7 @@ from __future__ import annotations
 import json
 from typing import Dict, List, Optional, Sequence
 
+import numpy as np
 import pandas as pd
 import pyarrow as pa
 import pyarrow.flight as flight
@@ -79,7 +80,12 @@ def _absorb_stream_stats(schema: pa.Schema) -> None:
 
 
 def _columns_to_arrow(columns: Dict[str, Sequence]) -> pa.Table:
-    return pa.table({k: list(v) for k, v in columns.items()})
+    # columns that are already arrays (numpy / arrow, dictionary-encoded
+    # tags included) go over as they are; only plain sequences are
+    # materialized
+    return pa.table({
+        k: v if isinstance(v, (np.ndarray, pa.Array, pa.ChunkedArray))
+        else list(v) for k, v in columns.items()})
 
 
 def _to_greptime_error(e: flight.FlightError) -> GreptimeError:

@@ -145,14 +145,13 @@ def build_servers(opts: StandaloneOptions):
 
 def standalone_start(args) -> None:
     opts = load_options(args)
-    from ..common.jax_cache import enable_compile_cache
     from ..common.telemetry import (configure_otlp, init_logging,
                                     install_panic_hook)
     init_logging(opts.log_level, opts.log_dir)
     if opts.otlp_endpoint:
         configure_otlp(opts.otlp_endpoint, service_name="greptimedb")
     install_panic_hook()
-    enable_compile_cache(opts.data_home)
+    _claim_device()
     fe, servers = build_servers(opts)
     for s in servers:
         s.start()
@@ -171,6 +170,19 @@ def standalone_start(args) -> None:
     for s in servers:
         s.shutdown()
     fe.shutdown()
+
+
+def _claim_device() -> None:
+    """Chip-owning roles: place the compile cache, bring the backend up
+    and log what this process runs on (exits when that is a CPU nobody
+    asked for)."""
+    from ..common.device import require_device
+    from ..common.jax_cache import enable_compile_cache
+    cache_dir = enable_compile_cache()
+    dev = require_device()
+    logging.info("device: platform=%s device_kind=%s device_count=%d "
+                 "(compile cache %s)", dev["platform"],
+                 dev["device_kind"], dev["device_count"], cache_dir)
 
 
 def _block_until_signal(on_shutdown) -> None:
@@ -300,7 +312,6 @@ def metasrv_start(args) -> None:
 def datanode_start(args) -> None:
     """Run a region-hosting worker: Flight data plane + meta heartbeats
     (reference: greptime datanode start)."""
-    from ..common.jax_cache import enable_compile_cache
     from ..common.telemetry import init_logging
     from ..datanode import DatanodeInstance, DatanodeOptions
     from ..meta import Peer
@@ -308,7 +319,7 @@ def datanode_start(args) -> None:
     from ..servers.flight import FlightDatanodeServer
 
     init_logging(args.log_level or "info")
-    enable_compile_cache(args.data_home or "./greptimedb_data")
+    _claim_device()
     # buffer-role trace sink: this process cannot decide tail-sampling
     # verdicts (it sees only its fragments of each trace) and cannot
     # write trace_spans — it buffers spans keyed by trace_id until the

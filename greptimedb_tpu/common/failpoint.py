@@ -30,7 +30,7 @@ Action grammar (``parse_action``)::
 
 Zero overhead when inactive: every entry point checks the module-level
 ``_ACTIVE`` bool first — one global load + branch per instrumented call,
-no dict lookup, no lock (BASELINE.md publishes the bench delta).
+no dict lookup, no lock (bench.py asserts the ingest differential).
 Evaluation while armed takes a lock; failpoints are a test/debug surface,
 never a production hot path.
 """

@@ -37,7 +37,7 @@ from collections import OrderedDict
 from typing import Dict, List, Optional, Tuple
 
 from .locks import TrackedLock
-from .runtime import env_float, env_int
+from ..utils import env_float, env_int
 from .tracking import tracked_state
 
 logger = logging.getLogger(__name__)

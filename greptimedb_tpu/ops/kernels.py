@@ -361,9 +361,12 @@ def apply_cmp(op: str, col: jax.Array, a, b=None) -> jax.Array:
 #           combined by prefix-sum difference (sum family) or an RMQ sparse
 #           table over block partials (min/max family);
 #   edges:  the two partial blocks — fixed-size masked gather windows.
-# Two-level sums also bound float32 error: naive full-array cumsum boundary
-# differences lose ~N*eps of the running prefix; per-block partials keep
-# absolute error at ~block*eps + NB*eps of block sums.
+# Float sums never difference a plain float32 running prefix: over a
+# resident scan of 17M rows of values near 50 the prefix reaches ~1e9,
+# whose float32 spacing is 64 — an hourly avg() came out wrong in the
+# third digit. The prefix over block sums is carried as an unevaluated
+# float32 pair (hi, lo) instead (_block_prefix), so a boundary difference
+# is good to float32 rounding of the SEGMENT sum, whatever the scan size.
 
 # Mini-block size: edge windows gather [num_groups, 2*block] elements, and
 # TPU scalar gather is ~20ns/element — small blocks keep edges cheap while
@@ -428,55 +431,77 @@ def _pad_block(x, ident, n):
 _SEG_HIGH_CARD_THRESHOLD = 8192
 
 
+def _pair_add(a, b):
+    """Sum of two unevaluated float32 pairs (hi, lo), renormalized:
+    Knuth two-sum keeps what hi + hi rounds away."""
+    ah, al = a
+    bh, bl = b
+    s = ah + bh
+    bb = s - ah
+    err = (ah - (s - bb)) + (bh - bb)
+    lo = err + (al + bl)
+    hi = s + lo
+    return hi, lo - (hi - s)
+
+
+def _block_prefix(block_sums):
+    """Exclusive prefix over per-block sums, [NB + 1]. Integers: exact
+    cumsum, (csum, None). Floats: an unevaluated (hi, lo) float32 pair
+    whose sum carries ~48 bits of the running prefix."""
+    zero = jnp.zeros(1, block_sums.dtype)
+    if jnp.issubdtype(block_sums.dtype, jnp.integer):
+        return jnp.concatenate([zero, jnp.cumsum(block_sums)]), None
+    hi, lo = jax.lax.associative_scan(
+        _pair_add, (block_sums, jnp.zeros_like(block_sums)))
+    return jnp.concatenate([zero, hi]), jnp.concatenate([zero, lo])
+
+
 def _sorted_seg_sum(x, starts, ends, bs, be, has_inner, n):
     """Per-segment sum of x (zeros where masked).
 
-    Low cardinality: per-segment block partials + edge windows (exact).
-    High cardinality: in-block inclusive scans + cumsum over block sums
-    form a global prefix P; each segment is P[end]-P[start] — measured
-    4-8x faster at 120k-1.2M groups on v5e (the edge-window design is
-    O(groups*block) random gather). Bounds always come from dense integer
-    group queries (this module's contract), so starts[g] == ends[g-1] and
-    the prefix at starts is a shift of the prefix at ends — halving the
-    O(G) gather count, the dominant cost at 1M+ groups."""
+    Low cardinality: per-segment block partials + edge windows.
+    High cardinality: in-block inclusive scans + the prefix over block
+    sums form a global prefix P; each segment is P[end]-P[start] —
+    measured 4-8x faster at 120k-1.2M groups on v5e (the edge-window
+    design is O(groups*block) random gather). Bounds always come from
+    dense integer group queries (this module's contract), so starts[g] ==
+    ends[g-1] and the prefix at starts is a shift of the prefix at ends —
+    halving the O(G) gather count, the dominant cost at 1M+ groups."""
     if jnp.issubdtype(x.dtype, jnp.integer):
         acc = jnp.promote_types(x.dtype, jnp.int32)  # exact int accumulation
     else:
         acc = jnp.promote_types(x.dtype, jnp.float32)
     B = _SEG_BLOCK
     num_groups = starts.shape[0]
+    xp, nb = _pad_block(x.astype(acc), 0, n)
     if num_groups <= _SEG_HIGH_CARD_THRESHOLD and \
             not jnp.issubdtype(x.dtype, jnp.integer):
-        xp, nb = _pad_block(x.astype(acc), 0, n)
-        block_sums = xp.reshape(nb, B).sum(axis=1)
-        csum = jnp.concatenate([jnp.zeros(1, acc),
-                                jnp.cumsum(block_sums)])
+        hi, lo = _block_prefix(xp.reshape(nb, B).sum(axis=1))
+        lo_b = jnp.minimum(bs, nb)
         inner = jnp.where(has_inner,
-                          csum[be] - csum[jnp.minimum(bs, nb)], 0)
+                          (hi[be] - hi[lo_b]) + (lo[be] - lo[lo_b]), 0)
         edges = _edge_windows(
             x.astype(acc), starts, ends,
             jnp.where(has_inner, bs, (starts // B) + 1),
             jnp.where(has_inner, be, starts // B + 1), 0, n)
         return inner + edges.sum(axis=1)
 
-    xp, nb = _pad_block(x.astype(acc), 0, n)
     inblock = jnp.cumsum(xp.reshape(nb, B), axis=1)      # inclusive scans
-    block_sums = inblock[:, -1]
-    csum = jnp.concatenate([jnp.zeros(1, acc), jnp.cumsum(block_sums)])
+    hi, lo = _block_prefix(inblock[:, -1])
 
-    def prefix(idx):
-        """Exclusive global prefix at row index idx ∈ [0, nb*B]."""
-        b = idx // B
-        r = idx % B
-        base = csum[b]                      # b == nb only when r == 0
-        inb = jnp.where(
-            r > 0,
-            inblock[jnp.minimum(b, nb - 1), jnp.maximum(r - 1, 0)], 0)
-        return base + inb
+    # exclusive global prefix at each segment end, idx ∈ [0, nb*B]
+    b = ends // B                           # b == nb only when r == 0
+    r = ends % B
+    inb = jnp.where(
+        r > 0,
+        inblock[jnp.minimum(b, nb - 1), jnp.maximum(r - 1, 0)], 0)
+    pe_hi = hi[b]
+    pe_lo = inb if lo is None else lo[b] + inb
 
-    pe = prefix(ends)
-    ps = jnp.concatenate([jnp.zeros(1, acc), pe[:-1]])
-    return pe - ps
+    def seg(pe):                            # P[end] - P[start], shifted
+        return pe - jnp.concatenate([jnp.zeros(1, acc), pe[:-1]])
+
+    return seg(pe_hi) + seg(pe_lo)
 
 
 def _floor_log2(ln, K):

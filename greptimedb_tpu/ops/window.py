@@ -538,16 +538,26 @@ def _rag_body(
 # When the window is a multiple of the step (rate(x[5m]) at 1m step — the
 # common dashboard shape), every per-(series, step) quantity the cumsum-op
 # family needs is a value at either index lo[k] or hi[k]-1, and lo is a
-# shifted view of hi over an EXTENDED grid. Measured on v5e: a stacked
-# [S, L, 8] take_along_axis costs the same as a single-channel gather
-# (~275ms at 10k series x 1440 steps), so ONE stacked gather at the
-# extended grid serves every op — rate + avg_over_time + ... over the same
-# selector share the bounds pass, the cumsums, and the gather, leaving only
-# tiny [S, T] vector epilogues per op.
+# shifted view of hi over an EXTENDED grid. So ONE set of channels gathered
+# at the extended grid serves every op — rate + avg_over_time + ... over
+# the same selector share the bounds pass, the cumsums, and the gathers,
+# leaving only tiny [S, T] vector epilogues per op.
 
 # tier-A channels (prefix/instant values)
 _CH_CSP, _CH_TS_PREV, _CH_TS_AT, _CH_VAL_PREV, _CH_VAL_AT, _CH_VAL_PREV2 = \
     range(6)
+
+
+def _gather_channels(channels, e):
+    """K channels [S, L+1] at positions e [S, T_ext] -> [S, T_ext, K].
+
+    One 2-D gather per channel. A single gather over the stacked
+    [S, L+1, K] operand never returns on jax 0.9.0 / libtpu 0.0.34 for
+    some shapes (on a v5e: [4000, 513, 6] hangs, [4000, 397, 6] and
+    [4000, 1025, 6] take 3-6 ms), with or without an optimization barrier
+    and with the channel axis in either place."""
+    return jnp.stack([jnp.take_along_axis(c, e, axis=1) for c in channels],
+                     axis=-1)
 
 
 @jax.jit
@@ -563,7 +573,7 @@ def _stack_prefix(ts2d, val2d, lengths, ext):
     csp = jnp.concatenate([jnp.zeros((S, 1), fv), jnp.cumsum(vz, axis=1)],
                           axis=1)
     tsf = ts2d.astype(fv)
-    stack = jnp.stack([
+    return _gather_channels([
         csp,
         jnp.concatenate([tsf[:, :1], tsf], axis=1),
         jnp.concatenate([tsf, tsf[:, -1:]], axis=1),
@@ -571,9 +581,7 @@ def _stack_prefix(ts2d, val2d, lengths, ext):
         jnp.concatenate([val2d, val2d[:, -1:]], axis=1).astype(fv),
         jnp.concatenate([val2d[:, :1], val2d[:, :1], val2d[:, :-1]],
                         axis=1).astype(fv),
-    ], axis=-1)
-    e = jnp.minimum(ext, L)
-    return jnp.take_along_axis(stack, e[:, :, None], axis=1)
+    ], jnp.minimum(ext, L))
 
 
 @jax.jit
@@ -587,12 +595,10 @@ def _stack_counter(ts2d, val2d, lengths, ext):
     pair_ok = valid & (idx[None, :] >= 1)
     contrib = jnp.where(pair_ok & (val2d < prev), prev, 0).astype(fv)
     adj = val2d + jnp.cumsum(contrib, axis=1)
-    stack = jnp.stack([
+    return _gather_channels([
         jnp.concatenate([adj[:, :1], adj], axis=1),
         jnp.concatenate([adj, adj[:, -1:]], axis=1),
-    ], axis=-1)
-    e = jnp.minimum(ext, L)
-    return jnp.take_along_axis(stack, e[:, :, None], axis=1)
+    ], jnp.minimum(ext, L))
 
 
 @jax.jit
@@ -605,8 +611,7 @@ def _stack_sq(ts2d, val2d, lengths, ext):
     vz = jnp.where(valid, val2d, 0).astype(fv)
     csp2 = jnp.concatenate(
         [jnp.zeros((S, 1), fv), jnp.cumsum(vz * vz, axis=1)], axis=1)
-    e = jnp.minimum(ext, L)
-    return jnp.take_along_axis(csp2[:, :, None], e[:, :, None], axis=1)
+    return _gather_channels([csp2], jnp.minimum(ext, L))
 
 
 @functools.partial(jax.jit, static_argnames=("step", "range_ms", "nsteps"))

@@ -50,13 +50,30 @@ _RANGE_FUNCS = {
 _KEEP_NAME_RANGE_FUNCS = {"last_over_time"}
 
 
-def _fetch_pair(v, ok):
-    """One batched device fetch for a (values, ok) kernel result — two
-    sequential np.asarray calls each pay a full device round trip."""
+def _run_window_kernel(kernel, matrix, t0, nsteps):
+    """kernel(...) -> host (values f64, ok), with the EXECUTED dispatch
+    put on record: TQL ANALYZE's `dispatch` row names the platform the
+    window kernel's result came from, as SQL's names the path a scan
+    took."""
+    import time as _time
+
     import jax
+
+    from ..common import exec_stats
+    t_start = _time.perf_counter()
+    v, ok = kernel(matrix, t0, nsteps)
+    devices = v.devices() if hasattr(v, "devices") else ()
+    where = next(iter(devices)).platform if devices else "host"
+    # one batched fetch: two sequential np.asarray calls would each pay
+    # a full device round trip
     if hasattr(v, "addressable_shards") or hasattr(ok, "addressable_shards"):
         v, ok = jax.device_get((v, ok))
-    return _from_device_f32(v), np.asarray(ok)
+    v, ok = _from_device_f32(v), np.asarray(ok)
+    exec_stats.set_dispatch(f"promql-row-path (window kernel on {where})")
+    exec_stats.record("window_kernel", rows=int(v.shape[0]),
+                      elapsed_s=_time.perf_counter() - t_start,
+                      steps=int(nsteps))
+    return v, ok
 
 
 def _from_device_f32(v) -> np.ndarray:
@@ -447,8 +464,8 @@ class _Eval:
             t = int(ends[0])
             if t < dmin or t - win_ms > dmax:
                 return VectorVal(selection.labels, out_vals, out_ok)
-            v, ok = kernel(selection.matrix, np.int64(t), 1)
-            v, ok = _fetch_pair(v, ok)
+            v, ok = _run_window_kernel(kernel, selection.matrix,
+                                       np.int64(t), 1)
             v = v[:, :1]
             ok = ok[:, :1]
             out_vals[:] = np.repeat(v, self.nsteps, axis=1)
@@ -463,9 +480,8 @@ class _Eval:
             return VectorVal(selection.labels, out_vals, out_ok)
         n_eval = j1 - j0 + 1
         n_pad = 1 << (n_eval - 1).bit_length() if n_eval > 1 else 1
-        v, ok = kernel(selection.matrix, np.int64(t0 + j0 * self.step),
-                       n_pad)
-        v, ok = _fetch_pair(v, ok)
+        v, ok = _run_window_kernel(
+            kernel, selection.matrix, np.int64(t0 + j0 * self.step), n_pad)
         v = v[:, :n_eval]
         ok = ok[:, :n_eval]
         out_vals[:, j0:j1 + 1] = v

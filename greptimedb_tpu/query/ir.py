@@ -203,14 +203,9 @@ def execute_agg_plan(table, plan: TpuPlan) -> pd.DataFrame:
             frames = [f for f in table.execute_tpu_plan(plan)
                       if f is not None and len(f)]
     else:
-        import time as _time
-
-        from .tpu_exec import _note_device_query_time
-        t0 = _time.perf_counter()
         with span("tpu_execute", table=table.name), \
                 timer("tpu_execute"):
             frames = region_moment_frames(table, plan)
-        _note_device_query_time(_time.perf_counter() - t0)
     if not frames:
         cols = group_key_columns(plan)
         if cols:

@@ -237,7 +237,8 @@ def _window_aggregate(wc: WindowCall, work: pd.DataFrame, pid: pd.Series,
         e = _bfill_at(pos, tie_end)
     empty = s > e
 
-    if not count_star and ser.dtype == object and op != "count":
+    if not count_star and op != "count" and \
+            pd.api.types.is_string_dtype(ser.dtype):   # object or `str`
         raise UnsupportedError(f"window {op} over non-numeric values")
 
     if count_star:

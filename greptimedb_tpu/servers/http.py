@@ -631,8 +631,11 @@ class HttpServer:
             if p is not None:
                 scan = p.describe()
         from ..query.tpu_exec import SCAN_CACHE
+        # a routing frontend hosts no regions and owns no device: it
+        # must not initialise a backend (and claim a chip) to answer
+        standalone = hasattr(self.frontend, "datanode")
         store = getattr(self.frontend.datanode, "store", None) \
-            if hasattr(self.frontend, "datanode") else None
+            if standalone else None
         ratio = store.hit_ratio() if hasattr(store, "hit_ratio") else None
         # degraded-mode health: regions whose background flush/compaction
         # has been failing, and the fault-injection state (robustness PR)
@@ -643,8 +646,12 @@ class HttpServer:
                 background_errors[r.name] = errs
         from ..common import failpoint
         from ..common.admission import GATE
+        from ..common.device import device_info
+        from ..storage.native_wal import wal_backend
         return web.json_response({
             "version": __version__,
+            "device": device_info() if standalone else None,
+            "wal_backend": wal_backend() if standalone else None,
             "admission": GATE.snapshot(),
             "uptime_s": round(time.time() - self._start_time, 3),
             "region_count": len(regions),

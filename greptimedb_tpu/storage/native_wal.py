@@ -8,15 +8,15 @@ API) with appends and group commit in C++: concurrent writers share one
 fdatasync instead of paying one each.
 
 The shared library builds on first use with g++ (cached next to the
-source, keyed by source mtime). If the toolchain is unavailable the
-caller falls back to the Python Wal — `load_library()` returns None.
+source, named by a hash of it: utils/native_build.py). If the toolchain
+is unavailable the caller falls back to the Python Wal —
+`load_library()` returns None.
 """
 
 from __future__ import annotations
 
 import ctypes
 import logging
-import os
 import subprocess
 import threading
 from typing import Optional
@@ -26,30 +26,9 @@ from .wal import Wal
 
 logger = logging.getLogger(__name__)
 
-_NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "native")
-_SRC = os.path.join(_NATIVE_DIR, "wal.cpp")
-_LIB = os.path.join(_NATIVE_DIR, "libgdbwal.so")
-
 _build_lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _lib_failed = False
-
-
-def _build() -> Optional[str]:
-    if os.path.exists(_LIB) and \
-            os.path.getmtime(_LIB) >= os.path.getmtime(_SRC):
-        return _LIB
-    cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-           "-o", _LIB + ".tmp", _SRC, "-lpthread"]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        from ..utils import atomic_publish
-        atomic_publish(_LIB + ".tmp", _LIB, fsync=False)  # build artifact
-        return _LIB
-    except (subprocess.SubprocessError, OSError) as e:
-        logger.warning("native WAL build failed (%s); using Python WAL", e)
-        return None
 
 
 def load_library() -> Optional[ctypes.CDLL]:
@@ -62,11 +41,14 @@ def load_library() -> Optional[ctypes.CDLL]:
     with _build_lock:
         if _lib is not None:
             return _lib
-        path = _build()
-        if path is None:
+        from ..utils.native_build import build_native_library
+        try:
+            lib = ctypes.CDLL(build_native_library("wal"))
+        except (subprocess.SubprocessError, OSError) as e:
+            logger.warning("native WAL build failed (%s); using Python "
+                           "WAL", e)
             _lib_failed = True
             return None
-        lib = ctypes.CDLL(path)
         lib.wal_open.restype = ctypes.c_void_p
         lib.wal_open.argtypes = [ctypes.c_char_p, ctypes.c_uint64,
                                  ctypes.c_uint32]
@@ -195,6 +177,11 @@ class NativeWal(Wal):
         except Exception:  # greptlint: disable=GL01 — finalizers must
             # never raise; at interpreter teardown even logging can fail
             pass
+
+
+def wal_backend() -> str:
+    """Which WAL `make_wal(backend="auto")` gives this process."""
+    return "native" if load_library() is not None else "python"
 
 
 def make_wal(dir_path: str, *, sync_on_write: bool = False,

@@ -69,7 +69,8 @@ class RegionDescriptor:
 @dataclass
 class IngestProfile:
     """Stage-by-stage wall-clock breakdown of one bulk_ingest call
-    (published in BASELINE.md; the perf-smoke test asserts the machinery).
+    (on /status as last_ingest_profile; the perf-smoke test asserts the
+    machinery).
     `sst_write` covers the parallel parquet encode + fsync of all chunks,
     so with N concurrent writers it is wall time, not CPU time."""
     rows: int = 0
@@ -731,8 +732,7 @@ class Region:
         orphaning their WAL entries at replay.
 
         Each call records its stage breakdown in `self.last_ingest_profile`
-        (series encode / sort / parquet+fsync / manifest — the profile
-        BASELINE.md publishes)."""
+        (series encode / sort / parquet+fsync / manifest)."""
         import os as _os
         import time as _time
 

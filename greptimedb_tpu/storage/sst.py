@@ -273,9 +273,10 @@ class AccessLayer:
         names.append(OP_COL)
         table = pa.table(dict(zip(names, arrays)))
         ts_name = schema.timestamp_column.name
-        # Encode/stat choices are ingest-rate critical (profiled in
-        # BASELINE.md): stats only on the two pruning columns (ts, sid) —
-        # per-page min/max on the metric columns bought nothing and cost
+        # Encode/stat choices are ingest-rate critical (profiled with
+        # Region.last_ingest_profile): stats only on the two pruning
+        # columns (ts, sid) — per-page min/max on the metric columns
+        # bought nothing and cost
         # ~35% of encode; dictionary encoding stays OFF for ts/sid (mostly
         # unique / already dense — hashing them is pure waste) and ON
         # elsewhere, where parquet's adaptive fallback bounds the cost on

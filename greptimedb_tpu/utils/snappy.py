@@ -12,17 +12,12 @@ from __future__ import annotations
 
 import ctypes
 import logging
-import os
 import subprocess
 import threading
 from typing import Optional, Tuple
 
 _logger = logging.getLogger(__name__)
 
-_NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "native")
-_SRC = os.path.join(_NATIVE_DIR, "snappy.cpp")
-_LIB_PATH = os.path.join(_NATIVE_DIR, "libgdbsnappy.so")
 _lib = None
 _lib_failed = False
 _build_lock = threading.Lock()
@@ -36,16 +31,8 @@ def _load() -> Optional[ctypes.CDLL]:
         if _lib is not None or _lib_failed:
             return _lib
         try:
-            if not (os.path.exists(_LIB_PATH) and
-                    os.path.getmtime(_LIB_PATH) >= os.path.getmtime(_SRC)):
-                subprocess.run(
-                    ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-                     "-o", _LIB_PATH + ".tmp", _SRC],
-                    check=True, capture_output=True, timeout=120)
-                from . import atomic_publish
-                atomic_publish(_LIB_PATH + ".tmp", _LIB_PATH,
-                               fsync=False)   # build artifact
-            lib = ctypes.CDLL(_LIB_PATH)
+            from .native_build import build_native_library
+            lib = ctypes.CDLL(build_native_library("snappy"))
             lib.snappy_max_compressed.restype = ctypes.c_uint64
             lib.snappy_max_compressed.argtypes = [ctypes.c_uint64]
             lib.snappy_compress.restype = ctypes.c_uint64

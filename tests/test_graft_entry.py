@@ -33,3 +33,11 @@ def test_driver_dryrun_multichip_verbatim():
         pytest.skip("needs the conftest 8-device virtual CPU mesh")
     assert not jax.config.jax_enable_x64  # the regime the driver uses
     graft._dryrun_impl(8)
+
+
+def test_dryrun_multichip_refuses_more_devices_than_exist():
+    """No quiet re-run on a virtual CPU mesh: asked for more devices than
+    the machine has, the dry run raises and names what it found."""
+    n = len(jax.devices())
+    with pytest.raises(RuntimeError, match=rf"has {n} device"):
+        graft.dryrun_multichip(n + 1)

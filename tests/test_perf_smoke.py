@@ -2,7 +2,7 @@
 
 Slow-marked so tier-1 stays inside its timeout; the driver's perf bars
 are measured by benchmarks/cold_scan.py — this test only asserts the
-profiling machinery BASELINE.md's breakdown is built from keeps working
+profiling machinery the ingest stage breakdown is built from keeps working
 (stages present, times positive, rows counted, merge() accumulates).
 """
 

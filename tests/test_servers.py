@@ -116,6 +116,13 @@ class TestHttpSql:
         status, body = req(server, "/status")
         assert status == 200
         data = json.loads(body)
+        # the node names the device it runs on and the WAL it writes
+        # (memory stats ride along where the backend keeps them: not CPU)
+        import jax
+        assert data["device"] == {
+            "platform": "cpu", "device_kind": jax.devices()[0].device_kind,
+            "device_count": len(jax.devices())}
+        assert data["wal_backend"] in ("native", "python")
         for key in ("version", "uptime_s", "region_count",
                     "read_cache_hit_ratio", "scan_cache_resident_bytes",
                     "last_ingest_profile", "last_scan_profile"):
