@@ -15,9 +15,24 @@ Stage vocabulary (shared with the scan/ingest profilers):
   ``device-resident`` / ``streamed-cold`` / ``aggregate-pushdown``
 - streamed scan: ``plan``, ``decode_reduce``, ``device_fetch``,
   ``fold`` (+ counters lean_slices / merged_slices / dedup_skip_slices)
-- resident scan: ``scan_prep``, ``reduce``
-- CPU fallback: ``scan``, ``filter``, ``aggregate``, ``project``
-- shared tail: ``finalize``
+- front: ``parse`` (the statement text, outside ``total``), ``plan``
+  (analyze, resolve, rewrite checks, the aggregate plan, the dispatch
+  decision)
+- resident scan: ``scan_prep``, ``reduce`` with its parts
+  ``reduce.runs`` / ``.mask`` / ``.upload`` / ``.launch`` / ``.fetch`` /
+  ``.collect``
+- CPU fallback: ``scan``, ``filter``, ``aggregate``
+- shared tail: ``finalize``, ``project`` (with ``project.sort`` and
+  ``project.to_batches``), and after ``total`` the protocol writer's
+  ``render`` (EXPLAIN ANALYZE only, servers/render.py)
+
+Every timed row is a span: `stage()` keeps the wall clock of its first
+entry (``t0_ns=`` at the end of the row's detail — the clock a device
+trace is anchored to) and is open as a profiler annotation of the same
+name (telemetry.annotation). A row named ``<parent>.<part>`` lies inside
+its parent's interval; rows without a dot that lie inside ``total`` do
+not overlap one another. The ``total`` row names the statement's
+``trace_id``, the identifier its telemetry spans carry.
 
 The collector is installed per top-level query (`collect()`), is
 thread-safe (streamed slices report from pool workers), and a missing
@@ -45,12 +60,38 @@ from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Callable, Dict, Iterator, List, Optional
 
+from .telemetry import annotation, current_span
+
 _tls = threading.local()
 
 #: wire key for datanode-side ExecStats riding a Flight response (stream
 #: schema metadata on do_get, the JSON ack on do_put) — one definition
 #: shared by both sides of the protocol so they cannot drift
 EXEC_STATS_WIRE_KEY = b"gdb.exec_stats"
+
+
+class Timed:
+    """One timed interval of the program: its start on the wall clock
+    (`t0_ns`), its length on the monotonic clock (`elapsed_s`), and a
+    profiler annotation of the same name while it is open."""
+
+    __slots__ = ("name", "t0_ns", "elapsed_s", "_t0", "_annotation")
+
+    def __init__(self, name: str):
+        self.name = name
+        self.t0_ns: Optional[int] = None
+        self.elapsed_s = 0.0
+
+    def __enter__(self) -> "Timed":
+        self._annotation = annotation(self.name)
+        self._annotation.__enter__()
+        self.t0_ns = time.time_ns()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc: object) -> None:
+        self.elapsed_s = time.perf_counter() - self._t0
+        self._annotation.__exit__(*exc)
 
 
 @dataclass
@@ -60,9 +101,17 @@ class StageStat:
     files: int = 0
     elapsed_s: float = 0.0
     detail: Dict[str, object] = field(default_factory=dict)
+    #: wall clock (unix ns) of the stage's first timed entry; None for a
+    #: row that only counts
+    t0_ns: Optional[int] = None
 
-    def detail_str(self) -> str:
-        return ", ".join(f"{k}={v}" for k, v in self.detail.items())
+    def detail_str(self, lead: str = "") -> str:
+        """`k=v, ...`, after `lead` if given, ending with `t0_ns=`."""
+        parts = [lead] if lead else []
+        parts += [f"{k}={v}" for k, v in self.detail.items()]
+        if self.t0_ns is not None:
+            parts.append(f"t0_ns={self.t0_ns}")
+        return ", ".join(parts)
 
 
 class ExecStats:
@@ -73,6 +122,8 @@ class ExecStats:
         self.stages: "OrderedDict[str, StageStat]" = OrderedDict()
         self.dispatch: Optional[str] = None
         self.total_s: float = 0.0
+        #: the statement's trace (set by collect() from the active span)
+        self.trace_id: Optional[str] = None
         #: node label -> {"stats": ExecStats, "wall_ms": float} — one
         #: sub-collector per datanode RPC (DistTable._scatter)
         self.nodes: "OrderedDict[str, dict]" = OrderedDict()
@@ -82,7 +133,8 @@ class ExecStats:
 
     # ---- recording ----
     def record(self, stage: str, *, rows: int = 0, files: int = 0,
-               elapsed_s: float = 0.0, **detail) -> None:
+               elapsed_s: float = 0.0, t0_ns: Optional[int] = None,
+               **detail) -> None:
         with self._lock:
             st = self.stages.get(stage)
             if st is None:
@@ -90,6 +142,8 @@ class ExecStats:
             st.rows += int(rows)
             st.files += int(files)
             st.elapsed_s += float(elapsed_s)
+            if st.t0_ns is None and t0_ns is not None:
+                st.t0_ns = int(t0_ns)
             for k, v in detail.items():
                 old = st.detail.get(k)
                 # numeric details accumulate across regions/slices so a
@@ -103,11 +157,14 @@ class ExecStats:
 
     @contextlib.contextmanager
     def stage(self, name: str, **detail) -> Iterator[None]:
-        t0 = time.perf_counter()
+        t = Timed(name)
         try:
-            yield
+            with t:
+                # the row takes its place now, ahead of its parts
+                self.record(name, t0_ns=t.t0_ns)
+                yield
         finally:
-            self.record(name, elapsed_s=time.perf_counter() - t0, **detail)
+            self.record(name, elapsed_s=t.elapsed_s, **detail)
 
     def set_dispatch(self, decision: str) -> None:
         """First decision wins: nested subqueries must not overwrite the
@@ -139,6 +196,7 @@ class ExecStats:
                 "stages": [{
                     "stage": st.stage, "rows": st.rows, "files": st.files,
                     "elapsed_ms": round(st.elapsed_s * 1e3, 3),
+                    "t0_ns": st.t0_ns,
                     "detail": {k: _json_safe(v)
                                for k, v in st.detail.items()},
                 } for st in self.stages.values()],
@@ -153,6 +211,7 @@ class ExecStats:
             self.record(st.get("stage", "?"), rows=st.get("rows", 0),
                         files=st.get("files", 0),
                         elapsed_s=float(st.get("elapsed_ms", 0.0)) / 1e3,
+                        t0_ns=st.get("t0_ns"),
                         **(st.get("detail") or {}))
         with self._lock:
             self.remote_total_ms += float(d.get("total_ms", 0.0))
@@ -260,8 +319,12 @@ class ExecStats:
             parts.append(f"total={self.total_s * 1e3:.1f}ms")
         return " ".join(parts)
 
-    def rows_table(self) -> Dict[str, List]:
-        """Column dict for the EXPLAIN ANALYZE per-stage batch."""
+    def rows_table(self, plan_text: Optional[str] = None,
+                   out_rows: int = 0) -> Dict[str, List]:
+        """Column dict for the EXPLAIN ANALYZE per-stage batch: `parse`
+        (outside `total`), `plan` leading with `plan_text` when the
+        caller has one, the dispatch decision, the stages in recording
+        order, `total`."""
         cols: Dict[str, List] = {"stage": [], "rows": [], "files": [],
                                  "elapsed_ms": [], "detail": []}
 
@@ -274,6 +337,16 @@ class ExecStats:
             cols["detail"].append(detail)
 
         with self._lock:
+            lead = {"parse"}
+            parse = self.stages.get("parse")
+            if parse is not None:
+                add("parse", 0, 0, parse.elapsed_s * 1e3,
+                    parse.detail_str())
+            if plan_text is not None:
+                lead.add("plan")
+                plan = self.stages.get("plan") or StageStat("plan")
+                add("plan", out_rows, 0, plan.elapsed_s * 1e3,
+                    plan.detail_str(plan_text))
             add("dispatch", 0, 0, 0.0, self.dispatch or "n/a")
             # node blocks sorted by label: gather completion order is
             # nondeterministic, golden files must not be
@@ -281,6 +354,8 @@ class ExecStats:
                                 key=lambda kv: node_sort_key(kv[0]))
             nodes_emitted = False
             for st in self.stages.values():
+                if st.stage in lead:
+                    continue
                 add(st.stage, st.rows, st.files, st.elapsed_s * 1e3,
                     st.detail_str())
                 if st.stage == "dist_scatter" and not nodes_emitted:
@@ -288,7 +363,8 @@ class ExecStats:
                     _add_node_rows(add, node_items)
             if node_items and not nodes_emitted:
                 _add_node_rows(add, node_items)
-            add("total", 0, 0, self.total_s * 1e3, "")
+            add("total", 0, 0, self.total_s * 1e3,
+                f"trace_id={self.trace_id}" if self.trace_id else "")
         return cols
 
 
@@ -350,6 +426,9 @@ def collect(stats: Optional[ExecStats] = None) -> Iterator[ExecStats]:
     prev = getattr(_tls, "stats", None)
     s = stats if stats is not None else ExecStats()
     _tls.stats = s
+    active = current_span()
+    if active is not None and s.trace_id is None:
+        s.trace_id = active["trace_id"]
     # publish to the process-list entry (if this statement is tracked):
     # the processes view reads live rows-scanned/bytes/RPC totals off
     # the collector WHILE the query runs

@@ -20,6 +20,11 @@ Python twin:
   opened on the `common/runtime` pools stay parented to the trace.
 - `timer(name)` — histogram observation (prometheus_client, the same
   registry the /metrics endpoint exports).
+- `annotation(name)` — the one bridge to the device profiler: spans,
+  timers and EXPLAIN ANALYZE stages (common/exec_stats.py) open a
+  `jax.profiler.TraceAnnotation` of their own name when jax is already
+  loaded in this process, so a profiler session shows the program's
+  stages on the host plane beside the device's `XLA Ops`.
 - `slow_query_threshold_ms()` — the SET/env-configurable threshold the
   frontend checks per statement (None = slow-query log off).
 - `install_panic_hook()` — top-level excepthook that logs crashes.
@@ -99,6 +104,25 @@ def current_span() -> Optional[Dict]:
     return stack[-1] if stack else None
 
 
+#: jax.profiler.TraceAnnotation once jax was seen loaded (resolved lazily)
+_TRACE_ANNOTATION: list = [None]
+
+
+def annotation(name: str):
+    """Context manager that puts `name` on the profiler's host timeline
+    (a `TraceMe`: a flag test while no profiler session is on). jax is
+    looked up in sys.modules and NEVER imported here — frontends and
+    metasrv run without it, and then this is a null context."""
+    cls = _TRACE_ANNOTATION[0]
+    if cls is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        cls = getattr(profiler, "TraceAnnotation", None)
+        if cls is None:
+            return contextlib.nullcontext()
+        _TRACE_ANNOTATION[0] = cls
+    return cls(name)
+
+
 @contextlib.contextmanager
 def span(name: str, **attrs: object) -> Iterator[Dict]:
     """Nested span: inherits trace_id from the parent, logs duration on
@@ -127,16 +151,17 @@ def span(name: str, **attrs: object) -> Iterator[Dict]:
     stack.append(s)
     status = "ok"
     try:
-        yield s
+        with annotation(name):
+            yield s
     except BaseException as e:  # greptlint: disable=GL02 — classified,
         status = _exc_status(e)  # re-raised untouched
         raise
     finally:
         stack.pop()
-        elapsed_ms = (time.perf_counter() - s["start"]) * 1e3
+        elapsed_ms = s["elapsed_ms"] = \
+            (time.perf_counter() - s["start"]) * 1e3
         logger.debug("span %s finished in %.2fms attrs=%s", name,
                      elapsed_ms, attrs)
-        _observe(f"span_{name}", elapsed_ms / 1e3)
         if not metrics_suppressed():
             exporter = _OTLP[0]
             if exporter is not None:
@@ -286,16 +311,37 @@ def remote_context(traceparent: Optional[str]) -> Iterator[Optional[Dict]]:
     if parsed is None:
         yield None
         return
-    trace_id, span_id = parsed
+    with _parent_frame("remote", *parsed, {"remote": True}) as frame:
+        yield frame
+
+
+@contextlib.contextmanager
+def continue_trace(parent: Optional[Tuple[str, str]]
+                   ) -> Iterator[Optional[Dict]]:
+    """Re-enter the trace of a span of THIS process that has already
+    ended: `parent` is its (trace_id, span_id). Spans opened underneath
+    hang off it as late children — a protocol writer's `render` span
+    under the statement's `execute_stmt` — and never root a trace of
+    their own. None is a no-op."""
+    if parent is None:
+        yield None
+        return
+    with _parent_frame("continued", *parent, {}) as frame:
+        yield frame
+
+
+@contextlib.contextmanager
+def _parent_frame(name: str, trace_id: str, span_id: str,
+                  attrs: Dict) -> Iterator[Dict]:
     stack = getattr(_tls, "spans", None)
     if stack is None:
         stack = _tls.spans = []
     frame = {
-        "name": "remote",
+        "name": name,
         "trace_id": trace_id,
         "span_id": span_id,
         "parent_id": None,
-        "attrs": {"remote": True},
+        "attrs": attrs,
         "start": time.perf_counter(),
         "start_unix_ns": time.time_ns(),
     }
@@ -560,10 +606,12 @@ def increment_counter(name: str, value: int = 1) -> None:
 
 @contextlib.contextmanager
 def timer(name: str) -> Iterator[None]:
-    """reference `timer!` macro: records elapsed seconds on exit."""
+    """reference `timer!` macro: records elapsed seconds on exit (and
+    shows as `name` on the profiler's host timeline, see annotation)."""
     t0 = time.perf_counter()
     try:
-        yield
+        with annotation(name):
+            yield
     finally:
         _observe(name, time.perf_counter() - t0)
 

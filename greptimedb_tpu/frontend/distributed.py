@@ -1341,9 +1341,12 @@ class DistInstance:
             span, timer)
         from ..sql import parse_statements
         from ..common.admission import GATE as _admission
+        from ..common import exec_stats
         ctx = ctx or QueryContext()
         outs = []
-        for stmt in parse_statements(sql):
+        with exec_stats.Timed("parse") as ctx.parse_span:
+            stmts = parse_statements(sql)
+        for stmt in stmts:
             # same admission gate as the standalone frontend: reject
             # past the in-flight limit, KILL/SET always admitted
             _admission.admit_statement(type(stmt).__name__)
@@ -1366,6 +1369,7 @@ class DistInstance:
                     "stmt_latency", _time.perf_counter() - t0,
                     stmt=type(stmt).__name__,
                     protocol=ctx.channel.value)
+            outs[-1].trace = (sp["trace_id"], sp["span_id"])
             increment_counter(f"stmt_{type(stmt).__name__.lower()}")
             elapsed_ms = (_time.perf_counter() - t0) * 1e3
             thr = slow_query_threshold_ms()

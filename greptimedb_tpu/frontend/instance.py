@@ -99,12 +99,13 @@ class FrontendInstance:
         interceptor = self._interceptor()
         if interceptor is not None:
             sql = interceptor.pre_parsing(sql, ctx)
-        stmts = parse_statements(sql)
+        from ..common import exec_stats, process_list
+        with exec_stats.Timed("parse") as ctx.parse_span:
+            stmts = parse_statements(sql)
         if interceptor is not None:
             stmts = interceptor.post_parsing(stmts, ctx)
         import time as _time
 
-        from ..common import process_list
         from ..common.telemetry import (
             increment_counter, observe_latency, slow_query_threshold_ms,
             span, timer)
@@ -164,6 +165,7 @@ class FrontendInstance:
                     stats.summary() if stats is not None else "n/a")
             if interceptor is not None:
                 out = interceptor.post_execute(out, ctx)
+            out.trace = (sp["trace_id"], sp["span_id"])
             outputs.append(out)
         return outputs
 

@@ -179,27 +179,28 @@ class QueryEngine:
         tracing spans and Region.last_scan_profile agree (reference:
         DataFusion's EXPLAIN ANALYZE over operator metrics)."""
         stats = exec_stats.ExecStats()
-        out_rows = 0
+        analyzed = None
         with exec_stats.collect(stats):
+            _record_parse(ctx)
             if isinstance(inner, Query):
-                out = self._execute_query_inner(inner, ctx)
-                out_rows = out.num_rows or 0
+                analyzed = self._execute_query_inner(inner, ctx)
         self.last_exec_stats = stats
-        cols = stats.rows_table()
-        # lead with the plan so the dispatch line stays next to the plan
-        # shape it annotates
-        cols["stage"].insert(0, "plan")
-        cols["rows"].insert(0, out_rows)
-        cols["files"].insert(0, 0)
-        cols["elapsed_ms"].insert(0, 0.0)
-        cols["detail"].insert(0, "\n".join(plan_lines))
+        # the plan row leads (with the time planning took), so the
+        # dispatch line stays next to the plan shape it annotates
+        cols = stats.rows_table(
+            "\n".join(plan_lines),
+            analyzed.num_rows if analyzed is not None else 0)
         schema = Schema([ColumnSchema("stage", dt.STRING),
                          ColumnSchema("rows", dt.INT64),
                          ColumnSchema("files", dt.INT64),
                          ColumnSchema("elapsed_ms", dt.FLOAT64),
                          ColumnSchema("detail", dt.STRING)])
         rb = RecordBatch.from_pydict(schema, cols)
-        return Output.record_batches([rb], schema)
+        out = Output.record_batches([rb], schema)
+        # the protocol writer encodes the analysed result too, discards
+        # the bytes and appends what that took as the `render` row
+        out.analyzed = analyzed
+        return out
 
     # ---- SELECT ----
     def execute_query(self, query: Query, ctx: QueryContext) -> Output:
@@ -210,44 +211,52 @@ class QueryEngine:
         if exec_stats.current() is not None:
             return self._execute_query_inner(query, ctx)
         with exec_stats.collect() as st:
+            _record_parse(ctx)
             out = self._execute_query_inner(query, ctx)
         self.last_exec_stats = st
         return out
 
     def _execute_query_inner(self, query: Query, ctx: QueryContext
                              ) -> Output:
+        """The `plan` stage is everything from here to the region work:
+        it is entered once per step below and again in
+        tpu_exec.try_execute / region_moment_frames (the aggregate plan,
+        the dispatch decision). Nested statements (subqueries, join
+        sides) run outside it and record stages of their own."""
         from ..common import process_list
         process_list.check_cancelled()     # KILL between sub-statements
         if isinstance(query, SetQuery):     # e.g. a UNION-bodied CTE /
             return self.execute_set_query(query, ctx)  # derived table
         self._rewrite_query_subqueries(query, ctx)
-        a = analyze(query)
+        with exec_stats.stage("plan"):
+            a = analyze(query)
         if query.joins:
             return self._execute_join(query, a, ctx)
 
-        table: Optional[Table] = None
-        if query.from_ is not None:
-            if query.from_.subquery is not None:
-                inner = self.execute_query(query.from_.subquery, ctx)
-                df = _batches_to_df(inner.batches)
-                return self._run_on_frame(df, a, query, None)
-            table = self.resolve_table(query.from_, ctx)
-
-        if table is None:
+        if query.from_ is not None and query.from_.subquery is not None:
+            inner = self.execute_query(query.from_.subquery, ctx)
+            df = _batches_to_df(inner.batches)
+            return self._run_on_frame(df, a, query, None)
+        if query.from_ is None:
             df = pd.DataFrame(index=[0])
             return self._run_on_frame(df, a, query, None)
 
-        # literal→timestamp coercion needs the table schema, so it runs
-        # post-resolution (reference: TypeConversionRule, optimizer.rs:33)
-        query.where = convert_time_literals(query.where, table.schema)
+        with exec_stats.stage("plan"):
+            table: Table = self.resolve_table(query.from_, ctx)
+            # literal→timestamp coercion needs the table schema, so it
+            # runs post-resolution (reference: TypeConversionRule,
+            # optimizer.rs:33)
+            query.where = convert_time_literals(query.where, table.schema)
 
-        # transparent rollup rewrite: a compatible GROUP BY date_bin is
-        # re-targeted at a flow's rollup sink (after an incremental
-        # refresh fold, so answers equal the raw scan); the rewritten
-        # statement then takes the normal dispatch chain below
-        rw = self._maybe_rollup_rewrite(table, a, query, ctx, refresh=True)
-        if rw is not None:
-            table, query, a, _ = rw
+            # transparent rollup rewrite: a compatible GROUP BY date_bin
+            # is re-targeted at a flow's rollup sink (after an
+            # incremental refresh fold, so answers equal the raw scan);
+            # the rewritten statement then takes the normal dispatch
+            # chain below
+            rw = self._maybe_rollup_rewrite(table, a, query, ctx,
+                                            refresh=True)
+            if rw is not None:
+                table, query, a, _ = rw
 
         # TPU fast path
         result = tpu_exec.try_execute(table, a, query)
@@ -858,6 +867,14 @@ class QueryEngine:
     def _project_and_finish(self, df: pd.DataFrame, a: Analysis, query: Query,
                             table: Optional[Table], aggregated: bool = False
                             ) -> Output:
+        """Window calls, the SELECT list, DISTINCT, ORDER BY / OFFSET /
+        LIMIT and the frame's conversion to a RecordBatch: the `project`
+        stage, with `project.sort` and `project.to_batches` inside it."""
+        with exec_stats.stage("project"):
+            return self._project(df, a, query, table, aggregated)
+
+    def _project(self, df: pd.DataFrame, a: Analysis, query: Query,
+                 table: Optional[Table], aggregated: bool) -> Output:
         if a.window_calls:
             from .window import compute_windows
             # windows over non-aggregate queries follow the time index so
@@ -922,6 +939,19 @@ class QueryEngine:
         if query.distinct:
             proj = proj.drop_duplicates()
 
+        with exec_stats.stage("project.sort"):
+            proj = self._order_and_limit(proj, df, a, query, aggregated)
+        with exec_stats.stage("project.to_batches"):
+            schema = _infer_schema(proj, table, source_cols,
+                                   dtype_overrides)
+            batch = _df_to_batch(proj, schema)
+        exec_stats.record("project", rows=len(proj))
+        return Output.record_batches([batch], schema)
+
+    @staticmethod
+    def _order_and_limit(proj: pd.DataFrame, df: pd.DataFrame,
+                         a: Analysis, query: Query,
+                         aggregated: bool) -> pd.DataFrame:
         # ORDER BY over the result frame (may reference hidden columns,
         # which are evaluated against the pre-projection frame)
         if query.order_by:
@@ -970,10 +1000,16 @@ class QueryEngine:
             proj = proj.iloc[query.offset:]
         if query.limit is not None:
             proj = proj.iloc[:query.limit]
+        return proj
 
-        schema = _infer_schema(proj, table, source_cols, dtype_overrides)
-        exec_stats.record("project", rows=len(proj))
-        return Output.record_batches([_df_to_batch(proj, schema)], schema)
+
+def _record_parse(ctx: QueryContext) -> None:
+    """The `parse` row of the statement's collector: what do_query timed
+    around the statement text, before (and outside) `total`."""
+    parsed = ctx.parse_span
+    if parsed is not None:
+        exec_stats.record("parse", elapsed_s=parsed.elapsed_s,
+                          t0_ns=parsed.t0_ns)
 
 
 def _conjunct_list(e):

@@ -216,9 +216,13 @@ def execute_agg_plan(table, plan: TpuPlan) -> pd.DataFrame:
                                   "approx_distinct") else np.nan)
                for slot, op, _ in plan.finals}
         return pd.DataFrame([row])
-    with exec_stats.stage("finalize", partial_frames=len(frames),
-                          partial_bytes=frames_nbytes(frames),
-                          aggs=_aggs_desc(plan)):
+    with exec_stats.stage("finalize"):
+        # sizing the frames walks their strings one by one (1 us a
+        # row): a part of its own, not time that no row shows
+        with exec_stats.stage("finalize.partial_bytes"):
+            exec_stats.record("finalize", partial_frames=len(frames),
+                              partial_bytes=frames_nbytes(frames),
+                              aggs=_aggs_desc(plan))
         merged = pd.concat(frames, ignore_index=True)
         try:
             out = _finalize(merged, plan)

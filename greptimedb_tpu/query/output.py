@@ -8,7 +8,7 @@ protocol servers chunk them on the way out.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import List, Optional
+from typing import List, Optional, Tuple
 
 from ..datatypes.record_batch import RecordBatch, pretty_print
 from ..datatypes.schema import Schema
@@ -19,6 +19,13 @@ class Output:
     affected_rows: Optional[int] = None
     batches: Optional[List[RecordBatch]] = None
     schema: Optional[Schema] = None
+    #: (trace_id, span_id) of the `execute_stmt` span that made this
+    #: result: the protocol writer's `render` span hangs off it
+    trace: Optional[Tuple[str, str]] = None
+    #: EXPLAIN ANALYZE only: the analysed statement's own result. The
+    #: protocol writer encodes it into a discarded buffer and reports
+    #: that as the `render` row (servers/render.py)
+    analyzed: Optional["Output"] = None
 
     @staticmethod
     def rows(n: int) -> "Output":

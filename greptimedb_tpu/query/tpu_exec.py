@@ -19,6 +19,7 @@ between its pushed-down scans and DataFusion.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -1115,19 +1116,21 @@ def configure_partial_pushdown(*, enabled: Optional[bool] = None) -> None:
 def try_execute(table, a: Analysis, query: Query) -> Optional[pd.DataFrame]:
     from ..common import exec_stats
 
-    plan = plan_for(table, a, query)
-    if plan is None:
-        return None
-    if not hasattr(table, "execute_tpu_plan"):
-        # Distributed tables always push down (the fallback would pull raw
-        # rows over the wire); local tables route small scans to the CPU
-        # columnar path, which is faster and float64-exact.
-        est = _estimated_table_rows(table)
-        if est is not None and est < _dispatch_min_rows():
-            exec_stats.set_dispatch(
-                f"cpu-small-scan (est_rows={est} < "
-                f"dispatch_floor={_dispatch_min_rows()})")
+    with exec_stats.stage("plan"):
+        plan = plan_for(table, a, query)
+        if plan is None:
             return None
+        if not hasattr(table, "execute_tpu_plan"):
+            # Distributed tables always push down (the fallback would
+            # pull raw rows over the wire); local tables route small
+            # scans to the CPU columnar path, which is faster and
+            # float64-exact.
+            est = _estimated_table_rows(table)
+            if est is not None and est < _dispatch_min_rows():
+                exec_stats.set_dispatch(
+                    f"cpu-small-scan (est_rows={est} < "
+                    f"dispatch_floor={_dispatch_min_rows()})")
+                return None
     # the ONE aggregate-node executor all three front ends share
     # (query/ir.py): scatter or local dispatch, then the moment fold
     from .ir import execute_agg_plan
@@ -1353,14 +1356,15 @@ def region_moment_frames(table, plan: TpuPlan,
         regions = [r for rn, r in table.regions.items() if rn in want]
     if not regions:
         return []
-    # indexed point/IN queries bypass both the cache and the slicer:
-    # the SST index resolves the predicate to candidate series and the
-    # scan opens only the files that may hold them
-    point_sids = [region_point_sids(r, plan) for r in regions]
-    cold = [False if s is not None else region_streams_cold(r)
-            for r, s in zip(regions, point_sids)]
-    exec_stats.set_dispatch(local_dispatch_decision(
-        table, cold, regions, plan=plan, point_sids=point_sids))
+    with exec_stats.stage("plan"):      # its last part: the dispatch
+        # indexed point/IN queries bypass both the cache and the slicer:
+        # the SST index resolves the predicate to candidate series and
+        # the scan opens only the files that may hold them
+        point_sids = [region_point_sids(r, plan) for r in regions]
+        cold = [False if s is not None else region_streams_cold(r)
+                for r, s in zip(regions, point_sids)]
+        exec_stats.set_dispatch(local_dispatch_decision(
+            table, cold, regions, plan=plan, point_sids=point_sids))
     frames = []
     from ..common import process_list
     for region, streams, sids in zip(regions, cold, point_sids):
@@ -1391,28 +1395,40 @@ def _execute_region(region, table, plan: TpuPlan) -> Optional[pd.DataFrame]:
     prof = ScanProfile(path="resident")
     _t0 = _time.perf_counter()
     with span("region_scan", region=region.name, path="resident"):
-        scan = SCAN_CACHE.get(region)
-        prep = _time.perf_counter() - _t0
-        prof.mark("scan_prep", prep)
+        with exec_stats.stage("scan_prep"):
+            scan = SCAN_CACHE.get(region)
+        prof.mark("scan_prep", _time.perf_counter() - _t0)
         outcome = SCAN_CACHE.last_outcome() or "full"
         # same outcome vocabulary as ExecStats (cache=...) and the
         # scan_cache_* prometheus counters: hit / incremental / full
         prof.bump(f"cache_{outcome}")
         prof.rows = scan.num_rows
-        exec_stats.record("scan_prep", rows=scan.num_rows, elapsed_s=prep,
-                          cache=outcome)
+        exec_stats.record("scan_prep", rows=scan.num_rows, cache=outcome)
         if scan.num_rows == 0:
             prof.total_s = _time.perf_counter() - _t0
             region.last_scan_profile = prof
             return None
         _t1 = _time.perf_counter()
-        out = _moment_frame_for_scan(scan, table.schema, plan)
+        with exec_stats.stage("reduce"):
+            out = _moment_frame_for_scan(scan, table.schema, plan)
         prof.mark("reduce", _time.perf_counter() - _t1)
         prof.total_s = _time.perf_counter() - _t0
         region.last_scan_profile = prof
-        exec_stats.record("reduce", rows=scan.num_rows,
-                          elapsed_s=prof.stages["reduce"])
+        exec_stats.record("reduce", rows=scan.num_rows)
     return out
+
+
+def _reduce_part(name: str):
+    """A part of the resident `reduce` stage: `reduce.<name>`."""
+    from ..common import exec_stats
+    return exec_stats.stage("reduce." + name)
+
+
+def _untimed_part(name: str):
+    """Streamed slices launch the same kernel from pool workers under
+    their own stages (query/stream_exec.py): no `reduce` row to be a
+    part of."""
+    return contextlib.nullcontext()
 
 
 @dataclass
@@ -1448,29 +1464,124 @@ def _moment_frame_for_scan(scan: MergedScan, schema,
 
     import jax
     t0 = _time.perf_counter()
-    launched = _launch_scan_kernel(scan, schema, plan)
+    launched = _launch_scan_kernel(scan, schema, plan, _reduce_part)
     if launched is None:
         return None
-    counts, res_np = jax.device_get((launched.counts,
-                                     list(launched.results)))
+    with _reduce_part("fetch"):     # blocked on the device, then D2H
+        counts, res_np = jax.device_get((launched.counts,
+                                         list(launched.results)))
     if launched.warm:
         _note_device_query_time(_time.perf_counter() - t0)
-    return _collect_moment_frame(launched, plan, counts, res_np)
+    with _reduce_part("collect"):
+        return _collect_moment_frame(launched, plan, counts, res_np)
 
 
-def _launch_scan_kernel(scan: MergedScan, schema,
-                        plan: TpuPlan) -> Optional[_Launched]:
+def _launch_scan_kernel(scan: MergedScan, schema, plan: TpuPlan,
+                        part=_untimed_part) -> Optional[_Launched]:
+    """`part(name)` times the host's steps for the resident path's
+    EXPLAIN ANALYZE: `runs` (run-id sweep), `mask`, `upload` (every
+    device_put), `launch` (the call that returns futures)."""
     import jax
 
     n = scan.num_rows
     if n == 0:
         return None
-    tag_names = schema.tag_names()
+    with part("runs"):
+        run_key, (rid, nruns, run_starts, buckets) = _scan_runs(scan, plan)
+    with part("mask"):
+        mask = _scan_row_mask(scan, schema, plan)
+    if mask is _NO_ROWS:
+        return None
 
-    # ---- host: run ids over (series [, bucket]) ----
-    # cached per scan + bucket spec: dashboards repeat the same grouping
-    # over a warm region, and the flags/cumsum/nonzero sweep is O(n) host
-    # work per query otherwise
+    # ---- device kernel (module-level jit; compile cache shared across
+    # queries with the same moment signature + shape bucket) ----
+    with part("upload"):
+        d_ts = scan.device_ts()
+        # unfiltered queries reuse the cached all-true device mask instead
+        # of uploading n bool bytes per query (50 MB at 50M rows, per
+        # query); padded streamed slices reuse the pre-staged padding mask
+        if mask is None:
+            d_mask = scan.device["__pad_mask"] \
+                if scan.valid_rows is not None \
+                else scan.device_valid_all()
+        else:
+            d_mask = jax.device_put(mask)
+
+        values = []
+        col_masks = []
+        ops = []
+        for m in plan.moments:
+            if m.op in ("min_ts", "max_ts"):
+                values.append(d_ts)
+                col_masks.append(scan.device_valid(m.column))
+                ops.append("min" if m.op == "min_ts" else "max")
+            elif m.column is None:
+                values.append(d_ts)   # dummy; count reads only the mask
+                col_masks.append(scan.device_valid_all())
+                ops.append("count")
+            else:
+                cs = schema.column_schema(m.column)
+                if cs.dtype.is_string or cs.dtype.is_binary:
+                    values.append(d_ts)
+                else:
+                    values.append(scan.device_field(m.column))
+                col_masks.append(scan.device_valid(m.column))
+                ops.append(m.op)
+
+    with part("runs"):
+        nbucket = shape_bucket(nruns, minimum=256)
+        # segment ends are free on the host (run boundaries are already
+        # computed); shipping them skips the device binary search, the
+        # dominant cost at high run cardinality
+        run_ends = np.full(nbucket, n, dtype=np.int32)
+        run_ends[:nruns - 1] = run_starts[1:]
+        # with host ends the kernel reads gids for first/last (arg-extreme
+        # tie-break) and for high-cardinality min/max (the shift-doubling
+        # kernel's same-segment guard); for every other op ts stands in
+        # for shape and both the O(n) rid cumsum and its upload are
+        # skipped
+        from ..ops.kernels import _SEG_HIGH_CARD_THRESHOLD, seg_len_bucket
+        high_card = nbucket > _SEG_HIGH_CARD_THRESHOLD
+        needs_gids = any(op in ("first", "last") for op in ops) or \
+            (high_card and any(op in ("min", "max") for op in ops))
+        seg_len_k = None
+        if needs_gids:
+            if rid is None:
+                starts_mark = np.zeros(n, dtype=np.int32)
+                starts_mark[run_starts[1:]] = 1
+                rid = np.cumsum(starts_mark, dtype=np.int32)
+                scan.device[run_key] = (rid, nruns, run_starts, buckets)
+            # static ceil-log2 of the longest run, bucketized to even
+            # values so nearby layouts share one compile
+            lens = np.diff(run_starts, append=np.int64(n))
+            seg_len_k = seg_len_bucket(int(lens.max()) if len(lens) else 1)
+    if needs_gids:
+        with part("upload"):
+            d_rid = jax.device_put(rid)
+    else:
+        d_rid = d_ts
+    with part("launch"):
+        results, counts = sorted_grouped_aggregate(
+            d_rid, d_mask, d_ts, tuple(values), tuple(col_masks),
+            num_groups=nbucket, ops=tuple(ops), has_col_masks=True,
+            ends=run_ends, seg_len_k=seg_len_k)
+    signature = (run_key, tuple((m.op, m.column) for m in plan.moments))
+    warm = signature in scan.launched
+    if len(scan.launched) >= 64:     # sweeping bucket origins never repeat
+        scan.launched.clear()
+    scan.launched.add(signature)
+    sids = scan.series_ids
+    return _Launched(tuple(results), counts, nruns, sids[run_starts],
+                     buckets[run_starts] if buckets is not None else None,
+                     scan.series_dict, scan.ts_base, warm)
+
+
+def _scan_runs(scan: MergedScan, plan: TpuPlan):
+    """-> (cache key, (rid, nruns, run_starts, buckets)): the run ids
+    over (series [, bucket]), cached per scan + bucket spec: dashboards
+    repeat the same grouping over a warm region, and the
+    flags/cumsum/nonzero sweep is O(n) host work per query otherwise."""
+    n = scan.num_rows
     sids = scan.series_ids
     if plan.bucket is not None:
         b = plan.bucket
@@ -1481,42 +1592,52 @@ def _launch_scan_kernel(scan: MergedScan, schema,
         run_key = "__runs:all"
     cached_runs = scan.device.get(run_key)
     if cached_runs is not None:
-        rid, nruns, run_starts, buckets = cached_runs
+        return run_key, cached_runs
+    if plan.bucket is not None:
+        b = plan.bucket
+        buckets = ((scan.ts - b.origin) // b.stride_ms).astype(np.int64)
+        flags = np.empty(n, dtype=bool)
+        flags[0] = True
+        np.not_equal(sids[1:], sids[:-1], out=flags[1:])
+        flags[1:] |= buckets[1:] != buckets[:-1]
     else:
-        if plan.bucket is not None:
-            b = plan.bucket
-            buckets = ((scan.ts - b.origin) // b.stride_ms).astype(np.int64)
-            flags = np.empty(n, dtype=bool)
+        buckets = None
+        flags = np.empty(n, dtype=bool)
+        flags[0] = True
+        np.not_equal(sids[1:], sids[:-1], out=flags[1:])
+        if not plan.tag_groups:
+            flags[:] = False
             flags[0] = True
-            np.not_equal(sids[1:], sids[:-1], out=flags[1:])
-            flags[1:] |= buckets[1:] != buckets[:-1]
-        else:
-            buckets = None
-            flags = np.empty(n, dtype=bool)
-            flags[0] = True
-            np.not_equal(sids[1:], sids[:-1], out=flags[1:])
-            if not plan.tag_groups:
-                flags[:] = False
-                flags[0] = True
-        rid = None          # lazy: only first/last reads per-row run ids
-        run_starts = np.nonzero(flags)[0]
-        nruns = len(run_starts)
-        scan.device[run_key] = (rid, nruns, run_starts, buckets)
-        # bound the per-scan run-context cache: each distinct bucket
-        # spec stores O(n) host arrays, and dashboards sweeping many
-        # strides over one hot region would otherwise grow host memory
-        # past the scan-cache budget unchecked
-        stale = [k for k in scan.device if k.startswith("__runs:")][:-4]
-        for k in stale:
-            scan.device.pop(k, None)
+    rid = None          # lazy: only first/last reads per-row run ids
+    run_starts = np.nonzero(flags)[0]
+    runs = (rid, len(run_starts), run_starts, buckets)
+    scan.device[run_key] = runs
+    # bound the per-scan run-context cache: each distinct bucket
+    # spec stores O(n) host arrays, and dashboards sweeping many
+    # strides over one hot region would otherwise grow host memory
+    # past the scan-cache budget unchecked
+    stale = [k for k in scan.device if k.startswith("__runs:")][:-4]
+    for k in stale:
+        scan.device.pop(k, None)
+    return run_key, runs
 
-    # ---- host: per-series tag predicate → row mask ----
+
+#: _scan_row_mask: the predicates leave no row (None means "every row")
+_NO_ROWS = object()
+
+
+def _scan_row_mask(scan: MergedScan, schema, plan: TpuPlan):
+    """-> the host row mask of the statement's predicates: a bool array,
+    None when nothing filters (the cached all-true device mask serves),
+    or _NO_ROWS."""
+    n = scan.num_rows
+    # ---- per-series tag predicate → row mask ----
     base_mask = None
     if plan.tag_predicates:
         sd = scan.series_dict
         S = sd.num_series
         tag_cols = {}
-        for i, tname in enumerate(tag_names):
+        for i, tname in enumerate(schema.tag_names()):
             tag_cols[tname] = sd.decode_tag_column(
                 np.arange(S, dtype=np.int32), i)
         sdf = pd.DataFrame(tag_cols)
@@ -1528,114 +1649,38 @@ def _launch_scan_kernel(scan: MergedScan, schema,
                 if isinstance(m, pd.Series) else np.full(S, bool(m))
             smask &= m
         if not smask.any():
-            return None
-        base_mask = smask[sids]
+            return _NO_ROWS
+        base_mask = smask[scan.series_ids]
 
-    # ---- row mask (host; cheap elementwise, skipped entirely for the
+    # ---- row mask (cheap elementwise, skipped entirely for the
     # unfiltered case so unpadded/pre-staged scans touch no O(n) host
     # memory here) ----
     unfiltered = base_mask is None and plan.time_lo is None and \
         plan.time_hi is None and not plan.field_filters
-    mask = None
-    if not (unfiltered and (scan.valid_rows is None
-                            or "__pad_mask" in scan.device)):
-        mask = base_mask.copy() if base_mask is not None \
-            else np.ones(n, dtype=bool)
-        if scan.valid_rows is not None and scan.valid_rows < n:
-            mask[scan.valid_rows:] = False   # shape-bucket padding rows
-        if plan.time_lo is not None:
-            mask &= scan.ts >= plan.time_lo
-        if plan.time_hi is not None:
-            mask &= scan.ts < plan.time_hi
-        for ff in plan.field_filters:
-            vals, valid = scan.fields[ff.column]
-            if vals.dtype == object:
-                raise UnsupportedError(
-                    f"filter on non-numeric {ff.column}")
-            v = vals.astype(np.float64)
-            cmp = {"eq": v == ff.value, "ne": v != ff.value,
-                   "lt": v < ff.value, "le": v <= ff.value,
-                   "gt": v > ff.value, "ge": v >= ff.value}[ff.op]
-            if valid is not None:
-                cmp &= valid
-            mask &= cmp
-        if not mask.any():
-            return None
-
-    # ---- device kernel (module-level jit; compile cache shared across
-    # queries with the same moment signature + shape bucket) ----
-    d_ts = scan.device_ts()
-    nbucket = shape_bucket(nruns, minimum=256)
-    # unfiltered queries reuse the cached all-true device mask instead of
-    # uploading n bool bytes per query (50 MB at 50M rows, per query);
-    # padded streamed slices reuse the pre-staged padding mask
-    if mask is None:
-        d_mask = scan.device["__pad_mask"] \
-            if scan.valid_rows is not None \
-            else scan.device_valid_all()
-    else:
-        d_mask = jax.device_put(mask)
-
-    values = []
-    col_masks = []
-    ops = []
-    for m in plan.moments:
-        if m.op in ("min_ts", "max_ts"):
-            values.append(d_ts)
-            col_masks.append(scan.device_valid(m.column))
-            ops.append("min" if m.op == "min_ts" else "max")
-        elif m.column is None:
-            values.append(d_ts)   # dummy; count reads only the mask
-            col_masks.append(scan.device_valid_all())
-            ops.append("count")
-        else:
-            cs = schema.column_schema(m.column)
-            if cs.dtype.is_string or cs.dtype.is_binary:
-                values.append(d_ts)
-            else:
-                values.append(scan.device_field(m.column))
-            col_masks.append(scan.device_valid(m.column))
-            ops.append(m.op)
-
-    # segment ends are free on the host (run boundaries are already
-    # computed); shipping them skips the device binary search, the dominant
-    # cost at high run cardinality
-    run_ends = np.full(nbucket, n, dtype=np.int32)
-    run_ends[:nruns - 1] = run_starts[1:]
-    # with host ends the kernel reads gids for first/last (arg-extreme
-    # tie-break) and for high-cardinality min/max (the shift-doubling
-    # kernel's same-segment guard); for every other op ts stands in for
-    # shape and both the O(n) rid cumsum and its upload are skipped
-    from ..ops.kernels import _SEG_HIGH_CARD_THRESHOLD, seg_len_bucket
-    high_card = nbucket > _SEG_HIGH_CARD_THRESHOLD
-    needs_gids = any(op in ("first", "last") for op in ops) or \
-        (high_card and any(op in ("min", "max") for op in ops))
-    seg_len_k = None
-    if needs_gids:
-        if rid is None:
-            starts_mark = np.zeros(n, dtype=np.int32)
-            starts_mark[run_starts[1:]] = 1
-            rid = np.cumsum(starts_mark, dtype=np.int32)
-            scan.device[run_key] = (rid, nruns, run_starts, buckets)
-        d_rid = jax.device_put(rid)
-        # static ceil-log2 of the longest run, bucketized to even values
-        # so nearby layouts share one compile
-        lens = np.diff(run_starts, append=np.int64(n))
-        seg_len_k = seg_len_bucket(int(lens.max()) if len(lens) else 1)
-    else:
-        d_rid = d_ts
-    results, counts = sorted_grouped_aggregate(
-        d_rid, d_mask, d_ts, tuple(values), tuple(col_masks),
-        num_groups=nbucket, ops=tuple(ops), has_col_masks=True,
-        ends=run_ends, seg_len_k=seg_len_k)
-    signature = (run_key, tuple((m.op, m.column) for m in plan.moments))
-    warm = signature in scan.launched
-    if len(scan.launched) >= 64:     # sweeping bucket origins never repeat
-        scan.launched.clear()
-    scan.launched.add(signature)
-    return _Launched(tuple(results), counts, nruns, sids[run_starts],
-                     buckets[run_starts] if buckets is not None else None,
-                     scan.series_dict, scan.ts_base, warm)
+    if unfiltered and (scan.valid_rows is None
+                       or "__pad_mask" in scan.device):
+        return None
+    mask = base_mask.copy() if base_mask is not None \
+        else np.ones(n, dtype=bool)
+    if scan.valid_rows is not None and scan.valid_rows < n:
+        mask[scan.valid_rows:] = False   # shape-bucket padding rows
+    if plan.time_lo is not None:
+        mask &= scan.ts >= plan.time_lo
+    if plan.time_hi is not None:
+        mask &= scan.ts < plan.time_hi
+    for ff in plan.field_filters:
+        vals, valid = scan.fields[ff.column]
+        if vals.dtype == object:
+            raise UnsupportedError(
+                f"filter on non-numeric {ff.column}")
+        v = vals.astype(np.float64)
+        cmp = {"eq": v == ff.value, "ne": v != ff.value,
+               "lt": v < ff.value, "le": v <= ff.value,
+               "gt": v > ff.value, "ge": v >= ff.value}[ff.op]
+        if valid is not None:
+            cmp &= valid
+        mask &= cmp
+    return mask if mask.any() else _NO_ROWS
 
 
 def _collect_moment_frame(launched: _Launched, plan: TpuPlan,

@@ -33,7 +33,7 @@ from typing import Dict, List, Optional, Tuple
 
 from ..common.locks import TrackedLock
 from ..common.process_list import check_cancelled
-from ..common.telemetry import increment_counter
+from ..common.telemetry import increment_counter, timer
 from ..errors import GreptimeError, InternalError
 
 #: hard bound on how long a follower parks for the leader's shared
@@ -147,11 +147,15 @@ class IngestCoalescer:
     # ---- follower: bounded park on the leader's shared insert ----
     def _follow(self, batch: _Batch, n_rows: int) -> int:
         deadline = time.monotonic() + _FOLLOW_TIMEOUT_S
-        while not batch.done.wait(timeout=0.05):
-            check_cancelled()              # killed mid-wait: bail out
-            if time.monotonic() > deadline:
-                raise InternalError(
-                    "coalesced ingest wait timed out (leader died?)")
+        # what a follower's request spends here is the leader's window
+        # plus its region_write: timed, or a batch's server time has a
+        # hole the size of another batch's write
+        with timer("ingest_coalesce_wait"):
+            while not batch.done.wait(timeout=0.05):
+                check_cancelled()          # killed mid-wait: bail out
+                if time.monotonic() > deadline:
+                    raise InternalError(
+                        "coalesced ingest wait timed out (leader died?)")
         if batch.error is not None:
             raise _recast(batch.error)
         increment_counter("ingest_coalesce_follower_acks")

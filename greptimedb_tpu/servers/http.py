@@ -27,6 +27,7 @@ from . import influxdb as influx_mod
 from . import opentsdb as tsdb_mod
 from . import prometheus as prom_mod
 from .auth import NoopUserProvider, UserProvider
+from .render import render
 
 logger = logging.getLogger(__name__)
 
@@ -53,6 +54,21 @@ def output_to_json(out: Output) -> Dict[str, Any]:
                          if isinstance(v, float) else v for v in r])
     return {"records": {"schema": {"column_schemas": col_schemas},
                         "rows": rows}}
+
+
+def sql_response(outputs: List[Output], t0: float) -> web.Response:
+    """The JSON envelope of a statement's results, made under the
+    `render` span: the rows, then the text."""
+    def encode(outs: List[Output], discard: bool):
+        body = json.dumps({
+            "code": 0,
+            "output": [output_to_json(o) for o in outs],
+            "execution_time_ms": int((time.perf_counter() - t0) * 1e3),
+        }).encode()
+        return body, len(body)
+
+    return web.Response(body=render("http", outputs, encode),
+                        content_type="application/json", charset="utf-8")
 
 
 class HttpServer:
@@ -231,11 +247,7 @@ class HttpServer:
             None,
             self._traced_call(request,
                               lambda: self.frontend.do_query(sql, ctx)))
-        return web.json_response({
-            "code": 0,
-            "output": [output_to_json(o) for o in outputs],
-            "execution_time_ms": int((time.perf_counter() - t0) * 1e3),
-        })
+        return sql_response(outputs, t0)
 
     async def handle_promql(self, request):
         t0 = time.perf_counter()
@@ -254,11 +266,7 @@ class HttpServer:
             None, self._traced_call(
                 request, lambda: self.frontend.execute_tql(
                     Tql("eval", start, end, step, None, query), ctx)))
-        return web.json_response({
-            "code": 0,
-            "output": [output_to_json(out)],
-            "execution_time_ms": int((time.perf_counter() - t0) * 1e3),
-        })
+        return sql_response([out], t0)
 
     # ---- coprocessor scripts (reference: /v1/scripts + /v1/run-script,
     # src/servers/src/http.rs:434-578 script routes) ----
@@ -310,11 +318,7 @@ class HttpServer:
                 None, self._traced_call(
                     request, lambda: engine.run(script, ctx=ctx,
                                                 is_script_text=True)))
-        return web.json_response({
-            "code": 0,
-            "output": [output_to_json(out)],
-            "execution_time_ms": int((time.perf_counter() - t0) * 1e3),
-        })
+        return sql_response([out], t0)
 
     async def handle_influx_write(self, request):
         ctx = self._ctx_influx(request)
@@ -325,9 +329,11 @@ class HttpServer:
         def work():
             from ..common.admission import GATE
             from .coalesce import COALESCER
+            from ..common.telemetry import timer
             with GATE.admit_ingest(len(body)):
-                inserts, tag_cols = influx_mod.body_to_inserts(body,
-                                                               precision)
+                with timer("ingest_parse"):
+                    inserts, tag_cols = influx_mod.body_to_inserts(
+                        body, precision)
                 n = 0
                 for table, cols in inserts.items():
                     # concurrent small bodies for the same measurement

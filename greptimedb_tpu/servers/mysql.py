@@ -26,6 +26,7 @@ from typing import Dict, List, Optional, Tuple
 
 from ..errors import GreptimeError
 from ..session import Channel, QueryContext
+from .render import render
 
 logger = logging.getLogger(__name__)
 
@@ -111,11 +112,14 @@ def native_password_scramble(password: str, nonce: bytes) -> bytes:
 
 
 class PacketIO:
-    """3-byte length + 1-byte sequence framing over a socket."""
+    """3-byte length + 1-byte sequence framing over a socket. Without a
+    socket the packets are framed, counted and dropped (servers/render.py
+    encodes an EXPLAIN ANALYZE'd result that way)."""
 
-    def __init__(self, sock: socket.socket):
+    def __init__(self, sock: Optional[socket.socket]):
         self.sock = sock
         self.seq = 0
+        self.bytes_out = 0
 
     def read_packet(self) -> Optional[bytes]:
         header = self._read_n(4)
@@ -142,7 +146,9 @@ class PacketIO:
             chunk = payload[offset:offset + 0xFFFFFF]
             header = len(chunk).to_bytes(3, "little") + bytes([self.seq])
             self.seq = (self.seq + 1) & 0xFF
-            self.sock.sendall(header + chunk)
+            if self.sock is not None:
+                self.sock.sendall(header + chunk)
+            self.bytes_out += len(header) + len(chunk)
             offset += len(chunk)
             if len(chunk) < 0xFFFFFF:
                 break
@@ -242,9 +248,11 @@ class _Connection:
 
     # ---- packets out ----
     def send_ok(self, affected: int = 0, status: int =
-                SERVER_STATUS_AUTOCOMMIT) -> None:
-        self.io.write_packet(b"\x00" + lenenc_int(affected) + lenenc_int(0)
-                             + struct.pack("<HH", status, 0))
+                SERVER_STATUS_AUTOCOMMIT,
+                io: Optional[PacketIO] = None) -> None:
+        (io or self.io).write_packet(
+            b"\x00" + lenenc_int(affected) + lenenc_int(0)
+            + struct.pack("<HH", status, 0))
 
     def send_err(self, message: str, errno: int = 1105,
                  sqlstate: str = "HY000") -> None:
@@ -252,8 +260,9 @@ class _Connection:
                              + sqlstate.encode()[:5].ljust(5, b"0")
                              + message.encode()[:512])
 
-    def send_eof(self, status: int = SERVER_STATUS_AUTOCOMMIT) -> None:
-        self.io.write_packet(b"\xfe" + struct.pack("<HH", 0, status))
+    def send_eof(self, status: int = SERVER_STATUS_AUTOCOMMIT,
+                 io: Optional[PacketIO] = None) -> None:
+        (io or self.io).write_packet(b"\xfe" + struct.pack("<HH", 0, status))
 
     def _column_def(self, name: str, col_type: int,
                     charset: int = CHARSET_UTF8MB4,
@@ -265,17 +274,19 @@ class _Connection:
                 + b"\x00\x00")
 
     def send_resultset(self, names: List[str], types: List[int],
-                       rows, binary: bool = False) -> None:
-        self.io.write_packet(lenenc_int(len(names)))
+                       rows, binary: bool = False,
+                       io: Optional[PacketIO] = None) -> None:
+        io = io or self.io
+        io.write_packet(lenenc_int(len(names)))
         for name, t in zip(names, types):
             charset = CHARSET_UTF8MB4 if t in (
                 T_VAR_STRING, T_STRING, T_VARCHAR, T_BLOB) else CHARSET_BINARY
-            self.io.write_packet(self._column_def(name, t, charset))
-        self.send_eof()
+            io.write_packet(self._column_def(name, t, charset))
+        self.send_eof(io=io)
         for row in rows:
-            self.io.write_packet(
+            io.write_packet(
                 self._binary_row(row) if binary else self._text_row(row))
-        self.send_eof()
+        self.send_eof(io=io)
 
     @staticmethod
     def _text_row(row) -> bytes:
@@ -463,13 +474,21 @@ class _Connection:
             logger.exception("mysql query failed: %s", sql)
             self.send_err(str(e))
             return
-        out = outputs[-1]
+        def encode(outs, discard: bool):
+            io = PacketIO(None) if discard else self.io
+            sent = io.bytes_out
+            self._send_output(outs[-1], binary, io)
+            return None, io.bytes_out - sent
+
+        render("mysql", outputs[-1:], encode)
+
+    def _send_output(self, out, binary: bool, io: PacketIO) -> None:
         if not out.is_batches:
-            self.send_ok(affected=out.affected_rows or 0)
+            self.send_ok(affected=out.affected_rows or 0, io=io)
             return
         batches = out.batches
         if not batches:
-            self.send_ok()
+            self.send_ok(io=io)
             return
         schema = batches[0].schema
         names = schema.names()
@@ -478,7 +497,7 @@ class _Connection:
             types = [T_VAR_STRING] * len(names)
         rows = (self._format_row(schema, row)
                 for b in batches for row in b.rows())
-        self.send_resultset(names, types, rows, binary=binary)
+        self.send_resultset(names, types, rows, binary=binary, io=io)
 
     @staticmethod
     def _format_row(schema, row) -> List:

@@ -21,6 +21,7 @@ from typing import Dict, List, Optional, Tuple
 
 from ..errors import GreptimeError
 from ..session import Channel, QueryContext
+from .render import render
 
 logger = logging.getLogger(__name__)
 
@@ -91,8 +92,13 @@ def _pg_text(v, dtype) -> Optional[bytes]:
 
 
 class _MessageIO:
-    def __init__(self, sock: socket.socket):
+    """Tagged, length-prefixed v3 messages over a socket. Without a
+    socket `send` frames, counts and drops (servers/render.py encodes an
+    EXPLAIN ANALYZE'd result that way)."""
+
+    def __init__(self, sock: Optional[socket.socket]):
         self.sock = sock
+        self.bytes_out = 0
 
     def _read_n(self, n: int) -> Optional[bytes]:
         chunks = []
@@ -125,7 +131,10 @@ class _MessageIO:
         return tag, body if body is not None else b""
 
     def send(self, tag: bytes, body: bytes = b"") -> None:
-        self.sock.sendall(tag + struct.pack("!I", len(body) + 4) + body)
+        message = tag + struct.pack("!I", len(body) + 4) + body
+        if self.sock is not None:
+            self.sock.sendall(message)
+        self.bytes_out += len(message)
 
     def send_raw(self, data: bytes) -> None:
         self.sock.sendall(data)
@@ -190,15 +199,17 @@ class _PgConnection:
         self.send_error(message, code)
         self._in_error = True
 
-    def send_row_description(self, schema) -> None:
+    def send_row_description(self, schema,
+                             io: Optional[_MessageIO] = None) -> None:
         body = struct.pack("!H", len(schema.column_schemas))
         for col in schema.column_schemas:
             body += (col.name.encode() + b"\x00"
                      + struct.pack("!IHIhih", 0, 0, _pg_oid(col.dtype),
                                    -1, -1, 0))
-        self.io.send(b"T", body)
+        (io or self.io).send(b"T", body)
 
-    def send_rows(self, batches) -> int:
+    def send_rows(self, batches, io: Optional[_MessageIO] = None) -> int:
+        io = io or self.io
         n = 0
         for b in batches:
             dtypes = [c.dtype for c in b.schema.column_schemas]
@@ -210,11 +221,33 @@ class _PgConnection:
                         body += struct.pack("!i", -1)
                     else:
                         body += struct.pack("!i", len(txt)) + txt
-                self.io.send(b"D", body)
+                io.send(b"D", body)
                 n += 1
         return n
 
-    def send_complete(self, sql: str, output) -> None:
+    def send_result(self, sql: str, out, described: bool = False) -> None:
+        """One result on the wire, under the `render` span:
+        RowDescription (unless a Describe sent it already), the DataRows,
+        CommandComplete."""
+        def encode(outs, discard: bool):
+            io = _MessageIO(None) if discard else self.io
+            sent = io.bytes_out
+            result = outs[-1]
+            if result.is_batches:
+                if result.batches:
+                    if not described:
+                        self.send_row_description(
+                            result.batches[0].schema, io)
+                    self.send_rows(result.batches, io)
+                elif not described:
+                    io.send(b"T", struct.pack("!H", 0))
+            self.send_complete(sql, result, io)
+            return None, io.bytes_out - sent
+
+        render("postgres", [out], encode)
+
+    def send_complete(self, sql: str, output,
+                      io: Optional[_MessageIO] = None) -> None:
         word = sql.lstrip().split(None, 1)
         word = word[0].upper() if word else ""
         if output.is_batches:
@@ -225,7 +258,7 @@ class _PgConnection:
             tag = f"DELETE {output.affected_rows or 0}"
         else:
             tag = word or "OK"
-        self.io.send(b"C", tag.encode() + b"\x00")
+        (io or self.io).send(b"C", tag.encode() + b"\x00")
 
     # ---- startup/auth ----
     def startup(self) -> bool:
@@ -319,15 +352,7 @@ class _PgConnection:
             self.send_ready()
             return
         try:
-            out = self._execute_sql(sql)
-            if out.is_batches:
-                batches = out.batches
-                if batches:
-                    self.send_row_description(batches[0].schema)
-                    self.send_rows(batches)
-                else:
-                    self.io.send(b"T", struct.pack("!H", 0))
-            self.send_complete(sql, out)
+            self.send_result(sql, self._execute_sql(sql))
         except GreptimeError as e:
             self.send_error(str(e), _sqlstate(e))
         except Exception as e:  # noqa: BLE001
@@ -477,15 +502,8 @@ class _PgConnection:
             described, portal.described = portal.described, False
             if out is None:
                 out = self._execute_sql(sql)
-            if out.is_batches:
-                batches = out.batches
-                if batches:
-                    if not described:  # Describe already sent the 'T'
-                        self.send_row_description(batches[0].schema)
-                    self.send_rows(batches)
-                elif not described:
-                    self.io.send(b"T", struct.pack("!H", 0))
-            self.send_complete(sql, out)
+            # `described`: the Describe already sent the 'T'
+            self.send_result(sql, out, described)
         except GreptimeError as e:
             self.ext_error(str(e), _sqlstate(e))
         except Exception as e:  # noqa: BLE001

@@ -32,6 +32,10 @@ class QueryContext:
         self.channel = channel
         self.username = username
         self.time_zone = "UTC"
+        #: how long parsing the statement text in flight took
+        #: (exec_stats.Timed, set by do_query); the query engine reports
+        #: it as the `parse` stage row
+        self.parse_span = None
 
     def set_current_schema(self, schema: str) -> None:
         self.current_schema = schema

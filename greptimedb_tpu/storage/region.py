@@ -620,6 +620,14 @@ class Region:
 
     # ---- write path ----
     def write(self, batch: WriteBatch) -> int:
+        """One write as its caller waits for it: the `region_write`
+        timer, with `wal_append` and `wal_group_wait` (and inside that
+        the WAL's `wal_fsync`) as its parts."""
+        from ..common.telemetry import timer
+        with timer("region_write"):
+            return self._write(batch)
+
+    def _write(self, batch: WriteBatch) -> int:
         """WAL append → memtable insert → sequence bump. Returns rows written.
 
         With WAL group commit active (sync_on_write + `SET
@@ -638,7 +646,7 @@ class Region:
         from ..common.telemetry import increment_counter, timer
         stall = False
         wal_ticket = None
-        with timer("region_write"), self._writer_lock:
+        with self._writer_lock:
             if self.closed:
                 raise RegionClosedError(f"region {self.name} closed")
             if self.fenced:
