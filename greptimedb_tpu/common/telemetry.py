@@ -588,7 +588,9 @@ def _observe(name: str, seconds: float) -> None:
     h.observe(seconds)
 
 
-def increment_counter(name: str, value: int = 1) -> None:
+def increment_counter(name: str, value: int = 1, **labels: object) -> None:
+    """`greptime_<name>_total{**labels}`; a counter's label NAMES are
+    those of its first increment."""
     if metrics_suppressed():
         return
     try:
@@ -599,9 +601,10 @@ def increment_counter(name: str, value: int = 1) -> None:
     with _metrics_lock:
         c = _counters.get(key)
         if c is None:
-            c = Counter(f"greptime_{key}_total", f"counter {name}")
+            c = Counter(f"greptime_{key}_total", f"counter {name}",
+                        labelnames=tuple(sorted(labels)))
             _counters[key] = c
-    c.inc(value)
+    (c.labels(**labels) if labels else c).inc(value)
 
 
 @contextlib.contextmanager

@@ -29,6 +29,17 @@ def null_column(dtype: ConcreteDataType, n: int):
     return data, np.zeros(n, dtype=bool)
 
 
+def python_values(data: np.ndarray, nulls: Optional[np.ndarray]) -> list:
+    """A column's values as Python objects, None where `nulls`: the loop
+    is `ndarray.tolist`'s (int, float, bool, str out, never a numpy
+    scalar), not one `.item()` a value."""
+    if nulls is None or not nulls.any():
+        return data.tolist()
+    held = data.astype(object)
+    held[nulls] = None
+    return held.tolist()
+
+
 class Vector:
     """A typed nullable column.
 
@@ -196,6 +207,11 @@ class Vector:
         return pa.array(self.data, type=self.dtype.pa_type, mask=mask)
 
     def to_pylist(self) -> list:
+        data = self.data
+        if isinstance(data, np.ndarray) and data.dtype != object and not (
+                self.dtype.is_boolean and data.dtype.kind != "b"):
+            return python_values(
+                data, None if self.validity is None else ~self.validity)
         if self.validity is None:
             if self.dtype.is_boolean:
                 return [bool(v) for v in self.data]

@@ -15,7 +15,7 @@ import json
 import logging
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 from aiohttp import web
 
@@ -27,6 +27,7 @@ from . import influxdb as influx_mod
 from . import opentsdb as tsdb_mod
 from . import prometheus as prom_mod
 from .auth import NoopUserProvider, UserProvider
+from .columnar import json_rows
 from .render import render
 
 logger = logging.getLogger(__name__)
@@ -41,31 +42,30 @@ def parse_db_param(db: Optional[str]) -> tuple:
     return DEFAULT_CATALOG_NAME, db
 
 
-def output_to_json(out: Output) -> Dict[str, Any]:
+def output_to_json(out: Output) -> Tuple[Dict[str, Any], int]:
+    """-> (one result of the envelope, rows of it that took the per-cell
+    path of servers/columnar.py)."""
     if not out.is_batches:
-        return {"affectedrows": out.affected_rows or 0}
+        return {"affectedrows": out.affected_rows or 0}, 0
     schema = out.schema
     col_schemas = [{"name": c.name, "data_type": c.dtype.name}
                    for c in schema.column_schemas] if schema else []
-    rows: List[list] = []
-    for b in out.batches or []:
-        for r in b.rows():
-            rows.append([None if v != v else v
-                         if isinstance(v, float) else v for v in r])
+    rows, cell_rows = json_rows(out.batches or [])
     return {"records": {"schema": {"column_schemas": col_schemas},
-                        "rows": rows}}
+                        "rows": rows}}, cell_rows
 
 
 def sql_response(outputs: List[Output], t0: float) -> web.Response:
     """The JSON envelope of a statement's results, made under the
     `render` span: the rows, then the text."""
     def encode(outs: List[Output], discard: bool):
+        results = [output_to_json(o) for o in outs]
         body = json.dumps({
             "code": 0,
-            "output": [output_to_json(o) for o in outs],
+            "output": [result for result, _ in results],
             "execution_time_ms": int((time.perf_counter() - t0) * 1e3),
         }).encode()
-        return body, len(body)
+        return body, len(body), sum(n for _, n in results)
 
     return web.Response(body=render("http", outputs, encode),
                         content_type="application/json", charset="utf-8")

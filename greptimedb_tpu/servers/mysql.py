@@ -22,10 +22,14 @@ import socketserver
 import ssl as ssl_mod
 import struct
 import threading
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Iterable, List, Optional, Tuple
+
+import numpy as np
 
 from ..errors import GreptimeError
 from ..session import Channel, QueryContext
+from .columnar import (MYSQL_TEXT, SlabWriter, TextColumn, cell_lengths,
+                       literal_columns, text_chunks)
 from .render import render
 
 logger = logging.getLogger(__name__)
@@ -111,15 +115,62 @@ def native_password_scramble(password: str, nonce: bytes) -> bytes:
     return bytes(a ^ b for a, b in zip(h1, h3))
 
 
-class PacketIO:
+class _LenencTable(dict):
+    """A cell's length -> its lenenc prefix, -1 -> what stands for a NULL:
+    a column's prefixes are one `map` over its cells' lengths, and only a
+    cell of 251 bytes or more calls Python."""
+
+    def __init__(self, null: bytes):
+        super().__init__((n, bytes([n])) for n in range(0xFB))
+        self[-1] = null
+
+    def __missing__(self, n: int) -> bytes:
+        return lenenc_int(n)
+
+
+_LENENC_TEXT = _LenencTable(b"\xfb")
+_LENENC_BINARY = _LenencTable(b"")      # a NULL is a bit of the row's bitmap
+
+
+def _row_parts(nrows: int, columns: List[TextColumn], binary: bool
+               ) -> Tuple[np.ndarray, List[List[bytes]]]:
+    """Row packets of a chunk, not yet interleaved -> (every row's payload
+    length, the lists whose i-th items joined are row i's payload). Text
+    rows are a lenenc string a cell and 0xFB for a NULL; binary rows (every
+    column declared VAR_STRING: the prepared-statement emulation, like the
+    reference's rewrite) lead with 0x00 and the NULL bitmap."""
+    table = _LENENC_BINARY if binary else _LENENC_TEXT
+    lengths = np.zeros(nrows, dtype=np.int64)
+    parts: List[List[bytes]] = []
+    if binary:
+        bitmap = np.zeros((nrows, 1 + (len(columns) + 9) // 8),
+                          dtype=np.uint8)
+        for i, (_, nulls) in enumerate(columns):
+            if nulls is not None:
+                bitmap[nulls, 1 + (i + 2) // 8] |= 1 << ((i + 2) % 8)
+        parts.append(bitmap.view(f"V{bitmap.shape[1]}")[:, 0].tolist())
+        lengths += bitmap.shape[1]
+    for cells, nulls in columns:
+        lens = cell_lengths(cells)
+        lengths += (lens + 1 + 2 * (lens >= 0xFB) + (lens >= 1 << 16)
+                    + 5 * (lens >= 1 << 24))
+        if nulls is not None:
+            lens[nulls] = -1
+            if binary:
+                lengths -= nulls
+        parts.append(list(map(table.__getitem__, lens.tolist())))
+        parts.append(cells)
+    return lengths, parts
+
+
+class PacketIO(SlabWriter):
     """3-byte length + 1-byte sequence framing over a socket. Without a
     socket the packets are framed, counted and dropped (servers/render.py
     encodes an EXPLAIN ANALYZE'd result that way)."""
 
     def __init__(self, sock: Optional[socket.socket]):
-        self.sock = sock
+        super().__init__(sock)
         self.seq = 0
-        self.bytes_out = 0
 
     def read_packet(self) -> Optional[bytes]:
         header = self._read_n(4)
@@ -141,17 +192,30 @@ class PacketIO:
         return b"".join(chunks)
 
     def write_packet(self, payload: bytes) -> None:
-        offset = 0
-        while True:
+        """One payload as its packets: 0xFFFFFF bytes each, and a last
+        shorter one (empty where the payload is a whole multiple)."""
+        framed = bytearray()
+        for offset in range(0, len(payload) + 1, 0xFFFFFF):
             chunk = payload[offset:offset + 0xFFFFFF]
-            header = len(chunk).to_bytes(3, "little") + bytes([self.seq])
+            framed += len(chunk).to_bytes(3, "little")
+            framed.append(self.seq)
+            framed += chunk
             self.seq = (self.seq + 1) & 0xFF
-            if self.sock is not None:
-                self.sock.sendall(header + chunk)
-            self.bytes_out += len(header) + len(chunk)
-            offset += len(chunk)
-            if len(chunk) < 0xFFFFFF:
-                break
+        self.write(framed)
+
+    def write_row_packets(self, lengths: np.ndarray,
+                          parts: List[List[bytes]]) -> None:
+        """One packet a row, framed together: row i's payload is the
+        i-th items of `parts` joined, `lengths[i]` bytes of it."""
+        n = len(lengths)
+        if int(lengths.max()) >= 0xFFFFFF:
+            for row in zip(*parts):     # a row that has to be split
+                self.write_packet(b"".join(row))
+            return
+        seqs = (self.seq + np.arange(n)) & 0xFF
+        self.seq = (self.seq + n) & 0xFF
+        heads = (lengths | seqs << 24).astype("<u4").view("V4").tolist()
+        self.write_rows(heads, *parts)
 
     def reset_seq(self) -> None:
         self.seq = 0
@@ -274,43 +338,27 @@ class _Connection:
                 + b"\x00\x00")
 
     def send_resultset(self, names: List[str], types: List[int],
-                       rows, binary: bool = False,
-                       io: Optional[PacketIO] = None) -> None:
+                       chunks: Iterable[Tuple[int, List[TextColumn], bool]],
+                       binary: bool = False,
+                       io: Optional[PacketIO] = None) -> int:
+        """Column definitions, EOF, the rows of `chunks` (what
+        `columnar.text_chunks` yields), EOF, in slabs -> rows that took
+        the per-cell path."""
         io = io or self.io
-        io.write_packet(lenenc_int(len(names)))
-        for name, t in zip(names, types):
-            charset = CHARSET_UTF8MB4 if t in (
-                T_VAR_STRING, T_STRING, T_VARCHAR, T_BLOB) else CHARSET_BINARY
-            io.write_packet(self._column_def(name, t, charset))
-        self.send_eof(io=io)
-        for row in rows:
-            io.write_packet(
-                self._binary_row(row) if binary else self._text_row(row))
-        self.send_eof(io=io)
-
-    @staticmethod
-    def _text_row(row) -> bytes:
-        out = b""
-        for v in row:
-            if v is None:
-                out += b"\xfb"
-            else:
-                out += lenenc_str(str(v).encode())
-        return out
-
-    @staticmethod
-    def _binary_row(row) -> bytes:
-        """Binary protocol row with every column declared VAR_STRING (the
-        prepared-statement emulation path, like the reference's rewrite)."""
-        ncols = len(row)
-        null_bitmap = bytearray((ncols + 9) // 8)
-        values = b""
-        for i, v in enumerate(row):
-            if v is None:
-                null_bitmap[(i + 2) // 8] |= 1 << ((i + 2) % 8)
-            else:
-                values += lenenc_str(str(v).encode())
-        return b"\x00" + bytes(null_bitmap) + values
+        cell_rows = 0
+        with io.slab():
+            io.write_packet(lenenc_int(len(names)))
+            for name, t in zip(names, types):
+                charset = CHARSET_UTF8MB4 if t in (
+                    T_VAR_STRING, T_STRING, T_VARCHAR, T_BLOB) \
+                    else CHARSET_BINARY
+                io.write_packet(self._column_def(name, t, charset))
+            self.send_eof(io=io)
+            for nrows, columns, fell_back in chunks:
+                io.write_row_packets(*_row_parts(nrows, columns, binary))
+                cell_rows += nrows * fell_back
+            self.send_eof(io=io)
+        return cell_rows
 
     # ---- handshake ----
     def handshake(self) -> bool:
@@ -456,8 +504,10 @@ class _Connection:
             if not names:
                 self.send_ok()
             else:
-                self.send_resultset(names, [T_VAR_STRING] * len(names),
-                                    rows, binary=binary)
+                self.send_resultset(
+                    names, [T_VAR_STRING] * len(names),
+                    [(len(rows), literal_columns(rows, len(names)), False)]
+                    if rows else [], binary=binary)
             return
         try:
             outputs = self.server.instance.do_query(sql, self.ctx)
@@ -477,43 +527,27 @@ class _Connection:
         def encode(outs, discard: bool):
             io = PacketIO(None) if discard else self.io
             sent = io.bytes_out
-            self._send_output(outs[-1], binary, io)
-            return None, io.bytes_out - sent
+            cell_rows = self._send_output(outs[-1], binary, io)
+            return None, io.bytes_out - sent, cell_rows
 
         render("mysql", outputs[-1:], encode)
 
-    def _send_output(self, out, binary: bool, io: PacketIO) -> None:
+    def _send_output(self, out, binary: bool, io: PacketIO) -> int:
         if not out.is_batches:
             self.send_ok(affected=out.affected_rows or 0, io=io)
-            return
+            return 0
         batches = out.batches
         if not batches:
             self.send_ok(io=io)
-            return
+            return 0
         schema = batches[0].schema
         names = schema.names()
         types = [_mysql_type(c.dtype) for c in schema.column_schemas]
         if binary:
             types = [T_VAR_STRING] * len(names)
-        rows = (self._format_row(schema, row)
-                for b in batches for row in b.rows())
-        self.send_resultset(names, types, rows, binary=binary, io=io)
-
-    @staticmethod
-    def _format_row(schema, row) -> List:
-        out = []
-        for col, v in zip(schema.column_schemas, row):
-            if v is None:
-                out.append(None)
-            elif col.dtype.is_timestamp:
-                from ..common.time import Timestamp
-                out.append(Timestamp(v, col.dtype.time_unit).to_datetime()
-                           .strftime("%Y-%m-%d %H:%M:%S.%f")[:-3])
-            elif isinstance(v, bool):
-                out.append(1 if v else 0)
-            else:
-                out.append(v)
-        return out
+        return self.send_resultset(names, types,
+                                   text_chunks(batches, MYSQL_TEXT),
+                                   binary=binary, io=io)
 
     # ---- prepared statements (emulation) ----
     def handle_stmt_prepare(self, sql: str) -> None:

@@ -19,8 +19,12 @@ import struct
 import threading
 from typing import Dict, List, Optional, Tuple
 
+import numpy as np
+
 from ..errors import GreptimeError
 from ..session import Channel, QueryContext
+from .columnar import (POSTGRES_TEXT, SlabWriter, TextColumn, cell_lengths,
+                       text_chunks)
 from .render import render
 
 logger = logging.getLogger(__name__)
@@ -79,26 +83,10 @@ def _decode_binary_param(raw: bytes, oid: int) -> str:
     return raw.decode("utf-8", errors="replace")
 
 
-def _pg_text(v, dtype) -> Optional[bytes]:
-    if v is None:
-        return None
-    if dtype is not None and dtype.is_timestamp:
-        from ..common.time import Timestamp
-        return Timestamp(v, dtype.time_unit).to_datetime().strftime(
-            "%Y-%m-%d %H:%M:%S.%f").encode()
-    if isinstance(v, bool):
-        return b"t" if v else b"f"
-    return str(v).encode()
-
-
-class _MessageIO:
+class _MessageIO(SlabWriter):
     """Tagged, length-prefixed v3 messages over a socket. Without a
     socket `send` frames, counts and drops (servers/render.py encodes an
     EXPLAIN ANALYZE'd result that way)."""
-
-    def __init__(self, sock: Optional[socket.socket]):
-        self.sock = sock
-        self.bytes_out = 0
 
     def _read_n(self, n: int) -> Optional[bytes]:
         chunks = []
@@ -131,10 +119,25 @@ class _MessageIO:
         return tag, body if body is not None else b""
 
     def send(self, tag: bytes, body: bytes = b"") -> None:
-        message = tag + struct.pack("!I", len(body) + 4) + body
-        if self.sock is not None:
-            self.sock.sendall(message)
-        self.bytes_out += len(message)
+        self.write(tag + struct.pack("!I", len(body) + 4) + body)
+
+    def send_data_rows(self, columns: List[TextColumn], nrows: int) -> None:
+        """One DataRow a row, framed together: int16 columns, then an
+        int32 length (-1 for NULL) and the text of every cell."""
+        lengths = np.full(nrows, 6 + 4 * len(columns), dtype=np.int64)
+        parts: List[List[bytes]] = []
+        for cells, nulls in columns:
+            lens = cell_lengths(cells)
+            lengths += lens
+            if nulls is not None:
+                lens[nulls] = -1
+            parts.append(lens.astype(">i4").view("V4").tolist())
+            parts.append(cells)
+        heads = np.empty(nrows, dtype=[("tag", "S1"), ("length", ">u4"),
+                                       ("columns", ">u2")])
+        heads["tag"], heads["length"], heads["columns"] = \
+            b"D", lengths, len(columns)
+        self.write_rows(heads.view("V7").tolist(), *parts)
 
     def send_raw(self, data: bytes) -> None:
         self.sock.sendall(data)
@@ -209,21 +212,14 @@ class _PgConnection:
         (io or self.io).send(b"T", body)
 
     def send_rows(self, batches, io: Optional[_MessageIO] = None) -> int:
+        """The DataRows of a result -> rows that took the per-cell path."""
         io = io or self.io
-        n = 0
-        for b in batches:
-            dtypes = [c.dtype for c in b.schema.column_schemas]
-            for row in b.rows():
-                body = struct.pack("!H", len(row))
-                for v, dt in zip(row, dtypes):
-                    txt = _pg_text(v, dt)
-                    if txt is None:
-                        body += struct.pack("!i", -1)
-                    else:
-                        body += struct.pack("!i", len(txt)) + txt
-                io.send(b"D", body)
-                n += 1
-        return n
+        cell_rows = 0
+        for nrows, columns, fell_back in text_chunks(batches,
+                                                     POSTGRES_TEXT):
+            io.send_data_rows(columns, nrows)
+            cell_rows += nrows * fell_back
+        return cell_rows
 
     def send_result(self, sql: str, out, described: bool = False) -> None:
         """One result on the wire, under the `render` span:
@@ -233,16 +229,18 @@ class _PgConnection:
             io = _MessageIO(None) if discard else self.io
             sent = io.bytes_out
             result = outs[-1]
-            if result.is_batches:
-                if result.batches:
-                    if not described:
-                        self.send_row_description(
-                            result.batches[0].schema, io)
-                    self.send_rows(result.batches, io)
-                elif not described:
-                    io.send(b"T", struct.pack("!H", 0))
-            self.send_complete(sql, result, io)
-            return None, io.bytes_out - sent
+            cell_rows = 0
+            with io.slab():
+                if result.is_batches:
+                    if result.batches:
+                        if not described:
+                            self.send_row_description(
+                                result.batches[0].schema, io)
+                        cell_rows = self.send_rows(result.batches, io)
+                    elif not described:
+                        io.send(b"T", struct.pack("!H", 0))
+                self.send_complete(sql, result, io)
+            return None, io.bytes_out - sent, cell_rows
 
         render("postgres", [out], encode)
 

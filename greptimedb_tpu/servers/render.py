@@ -5,7 +5,10 @@ The HTTP, MySQL and Postgres writers encode every result through
 hangs off the statement's `execute_stmt` span (so OTLP and the trace
 store show it in the statement's trace, after the engine's spans), the
 histogram `greptime_render_seconds{protocol}` on /metrics, and the span's
-annotation on the profiler's host timeline.
+annotation on the profiler's host timeline. The encoders make a result's
+bytes a column at a time (servers/columnar.py); the span's `path` and
+`greptime_render_rows_total{protocol, path}` say whether any row took the
+per-cell code instead.
 
 `EXPLAIN ANALYZE` answers with stage rows, not with the statement's
 result, so its writer would skip the cost a client of the plain statement
@@ -21,15 +24,17 @@ from __future__ import annotations
 from typing import Callable, List, Tuple, TypeVar
 
 from ..common.exec_stats import StageStat
-from ..common.telemetry import continue_trace, observe_latency, span
+from ..common.telemetry import (continue_trace, increment_counter,
+                                observe_latency, span)
 from ..datatypes.record_batch import RecordBatch
 from ..query.output import Output
 
 T = TypeVar("T")
 
-#: encode(outputs, discard) -> (what the writer wants back, bytes made);
-#: with `discard` the bytes go nowhere (no socket, no sequence numbers)
-Encoder = Callable[[List[Output], bool], Tuple[T, int]]
+#: encode(outputs, discard) -> (what the writer wants back, bytes made,
+#: rows that took servers/columnar.py's per-cell path); with `discard`
+#: the bytes go nowhere (no socket, no sequence numbers)
+Encoder = Callable[[List[Output], bool], Tuple[T, int, int]]
 
 
 def render(protocol: str, outputs: List[Output], encode: Encoder) -> T:
@@ -54,8 +59,12 @@ def _encode(protocol: str, outputs: List[Output], encode: Encoder,
     rows = sum(o.num_rows for o in outputs if o.is_batches)
     with continue_trace(outputs[-1].trace if outputs else None), \
             span("render", protocol=protocol, rows=rows) as sp:
-        value, sp["attrs"]["bytes"] = encode(outputs, discard)
+        value, sp["attrs"]["bytes"], cell_rows = encode(outputs, discard)
+        sp["attrs"]["path"] = "cell" if cell_rows else "columnar"
     observe_latency("render", sp["elapsed_ms"] / 1e3, protocol=protocol)
+    for path, n in (("columnar", rows - cell_rows), ("cell", cell_rows)):
+        if n:
+            increment_counter("render_rows", n, protocol=protocol, path=path)
     return value, sp
 
 
