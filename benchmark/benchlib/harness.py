@@ -9,10 +9,10 @@ import shutil
 import statistics
 import time
 
-from .data import Dataset
-from .loops import LOOPS, Context, log
+from .loops import Context, log
 from .server import ROOT, Server
-from .spec import Cell, load_json, load_layer_reader
+from .spec import (Cell, load_generator, load_json, load_layer_reader,
+                   load_loop)
 from .trace import DeviceTrace
 from .wire import Http
 
@@ -44,6 +44,9 @@ def end_to_end(cell: Cell, run: dict) -> dict:
     of the round in flight)."""
     seconds = run["window_s"]
     out = {"setup_s": run["setup_s"]}
+    # a mix may report its quantities under names of its own (`reports`),
+    # so that a noisier mix has bounds of its own
+    names = run["mix"].get("reports", {})
     done = [r for r in run.get("statements", ())
             if r["in_window"] and r["ok"]]
     if done:        # a run whose every statement failed has no latency
@@ -55,9 +58,6 @@ def end_to_end(cell: Cell, run: dict) -> dict:
         # the median of such a family jumps by a fifth with the parity of
         # the rounds in the window
         means = [statistics.fmean(v) for v in by_family.values()]
-        # a mix may report these under names of its own (`reports`), so
-        # that a noisier mix has bounds of its own
-        names = run["mix"].get("reports", {})
         out[names.get("geomean_ms", "stmt_geomean_ms")] = \
             statistics.geometric_mean(means)
         out[names.get("p90_ms", "stmt_p90_ms")] = percentile(
@@ -71,8 +71,27 @@ def end_to_end(cell: Cell, run: dict) -> dict:
     if "batches" in run:
         rows = sum(r["rows"] for r in run["batches"]
                    if r["in_window"] and r["ok"])
-        out["ingest_rows_per_s"] = rows / seconds
+        out[names.get("rows_per_s", "ingest_rows_per_s")] = rows / seconds
         run["window_rows"] = rows
+    return out
+
+
+def compared_numbers(run: dict) -> dict:
+    """Every number the check compared, beside its limit, under short
+    plain names: per family the largest error of its answers and the
+    count of wrong ones; per read-back the count and sum errors. None
+    where there was nothing to subtract (the result's keys differed)."""
+    out = {}
+    for family, c in run.get("compared", {}).items():
+        out[f"{family}.{c['number']}"] = {"value": c["largest"],
+                                          "limit": c["limit"]}
+        out[f"{family}.wrong_answers"] = {"value": c["wrong"], "limit": 0}
+    for when, rb in run.get("read_back", {}).items():
+        out[f"read_back.{when}.count_max_abs_err"] = {
+            "value": rb["count"]["max_abs_err"], "limit": 0.0}
+        out[f"read_back.{when}.sum_max_rel_err"] = {
+            "value": rb["sums"]["max_rel_err"],
+            "limit": run["mix"]["sum_tolerance"]["rtol"]}
     return out
 
 
@@ -101,8 +120,9 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
         extra = int(mix.get("extra_ticks", 0))
         if debug:
             extra = int(mix.get("debug_extra_ticks", extra))
-        ds = Dataset(config, seed, extra_ticks=extra, scale=size["scale"],
-                     ticks=size["duration_s"] // config["log_interval_s"])
+        ds = load_generator(config)(
+            config, seed, extra_ticks=extra, scale=size["scale"],
+            ticks=size["duration_s"] // config["log_interval_s"])
         run["generate_s"] = time.monotonic() - t_setup
         try:
             status = server.wait_ready()
@@ -133,7 +153,7 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
                                f"{ds.rows} rows")
         log(f"loaded {acked:,} rows over Flight in {run['load_s']:.1f} s")
         ctx = Context(cell, ds, server, run, seed, traced, debug, perturb)
-        loop = LOOPS[mix["loop"]](ctx)
+        loop = load_loop(mix["loop"])(ctx)
         loop.prepare()
         run["setup_s"] = time.monotonic() - t_setup
 
@@ -204,6 +224,7 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
         device["window_s"] = trace.window_s
         result["breakdown"] = {"device_ops": trace.device_ops(),
                                "idle_gaps": trace.idle_gaps()}
+    result["compared"] = compared_numbers(run)      # the line's last key
     save_record(work, run, result)
     return result
 

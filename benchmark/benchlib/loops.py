@@ -71,7 +71,8 @@ class Sender:
             return self.mysql.query_raw(sql)
         return self.http.sql_raw(sql)
 
-    def decode(self, via: str, raw, sql: str = ""):
+    @staticmethod
+    def decode(via: str, raw, sql: str = ""):
         if via == "mysql":
             return MiniMysql.decode_rows(raw)
         return Http.decode_sql(raw, sql)

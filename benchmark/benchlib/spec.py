@@ -1,6 +1,6 @@
 """Finding things by name: a cell in BENCHMARK.json, its configuration and
-traffic files, the family and per-layer reader modules. There is no
-central table: a later PR adds files and entries."""
+traffic files, the family, per-layer reader, loop-kind and generator
+modules. There is no central table: a later PR adds files and entries."""
 
 from __future__ import annotations
 
@@ -33,6 +33,25 @@ def load_layer_reader(name: str):
     named `<reader>.<tag>` is read by layers/<reader>.py: one quantity
     split over cells whose end-to-end metrics differ."""
     return _load_module("layers", name.split(".", 1)[0]).read
+
+
+def load_loop(name: str):
+    """A traffic file's `loop` -> the loop class: `statements` and
+    `ingest` live in benchlib/loops.py, any other kind is
+    benchmark/loops/<kind>.py and its LOOP."""
+    from .loops import LOOPS
+    return LOOPS.get(name) or _load_module("loops", name).LOOP
+
+
+def load_generator(config: dict):
+    """A configuration's optional `generator` -> the Dataset class of
+    benchmark/generators/<name>.py; absent means TSBS devops cpu-only,
+    benchlib/data.py."""
+    name = config.get("generator")
+    if name is None:
+        from .data import Dataset
+        return Dataset
+    return _load_module("generators", name).Dataset
 
 
 def load_json(*parts) -> dict:

@@ -11,10 +11,18 @@ cell compares beside its limit.
 A write cell (`"loop": "ingest"`) states no precision, so its control
 breaks the guarantee its configuration states, durability of every
 acknowledged row: a whole run on the chip with a short window, in which
-the benchmark books one acknowledgement for a batch the server never got.
+the benchmark books one acknowledgement for a batch the server never got
+(`--perturb lost-batch`, the default there).
 
-    python3 benchmark/control.py --workload <cell> --seed <n>
-                                 [--debug] [--draws <k>] [--seconds <s>]
+A cell that reads while it writes (`"loop": "mixed"`) has both: without
+`--perturb` the bf16 control of its families, and with `--perturb
+stale-lastpoint+lost-batch` one whole run in which every `live` answer is
+one tick older than what had been acknowledged when it was sent and one
+acknowledged batch was never stored; each part has to fail its own
+numbers.
+
+    python3 benchmark/control.py --workload <cell> --seed <n> [--debug]
+                    [--draws <k>] [--seconds <s>] [--perturb <a>[+<b>]]
 """
 
 from __future__ import annotations
@@ -31,13 +39,13 @@ sys.path.insert(0, HERE)
 
 def control(workload: str, seed: int, debug: bool, draws: int) -> dict:
     from benchlib import check as chk
-    from benchlib.data import Dataset
     from benchlib.loops import family_rng
-    from benchlib.spec import Cell, load_family
+    from benchlib.spec import Cell, load_family, load_generator
     cell = Cell(workload)
     size = cell.config["debug"] if debug else cell.config
-    ds = Dataset(cell.config, seed, scale=size["scale"],
-                 ticks=size["duration_s"] // cell.config["log_interval_s"])
+    ds = load_generator(cell.config)(
+        cell.config, seed, scale=size["scale"],
+        ticks=size["duration_s"] // cell.config["log_interval_s"])
     mirror = copy.copy(ds)      # the same deployment over bf16 mirrors
     mirror.data = chk.bf16_round(ds.data.reshape(-1)).reshape(ds.data.shape)
     out = {}
@@ -62,6 +70,20 @@ def control(workload: str, seed: int, debug: bool, draws: int) -> dict:
     return out
 
 
+#: where a perturbation has to show among a run's compared numbers
+SHOWS_IN = {"bf16-answers": "answers", "stale-lastpoint": "answers",
+            "lost-batch": "read_back"}
+
+
+def parts_off(compared: dict) -> set:
+    """Which parts of a run's compared numbers are outside their limits:
+    `answers` (a family's statements), `read_back` (a count that is off,
+    or ticks that differ, which leaves no number)."""
+    return {"read_back" if name.startswith("read_back.") else "answers"
+            for name, c in compared.items()
+            if c["value"] is None or c["value"] > c["limit"]}
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -70,17 +92,23 @@ def main() -> int:
                     help="the configuration's debug size")
     ap.add_argument("--draws", type=int, default=6)
     ap.add_argument("--seconds", type=float, default=10.0,
-                    help="the write cell's window")
+                    help="the window of a whole perturbed run")
+    ap.add_argument("--perturb", default=None,
+                    help="a whole run with the timed path's output broken")
     args = ap.parse_args()
     from benchlib.spec import Cell
-    if Cell(args.workload).mix["loop"] == "ingest":
+    perturb = args.perturb or {"ingest": "lost-batch"}.get(
+        Cell(args.workload).mix["loop"])
+    if perturb:
         from benchlib.harness import run_cell
         result = run_cell(args.workload, args.seed, args.seconds, False,
-                          "cpu" if args.debug else None,
-                          perturb="lost-batch")
+                          "cpu" if args.debug else None, perturb=perturb)
+        off = parts_off(result["compared"])
         print(json.dumps({"workload": args.workload, "seed": args.seed,
-                          "control": "lost-batch", "result": result}))
-        return 1 if result["correct"] else 0
+                          "control": perturb, "parts_off": sorted(off),
+                          "result": result}))
+        wanted = {SHOWS_IN[p] for p in perturb.split("+")}
+        return 0 if not result["correct"] and wanted <= off else 1
     out = control(args.workload, args.seed, args.debug, args.draws)
     print(json.dumps({"workload": args.workload, "seed": args.seed,
                       "control": out}))

@@ -10,7 +10,9 @@ that owns the chip, refuses to go on unless that child reports a TPU with
 as many chips as the cell asks for, makes the data from --seed, loads it,
 warms only the cell's own statements (all of that is `setup_s`), measures
 for --seconds, checks the answers outside the window, stops the server and
-prints the result as the last line of its standard output. With --trace 1
+prints the result as the last line of its standard output (its last key,
+`compared`, holds every number the check compared beside its limit; the
+same go out as the last lines of standard error). With --trace 1
 the window is also the profiler's window and the metrics are the cell's
 per-layer metrics.
 
@@ -56,6 +58,10 @@ def main(argv=None) -> int:
         return 3
     if "jax" in sys.modules:
         raise AssertionError("the benchmark's parent imported jax")
+    for name, c in result["compared"].items():
+        print(f"compared {name}: {c['value']} (limit {c['limit']})",
+              file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 3 if args.debug_platform else 0
 

@@ -13,6 +13,8 @@ tier-1 tests (those live in tests/, which a benchmark PR may not touch).
 
 from __future__ import annotations
 
+import contextlib
+import glob
 import json
 import os
 import shutil
@@ -25,7 +27,12 @@ ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 
 SECONDS = 3
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "compared"}
+#: what must turn `correct` false, by the mix's loop kind: the timed
+#: path's output broken on the benchmark's side, one perturbation a run
+PERTURBATIONS = {"statements": ["bf16-answers"], "ingest": ["lost-batch"],
+                 "mixed": ["stale-lastpoint", "lost-batch"]}
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
 
 
@@ -43,6 +50,9 @@ def parse_result_line(line: str, cell, traced: bool) -> dict:
     result = json.loads(line)
     extra = {"breakdown"} if traced else set()
     assert set(result) - extra == RESULT_KEYS, sorted(result)
+    assert list(result)[-1] == "compared" and result["compared"]
+    for name, c in result["compared"].items():
+        assert set(c) == {"value", "limit"}, (name, c)
     assert isinstance(result["correct"], bool)
     assert isinstance(result["attempted"], int) and result["attempted"] > 0
     assert isinstance(result["failed"], int)
@@ -69,28 +79,80 @@ def parse_result_line(line: str, cell, traced: bool) -> dict:
     return result
 
 
-def workloads() -> list:
+def benchmark_json() -> dict:
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        return [w["name"] for w in json.load(f)["workloads"]]
+        return json.load(f)
+
+
+def workloads() -> list:
+    return [w["name"] for w in benchmark_json()["workloads"]]
+
+
+def queued_cells() -> dict:
+    """benchmark/queued/<cell>.json: a cell kept for later with every
+    entry of BENCHMARK.json it needs -> {cell name: BENCHMARK.json with
+    those entries merged in} (a bound not set yet reads 0.25)."""
+    out = {}
+    for path in sorted(glob.glob(os.path.join(HERE, "queued", "*.json"))):
+        with open(path) as f:
+            queued = json.load(f)
+        spec = benchmark_json()
+        spec["workloads"] += queued["workloads"]
+        spec["end_to_end"][-1:-1] = [       # `setup_s` stays last
+            dict(m, bound=m["bound"] or 0.25) for m in queued["end_to_end"]]
+        spec["per_layer"] += queued["per_layer"]
+        for w in queued["workloads"]:
+            out[w["name"]] = spec
+    return out
+
+
+@contextlib.contextmanager
+def copy_of_the_checkout(spec: dict = None):
+    """-> a temporary checkout: benchmark/ copied, the program linked,
+    `spec` as its BENCHMARK.json (or left for the caller to write)."""
+    work = os.path.join(ROOT, ".bench_work")
+    os.makedirs(work, exist_ok=True)
+    with tempfile.TemporaryDirectory(dir=work, prefix="copy_") as tmp:
+        shutil.copytree(HERE, os.path.join(tmp, "benchmark"),
+                        ignore=shutil.ignore_patterns("__pycache__"))
+        os.symlink(os.path.join(ROOT, "greptimedb_tpu"),
+                   os.path.join(tmp, "greptimedb_tpu"))
+        if spec is not None:
+            write_spec(tmp, spec)
+        yield tmp
+
+
+def write_spec(checkout: str, spec: dict) -> None:
+    with open(os.path.join(checkout, "BENCHMARK.json"), "w") as f:
+        json.dump(spec, f)
 
 
 def test_cells_end_to_end():
     """Every cell, --trace 0 and --trace 1, through the real command line:
     exit code 3 (a debug run is never a result), `correct` true, the last
-    line strict."""
+    line strict. A queued cell runs in a copy of the checkout whose
+    BENCHMARK.json has its entries."""
     from benchlib.spec import Cell
-    for name in workloads():
+
+    def drive(name, root):
         for traced in (0, 1):
             rc, out = run_command(
                 ["benchmark/run.py", "--workload", name, "--seed",
                  "2147483659", "--seconds", str(SECONDS), "--trace",
-                 str(traced), "--debug-platform", "cpu"])
+                 str(traced), "--debug-platform", "cpu"], cwd=root)
             assert rc == 3, (name, traced, rc, out[-15:])
-            result = parse_result_line(out[-1], Cell(name), bool(traced))
+            result = parse_result_line(out[-1], Cell(name, root),
+                                       bool(traced))
             assert result["correct"] is True, (name, traced, out[-15:])
             assert result["failed"] == 0
             assert result["device"]["platform"] == "cpu"
             print(f"ok: {name} --trace {traced}: {out[-1][:160]}")
+
+    for name in workloads():
+        drive(name, ROOT)
+    for name, spec in queued_cells().items():
+        with copy_of_the_checkout(spec) as tmp:
+            drive(name, tmp)
 
 
 def test_refuses_without_a_chip():
@@ -183,42 +245,71 @@ def test_peaks():
 def test_negative_controls():
     """The timed path's output broken on the benchmark's side: every query
     cell with its answers rounded to bf16 before the comparison, the
-    ingest cell with an acknowledgement for rows that were never stored.
-    The rest of the run is driven as always; `correct` must come out
-    false. And the reference itself over bf16 mirrors (control.py) fails
-    every family."""
+    ingest cell with an acknowledgement for rows that were never stored,
+    the cell that reads while it writes with every `lastpoint-live` answer
+    one tick older than what had been acknowledged when it was sent, and
+    again with the lost batch. The rest of the run is driven as always;
+    `correct` must come out false, and the numbers outside their limits
+    must be the perturbed part's. And the reference itself over bf16
+    mirrors (control.py) fails every family."""
     from benchlib.harness import run_cell
     from benchlib.spec import Cell
-    from control import control
+    from control import SHOWS_IN, control, parts_off
     for name in workloads():
-        ingest = Cell(name).mix["loop"] == "ingest"
-        result = run_cell(name, 77, SECONDS, False, "cpu",
-                          perturb="lost-batch" if ingest else "bf16-answers")
-        assert result["correct"] is False, (name, result)
-        print(f"ok: {name} perturbed -> correct false")
-        if not ingest:
+        mix = Cell(name).mix
+        for perturb in PERTURBATIONS[mix["loop"]]:
+            result = run_cell(name, 77, SECONDS, False, "cpu",
+                              perturb=perturb)
+            assert result["correct"] is False, (name, perturb, result)
+            assert SHOWS_IN[perturb] in parts_off(result["compared"]), (
+                name, perturb, result["compared"])
+            print(f"ok: {name} with {perturb} -> correct false")
+        if "families" in mix:
             out = control(name, 77, True, 3)
             assert all(v["fails"] for v in out.values()), out
+    for name, spec in queued_cells().items():
+        with copy_of_the_checkout(spec) as tmp:
+            mix = Cell(name, tmp).mix
+            for perturb in PERTURBATIONS[mix["loop"]] + [None]:
+                # control.py exits 0 when its control comes out as not
+                # correct, in the perturbed part (None: the bf16 mirrors)
+                rc, out = run_command(
+                    ["benchmark/control.py", "--workload", name, "--seed",
+                     "77", "--debug", "--seconds", str(SECONDS)]
+                    + (["--perturb", perturb] if perturb else []), cwd=tmp)
+                assert rc == 0, (name, perturb, rc, out[-15:])
+                print(f"ok: {name} with {perturb or 'bf16 mirrors'} -> "
+                      "not correct")
 
 
 def test_extensible():
-    """A configuration, a traffic mix, a family and a per-layer metric,
-    each added as new files plus new entries of BENCHMARK.json, in a
-    temporary copy; no file that was there is edited."""
-    work = os.path.join(ROOT, ".bench_work")
-    os.makedirs(work, exist_ok=True)
-    with tempfile.TemporaryDirectory(dir=work, prefix="copy_") as tmp:
+    """A configuration, a generator, a traffic mix, a loop kind, a family
+    and a per-layer metric, each added as new files plus new entries of
+    BENCHMARK.json, in a temporary copy; no file that was there is
+    edited."""
+    with copy_of_the_checkout() as tmp:
         bench = os.path.join(tmp, "benchmark")
-        shutil.copytree(HERE, bench,
-                        ignore=shutil.ignore_patterns("__pycache__"))
-        os.symlink(os.path.join(ROOT, "greptimedb_tpu"),
-                   os.path.join(tmp, "greptimedb_tpu"))
-        with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-            spec = json.load(f)
+        spec = benchmark_json()
         with open(os.path.join(bench, "configs", "tsbs-cpu-4000.json")) as f:
             config = json.load(f)
         config["name"] = "throwaway-config"
         config["debug"]["scale"] = 300
+        config["generator"] = "throwaway-generator"
+        os.makedirs(os.path.join(bench, "generators"), exist_ok=True)
+        with open(os.path.join(bench, "generators",
+                               "throwaway-generator.py"), "w") as f:
+            f.write("from benchlib.data import Dataset as Tsbs\n\n\n"
+                    "class Dataset(Tsbs):\n"
+                    "    def __init__(self, config, seed, **size):\n"
+                    "        super().__init__(config, seed, **size)\n"
+                    "        self.table = 'cpu_of_a_generator'\n")
+        with open(os.path.join(bench, "loops", "throwaway-loop.py"),
+                  "w") as f:
+            f.write("from benchlib.loops import StatementLoop\n\n\n"
+                    "class LOOP(StatementLoop):\n"
+                    "    def prepare(self):\n"
+                    "        super().prepare()\n"
+                    "        self.ctx.run['table'] = self.ctx.ds.table\n")
         with open(os.path.join(bench, "configs", "throwaway-config.json"),
                   "w") as f:
             json.dump(config, f)
@@ -229,13 +320,15 @@ def test_extensible():
                     "'mysql')\n")
         with open(os.path.join(bench, "traffic", "throwaway-mix.json"),
                   "w") as f:
-            json.dump({"loop": "statements", "clients": 1,
+            json.dump({"loop": "throwaway-loop", "clients": 1,
                        "prime": "lastpoint",
                        "families": ["throwaway-family", "lastpoint"],
                        "warm_statements": 2, "max_statements": 2000}, f)
         with open(os.path.join(bench, "layers", "throwaway_count.py"),
                   "w") as f:
             f.write("def read(run):\n"
+                    "    if run.get('table') != 'cpu_of_a_generator':\n"
+                    "        return None\n"
                     "    return len(run.get('statements', ()))\n")
         spec["configs"].append({
             "name": "throwaway-config", "source": config["source"],
@@ -251,8 +344,7 @@ def test_extensible():
             "name": "throwaway_count", "unit": "count", "better": "higher",
             "source": "program_counter", "layer": "protocol servers",
             "moves": "stmt_per_s", "workloads": ["throwaway-cell"]})
-        with open(os.path.join(tmp, "BENCHMARK.json"), "w") as f:
-            json.dump(spec, f)
+        write_spec(tmp, spec)
         for traced in (0, 1):
             rc, out = run_command(
                 ["benchmark/run.py", "--workload", "throwaway-cell",
@@ -262,8 +354,9 @@ def test_extensible():
             result = json.loads(out[-1])
             assert result["correct"] is True, out[-15:]
         assert result["metrics"]["throwaway_count"]["value"] > 0
-        print("ok: a throw-away config, mix, family and per-layer metric "
-              f"ran as files of their own: {sorted(result['metrics'])}")
+        print("ok: a throw-away config, generator, mix, loop kind, family "
+              "and per-layer metric ran as files of their own: "
+              f"{sorted(result['metrics'])}")
 
 
 TESTS = {"cells": test_cells_end_to_end,
