@@ -226,6 +226,9 @@ class FrontendInstance:
             return ex.copy(stmt, ctx)
         if isinstance(stmt, ast.Tql):
             return self.execute_tql(stmt, ctx)
+        if isinstance(stmt, ast.Explain) and \
+                isinstance(stmt.statement, ast.Tql):
+            return self.promql_engine().explain_tql(stmt, ctx)
         return self.query_engine.execute(stmt, ctx)
 
     def promql_engine(self):

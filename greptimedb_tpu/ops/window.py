@@ -44,21 +44,67 @@ RANGE_OPS = CUMSUM_OPS | GATHER_OPS
 class SeriesMatrix:
     """Dense padded [num_series, max_len] layout of a set of time series."""
 
-    __slots__ = ("ts", "values", "lengths", "num_series", "max_len")
+    __slots__ = ("ts", "values", "lengths", "num_series", "max_len",
+                 "_base")
 
     def __init__(self, ts: np.ndarray, values: np.ndarray, lengths: np.ndarray):
         self.ts = ts
         self.values = values
         self.lengths = lengths
         self.num_series, self.max_len = ts.shape
+        self._base = None
+
+    @property
+    def value_base(self) -> np.ndarray:
+        """[S] float64: every series' first sample (0 for an empty row).
+        The device computes in float32, and a counter that has run for a
+        month (2.6e6 CPU seconds, 1e12 bytes) has no digits left for the
+        growth inside a window once it is cast as it is; its offset from
+        this base has them all."""
+        if self._base is None:
+            first = np.asarray(self.values[:, 0], dtype=np.float64)
+            self._base = np.where(self.lengths > 0, first, 0.0)
+        return self._base
+
+    def rebased_values(self) -> np.ndarray:
+        """values - value_base in float64 (padding holds nothing a kernel
+        reads): what every shift-invariant range function (delta, stddev,
+        deriv, changes...) computes on as it is, and the others (last,
+        avg, min, max, quantiles, predict_linear, instant selection) add
+        the base back to in float64 on the host."""
+        return np.asarray(self.values, dtype=np.float64) \
+            - self.value_base[:, None]
+
+    def counter_adjusted(self) -> np.ndarray:
+        """The reset-corrected counter (value + every value seen before a
+        reset so far) as its offset from its own first sample, in
+        float64: monotone from 0, so `rate` / `increase` are differences
+        of numbers no larger than the growth over the matrix. The value
+        before a reset is as large as the counter was: added on the
+        device in float32 it would cost the digits again."""
+        v = np.asarray(self.values, dtype=np.float64)
+        out = self.rebased_values()
+        rows, cols = np.nonzero(v[:, 1:] < v[:, :-1])
+        real = cols + 1 < self.lengths[rows]     # not the step into padding
+        rows, cols = rows[real], cols[real]
+        if len(rows) <= self.num_series:
+            # resets are rare (a reboot): each lifts the rest of its row
+            for r, c in zip(rows.tolist(), cols.tolist()):
+                out[r, c + 1:] += v[r, c]
+            return out
+        lift = np.zeros_like(v)
+        lift[rows, cols + 1] = v[rows, cols]
+        return out + np.cumsum(lift, axis=1)
 
     @staticmethod
     def build(series_ids: np.ndarray, ts: np.ndarray, values: np.ndarray,
               num_series: int, max_len: Optional[int] = None) -> "SeriesMatrix":
         """Build from flat arrays sorted by (series_id, ts). Rows whose
         series_id is outside [0, num_series) are dropped."""
-        sel = (series_ids >= 0) & (series_ids < num_series)
-        series_ids, ts, values = series_ids[sel], ts[sel], values[sel]
+        if len(series_ids) and (series_ids.min() < 0
+                                or series_ids.max() >= num_series):
+            sel = (series_ids >= 0) & (series_ids < num_series)
+            series_ids, ts, values = series_ids[sel], ts[sel], values[sel]
         counts = np.bincount(series_ids, minlength=num_series)
         longest = int(counts.max(initial=0))
         if max_len is not None and max_len < longest:
@@ -84,15 +130,18 @@ class SeriesMatrix:
         int32 offsets from `base` (padding becomes int32 max, preserving the
         sentinel ordering); callers must rebase query times by the same base.
         """
-        valid = self.ts != TS_PAD
+        # rows lie sorted by time with their padding last, so the span
+        # is in each row's first and last sample: no pass over the cells
+        some = np.nonzero(self.lengths > 0)[0]
+        first = last = 0
+        if len(some):
+            first = int(self.ts[some, 0].min())
+            last = int(self.ts[some, self.lengths[some] - 1].max())
         if base is None:
-            base = int(self.ts[valid].min()) if valid.any() else 0
-        span_ok = True
-        if valid.any():
-            span_ok = (int(self.ts[valid].max()) - base) < 2**31 - 1 and \
-                base <= int(self.ts[valid].min())
-        if span_ok:
-            rel = np.where(valid, self.ts - base, np.iinfo(np.int32).max)
+            base = first
+        if not len(some) or (last - base < 2**31 - 1 and base <= first):
+            rel = np.where(self.ts != TS_PAD, self.ts - base,
+                           np.iinfo(np.int32).max)
             return rel.astype(np.int32), self.values, self.lengths, base
         return self.ts, self.values, self.lengths, 0
 
@@ -235,8 +284,16 @@ def _rebase_i64_host(ts2d, t0, step=0, nsteps=1, range_ms=0):
 def range_aggregate_cumsum(
     ts2d, val2d, lengths, t0, step, range_ms, *, op: str, nsteps: int,
     param: float = 0.0, bounds: Optional[Tuple[jax.Array, jax.Array]] = None,
+    counter: Optional[Tuple[jax.Array, jax.Array]] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Evaluate a cumsum-path range function on the aligned step grid.
+
+    `counter` = (adj2d, abs2d) lets a caller whose `val2d` is rebased
+    (SeriesMatrix.rebased_values) keep counter semantics exact: `adj2d`
+    is SeriesMatrix.counter_adjusted (rate / increase difference it
+    instead of correcting resets here, either may be None), `abs2d` the
+    values as they are (where a counter's own level counts: the
+    extrapolation's zero point, irate's value after a reset).
 
     Returns (result [S, T], ok [S, T]) — ok False means "no point for this
     series at this step" (NaN / absent in PromQL terms).
@@ -248,37 +305,42 @@ def range_aggregate_cumsum(
     evaluation at 10k-series scale.
     """
     ts2d, t0 = _rebase_i64_host(ts2d, t0, step, nsteps, range_ms)
+    adj2d, abs2d = counter if counter is not None else (None, None)
     if bounds is not None:
         return _range_aggregate_cumsum_pre(
             ts2d, val2d, lengths, t0, step, range_ms, bounds[0], bounds[1],
-            op=op, nsteps=nsteps, param=param)
+            adj2d, abs2d, op=op, nsteps=nsteps, param=param)
     return _range_aggregate_cumsum(ts2d, val2d, lengths, t0, step, range_ms,
-                                   op=op, nsteps=nsteps, param=param)
+                                   adj2d, abs2d, op=op, nsteps=nsteps,
+                                   param=param)
 
 
 @functools.partial(jax.jit, static_argnames=("op", "nsteps"))
 def _range_aggregate_cumsum(
     ts2d: jax.Array, val2d: jax.Array, lengths: jax.Array,
-    t0, step, range_ms, *, op: str, nsteps: int, param: float = 0.0,
+    t0, step, range_ms, adj2d=None, abs2d=None, *, op: str, nsteps: int,
+    param: float = 0.0,
 ) -> Tuple[jax.Array, jax.Array]:
     step_ends = t0 + jnp.arange(nsteps, dtype=ts2d.dtype) * step
     lo, hi = window_bounds(ts2d, step_ends, range_ms)
     return _rac_body(ts2d, val2d, lengths, lo, hi, step_ends, range_ms,
-                     op=op, nsteps=nsteps)
+                     op=op, nsteps=nsteps, adj2d=adj2d, abs2d=abs2d)
 
 
 @functools.partial(jax.jit, static_argnames=("op", "nsteps"))
 def _range_aggregate_cumsum_pre(
     ts2d: jax.Array, val2d: jax.Array, lengths: jax.Array,
-    t0, step, range_ms, lo, hi, *, op: str, nsteps: int, param: float = 0.0,
+    t0, step, range_ms, lo, hi, adj2d=None, abs2d=None, *, op: str,
+    nsteps: int, param: float = 0.0,
 ) -> Tuple[jax.Array, jax.Array]:
     step_ends = t0 + jnp.arange(nsteps, dtype=ts2d.dtype) * step
     return _rac_body(ts2d, val2d, lengths, lo, hi, step_ends, range_ms,
-                     op=op, nsteps=nsteps)
+                     op=op, nsteps=nsteps, adj2d=adj2d, abs2d=abs2d)
 
 
 def _rac_body(ts2d, val2d, lengths, lo, hi, step_ends, range_ms, *,
-              op: str, nsteps: int) -> Tuple[jax.Array, jax.Array]:
+              op: str, nsteps: int, adj2d=None, abs2d=None
+              ) -> Tuple[jax.Array, jax.Array]:
     S, L = ts2d.shape
     idx = jnp.arange(L, dtype=jnp.int32)
     valid = idx[None, :] < lengths[:, None]
@@ -329,8 +391,10 @@ def _rac_body(ts2d, val2d, lengths, lo, hi, step_ends, range_ms, *,
         prev = _gather(val2d, jnp.maximum(hi - 2, 0))
         if op == "irate_num":
             # prometheus instantValue counter-reset rule: on reset
-            # (last < prev) the delta is the last sample alone
-            return jnp.where(last < prev, last, last - prev), ok2
+            # (last < prev) the delta is the last sample alone, at the
+            # counter's own level
+            alone = last if abs2d is None else _gather(abs2d, hi1)
+            return jnp.where(last < prev, alone, last - prev), ok2
         return last - prev, ok2
 
     if op in ("changes", "resets"):
@@ -357,13 +421,18 @@ def _rac_body(ts2d, val2d, lengths, lo, hi, step_ends, range_ms, *,
             raw = last_v - first_v
             is_counter = False
         else:
-            # counter-reset correction: adjusted[i] = v[i] + sum of resets<=i
-            prev = jnp.concatenate([val2d[:, :1], val2d[:, :-1]], axis=1)
-            pair_ok = valid & (idx[None, :] >= 1)
-            contrib = jnp.where(pair_ok & (val2d < prev), prev, 0).astype(fv)
-            corr = jnp.cumsum(contrib, axis=1)
-            adj = val2d + corr
+            adj = adj2d
+            if adj is None:
+                # counter-reset correction: adjusted[i] = v[i] + sum of
+                # resets <= i
+                prev = jnp.concatenate([val2d[:, :1], val2d[:, :-1]], axis=1)
+                pair_ok = valid & (idx[None, :] >= 1)
+                contrib = jnp.where(pair_ok & (val2d < prev), prev,
+                                    0).astype(fv)
+                adj = val2d + jnp.cumsum(contrib, axis=1)
             raw = _gather(adj, hi1) - _gather(adj, jnp.minimum(lo, L - 1))
+            if abs2d is not None:   # the zero point is the counter's own
+                first_v = _gather(abs2d, jnp.minimum(lo, L - 1))
             is_counter = True
         return _extrapolate(raw, first_t, last_t, first_v, count, step_ends,
                             range_ms, op=op, is_counter=is_counter)
@@ -502,21 +571,24 @@ def _rag_body(
             return _masked_quantile(vals, inwin, param), ok1
         if op in ("deriv", "predict_linear"):
             ok2 = count >= 2
-            # least-squares slope with times centered on the window end
+            # least squares around the window's own means: the textbook
+            # n*sxy - sx*sy subtracts two float32 products that agree in
+            # their leading digits (values of 1e8 B, slope off by 1e-3)
             t_sec = (tvals.astype(fv) - step_ends[None, :, None].astype(fv)) / 1000.0
             m = inwin.astype(fv)
             n = jnp.maximum(jnp.sum(m, axis=2), 1)
-            sx = jnp.sum(t_sec * m, axis=2)
-            sy = jnp.sum(vals * m, axis=2)
-            sxx = jnp.sum(t_sec * t_sec * m, axis=2)
-            sxy = jnp.sum(t_sec * vals * m, axis=2)
-            denom = n * sxx - sx * sx
-            slope = jnp.where(denom != 0, (n * sxy - sx * sy) /
-                              jnp.where(denom == 0, 1, denom), jnp.nan)
+            xm = jnp.sum(t_sec * m, axis=2) / n
+            ym = jnp.sum(vals * m, axis=2) / n
+            dx = (t_sec - xm[:, :, None]) * m
+            dy = (vals - ym[:, :, None]) * m
+            sxx = jnp.sum(dx * dx, axis=2)
+            sxy = jnp.sum(dx * dy, axis=2)
+            slope = jnp.where(sxx != 0, sxy / jnp.where(sxx == 0, 1, sxx),
+                              jnp.nan)
             if op == "deriv":
                 return slope, ok2
-            intercept = (sy - slope * sx) / n
-            return intercept + slope * param, ok2
+            # the line at the step (x = 0), then `param` seconds on
+            return ym + slope * (param - xm), ok2
         if op == "holt_winters":
             return _holt_winters(vals, inwin, param, param2), count >= 2
         raise ValueError(f"not a gather-path op: {op}")
@@ -599,6 +671,36 @@ def _stack_counter(ts2d, val2d, lengths, ext):
         jnp.concatenate([adj[:, :1], adj], axis=1),
         jnp.concatenate([adj, adj[:, -1:]], axis=1),
     ], jnp.minimum(ext, L))
+
+
+@jax.jit
+def _stack_rate(ts2d, adj2d, abs2d, ext):
+    """rate / increase over host-prepared counter arrays
+    (SeriesMatrix.counter_adjusted, and the values as they are for the
+    zero point): [ts_prev, ts_at, adj_prev, adj_at, abs_at]."""
+    L = ts2d.shape[1]
+    fv = adj2d.dtype
+    tsf = ts2d.astype(fv)
+    return _gather_channels([
+        jnp.concatenate([tsf[:, :1], tsf], axis=1),
+        jnp.concatenate([tsf, tsf[:, -1:]], axis=1),
+        jnp.concatenate([adj2d[:, :1], adj2d], axis=1),
+        jnp.concatenate([adj2d, adj2d[:, -1:]], axis=1),
+        jnp.concatenate([abs2d, abs2d[:, -1:]], axis=1).astype(fv),
+    ], jnp.minimum(ext, L))
+
+
+@functools.partial(jax.jit, static_argnames=("op", "nsteps", "shift"))
+def _rate_from_stack(gr, lo, hi, t0, step, range_ms, *, op: str,
+                     nsteps: int, shift: int):
+    T = nsteps
+    count = (hi - lo).astype(jnp.int32)
+    step_ends = t0 + jnp.arange(T, dtype=jnp.int32) * step
+    first_t = gr[:, :T, 1]
+    last_t = gr[:, shift:, 0]
+    raw = gr[:, shift:, 2] - gr[:, :T, 3]
+    return _extrapolate(raw, first_t, last_t, gr[:, :T, 4], count,
+                        step_ends, range_ms, op=op, is_counter=True)
 
 
 @jax.jit
@@ -704,17 +806,30 @@ class AlignedWindowEval:
     each op adds only a [S, T] vector epilogue. The PromQL engine caches
     one of these per (selector, window) within an evaluation."""
 
-    def __init__(self, ts2d, val2d, lengths, t0, step, range_ms, nsteps):
+    def __init__(self, ts2d, val2d, lengths, t0, step, range_ms, nsteps,
+                 counter=None):
+        """`val2d`: the values, or a callable -> them, asked when a
+        function first reads values. `counter`: a callable -> (adj2d,
+        abs2d) as `range_aggregate_cumsum` takes them, asked only when
+        a counter function is evaluated; None computes them from
+        `val2d` on the device, as a caller with plain values wants."""
         step, range_ms, nsteps = int(step), int(range_ms), int(nsteps)
         if step <= 0 or range_ms < 0 or range_ms % step:
             raise ValueError("AlignedWindowEval needs range % step == 0")
         ts2d, t0 = _rebase_i64_host(ts2d, t0, step, nsteps, range_ms)
-        self.ts2d, self.val2d, self.lengths = ts2d, val2d, lengths
+        self.ts2d, self._val2d, self.lengths = ts2d, val2d, lengths
         self.t0, self.step, self.range_ms = t0, step, range_ms
         self.nsteps = nsteps
         self.shift = range_ms // step
         self._ext = None
-        self._ga = self._gb = self._gc = None
+        self._ga = self._gb = self._gc = self._gr = None
+        self._counter = counter
+
+    @property
+    def val2d(self):
+        if callable(self._val2d):
+            self._val2d = self._val2d()
+        return self._val2d
 
     def ext(self):
         if self._ext is None:
@@ -734,11 +849,20 @@ class AlignedWindowEval:
         if op in ("count_over_time", "present_over_time"):
             return _count_from_bounds(lo, hi, op=op,
                                       fv=self.val2d.dtype)
-        if op in ("changes", "resets"):
+        if op in ("changes", "resets") or (
+                op == "irate_num" and self._counter is not None):
             # outside the stack family; still shares the bounds pass
             return range_aggregate_cumsum(
                 self.ts2d, self.val2d, self.lengths, self.t0, self.step,
-                self.range_ms, op=op, nsteps=self.nsteps, bounds=(lo, hi))
+                self.range_ms, op=op, nsteps=self.nsteps, bounds=(lo, hi),
+                counter=self._counter() if op == "irate_num" else None)
+        if op in ("rate", "increase") and self._counter is not None:
+            if self._gr is None:
+                adj2d, abs2d = self._counter()
+                self._gr = _stack_rate(self.ts2d, adj2d, abs2d, self.ext())
+            return _rate_from_stack(
+                self._gr, lo, hi, self.t0, self.step, self.range_ms, op=op,
+                nsteps=self.nsteps, shift=self.shift)
         if self._ga is None:
             self._ga = _stack_prefix(self.ts2d, self.val2d, self.lengths,
                                      self.ext())

@@ -16,8 +16,11 @@ masked on host so rebased int32 device timestamps never overflow.
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import math
 import re
+import time as _time
 from dataclasses import dataclass, field as dc_field
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -48,31 +51,111 @@ _RANGE_FUNCS = {
 }
 # which drop the metric name from results (all except last_over_time)
 _KEEP_NAME_RANGE_FUNCS = {"last_over_time"}
+# kernel ops that need a counter's own level beside its offsets
+_COUNTER_OPS = {"rate", "increase", "irate_num"}
+# kernel ops whose value is a value of the series: computed on offsets
+# from each series' first sample, they get it back in float64
+_ADD_BASE_OPS = {
+    "avg_over_time", "min_over_time", "max_over_time", "last_over_time",
+    "first_over_time", "quantile_over_time", "predict_linear",
+    "holt_winters",
+}
 
 
-def _run_window_kernel(kernel, matrix, t0, nsteps):
-    """kernel(...) -> host (values f64, ok), with the EXECUTED dispatch
-    put on record: TQL ANALYZE's `dispatch` row names the platform the
-    window kernel's result came from, as SQL's names the path a scan
-    took."""
-    import time as _time
+class _DeviceMatrix:
+    """One selector's SeriesMatrix as the window kernels take it: int32
+    timestamps rebased to the matrix's first sample, and float32 value
+    arrays made from float64 on the host, each uploaded once, when a
+    function first asks for it, and shared by every range function over
+    the selector:
 
+    - ``rel``: values minus the series' first value (`value_base`);
+    - ``adj``: the reset-corrected counter from 0 (rate / increase);
+    - ``abs``: the values as they are (a counter's own level).
+
+    When the time span does not fit int32 the timestamps stay int64 on
+    the host and every array with them (the kernels' safety net rebases
+    per call)."""
+
+    def __init__(self, matrix):
+        import jax
+        self.matrix = matrix            # pinned: id() keys need it alive
+        ts2d, _, lengths, self.ts_base = matrix.device_arrays()
+        self.on_host = ts2d.dtype == np.int64
+        self.f32 = not jax.config.jax_enable_x64
+        self.ts2d, self.lengths = self._put(ts2d), self._put(lengths)
+        self._values: Dict[str, object] = {}
+
+    def _put(self, a: np.ndarray):
+        if self.on_host:
+            return a
+        import jax
+
+        from ..common.telemetry import increment_counter
+        increment_counter("promql_upload_bytes", int(a.nbytes))
+        return jax.device_put(a)
+
+    def values(self, kind: str):
+        a = self._values.get(kind)
+        if a is None:
+            m = self.matrix
+            a = {"rel": m.rebased_values, "adj": m.counter_adjusted,
+                 "abs": lambda: m.values}[kind]()
+            if a.dtype == np.float64 and self.f32:
+                a = a.astype(np.float32)
+            a = self._values[kind] = self._put(a)
+        return a
+
+    def counter(self):
+        return self.values("adj"), self.values("abs")
+
+
+@dataclass
+class _KernelOut:
+    """What a window kernel hands back: device values and ok mask, and
+    how the host turns values computed on `rel` into the function's
+    own: `base` [S] float64 is added to every value (times `weight`
+    [S, T], sum_over_time's sample count)."""
+    values: object
+    ok: object
+    base: Optional[np.ndarray] = None
+    weight: object = None
+
+
+def _run_window_kernel(kernel, ev: "_Eval", matrix, needs, t0, nsteps):
+    """kernel(dm, t0r, nsteps) -> _KernelOut; -> host (values f64, ok),
+    with the EXECUTED dispatch put on record: the `dispatch` row names
+    the platform the window kernel's result came from, as SQL's names
+    the path a scan took. Three parts under `window`: `.upload` (the
+    float32 arrays made on the host and put on the device, once a
+    matrix), `.launch` (the jitted programs called: microseconds where
+    they are compiled), `.fetch` (blocked until the device is done, the
+    copy back, and the result made float64)."""
     import jax
 
     from ..common import exec_stats
-    t_start = _time.perf_counter()
-    v, ok = kernel(matrix, t0, nsteps)
-    devices = v.devices() if hasattr(v, "devices") else ()
-    where = next(iter(devices)).platform if devices else "host"
-    # one batched fetch: two sequential np.asarray calls would each pay
-    # a full device round trip
-    if hasattr(v, "addressable_shards") or hasattr(ok, "addressable_shards"):
-        v, ok = jax.device_get((v, ok))
-    v, ok = _from_device_f32(v), np.asarray(ok)
-    exec_stats.set_dispatch(f"promql-row-path (window kernel on {where})")
-    exec_stats.record("window_kernel", rows=int(v.shape[0]),
-                      elapsed_s=_time.perf_counter() - t_start,
-                      steps=int(nsteps))
+    with exec_stats.stage("window", steps=int(nsteps)):
+        with exec_stats.stage("window.upload"):
+            dm = ev._device_matrix(matrix)
+            for kind in needs:
+                dm.values(kind)
+        with exec_stats.stage("window.launch"):
+            out = kernel(dm, np.int64(t0) - dm.ts_base, nsteps)
+        with exec_stats.stage("window.fetch"):
+            v, ok, w = out.values, out.ok, out.weight
+            devices = v.devices() if hasattr(v, "devices") else ()
+            where = next(iter(devices)).platform if devices else "host"
+            # one batched fetch: sequential np.asarray calls would each
+            # pay a full device round trip
+            v, ok, w = jax.device_get((v, ok, w))
+            v, ok = _from_device_f32(v), np.asarray(ok)
+            if out.base is not None:
+                base = out.base[:, None]
+                v = v + (base if w is None
+                         else np.asarray(w, dtype=np.float64) * base)
+        exec_stats.set_dispatch(
+            f"promql-row-path (window kernel on {where})")
+        exec_stats.record("window", rows=int(v.shape[0]))
     return v, ok
 
 
@@ -203,31 +286,57 @@ class PromqlEngine:
     def execute_tql(self, stmt: sqlast.Tql, ctx: QueryContext) -> Output:
         if stmt.kind not in ("eval", "evaluate", "explain", "analyze"):
             raise UnsupportedError(f"TQL {stmt.kind.upper()} not supported")
-        start_ms = _parse_tql_time(stmt.start)
-        end_ms = _parse_tql_time(stmt.end)
-        step_ms = _parse_tql_duration(stmt.step)
-        lookback = _parse_tql_duration(stmt.lookback) if stmt.lookback \
-            else DEFAULT_LOOKBACK_MS
-        expr = parse_promql(stmt.query)
-        ev = _Eval(self, ctx, start_ms, end_ms, step_ms, lookback)
         if stmt.kind == "explain":
+            expr, ev = self._plan(stmt, ctx)
             return self._explain_output(expr, None, ev=ev)
         if stmt.kind == "analyze":
-            import time as _time
-
-            from ..common import exec_stats
-            stats = exec_stats.ExecStats()
-            t0 = _time.perf_counter()
-            with exec_stats.collect(stats):
-                val = ev.eval(expr)
-            elapsed_ms = (_time.perf_counter() - t0) * 1e3
-            nseries = len(getattr(val, "labels", [])) or 1
+            stats, expr, ev, out = self._analyzed(stmt, ctx)
             return self._explain_output(expr, {
-                "elapsed_ms": round(elapsed_ms, 2),
-                "series": nseries, "steps": len(ev.steps),
+                "elapsed_ms": round(stats.total_s * 1e3, 2),
+                "series": ev.series or 1, "steps": len(ev.steps),
                 "stats": stats}, ev=ev)
-        val = ev.eval(expr)
-        return _to_record_batches(val, ev.steps)
+        expr, ev = self._plan(stmt, ctx)
+        return ev.run(expr)
+
+    def _plan(self, stmt: sqlast.Tql, ctx: QueryContext):
+        """The `plan` row of a TQL statement: its times and its query
+        text to an expression and the evaluation's step grid."""
+        from ..common import exec_stats
+        with exec_stats.stage("plan"):
+            start_ms = _parse_tql_time(stmt.start)
+            end_ms = _parse_tql_time(stmt.end)
+            step_ms = _parse_tql_duration(stmt.step)
+            lookback = _parse_tql_duration(stmt.lookback) \
+                if stmt.lookback else DEFAULT_LOOKBACK_MS
+            expr = parse_promql(stmt.query)
+            return expr, _Eval(self, ctx, start_ms, end_ms, step_ms,
+                               lookback)
+
+    def _analyzed(self, stmt: sqlast.Tql, ctx: QueryContext):
+        """Run the statement under a collector of its own -> (stats,
+        expression, evaluation, its result)."""
+        from ..common import exec_stats
+        from ..query.engine import _record_parse
+        stats = exec_stats.ExecStats()
+        with exec_stats.collect(stats):
+            _record_parse(ctx)
+            expr, ev = self._plan(stmt, ctx)
+            out = ev.run(expr)
+        return stats, expr, ev, out
+
+    def explain_tql(self, stmt: sqlast.Explain, ctx: QueryContext
+                    ) -> Output:
+        """EXPLAIN [ANALYZE] of a TQL statement. ANALYZE executes it and
+        answers the stage rows every analyzed statement has (`stage`,
+        `rows`, `files`, `elapsed_ms`, `detail`, the executed
+        `dispatch`); `TQL ANALYZE` renders the same rows as text."""
+        if not stmt.analyze:
+            expr, ev = self._plan(stmt.statement, ctx)
+            return self._explain_output(expr, None, ev=ev)
+        from ..query.engine import stage_rows_output
+        stats, expr, ev, analyzed = self._analyzed(stmt.statement, ctx)
+        return stage_rows_output(stats, self._plan_lines(expr, ev),
+                                 analyzed)
 
     def explain_lines(self, query: str, start_ms: int, end_ms: int,
                       step_ms: int, ctx: Optional[QueryContext] = None,
@@ -318,7 +427,7 @@ class PromqlEngine:
         ctx = ctx or QueryContext()
         expr = parse_promql(query)
         ev = _Eval(self, ctx, start_ms, end_ms, step_ms, lookback_ms)
-        return ev.eval(expr), ev.steps
+        return ev.evaluate(expr), ev.steps
 
     def query_to_prom_json(self, query: str, start_ms: int, end_ms: int,
                            step_ms: int, ctx: Optional[QueryContext] = None,
@@ -331,7 +440,7 @@ class PromqlEngine:
             step_ms = max(step_ms, 1)
         ev = _Eval(self, ctx, start_ms, end_ms, step_ms, lookback_ms,
                    raw_matrix_ok=instant)
-        val = ev.eval(expr)
+        val = ev.evaluate(expr)
         return _to_prom_json(val, ev.steps, instant=instant)
 
     # ------------------------------------------------------------------
@@ -376,6 +485,37 @@ def _is_sorted(gids: np.ndarray, ts: np.ndarray) -> bool:
 # evaluation
 # ---------------------------------------------------------------------------
 
+class _Outer:
+    """The `outer` row of a statement's collector: the pieces of its
+    evaluation that lie outside `select` and `window`, summed. `t0_ns`
+    is the first piece's start; each piece is open as a profiler
+    annotation of the same name."""
+
+    def __init__(self):
+        self.elapsed_s = 0.0
+        self.t0_ns: Optional[int] = None
+        self.running = False
+
+    def resume(self) -> None:
+        from ..common.telemetry import annotation
+        self._annotation = annotation("outer")
+        self._annotation.__enter__()
+        if self.t0_ns is None:
+            self.t0_ns = _time.time_ns()
+        self._t0 = _time.perf_counter()
+        self.running = True
+
+    def pause(self) -> None:
+        self.elapsed_s += _time.perf_counter() - self._t0
+        self.running = False
+        self._annotation.__exit__(None, None, None)
+
+    def record(self, rows: int) -> None:
+        from ..common import exec_stats
+        exec_stats.record("outer", rows=rows, elapsed_s=self.elapsed_s,
+                          t0_ns=self.t0_ns)
+
+
 class _Eval:
     def __init__(self, engine: PromqlEngine, ctx: QueryContext,
                  start_ms: int, end_ms: int, step_ms: int, lookback_ms: int,
@@ -398,8 +538,66 @@ class _Eval:
         # window bounds are shared across range functions over the same
         # selector (rate + avg_over_time recompute identical bounds
         # otherwise — the dominant cost at 10k-series scale)
-        self._dev_cache: Dict[int, tuple] = {}
+        self._dev_cache: Dict[int, _DeviceMatrix] = {}
         self._bounds_cache: Dict[tuple, tuple] = {}
+        #: an aggregate of this evaluation took the lowered path
+        self.lowered = False
+        #: series of the result run() shaped
+        self.series = 0
+        self._outer: Optional[_Outer] = None
+
+    # -- a whole statement --
+    def evaluate(self, e: PromExpr):
+        """eval() of the statement's root, with the statement counted by
+        the path it took and, under a collector, the `outer` row: what
+        evaluation spends outside the rows of its selectors and window
+        kernels (label grouping, vector matching, binary operators,
+        `topk`, shaping the result)."""
+        from ..common import exec_stats
+        from ..common.telemetry import increment_counter
+        self._outer = _Outer() if exec_stats.current() is not None else None
+        try:
+            with self._in_outer():
+                return self.eval(e)
+        finally:
+            increment_counter("promql_statements",
+                              path="lowered" if self.lowered else "row")
+
+    def run(self, e: PromExpr) -> Output:
+        """evaluate() and the result as record batches (TQL EVAL)."""
+        val = self.evaluate(e)
+        with self._in_outer():
+            out = _to_record_batches(val, self.steps)
+        self.series = len(getattr(val, "labels", ()))
+        if self._outer is not None:
+            self._outer.record(out.num_rows)
+        return out
+
+    @contextlib.contextmanager
+    def _in_outer(self):
+        outer = self._outer
+        if outer is None:
+            yield
+            return
+        outer.resume()
+        try:
+            yield
+        finally:
+            outer.pause()
+
+    @contextlib.contextmanager
+    def _outside_outer(self):
+        """Around what records rows of its own: select, window, the
+        lowered aggregate's scan."""
+        outer = self._outer
+        if outer is None or not outer.running:
+            yield
+            return
+        outer.pause()
+        try:
+            yield
+        finally:
+            outer.resume()
 
     # -- top-level dispatch --
     def eval(self, e: PromExpr):
@@ -444,15 +642,18 @@ class _Eval:
             ends = np.full(self.nsteps, int(at_ms) - offset_ms, np.int64)
         return ends
 
-    def _window_eval(self, sel: VectorSelector, win_ms: int, kernel):
+    def _window_eval(self, sel: VectorSelector, win_ms: int, kernel,
+                     needs: Sequence[str] = ("rel",)):
         """Shared instant/range evaluation: fetch, clip the step grid to the
         data span, run the device kernel on the in-range steps, mask the
-        rest. kernel(matrix, t0_rel, nsteps) -> (vals [S,T'], ok [S,T'])."""
+        rest. kernel(dm, t0_rel, nsteps) -> _KernelOut; `needs` names the
+        value arrays of the _DeviceMatrix it reads."""
         ends = self._grid(sel.offset_ms, sel.at_ms)
         fixed = sel.at_ms is not None
         lo = int(ends.min()) - win_ms + 1
         hi = int(ends.max())
-        selection = self.engine.select(sel, lo, hi, self.ctx)
+        with self._outside_outer():
+            selection = self.engine.select(sel, lo, hi, self.ctx)
         S = len(selection.labels)
         out_vals = np.full((S, self.nsteps), np.nan, dtype=np.float64)
         out_ok = np.zeros((S, self.nsteps), dtype=bool)
@@ -464,10 +665,11 @@ class _Eval:
             t = int(ends[0])
             if t < dmin or t - win_ms > dmax:
                 return VectorVal(selection.labels, out_vals, out_ok)
-            v, ok = _run_window_kernel(kernel, selection.matrix,
-                                       np.int64(t), 1)
-            v = v[:, :1]
-            ok = ok[:, :1]
+            with self._outside_outer():
+                v, ok = _run_window_kernel(
+                    kernel, self, selection.matrix, needs, t, 1)
+            v = v[:S, :1]               # the matrix's rows are bucketed
+            ok = ok[:S, :1]
             out_vals[:] = np.repeat(v, self.nsteps, axis=1)
             out_ok[:] = np.repeat(ok, self.nsteps, axis=1)
             return VectorVal(selection.labels, out_vals, out_ok)
@@ -480,43 +682,33 @@ class _Eval:
             return VectorVal(selection.labels, out_vals, out_ok)
         n_eval = j1 - j0 + 1
         n_pad = 1 << (n_eval - 1).bit_length() if n_eval > 1 else 1
-        v, ok = _run_window_kernel(
-            kernel, selection.matrix, np.int64(t0 + j0 * self.step), n_pad)
-        v = v[:, :n_eval]
-        ok = ok[:, :n_eval]
+        with self._outside_outer():
+            v, ok = _run_window_kernel(
+                kernel, self, selection.matrix, needs,
+                t0 + j0 * self.step, n_pad)
+        v = v[:S, :n_eval]              # the matrix's rows are bucketed
+        ok = ok[:S, :n_eval]
         out_vals[:, j0:j1 + 1] = v
         out_ok[:, j0:j1 + 1] = ok
         return VectorVal(selection.labels, out_vals, out_ok)
 
-    def _device_args(self, matrix, t0: np.int64, nsteps: int):
-        """Rebase (ts2d, t0) for int32 device transfer; arrays are
-        device_put once per matrix and reused across range functions."""
-        # the cache entry holds `matrix` itself: id() keys are only unique
-        # while the object is alive, so pin it for the evaluation
-        ent = self._dev_cache.get(id(matrix))
-        if ent is None:
-            import jax
-            ts2d, val2d, lengths, base = matrix.device_arrays()
-            if val2d.dtype == np.float64 and not jax.config.jax_enable_x64:
-                val2d = val2d.astype(np.float32)
-            if ts2d.dtype != np.int64:   # int64 stays host for the safety net
-                ts2d = jax.device_put(ts2d)
-                val2d = jax.device_put(val2d)
-                lengths = jax.device_put(lengths)
-            ent = (matrix, ts2d, val2d, lengths, base)
-            self._dev_cache[id(matrix)] = ent
-        _, ts2d, val2d, lengths, base = ent
-        return ts2d, val2d, lengths, np.int64(t0) - base
+    def _device_matrix(self, matrix) -> _DeviceMatrix:
+        """The matrix's device arrays, put once per evaluation and reused
+        across range functions."""
+        dm = self._dev_cache.get(id(matrix))
+        if dm is None:
+            dm = self._dev_cache[id(matrix)] = _DeviceMatrix(matrix)
+        return dm
 
-    def _cached_bounds(self, matrix, ts2d, t0r, win: int, nsteps: int):
+    def _cached_bounds(self, dm: _DeviceMatrix, t0r, win: int, nsteps: int):
         """Window bounds shared across range functions on one selector."""
         from ..ops.window import compute_window_bounds
-        key = (id(matrix), int(t0r), int(win), nsteps)
+        key = (id(dm.matrix), int(t0r), int(win), nsteps)
         ent = self._bounds_cache.get(key)
         if ent is None:
-            b = compute_window_bounds(ts2d, t0r, step=self.step,
+            b = compute_window_bounds(dm.ts2d, t0r, step=self.step,
                                       range_ms=int(win), nsteps=nsteps)
-            ent = (matrix, b)   # pin matrix: id() keys need it alive
+            ent = (dm.matrix, b)   # pin matrix: id() keys need it alive
             self._bounds_cache[key] = ent
         return ent[1]
 
@@ -530,39 +722,40 @@ class _Eval:
         return (win % self.step == 0 and win >= 0 and
                 win // self.step + nsteps <= self._ALIGNED_MAX_EXT)
 
-    def _aligned_eval(self, matrix, ts2d, val2d, lengths, t0r, win: int,
-                      nsteps: int):
+    def _aligned_eval(self, dm: _DeviceMatrix, t0r, win: int, nsteps: int):
         """AlignedWindowEval shared across range functions on one selector
         (step-aligned windows): one bounds pass + one stacked gather serve
-        rate, avg_over_time, and the rest of the cumsum family."""
+        rate, avg_over_time, and the rest of the cumsum family. It asks
+        the device matrix for a value array when a function first reads
+        it, so one evaluator serves whatever functions meet on the
+        selector (`rate(x[5m]) / avg_over_time(x[5m])`)."""
         from ..ops.window import AlignedWindowEval
-        key = ("awe", id(matrix), int(t0r), int(win), nsteps)
+        key = ("awe", id(dm.matrix), int(t0r), int(win), nsteps)
         ent = self._bounds_cache.get(key)
         if ent is None:
-            awe = AlignedWindowEval(ts2d, val2d, lengths, t0r, self.step,
-                                    int(win), nsteps)
-            ent = (matrix, awe)   # pin matrix: id() keys need it alive
+            awe = AlignedWindowEval(
+                dm.ts2d, functools.partial(dm.values, "rel"), dm.lengths,
+                t0r, self.step, int(win), nsteps, counter=dm.counter)
+            ent = (dm.matrix, awe)   # pin matrix: id() keys need it alive
             self._bounds_cache[key] = ent
         return ent[1]
 
-    def _bounds_for(self, matrix, ts2d, val2d, lengths, t0r, win: int,
-                    nsteps: int):
+    def _bounds_for(self, dm: _DeviceMatrix, t0r, win: int, nsteps: int):
         """Window bounds for any kernel path (None when ts stays host
         int64 for the safety net)."""
-        if ts2d.dtype == np.int64:
+        if dm.on_host:
             return None
         if self._aligned_ok(win, nsteps):
-            return self._aligned_eval(matrix, ts2d, val2d, lengths, t0r,
-                                      win, nsteps).bounds()
-        return self._cached_bounds(matrix, ts2d, t0r, win, nsteps)
+            return self._aligned_eval(dm, t0r, win, nsteps).bounds()
+        return self._cached_bounds(dm, t0r, win, nsteps)
 
     def _instant(self, sel: VectorSelector) -> VectorVal:
         from ..ops.window import instant_select
 
-        def kernel(matrix, t0, nsteps):
-            ts2d, val2d, lengths, t0r = self._device_args(matrix, t0, nsteps)
-            return instant_select(ts2d, val2d, t0r, self.step, self.lookback,
-                                  nsteps=nsteps)
+        def kernel(dm, t0r, nsteps):
+            v, ok = instant_select(dm.ts2d, dm.values("rel"), t0r,
+                                   self.step, self.lookback, nsteps=nsteps)
+            return _KernelOut(v, ok, dm.matrix.value_base)
 
         return self._window_eval(sel, self.lookback, kernel)
 
@@ -581,28 +774,46 @@ class _Eval:
         if func == "absent_over_time":
             op = "count_over_time"
 
-        def kernel(matrix, t0, nsteps):
-            ts2d, val2d, lengths, t0r = self._device_args(matrix, t0, nsteps)
-            if op in CUMSUM_OPS and ts2d.dtype != np.int64 \
-                    and self._aligned_ok(win, nsteps):
-                awe = self._aligned_eval(matrix, ts2d, val2d, lengths, t0r,
-                                         win, nsteps)
-                return awe.eval(op)
-            bounds = self._bounds_for(matrix, ts2d, val2d, lengths, t0r,
-                                      win, nsteps)
-            if op in CUMSUM_OPS:
-                return range_aggregate_cumsum(
-                    ts2d, val2d, lengths, t0r, self.step, win,
-                    op=op, nsteps=nsteps, param=param, bounds=bounds)
-            if op in GATHER_OPS:
-                maxw = int(matrix.max_len)
-                return range_aggregate_gather(
-                    ts2d, val2d, t0r, self.step, win, op=op, nsteps=nsteps,
-                    maxw=max(maxw, 2), param=param, param2=param2,
-                    bounds=bounds)
-            raise UnsupportedError(f"range function {func} not implemented")
+        # what the host adds back to a value computed on offsets from
+        # each series' first sample: nothing where the function is
+        # shift-invariant, the base where it is a value of the series,
+        # the base per sample for sum_over_time
+        counter_op = op in _COUNTER_OPS
+        needs = ("adj", "abs") if op in ("rate", "increase") else \
+            ("rel", "abs") if counter_op else ("rel",)
 
-        out = self._window_eval(sel, win, kernel)
+        def kernel(dm, t0r, nsteps):
+            matrix = dm.matrix
+
+            def run(op):
+                if op in CUMSUM_OPS and not dm.on_host \
+                        and self._aligned_ok(win, nsteps):
+                    return self._aligned_eval(dm, t0r, win, nsteps).eval(op)
+                bounds = self._bounds_for(dm, t0r, win, nsteps)
+                # rate / increase read the counter arrays alone: `rel` is
+                # neither made nor uploaded for them
+                val2d = dm.values(needs[0])
+                if op in CUMSUM_OPS:
+                    return range_aggregate_cumsum(
+                        dm.ts2d, val2d, dm.lengths, t0r, self.step, win,
+                        op=op, nsteps=nsteps, param=param, bounds=bounds,
+                        counter=dm.counter() if counter_op else None)
+                if op in GATHER_OPS:
+                    return range_aggregate_gather(
+                        dm.ts2d, val2d, t0r, self.step, win, op=op,
+                        nsteps=nsteps, maxw=max(int(matrix.max_len), 2),
+                        param=param, param2=param2, bounds=bounds)
+                raise UnsupportedError(
+                    f"range function {func} not implemented")
+
+            v, ok = run(op)
+            if op == "sum_over_time":
+                return _KernelOut(v, ok, matrix.value_base,
+                                  run("count_over_time")[0])
+            return _KernelOut(
+                v, ok, matrix.value_base if op in _ADD_BASE_OPS else None)
+
+        out = self._window_eval(sel, win, kernel, needs)
         if func == "irate":
             # irate = last difference / gap seconds; approximate gap from
             # idelta pair — recompute via two instant gathers host-side
@@ -621,29 +832,24 @@ class _Eval:
         from ..ops.window import range_aggregate_cumsum
         win = sel.range_ms
 
-        def kernel(matrix, t0, nsteps):
-            import jax
-            ts2d, val2d, lengths, t0r = self._device_args(matrix, t0, nsteps)
-            bounds = self._bounds_for(matrix, ts2d, val2d, lengths, t0r,
-                                      win, nsteps)
+        def kernel(dm, t0r, nsteps):
+            bounds = self._bounds_for(dm, t0r, win, nsteps)
             # idelta over *rebased* sample times: absolute epoch seconds
             # (~1.7e9) as float32 device values would cancel to 0 between
             # adjacent samples; a gap of relative seconds is exact
-            rel = np.asarray(ts2d, dtype=np.float64) / 1000.0
-            rel = np.where(np.asarray(matrix.ts) == _ts_pad(), 0.0, rel)
-            return range_aggregate_cumsum(
-                ts2d, jax.device_put(rel.astype(np.float32)
-                                     if val2d.dtype == np.float32 else rel),
-                lengths, t0r, self.step, win, op="idelta", nsteps=nsteps,
-                bounds=bounds)
+            v, ok = range_aggregate_cumsum(
+                dm.ts2d, _relative_seconds(dm), dm.lengths, t0r, self.step,
+                win, op="idelta", nsteps=nsteps, bounds=bounds)
+            return _KernelOut(v, ok)
 
-        return self._window_eval(sel, win, kernel)
+        return self._window_eval(sel, win, kernel, ())
 
     def _raw_matrix(self, sel: VectorSelector) -> MatrixVal:
         ends = self._grid(sel.offset_ms, sel.at_ms)
         t = int(ends[0])
-        selection = self.engine.select(sel, t - sel.range_ms + 1, t,
-                                       self.ctx)
+        with self._outside_outer():
+            selection = self.engine.select(sel, t - sel.range_ms + 1, t,
+                                           self.ctx)
         if selection.empty:
             return MatrixVal([], [], [])
         sm = selection.matrix
@@ -805,27 +1011,16 @@ class _Eval:
     def _instant_ts(self, sel: VectorSelector) -> VectorVal:
         """Instant select over the sample timestamps (seconds)."""
         from ..ops.window import instant_select
-        import jax
-        base_holder = {}
 
-        def kernel(matrix, t0, nsteps):
-            ts2d, val2d, lengths, t0r = self._device_args(matrix, t0, nsteps)
+        def kernel(dm, t0r, nsteps):
             # relative seconds on device (absolute epoch seconds lose up to
-            # ~128s as float32); the base is added back on host below
-            _, _, _, base = matrix.device_arrays()
-            base_holder["base"] = base
-            rel = np.asarray(ts2d, dtype=np.float64) / 1000.0
-            rel = np.where(np.asarray(matrix.ts) == _ts_pad(), 0.0, rel)
-            return instant_select(ts2d,
-                                  jax.device_put(rel.astype(np.float32)
-                                                 if val2d.dtype == np.float32
-                                                 else rel),
-                                  t0r, self.step, self.lookback,
-                                  nsteps=nsteps)
+            # ~128s as float32); the base is added back on the host
+            v, ok = instant_select(dm.ts2d, _relative_seconds(dm), t0r,
+                                   self.step, self.lookback, nsteps=nsteps)
+            return _KernelOut(v, ok, np.full(dm.matrix.num_series,
+                                             dm.ts_base / 1000.0))
 
-        out = self._window_eval(sel, self.lookback, kernel)
-        base_sec = base_holder.get("base", 0) / 1000.0
-        return VectorVal(out.labels, out.values + base_sec, out.ok)
+        return self._window_eval(sel, self.lookback, kernel, ())
 
     def _time_component(self, e: Call, f: str) -> VectorVal:
         import pandas as pd
@@ -961,9 +1156,12 @@ class _Eval:
         # — or that the executor degrades (cost-based raw-pull, version
         # skew, sketch decode) — evaluates on the proven row path
         from . import lowering
-        v = lowering.try_lowered_inner(self, e)
+        with self._outside_outer():
+            v = lowering.try_lowered_inner(self, e)
         if v is None:
             v = self.eval(e.expr)
+        else:
+            self.lowered = True
         if not isinstance(v, VectorVal):
             raise PromqlParseError(f"{e.op} expects an instant vector")
         param = None
@@ -1249,9 +1447,17 @@ class _Eval:
         return VectorVal(labels, np.asarray(vals), np.asarray(oks))
 
 
-def _ts_pad():
+def _relative_seconds(dm: _DeviceMatrix):
+    """A matrix's sample times in seconds from its first sample, as a
+    value array (padding 0), where its timestamps are."""
     from ..ops.window import TS_PAD
-    return TS_PAD
+    if dm.on_host:
+        rel = (dm.ts2d - dm.ts_base) / 1000.0
+        return np.where(dm.ts2d == TS_PAD, 0.0, rel)
+    import jax.numpy as jnp
+    pad = jnp.iinfo(dm.ts2d.dtype).max
+    fv = jnp.float32 if dm.f32 else jnp.float64
+    return jnp.where(dm.ts2d == pad, 0, dm.ts2d.astype(fv) / 1000)
 
 
 def _masked_quantile_np(vals: np.ndarray, ok: np.ndarray, q: float
@@ -1372,23 +1578,22 @@ def _to_record_batches(val, steps: np.ndarray) -> Output:
         return Output.record_batches([RecordBatch.from_pydict(schema, data)])
     if not isinstance(val, VectorVal):
         raise UnsupportedError("TQL result must be a vector or scalar")
+    # a column at a time: the present points in series-major order, a
+    # series' label value repeated over its points
     label_keys = sorted({k for lbl in val.labels for k in lbl})
-    cols: Dict[str, list] = {k: [] for k in label_keys}
-    ts_out, v_out = [], []
-    for i, lbl in enumerate(val.labels):
-        for j in np.nonzero(val.ok[i])[0]:
-            for k in label_keys:
-                cols[k].append(lbl.get(k, ""))
-            ts_out.append(int(steps[j]))
-            v_out.append(float(val.values[i, j]))
+    series, step = np.nonzero(val.ok)
+    data: Dict[str, list] = {
+        k: np.array([lbl.get(k, "") for lbl in val.labels],
+                    dtype=object)[series].tolist()
+        for k in label_keys}
+    data["ts"] = steps[step].tolist()
+    data["value"] = np.asarray(val.values, dtype=np.float64)[
+        series, step].tolist()
     schema = Schema(
         [ColumnSchema(k, dt.STRING) for k in label_keys] +
         [ColumnSchema("ts", dt.TIMESTAMP_MILLISECOND, nullable=False,
                       semantic_type=SemanticType.TIMESTAMP),
          ColumnSchema("value", dt.FLOAT64)])
-    data = dict(cols)
-    data["ts"] = ts_out
-    data["value"] = v_out
     return Output.record_batches([RecordBatch.from_pydict(schema, data)])
 
 

@@ -213,8 +213,12 @@ def try_lower(ev, e: Aggregate):
     elif func in ("rate", "increase", "delta"):
         aggs += [("__first", "first", field), ("__last", "last", field)]
         mspec += [("__mnt", "min_ts", field), ("__mxt", "max_ts", field)]
-        if func != "delta":
-            mspec.append(("__corr", "reset_corr", field))
+        # for delta too, which does not read it: a host-only moment
+        # keeps the whole fold in float64 on the host. last - first of
+        # the device's f32 mirrors has no digits left for a window's
+        # growth once the level is large (a gauge at 1e12 that moves by
+        # 6e4 a window came out 31% off)
+        mspec.append(("__corr", "reset_corr", field))
     elif func in ("last_over_time",):
         aggs.append(("__v", "last", field))
     elif func != "count_over_time":
@@ -369,8 +373,10 @@ def try_lowered_inner(ev, e: Aggregate):
     via the IR, or None to keep the row path. Degrades (never errors)
     when the executor rejects the plan — cost-based raw-pull, a
     version-skewed datanode, a sketch decode failure."""
+    from ..common import exec_stats
     from .engine import VectorVal
-    low, _reason = try_lower(ev, e)
+    with exec_stats.stage("plan"):
+        low, _reason = try_lower(ev, e)
     if low is EMPTY:
         T = ev.nsteps
         return VectorVal([], np.zeros((0, T)), np.zeros((0, T), bool))
@@ -478,7 +484,29 @@ def select_series(engine, sel: VectorSelector, lo_ms: int, hi_ms: int,
     scan cache / streamed cold reads / SST-index sid pruning); a
     DistTable whose datanodes are remote has no in-process regions, so
     the same selector is served by an IR RawScan over the wire —
-    pruned, filter-pushed, never silently empty."""
+    pruned, filter-pushed, never silently empty.
+
+    The `select` row of the statement's collector, with its parts:
+    `.scan` (the region's rows as host arrays), `.filter` (matchers to a
+    series mask, then to the rows kept), `.labels` (the kept series'
+    label sets) and `.matrix` (the [series, samples] matrix built)."""
+    from ..common import exec_stats
+    from ..common.telemetry import increment_counter
+    with exec_stats.stage("select"):
+        selection = _select_series(engine, sel, lo_ms, hi_ms, ctx)
+        sm = selection.matrix
+        if sm is not None:
+            exec_stats.record("select", rows=len(selection.labels))
+            increment_counter("promql_series_selected",
+                              len(selection.labels))
+            increment_counter("promql_matrix_cells",
+                              sm.num_series * sm.max_len)
+    return selection
+
+
+def _select_series(engine, sel: VectorSelector, lo_ms: int, hi_ms: int,
+                   ctx):
+    from ..common.exec_stats import stage
     from ..ops.window import SeriesMatrix
     from .engine import (
         _is_sorted, _label_str, _matcher_keep, _matches_empty, _Selection,
@@ -515,88 +543,230 @@ def select_series(engine, sel: VectorSelector, lo_ms: int, hi_ms: int,
     ref_idx = sorted({tag_names.index(m.name) for m in sel.matchers
                       if m.name in tagset})
     for region in regions.values():
-        sid_set = matcher_sids(region, tag_names, eq_matchers)
+        with stage("select.filter"):
+            sid_set = matcher_sids(region, tag_names, eq_matchers)
         if sid_set is not None and len(sid_set) == 0:
             continue                 # no series of this region match
-        scan = region_scan(region, fields, lo_ms, hi_ms, sid_set=sid_set)
+        with stage("select.scan"):
+            scan = region_scan(region, fields, lo_ms, hi_ms,
+                               sid_set=sid_set)
         if scan is None or scan.num_rows == 0:
             continue
         sd = scan.series_dict
         S = sd.num_series
         if S == 0:
             continue
-        ids = np.arange(S, dtype=np.int32)
-        tag_strs: Dict[int, List[str]] = {
-            i: [_label_str(v) for v in sd.decode_tag_column(ids, i)]
-            for i in ref_idx}
-        keep = np.ones(S, dtype=bool)
-        for m in sel.matchers:
-            if m.name in ("__name__", "__field__"):
+        with stage("select.filter"):
+            ids = np.arange(S, dtype=np.int32)
+            tag_strs: Dict[int, List[str]] = {
+                i: [_label_str(v) for v in sd.decode_tag_column(ids, i)]
+                for i in ref_idx}
+            keep = np.ones(S, dtype=bool)
+            for m in sel.matchers:
+                if m.name in ("__name__", "__field__"):
+                    continue
+                if m.name not in tagset:
+                    # matching a non-existent label: only ""-matching
+                    # ops keep
+                    if not _matches_empty(m):
+                        keep[:] = False
+                    continue
+                keep &= _matcher_keep(tag_strs[tag_names.index(m.name)], m)
+            if not keep.any():
                 continue
-            if m.name not in tagset:
-                # matching a non-existent label: only ""-matching ops keep
-                if not _matches_empty(m):
-                    keep[:] = False
+        if len(regions) == 1 and not multi_field:
+            with stage("select.matrix"):
+                direct = _matrix_from_runs(scan, fields[0], keep, sid_set,
+                                           lo_ms, hi_ms)
+            if direct is not None:
+                return _selection_from_runs(direct, sd, metric, tag_names,
+                                            tag_strs)
+        with stage("select.filter"):
+            rows = _rows_kept(scan, keep, sid_set, lo_ms, hi_ms)
+            if not len(rows):
                 continue
-            keep &= _matcher_keep(tag_strs[tag_names.index(m.name)], m)
-        if not keep.any():
-            continue
-        row_keep = keep[scan.series_ids] & (scan.ts >= lo_ms) & \
-            (scan.ts <= hi_ms)
-        if not row_keep.any():
-            continue
+            survivors = np.unique(scan.series_ids[rows]).astype(np.int32)
 
         # decode the remaining tag columns only for surviving series
-        survivors = np.unique(scan.series_ids[row_keep]).astype(np.int32)
-        label_of: Dict[int, tuple] = {}
-        cols = {i: tag_strs[i] if i in tag_strs else
-                [_label_str(v) for v in
-                 sd.decode_tag_column(survivors, i)]
-                for i in range(len(tag_names))}
-        for j, s in enumerate(survivors):
-            label_of[int(s)] = tuple(
-                cols[i][int(s)] if i in ref_idx else cols[i][j]
-                for i in range(len(tag_names)))
+        with stage("select.labels"):
+            label_of = dict(zip(survivors.tolist(), _label_keys(
+                sd, len(tag_names), tag_strs, survivors)))
 
         for fname in fields:
-            vals, valid = scan.fields[fname]
-            rk = row_keep if valid is None else (row_keep & valid)
-            if not rk.any():
-                continue
-            sids = scan.series_ids[rk]
-            ts = scan.ts[rk]
-            v = vals[rk].astype(np.float64)
+            with stage("select.filter"):
+                vals, valid = scan.fields[fname]
+                rk = rows if valid is None else rows[valid[rows]]
+                if not len(rk):
+                    continue
+                sids = scan.series_ids[rk]
+                ts = scan.ts[rk]
+                v = vals[rk].astype(np.float64)
             # map region series → global series ids
-            uniq = np.unique(sids)
-            remap = np.full(S, -1, dtype=np.int32)
-            for s in uniq:
-                lbl_key = label_of[int(s)]
-                gkey = lbl_key + ((fname,) if multi_field else ())
-                gid = key_to_gid.get(gkey)
-                if gid is None:
-                    gid = len(glabels)
-                    key_to_gid[gkey] = gid
-                    lbl = {"__name__": metric}
-                    for tn, tv in zip(tag_names, lbl_key):
-                        if tv != "":
-                            lbl[tn] = tv
-                    if multi_field:
-                        lbl["__field__"] = fname
-                    glabels.append(lbl)
-                remap[s] = gid
-            parts.append((remap[sids], ts, v))
+            with stage("select.labels"):
+                uniq = np.unique(sids)
+                remap = np.full(S, -1, dtype=np.int32)
+                for s in uniq:
+                    lbl_key = label_of[int(s)]
+                    gkey = lbl_key + ((fname,) if multi_field else ())
+                    gid = key_to_gid.get(gkey)
+                    if gid is None:
+                        gid = len(glabels)
+                        key_to_gid[gkey] = gid
+                        lbl = {"__name__": metric}
+                        for tn, tv in zip(tag_names, lbl_key):
+                            if tv != "":
+                                lbl[tn] = tv
+                        if multi_field:
+                            lbl["__field__"] = fname
+                        glabels.append(lbl)
+                    remap[s] = gid
+                parts.append((remap[sids], ts, v))
 
     if not parts:
         return _Selection([], None)
-    gids = np.concatenate([p[0] for p in parts])
-    ts = np.concatenate([p[1] for p in parts])
-    vals = np.concatenate([p[2] for p in parts])
-    # already sorted when a single region/field contributed in order
-    if len(parts) > 1 or not _is_sorted(gids, ts):
-        order = np.lexsort((ts, gids))
-        gids, ts, vals = gids[order], ts[order], vals[order]
-    sm = SeriesMatrix.build(gids, ts, vals, len(glabels))
-    return _Selection(glabels, sm, int(ts.min()), int(ts.max()))
+    with stage("select.matrix"):
+        gids, ts, vals = parts[0] if len(parts) == 1 else (
+            np.concatenate([p[i] for p in parts]) for i in range(3))
+        # already sorted when a single region/field contributed in order
+        if len(parts) > 1 or not _is_sorted(gids, ts):
+            order = np.lexsort((ts, gids))
+            gids, ts, vals = gids[order], ts[order], vals[order]
+        sm = SeriesMatrix.build(gids, ts, vals,
+                                series_bucket(len(glabels)))
+        return _Selection(glabels, sm, int(ts.min()), int(ts.max()))
+
+
+def _label_keys(sd, ntags: int, tag_strs: Dict[int, List[str]],
+                survivors: np.ndarray) -> List[tuple]:
+    """The surviving series' label values, one tuple a series in tag
+    order. `tag_strs` holds the columns the matchers already decoded
+    (for every series of the dictionary); the rest decode here, for the
+    survivors only."""
+    from .engine import _label_str
+    cols = []
+    for i in range(ntags):
+        if i in tag_strs:
+            whole = tag_strs[i]
+            cols.append([whole[s] for s in survivors.tolist()])
+        else:
+            cols.append([_label_str(v)
+                         for v in sd.decode_tag_column(survivors, i)])
+    return list(zip(*cols)) if cols else [()] * len(survivors)
+
+
+def _bisect_runs(ts: np.ndarray, first: np.ndarray, end: np.ndarray,
+                 t: int, after: bool) -> np.ndarray:
+    """Per run [first, end) of a time-sorted array the first position
+    whose time is >= t (or > t with `after`): every run bisected at
+    once, one gather of a sample a run and step."""
+    lo, hi = first.copy(), end.copy()
+    last = len(ts) - 1
+    while True:
+        open_ = lo < hi
+        if not open_.any():
+            return lo
+        mid = (lo + hi) >> 1
+        v = ts[np.minimum(mid, last)]
+        right = open_ & ((v <= t) if after else (v < t))
+        lo = np.where(right, mid + 1, lo)
+        hi = np.where(open_ & ~right, mid, hi)
+
+
+def _matrix_from_runs(scan, field: str, keep: np.ndarray, sid_set,
+                      lo_ms: int, hi_ms: int):
+    """The selection's matrix cut straight from the scan cache's rows,
+    which lie sorted by series and then time: a series' samples in
+    [lo_ms, hi_ms] are ONE slice of them, found by bisection, and the
+    [series, samples] matrix is two row-wise gathers. The general path
+    (a mask over every row, flat copies of the kept rows, a sort check,
+    a scatter into the matrix) reads and writes the selection some
+    twenty times over; a panel over a whole table is bound by exactly
+    those passes. -> (kept series ids, SeriesMatrix, first time, last
+    time), () when no series has a sample there, or None where only the
+    general path applies (rows of a cold read, a field with nulls)."""
+    from ..ops.window import TS_PAD, SeriesMatrix
+    from ..query.tpu_exec import MergedScan
+    vals, valid = scan.fields[field]
+    if valid is not None or not isinstance(scan, MergedScan):
+        return None
+    sids = np.nonzero(keep)[0] if sid_set is None else \
+        sid_set[sid_set < len(keep)]
+    sids = sids[keep[sids]]
+    first = np.searchsorted(scan.series_ids, sids, side="left")
+    end = np.searchsorted(scan.series_ids, sids, side="right")
+    start = _bisect_runs(scan.ts, first, end, lo_ms, after=False)
+    count = _bisect_runs(scan.ts, start, end, hi_ms, after=True) - start
+    has = count > 0
+    if not has.any():
+        return ()
+    sids, start, count = sids[has], start[has], count[has]
+    width = int(count.max())
+    width = 1 << (width - 1).bit_length() if width > 1 else 1
+    rows = series_bucket(len(sids))
+    lengths = np.zeros(rows, dtype=np.int32)
+    lengths[:len(sids)] = count
+    begin = np.zeros(rows, dtype=np.int64)
+    begin[:len(sids)] = start
+    cell = np.arange(width)[None, :]
+    pad = cell >= lengths[:, None]
+    at = np.minimum(begin[:, None] + cell, len(scan.ts) - 1)
+    ts2d = scan.ts[at]
+    ts2d[pad] = TS_PAD
+    val2d = vals[at].astype(np.float64, copy=False)
+    val2d[pad] = 0.0
+    return (sids, SeriesMatrix(ts2d, val2d, lengths),
+            int(scan.ts[start].min()), int(scan.ts[start + count - 1].max()))
+
+
+def _selection_from_runs(direct, sd, metric: str, tag_names: List[str],
+                         tag_strs: Dict[int, List[str]]):
+    from ..common.exec_stats import stage
+    from .engine import _Selection
+    if not direct:
+        return _Selection([], None)
+    sids, sm, data_min, data_max = direct
+    with stage("select.labels"):
+        glabels = []
+        for key in _label_keys(sd, len(tag_names), tag_strs,
+                               sids.astype(np.int32)):
+            lbl = {"__name__": metric}
+            for tn, tv in zip(tag_names, key):
+                if tv != "":
+                    lbl[tn] = tv
+            glabels.append(lbl)
+    return _Selection(glabels, sm, data_min, data_max)
+
+
+def _rows_kept(scan, keep: np.ndarray, sid_set, lo_ms: int, hi_ms: int
+               ) -> np.ndarray:
+    """Positions of the scan's rows whose series is kept and whose time
+    lies in [lo_ms, hi_ms], ascending. Where equality matchers resolved
+    candidate series (`matcher_sids`) and the rows are the scan cache's
+    (sorted by series, then time), only the candidates' runs are read:
+    one node's panel then touches 64 runs of a table of 11.5M rows, not
+    every row of it three times."""
+    from ..query.tpu_exec import MergedScan
+    if sid_set is None or not isinstance(scan, MergedScan):
+        return np.nonzero(keep[scan.series_ids] & (scan.ts >= lo_ms)
+                          & (scan.ts <= hi_ms))[0]
+    first = np.searchsorted(scan.series_ids, sid_set, side="left")
+    counts = np.searchsorted(scan.series_ids, sid_set, side="right") - first
+    rows = np.repeat(first - (np.cumsum(counts) - counts), counts) \
+        + np.arange(int(counts.sum()))
+    ts = scan.ts[rows]
+    return rows[keep[scan.series_ids[rows]] & (ts >= lo_ms) & (ts <= hi_ms)]
+
+
+def series_bucket(n: int) -> int:
+    """Rows of a selection's matrix: the next power of two up to 1,024,
+    the next multiple of 1,024 above. A fleet whose targets come and go
+    selects another count of series at every refresh of a panel (1,020
+    or 1,010 of 1,000 live targets with 1% replaced every 10 min), and
+    every new count would be a new program to compile; the empty rows
+    answer nothing."""
+    if n <= 1024:
+        return 1 << (n - 1).bit_length() if n > 1 else 1
+    return -(-n // 1024) * 1024
 
 
 def _wire_scan_selection(table, sel: VectorSelector, metric: str,
@@ -686,7 +856,7 @@ def _wire_scan_selection(table, sel: VectorSelector, metric: str,
     if not _is_sorted(gids, tsa):
         order = np.lexsort((tsa, gids))
         gids, tsa, vals = gids[order], tsa[order], vals[order]
-    sm = SeriesMatrix.build(gids, tsa, vals, len(glabels))
+    sm = SeriesMatrix.build(gids, tsa, vals, series_bucket(len(glabels)))
     return _Selection(glabels, sm, int(tsa.min()), int(tsa.max()))
 
 

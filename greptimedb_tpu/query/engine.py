@@ -185,22 +185,7 @@ class QueryEngine:
             if isinstance(inner, Query):
                 analyzed = self._execute_query_inner(inner, ctx)
         self.last_exec_stats = stats
-        # the plan row leads (with the time planning took), so the
-        # dispatch line stays next to the plan shape it annotates
-        cols = stats.rows_table(
-            "\n".join(plan_lines),
-            analyzed.num_rows if analyzed is not None else 0)
-        schema = Schema([ColumnSchema("stage", dt.STRING),
-                         ColumnSchema("rows", dt.INT64),
-                         ColumnSchema("files", dt.INT64),
-                         ColumnSchema("elapsed_ms", dt.FLOAT64),
-                         ColumnSchema("detail", dt.STRING)])
-        rb = RecordBatch.from_pydict(schema, cols)
-        out = Output.record_batches([rb], schema)
-        # the protocol writer encodes the analysed result too, discards
-        # the bytes and appends what that took as the `render` row
-        out.analyzed = analyzed
-        return out
+        return stage_rows_output(stats, plan_lines, analyzed)
 
     # ---- SELECT ----
     def execute_query(self, query: Query, ctx: QueryContext) -> Output:
@@ -1001,6 +986,28 @@ class QueryEngine:
         if query.limit is not None:
             proj = proj.iloc[:query.limit]
         return proj
+
+
+def stage_rows_output(stats: "exec_stats.ExecStats", plan_lines: List[str],
+                      analyzed: Optional[Output]) -> Output:
+    """EXPLAIN ANALYZE's answer: one row a stage (`stage`, `rows`,
+    `files`, `elapsed_ms`, `detail`), for a SQL and a TQL statement
+    alike. The plan row leads (with the time planning took), so the
+    dispatch line stays next to the plan shape it annotates."""
+    cols = stats.rows_table(
+        "\n".join(plan_lines),
+        analyzed.num_rows if analyzed is not None else 0)
+    schema = Schema([ColumnSchema("stage", dt.STRING),
+                     ColumnSchema("rows", dt.INT64),
+                     ColumnSchema("files", dt.INT64),
+                     ColumnSchema("elapsed_ms", dt.FLOAT64),
+                     ColumnSchema("detail", dt.STRING)])
+    out = Output.record_batches([RecordBatch.from_pydict(schema, cols)],
+                                schema)
+    # the protocol writer encodes the analysed result too, discards
+    # the bytes and appends what that took as the `render` row
+    out.analyzed = analyzed
+    return out
 
 
 def _record_parse(ctx: QueryContext) -> None:

@@ -1,0 +1,663 @@
+"""A Prometheus fleet on the served path (ISSUE 28): the node_exporter
+deployment of `benchmark/configs/prom-node-1k.json` at its debug size (20
+targets, 30 min), every family of the `prom-dashboard` mix through
+`do_query("TQL EVAL ...")` against the float64 reference
+(`benchmark/benchlib/promref.py`), counters that have run for a month,
+`EXPLAIN ANALYZE TQL EVAL`, the TQL span rows and counters, and the
+tables a remote write makes against the generator's.
+"""
+
+import json
+import os
+import re
+import sys
+import urllib.parse
+import urllib.request
+
+import numpy as np
+import pytest
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+from benchlib import check as chk  # noqa: E402
+from benchlib import promref as ref  # noqa: E402
+from benchlib.loops import family_rng  # noqa: E402
+from benchlib.spec import (load_family, load_generator,  # noqa: E402
+                           load_json)
+
+from greptimedb_tpu.datanode.instance import (  # noqa: E402
+    DatanodeInstance, DatanodeOptions)
+from greptimedb_tpu.datatypes.record_batch import (  # noqa: E402
+    arrow_to_ingest_columns)
+from greptimedb_tpu.frontend.instance import FrontendInstance  # noqa: E402
+from greptimedb_tpu.ops.window import (  # noqa: E402
+    SeriesMatrix, range_aggregate_cumsum, range_aggregate_gather)
+from greptimedb_tpu.servers import prometheus as prom  # noqa: E402
+from greptimedb_tpu.servers.http import HttpServer  # noqa: E402
+
+SEED = 2147483659
+CONFIG = load_json(BENCH, "configs", "prom-node-1k.json")
+MIX = load_json(BENCH, "traffic", "prom-dashboard.json")
+FAMILIES = MIX["families"]
+ROW_PATH_HERE = "promql-row-path (window kernel on cpu)"
+T0_NS = re.compile(r"t0_ns=(\d+)$")
+
+
+def dataset(seed=SEED):
+    size = CONFIG["debug"]
+    return load_generator(CONFIG)(
+        CONFIG, seed, scale=size["scale"],
+        ticks=size["duration_s"] // CONFIG["log_interval_s"])
+
+
+def frontend(data_home: str) -> FrontendInstance:
+    fe = FrontendInstance(DatanodeInstance(DatanodeOptions(
+        data_home=data_home, register_numbers_table=False)))
+    fe.start()
+    return fe
+
+
+def rows_of(out) -> list:
+    """An Output's rows as the HTTP writer would send them."""
+    return [list(r) for b in out.batches for r in b.rows()]
+
+
+class Fleet:
+    """One frontend with the debug-size deployment loaded the way the
+    harness loads it (the generator's Arrow tables through the bulk
+    path), behind an HTTP server."""
+
+    def __init__(self, data_home: str):
+        self.ds = dataset()
+        self.fe = frontend(data_home)
+        self.fe.do_query(self.ds.create_table_sql())
+        loaded = 0
+        for name, tags, table in self.ds.arrow_chunks(
+                CONFIG["debug"]["load_chunk_ticks"]):
+            target = self.fe.catalog.table("greptime", "public", name)
+            loaded += self.fe.handle_bulk_load(
+                name, arrow_to_ingest_columns(table, target.schema,
+                                              extra="keep"),
+                tag_columns=tags, timestamp_column=self.ds.time_index)
+        assert loaded == self.ds.rows
+        self.http = HttpServer(self.fe, addr="127.0.0.1:0")
+        self.http.start()
+
+    def close(self):
+        self.http.shutdown()
+        self.fe.shutdown()
+
+    def get(self, path: str, params=None) -> bytes:
+        url = f"http://127.0.0.1:{self.http.port}{path}"
+        if params:
+            url += "?" + urllib.parse.urlencode(params)
+        with urllib.request.urlopen(url, timeout=120) as r:
+            return r.read()
+
+    def sql(self, sql: str) -> list:
+        body = json.loads(self.get("/v1/sql", {"sql": sql}))
+        assert body["code"] == 0, body
+        return body["output"][-1]["records"]["rows"]
+
+    def counters(self) -> dict:
+        out = {}
+        for line in self.get("/metrics").decode().splitlines():
+            if line.startswith("greptime_promql_"):
+                name, _, value = line.rpartition(" ")
+                out[name] = float(value)
+        return out
+
+    def judge(self, fam, params) -> dict:
+        sql = fam.sql(params, self.ds)
+        got = fam.parse(rows_of(self.fe.do_query(sql)[-1]), self.ds)
+        return chk.compare(got, fam.reference(params, self.ds),
+                           fam.tolerance)
+
+
+@pytest.fixture(scope="module")
+def fleet(tmp_path_factory):
+    f = Fleet(str(tmp_path_factory.mktemp("fleet")))
+    yield f
+    f.close()
+
+
+def stage_rows(rows) -> dict:
+    assert all(len(r) == 5 for r in rows), "the table keeps five columns"
+    return {r[0]: (int(r[1]), float(r[3]), r[4] or "") for r in rows}
+
+
+# ---------------------------------------------------------------------------
+# the six families against the reference
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("name", FAMILIES)
+def test_family_answers_the_reference(fleet, name):
+    fam = load_family(name)
+    rng = family_rng(SEED, name, "window")
+    for _ in range(3):
+        params = fam.draw(rng, fleet.ds)
+        res = fleet.judge(fam, params)
+        assert res["ok"] and res["rows"] > 0, (params, res)
+    assert fam.dispatch == ROW_PATH_HERE    # the debug size's platform
+
+
+@pytest.mark.parametrize("name", FAMILIES)
+def test_family_executes_where_its_file_says(fleet, name):
+    fam = load_family(name)
+    params = fam.draw(family_rng(SEED, name, "explain"), fleet.ds)
+    stages = stage_rows(rows_of(fleet.fe.do_query(
+        "EXPLAIN ANALYZE " + fam.sql(params, fleet.ds))[-1]))
+    assert stages["dispatch"][2] == fam.dispatch
+
+
+def special_targets(ds) -> dict:
+    ended = int(np.argmax(np.where(ds.last < ds.ticks, ds.last, -1)))
+    began = int(np.nonzero(ds.first > 0)[0][0])
+    rebooted = int(np.nonzero(ds.reboot_tick >= 0)[0][0])
+    oldest = int(np.argmax(np.where(
+        (ds.first == 0) & (ds.last == ds.ticks) & (ds.reboot_tick < 0),
+        ds.uptime_s, -1.0)))
+    return {"ended": ended, "began": began, "rebooted": rebooted,
+            "oldest": oldest}
+
+
+@pytest.mark.parametrize("which", ["ended", "began", "rebooted", "oldest"])
+def test_one_nodes_panel_of_a_target_that(fleet, which):
+    """A target that churned out or in, one whose counters restarted at
+    0 inside the span, and the one that has been up longest (its idle
+    counters are the largest of the fleet): `sum by (mode) (rate(...))`
+    of each, at every end the mix can draw."""
+    ds = fleet.ds
+    target = special_targets(ds)[which]
+    fam = load_family("prom-cpu-by-mode-1")
+    fam.draw(family_rng(SEED, "x", "x"), ds)       # notes the debug size
+    if which == "rebooted":
+        cpu = ds.samples("node_cpu_seconds_total")
+        one = cpu.values[cpu.labels["instance"] == ds.instances[target]]
+        assert (np.diff(one, axis=1) < 0).any(axis=1).all()
+    for end_s in (1200, 1500, 1800):
+        res = fleet.judge(fam, {"end_s": end_s,
+                                "instance": ds.instances[target]})
+        assert res["ok"] and res["rows"] > 0, (which, end_s, res)
+
+
+def test_the_fleet_has_a_counter_near_a_month_of_cpu_seconds(fleet):
+    """The data the families are judged on holds what breaks f32 as it
+    is: an idle counter above 1e6 s, a byte counter above 1e12."""
+    ds = fleet.ds
+    assert ds.samples("node_cpu_seconds_total").values.max() > 1e6
+    assert ds.samples("node_network_receive_bytes_total").values.max() > 1e12
+
+
+# ---------------------------------------------------------------------------
+# counters that have run for a month, by hand
+# ---------------------------------------------------------------------------
+
+COUNTERS = {
+    # name: (start, growth a second, restart at sample or None)
+    "cpu_2_6e6": (2.6e6, 0.93, None),
+    "bytes_1e12": (1e12, 1000.137, None),
+    "bytes_1e12_reset": (1e12, 1000.137, 100),
+    "from_zero": (0.0, 0.93, None),
+}
+
+
+@pytest.fixture(scope="module")
+def counters(tmp_path_factory):
+    fe = frontend(str(tmp_path_factory.mktemp("counters")))
+    fe.do_query("CREATE TABLE c (name STRING, greptime_timestamp TIMESTAMP "
+                "TIME INDEX, greptime_value DOUBLE, PRIMARY KEY(name))")
+    values = []
+    for name, (start, growth, reset) in COUNTERS.items():
+        for k in range(180):
+            v = start + growth * 10 * k if reset is None or k < reset \
+                else growth * 10 * (k - reset + 1)
+            values.append(f"('{name}', {k * 10_000}, {v!r})")
+    fe.do_query("INSERT INTO c VALUES " + ", ".join(values))
+    yield fe
+    fe.do_query("SET tpu_dispatch_min_rows = 131072")
+    fe.shutdown()
+
+
+def tql(fe, query: str, start=600, end=1500, step="15s") -> dict:
+    rows = rows_of(fe.do_query(
+        f"TQL EVAL ({start}, {end}, '{step}') {query}")[-1])
+    out = {}
+    for r in rows:      # `name` is the last label column
+        out.setdefault(r[-3], []).append(float(r[-1]))
+    return out
+
+
+@pytest.mark.parametrize("name", sorted(COUNTERS))
+def test_rate_of_a_counter_that_has_run_for_a_month(counters, name):
+    """`rate(c[5m])` at a 15 s step over 10 s samples: a byte counter at
+    1e12 growing 1,000.137 B/s (13% off when cast to f32 as it is), a
+    CPU's seconds after 30 days, and the same across a restart at 0,
+    within the tolerance the dashboard's rate families state."""
+    tol = load_family("prom-cpu-by-mode-1").tolerance["rtol"]
+    growth = COUNTERS[name][1]
+    got = tql(counters, f'rate(c{{name="{name}"}}[5m])')[name]
+    assert len(got) == 61
+    assert np.abs(np.array(got) / growth - 1).max() < tol / 2, got
+
+
+@pytest.mark.parametrize("func, exact", [
+    ("increase(c[5m])", 300 * 1000.137),
+    ("delta(c[5m])", 300 * 1000.137),
+    ("idelta(c[5m])", 10 * 1000.137),
+    ("deriv(c[5m])", 1000.137),
+    ("irate(c[5m])", 1000.137),
+])
+def test_shift_invariant_functions_keep_their_digits(counters, func, exact):
+    got = tql(counters, func.replace("c[", 'c{name="bytes_1e12"}['))
+    assert np.abs(np.array(got["bytes_1e12"]) / exact - 1).max() < 2e-5
+
+
+@pytest.mark.parametrize("func, of", [
+    ("last_over_time(c[5m])", lambda w: w[-1]),
+    ("avg_over_time(c[5m])", np.mean),
+    ("min_over_time(c[5m])", np.min),
+    ("max_over_time(c[5m])", np.max),
+    ("sum_over_time(c[5m])", np.sum),
+    ("quantile_over_time(0.5, c[5m])", np.median),
+    ("predict_linear(c[5m], 600)", lambda w: w[-1] + 600 * 1000.137),
+    ("c", lambda w: w[-1]),
+])
+def test_values_of_a_series_get_their_level_back(counters, func, of):
+    """Functions whose result is a value of the series are computed on
+    offsets from its first sample and get the level back in float64: at
+    1e12 they answer to a byte where f32 as it is steps by 65,536."""
+    start, growth, _ = COUNTERS["bytes_1e12"]
+    got = tql(counters, func.replace("c[", 'c{name="bytes_1e12"}[')
+              if "[" in func else 'c{name="bytes_1e12"}',
+              start=900, end=900)["bytes_1e12"]
+    # the window (600 s, 900 s] holds samples 61..90
+    window = start + growth * 10 * np.arange(61, 91)
+    want = float(of(window))
+    assert abs(got[0] - want) <= 2e-6 * (want - 30 * start
+                                         if func.startswith("sum")
+                                         else want - start) + 1.0, \
+        (func, got, want)
+
+
+def test_a_lowered_delta_keeps_its_digits(counters):
+    """A tumbling window lowers to first / last moments; over the f32
+    mirrors `delta` of a gauge at 1e12 came out 31% off. The fold runs in
+    float64 on the host, as `rate`'s always did."""
+    counters.do_query("SET tpu_dispatch_min_rows = 1")
+    for func, exact in (("delta", 60 * 1000.137), ("rate", 1000.137)):
+        got = tql(counters, f'sum by (name) ({func}(c{{name="bytes_1e12"}}'
+                  '[1m]))', step="1m")["bytes_1e12"]
+        assert np.abs(np.array(got) / exact - 1).max() < 1e-6, (func, got)
+
+
+@pytest.mark.parametrize("n, rows", [(1, 1), (64, 64), (66, 128),
+                                     (1010, 1024), (1020, 1024),
+                                     (8160, 8192), (65280, 65536)])
+def test_a_selections_rows_are_bucketed(n, rows):
+    """1% of a fleet replaced every 10 min selects 1,020 or 1,010 x k
+    series by the window a panel reads: one program, not two."""
+    from greptimedb_tpu.promql.lowering import series_bucket
+    assert series_bucket(n) == rows
+
+
+def test_candidate_runs_select_the_rows_the_mask_selects(fleet):
+    """`_rows_kept` reads only the runs of the series an equality
+    matcher resolved (the scan cache's rows are sorted by series): the
+    same rows as the mask over every row of the table."""
+    from greptimedb_tpu.promql import lowering
+    from greptimedb_tpu.query.tpu_exec import SCAN_CACHE
+    table = fleet.fe.catalog.table("greptime", "public",
+                                   "node_cpu_seconds_total")
+    (region,) = table.regions.values()
+    scan = SCAN_CACHE.get(region)
+    series = scan.series_dict.num_series
+    rng = np.random.default_rng(0)
+    candidates = np.nonzero(rng.random(series) < 0.5)[0].astype(np.int32)
+    keep = np.zeros(series, dtype=bool)
+    keep[candidates[::3]] = True        # candidates are a superset
+    lo, hi = fleet.ds.ms(30), fleet.ds.ms(120)
+    by_runs = lowering._rows_kept(scan, keep, candidates, lo, hi)
+    by_mask = lowering._rows_kept(scan, keep, None, lo, hi)
+    assert len(by_mask) > 0 and by_runs.tolist() == by_mask.tolist()
+    nothing = lowering._rows_kept(scan, keep, candidates[:0], lo, hi)
+    assert len(nothing) == 0
+
+
+@pytest.mark.parametrize("selector, lo, hi", [
+    ("node_cpu_seconds_total", 300, 1500),
+    ('node_cpu_seconds_total{mode="idle"}', 0, 1799),
+    ('node_cpu_seconds_total{instance="host_3:9100", mode!="idle"}', 605, 610),
+    ('node_network_receive_bytes_total{device!="lo"}', 1195, 1800),
+    ('node_filesystem_avail_bytes{fstype!="tmpfs"}', 900, 1500),
+    ('node_load1{instance="no_such_target"}', 0, 1800),
+])
+def test_a_matrix_cut_from_runs_is_the_general_paths(fleet, monkeypatch,
+                                                     selector, lo, hi):
+    """`_matrix_from_runs` (bisection and two gathers over the sorted
+    scan cache) against the path every other input takes (a mask over
+    every row, flat copies, a scatter): the same labels in the same
+    order, the same matrix."""
+    from greptimedb_tpu.promql import lowering, parse_promql
+    from greptimedb_tpu.session import QueryContext
+    engine = fleet.fe.promql_engine()
+    sel = parse_promql(selector)
+    lo_ms, hi_ms = fleet.ds.t0_ms + lo * 1000, fleet.ds.t0_ms + hi * 1000
+    direct = lowering.select_series(engine, sel, lo_ms, hi_ms,
+                                    QueryContext())
+    monkeypatch.setattr(lowering, "_matrix_from_runs",
+                        lambda *a, **k: None)
+    general = lowering.select_series(engine, sel, lo_ms, hi_ms,
+                                     QueryContext())
+    assert direct.labels == general.labels
+    if general.matrix is None:
+        assert direct.matrix is None and not direct.labels
+        return
+    assert (direct.data_min, direct.data_max) == (general.data_min,
+                                                  general.data_max)
+    for name in ("ts", "values", "lengths"):
+        a, b = getattr(direct.matrix, name), getattr(general.matrix, name)
+        assert a.shape == b.shape and a.dtype == b.dtype and (a == b).all()
+
+
+def test_the_kernels_take_counter_arrays():
+    """ops/window.py by itself: a caller that hands rebased values and
+    the host-made counter arrays gets rate to 6 digits at 1e12, the same
+    call on the values as they are does not."""
+    ts = np.tile(np.arange(64, dtype=np.int64) * 10_000, (2, 1))
+    vals = np.stack([1e12 + 1000.137 * 10 * np.arange(64),
+                     2.6e6 + 0.93 * 10 * np.arange(64)])
+    vals[1, 40:] -= vals[1, 39]                  # a restart at 0
+    m = SeriesMatrix(ts, vals, np.array([64, 64], dtype=np.int32))
+    assert m.value_base.tolist() == [1e12, 2.6e6]
+    assert m.counter_adjusted()[1, -1] == pytest.approx(0.93 * 10 * 63)
+    f32 = np.float32
+    args = (300_000, 15_000, 300_000)
+    good, ok = range_aggregate_cumsum(
+        ts, m.rebased_values().astype(f32), m.lengths, *args, op="rate",
+        nsteps=8, counter=(m.counter_adjusted().astype(f32),
+                           vals.astype(f32)))
+    assert bool(np.all(ok))
+    assert np.abs(np.asarray(good)[0] / 1000.137 - 1).max() < 1e-6
+    assert np.abs(np.asarray(good)[1] / 0.93 - 1).max() < 1e-5
+    plain, _ = range_aggregate_cumsum(
+        ts, vals.astype(f32), m.lengths, *args, op="rate", nsteps=8)
+    assert np.abs(np.asarray(plain)[0] / 1000.137 - 1).max() > 1e-2
+    # the gather path's least squares, around the window's means
+    slope, _ = range_aggregate_gather(
+        ts, m.rebased_values().astype(f32), *args, op="deriv", nsteps=8,
+        maxw=64)
+    assert np.abs(np.asarray(slope)[0] / 1000.137 - 1).max() < 1e-5
+
+
+# ---------------------------------------------------------------------------
+# EXPLAIN ANALYZE of a TQL statement, its spans and counters
+# ---------------------------------------------------------------------------
+
+def family_sql(fleet, name="prom-cpu-busy-all") -> str:
+    fam = load_family(name)
+    return fam.sql(fam.draw(family_rng(SEED, name, "spans"), fleet.ds),
+                   fleet.ds)
+
+
+def test_explain_analyze_tql_runs_the_query(fleet):
+    sql = family_sql(fleet)
+    stages = stage_rows(fleet.sql("EXPLAIN ANALYZE " + sql))
+    assert list(stages)[:3] == ["parse", "plan", "dispatch"]
+    assert stages["dispatch"][2] == ROW_PATH_HERE
+    assert stages["plan"][2].startswith("PromBinary: -")
+    assert stages["plan"][0] == len(fleet.sql(sql)) > 0    # rows answered
+    # the idle series of every target with a sample in the 20 min read
+    assert stages["select"][0] in (8 * 20, 8 * 21, 8 * 22)
+    assert stages["total"][1] > 0 and "trace_id=" in stages["total"][2]
+    assert list(stages)[-1] == "render"
+    assert "protocol=http" in stages["render"][2]
+
+
+def test_tql_analyze_keeps_its_text_over_the_same_rows(fleet):
+    rows = fleet.sql(family_sql(fleet).replace("TQL EVAL", "TQL ANALYZE"))
+    assert [r[0] for r in rows] == ["logical_plan", "analyze"]
+    text = rows[1][1]
+    assert text.startswith("elapsed: ") and ", steps: 61" in text
+    for stage in ("dispatch: ", "select: rows=", "window.launch: ",
+                  "outer: rows="):
+        assert "\n" + stage in text, text
+    assert ROW_PATH_HERE in text
+
+
+def test_plain_explain_of_a_tql_statement_is_tql_explain(fleet):
+    sql = family_sql(fleet)
+    assert fleet.sql("EXPLAIN " + sql) == \
+        fleet.sql(sql.replace("TQL EVAL", "TQL EXPLAIN"))
+
+
+TOP_LEVEL = ["plan", "select", "window", "outer"]
+PARTS = ["select.scan", "select.filter", "select.labels", "select.matrix",
+         "window.upload", "window.launch", "window.fetch"]
+
+
+def interval(stages: dict, name: str):
+    found = T0_NS.search(stages[name][2])
+    assert found, f"row {name!r} has no t0_ns: {stages[name][2]!r}"
+    start = int(found.group(1))
+    return start, start + int(stages[name][1] * 1e6)
+
+
+@pytest.mark.parametrize("name", FAMILIES)
+def test_span_rows_add_up_to_total(fleet, name):
+    """Every row the issue names exists and carries its wall-clock start;
+    the rows directly under `total` add up to it but for what no span
+    covers, and that is small."""
+    sql = family_sql(fleet, name)
+    fleet.sql(sql)                                   # warm
+    stages = stage_rows(fleet.sql("EXPLAIN ANALYZE " + sql))
+    for row in ["parse"] + TOP_LEVEL + PARTS + ["render"]:
+        interval(stages, row)
+    total = stages["total"][1]
+    covered = sum(stages[row][1] for row in TOP_LEVEL)
+    untimed = total - covered
+    assert -0.05 <= untimed < max(2.0, 0.05 * total), (untimed, stages)
+    for parent in ("select", "window"):
+        parts = sum(stages[p][1] for p in PARTS
+                    if p.startswith(parent + "."))
+        assert parts <= stages[parent][1] + 0.05
+    t_start = interval(stages, "plan")[0]
+    for row in TOP_LEVEL + PARTS:
+        assert interval(stages, row)[0] >= t_start
+    assert interval(stages, "render")[0] >= t_start + int(covered * 1e6)
+
+
+def test_promql_counters_count_what_a_statement_moved(fleet):
+    sql = family_sql(fleet, "prom-fs-predict")
+    fleet.sql(sql)
+    before = fleet.counters()
+    fleet.sql(sql)
+    after = fleet.counters()
+
+    def delta(name):
+        return after.get(name, 0.0) - before.get(name, 0.0)
+
+    series = 3 * len(fleet.ds.instances)            # all but tmpfs
+    assert delta("greptime_promql_series_selected_total") == series
+    # the matrix's rows are bucketed (66 -> 128), its samples too
+    assert delta("greptime_promql_matrix_cells_total") == 128 * 256
+    # int32 timestamps, f32 offsets, int32 lengths
+    assert delta("greptime_promql_upload_bytes_total") == \
+        128 * 256 * 8 + 128 * 4
+    assert delta('greptime_promql_statements_total{path="row"}') == 1
+    assert delta('greptime_promql_statements_total{path="lowered"}') == 0
+
+
+# ---------------------------------------------------------------------------
+# the generator
+# ---------------------------------------------------------------------------
+
+def test_generator_gives_the_same_bytes_for_the_same_seed():
+    a, b, c = dataset(SEED), dataset(SEED), dataset(SEED + 1)
+    assert a.data.tobytes() == b.data.tobytes()
+    assert a.instances == b.instances and (a.last == b.last).all()
+    assert a.data.tobytes() != c.data.tobytes()
+    assert a.rows == b.rows == 77 * 20 * 180
+
+
+def test_generator_makes_the_fleet_its_configuration_states():
+    ds = dataset()
+    assert list(ds.tables) == CONFIG["tables"]
+    assert len(ds.instances) == 22              # 20 live + 2 x 1 replaced
+    assert ((ds.last - ds.first) > 0).all()
+    per_tick = sum(((ds.first <= k) & (k < ds.last)).sum()
+                   for k in (0, 59, 60, 119, 120, 179))
+    assert per_tick == 6 * 20                   # 20 live at any moment
+    cpu = ds.samples("node_cpu_seconds_total")
+    assert cpu.values.shape == (22 * 64, 180)
+    one_cpu = np.diff(cpu.values[:8], axis=1).sum(axis=0)
+    np.testing.assert_allclose(one_cpu, 10.0, rtol=1e-9)   # 1 s/s
+    fs = ds.samples("node_filesystem_avail_bytes")
+    assert sorted(fs.labels) == ["device", "fstype", "instance", "job",
+                                 "mountpoint"]
+    assert not float(fs.values[0, 1]).is_integer()          # no integers
+
+
+def scrape_as_remote_write(ds, tick: int) -> bytes:
+    series = []
+    for t in ds.tables.values():
+        s = ds.samples(t.name)
+        for i in np.nonzero((s.first <= tick) & (tick < s.last))[0]:
+            labels = {"__name__": t.name}
+            labels.update({k: str(v[i]) for k, v in t.labels.items()})
+            series.append(prom.TimeSeries(
+                labels, [(float(s.values[i, tick]), int(s.times[tick]))]))
+    return prom.encode_write_request(series)
+
+
+@pytest.fixture(scope="module")
+def two_ways(tmp_path_factory):
+    """One scrape through /v1/prometheus/write, and the generator's
+    CREATE TABLEs plus its Arrow tables through the bulk path."""
+    ds = dataset()
+    written = frontend(str(tmp_path_factory.mktemp("remote_write")))
+    http = HttpServer(written, addr="127.0.0.1:0")
+    http.start()
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{http.port}/v1/prometheus/write",
+        data=scrape_as_remote_write(ds, 0), method="POST")
+    with urllib.request.urlopen(req, timeout=120) as r:
+        assert r.status in (200, 204)
+    loaded = frontend(str(tmp_path_factory.mktemp("flight")))
+    loaded.do_query(ds.create_table_sql())
+    for name, tags, table in ds.arrow_chunks(180):
+        target = loaded.catalog.table("greptime", "public", name)
+        loaded.handle_bulk_load(
+            name, arrow_to_ingest_columns(table, target.schema,
+                                          extra="keep"),
+            tag_columns=tags, timestamp_column=ds.time_index)
+    yield written, loaded
+    http.shutdown()
+    written.shutdown()
+    loaded.shutdown()
+
+
+def shape(fe, table: str) -> dict:
+    schema = fe.catalog.table("greptime", "public", table).schema
+    return {"tags": list(schema.tag_names()),
+            "time_index": schema.timestamp_column.name,
+            "types": {c.name: (str(c.dtype), str(c.semantic_type))
+                      for c in schema.column_schemas}}
+
+
+@pytest.mark.parametrize("table", CONFIG["tables"])
+def test_remote_write_and_the_generator_make_the_same_table(two_ways,
+                                                            table):
+    written, loaded = two_ways
+    a, b = shape(written, table), shape(loaded, table)
+    assert a["tags"] == b["tags"] and "job" in a["tags"]
+    assert a["time_index"] == b["time_index"] == prom.GREPTIME_TIMESTAMP
+    assert a["types"] == b["types"]
+    assert prom.GREPTIME_VALUE in a["types"]
+    # and one scrape reads the same from both
+    q = f"SELECT count(*) FROM {table} WHERE greptime_timestamp = " \
+        f"{dataset().t0_ms}"
+    assert rows_of(written.do_query(q)[-1]) == rows_of(
+        loaded.do_query(q)[-1])
+
+
+# ---------------------------------------------------------------------------
+# the reference, by hand
+# ---------------------------------------------------------------------------
+
+class Hand:
+    """Two series on a 10 s grid: a counter 2/s that restarts at sample
+    30, and one that exists for samples 10..19 only."""
+
+    def __init__(self):
+        self.times = np.arange(60, dtype=np.int64) * 10_000
+        a = 20.0 * np.arange(60) + 1000.0
+        a[30:] = 20.0 * np.arange(1, 31)
+        self.values = np.stack([a, 5.0 * np.arange(60)])
+        self.first = np.array([0, 10])
+        self.last = np.array([60, 20])
+        self.labels = {"host": np.array(["a", "b"], dtype=object)}
+
+
+def test_reference_rate_extrapolates_and_corrects_resets():
+    s, all_ = Hand(), np.ones(2, dtype=bool)
+    steps = np.array([200_000, 320_000, 400_000], dtype=np.int64)
+    rate, ok = ref.extrapolated_rate(s, all_, steps, 60_000)
+    # (140, 200]: six samples 150..200, 50 s sampled, edges 10 s and 0 s
+    # away, both nearer than 1.1 intervals: extrapolated by the 10 s
+    assert ok[0].all() and rate[0, 0] == pytest.approx(100 * 60 / 50 / 60)
+    # across the restart the increase is still 20 a sample
+    assert rate[0, 1] == pytest.approx(2.0)
+    # right after it the counter's zero point caps the extrapolation
+    inc, _ = ref.extrapolated_rate(s, all_, np.array([350_000]), 60_000,
+                                   per_second=False)
+    assert inc[0, 0] == pytest.approx(100 + 2 * 10 + 0)
+    # series b: (140, 200] holds samples 15..19 (it ends at 19)
+    assert ok[1].tolist() == [True, False, False]
+    delta, _ = ref.extrapolated_rate(s, all_, steps, 60_000, counter=False,
+                                     per_second=False)
+    assert delta[1, 0] == pytest.approx(20 * (40 + 10 + 10) / 40)
+
+
+def test_reference_instant_lookback_is_left_open():
+    s, all_ = Hand(), np.ones(2, dtype=bool)
+    v, ok = ref.instant(s, all_, np.array([190_000, 195_000, 489_999,
+                                           490_000]), 300_000)
+    # b's last sample is at 190 s: seen until just before 490 s
+    assert ok[1].tolist() == [True, True, True, False]
+    assert v[1, :3].tolist() == [95.0, 95.0, 95.0]
+    assert ok[0].all() and v[0, 1] == 1000.0 + 20 * 19
+
+
+def test_reference_predict_linear_is_the_line():
+    s = Hand()
+    pred, ok = ref.predict_linear(s, np.array([False, True]),
+                                  np.array([195_000]), 100_000, 3600.0)
+    # b is the line 0.5/s through (190 s, 95): at 195 s + 3600 s
+    assert ok[0, 0] and pred[0, 0] == pytest.approx(95 + 0.5 * 3605)
+
+
+def test_reference_aggregate_topk_and_matching():
+    values = np.array([[1.0, 5.0], [3.0, 4.0], [2.0, 6.0]])
+    ok = np.array([[True, True], [True, False], [True, True]])
+    by, total, present = ref.aggregate(
+        "sum", values, ok, [np.array(["x", "y", "x"], dtype=object)])
+    assert by[0].tolist() == ["x", "y"]
+    assert total.tolist()[0] == [3.0, 11.0] and total[1, 0] == 3.0
+    assert present.tolist() == [[True, True], [True, False]]
+    _, mean, _ = ref.aggregate(
+        "avg", values, ok, [np.array(["x", "y", "x"], dtype=object)])
+    assert mean[0].tolist() == [1.5, 5.5]
+    assert ref.topk(2, values, ok).tolist() == [
+        [False, True], [True, False], [True, True]]
+    li, ri = ref.one_to_one([np.array(["a", "b", "c"])],
+                            [np.array(["c", "a"])])
+    assert li.tolist() == [0, 2] and ri.tolist() == [1, 0]
+    with pytest.raises(ValueError):
+        ref.one_to_one([np.array(["a"])], [np.array(["a", "a"])])
+    pts = ref.points([np.array(["x", "y"], dtype=object)],
+                     np.array([10, 20]), total, present)
+    assert pts == {("x", 10): [3.0], ("x", 20): [11.0], ("y", 10): [3.0]}
