@@ -10,9 +10,27 @@ window (t_j - range, t_j] of every series is located with a vmapped
 `searchsorted`, and:
 
 - sum/count/avg/stddev/rate/increase/delta/changes/resets/last/first/idelta
-  evaluate O(1) per window from per-series prefix sums (cumsum path);
-- min/max/quantile/deriv/predict_linear gather bounded windows (MAXW static)
-  and reduce with masking (gather path).
+  evaluate O(1) per window from per-series prefix sums (cumsum path):
+  a few values of a channel read at a window's bounds;
+- min/max/deriv/predict_linear reduce the window's samples;
+- quantile/mad/holt_winters need the window's samples side by side:
+  they gather bounded windows (MAXW static) and sort or scan them.
+
+How a sample is READ has two forms, chosen from the shapes alone
+(`window_read_path`), because a gather is what is slow on a TPU (10 to
+14 ns a value fetched on a v5e) and a compare is not (0.001 to 0.007 ns a
+cell; the measurements stand beside `_DENSE_WINDOW_MAX_RATIO` and
+`_DENSE_POINT_MAX_LEN`):
+
+- *dense*: a row is sorted and a window is the index range [lo, hi), so a
+  window's reduction is `reduce_l(where(lo <= l < hi, row[l], identity))`
+  and a point read is `sum_l(where(l == e, bits(row[l]), 0))`: compare,
+  select and reduce fused over the row axis, O(S*T*L), no [.., W] array
+  of fetched samples. What a dashboard's rows take (L of 128 to a few
+  thousand): predict_linear over [3072, 256] x 64 steps 1,277 -> 2 ms,
+  rate's five channel reads over [65536, 129] x 84 292 -> 4 ms.
+- *gather*: `take_along_axis`, O(S*T*W) or O(S*T): long rows, and the
+  ops that need the samples side by side.
 
 Counter resets are handled with a per-series cumulative correction array so
 `increase` is a pure difference of adjusted prefix values — no per-window
@@ -39,6 +57,57 @@ CUMSUM_OPS = {
 GATHER_OPS = {"min_over_time", "max_over_time", "quantile_over_time",
               "deriv", "predict_linear", "mad_over_time", "holt_winters"}
 RANGE_OPS = CUMSUM_OPS | GATHER_OPS
+#: gather-family ops that only reduce a window (sum, mean, min, max)
+REDUCE_OPS = frozenset({"min_over_time", "max_over_time", "deriv",
+                        "predict_linear"})
+
+#: A window reduction (REDUCE_OPS) goes dense while the row is at most
+#: this many times the window a gather would fetch: dense costs a*S*T*L,
+#: the gather b*S*T*maxw. Measured on a v5e (PR 29, [3072, L] x 64 steps,
+#: bounds given): dense predict_linear 1.97 ms at L = 256, 2.95 at 1024,
+#: 5.86 at 4096, 21.4 at 16384 (a = 0.0066 ns a cell; 1.5 ms of it is the
+#: launch), max_over_time [65536, 128] x 64 in 4.4 ms (0.0082); the gather
+#: 1,277 ms at maxw = L = 256, and at maxw = 64 322 ms at L = 256 or 1024
+#: and 545 at L = 4096 (b = 25.5 ns a fetched sample for the least
+#: squares' two arrays and rising with the row; 12.8 for min / max's one,
+#: 161 ms). b / a is 1,560 to 3,860: the constant sits under both. The
+#: PromQL engine hands maxw = the row, so there it never crosses.
+_DENSE_WINDOW_MAX_RATIO = 1024
+
+#: A point read (one value of a channel at a window's bound: every
+#: CUMSUM_OPS function but the counts) goes dense up to this row length:
+#: dense costs O(S*T*L) a channel, the gather O(S*T). Measured on a v5e
+#: (PR 29, `_stack_rate`'s five channels read at 84 positions a series;
+#: ms gathered / dense): [65536, 129] 292.0 / 3.95, [8192, 129] 36.1 /
+#: 1.12, [16384, 1025] 95.2 / 9.88, and at [4096, L + 1] L = 2048 24.4 /
+#: 3.56, 4096 25.3 / 9.50, 8192 34.9 / 14.3, 16384 40.2 / 35.9. A gathered
+#: value costs 10.6 to 23 ns (more in longer rows), a compared cell
+#: 0.0010 to 0.0013 ns: they meet past L = 16384, and the constant is the
+#: longest row at which dense was still more than twice as fast.
+_DENSE_POINT_MAX_LEN = 8192
+
+#: cells of one dense block: were the compare-select-reduce ever left
+#: unfused, this bounds what it would write (2**26 f32 = 256 MB)
+_DENSE_BLOCK_CELLS = 1 << 26
+
+
+def window_read_path(op: str, row_len: int, maxw: int = 0) -> Optional[str]:
+    """"dense" or "gather": the form that reads `op`'s samples from rows
+    of `row_len` (`maxw`: the samples a gathered window would hold); None
+    for an op that reads no sample (a count is a difference of bounds).
+    The kernels below choose by this function and by nothing else, so a
+    caller that asks it knows what ran."""
+    if op in ("count_over_time", "present_over_time"):
+        return None
+    if op in CUMSUM_OPS:
+        return "dense" if _point_reads_dense(row_len) else "gather"
+    if op in REDUCE_OPS and row_len <= _DENSE_WINDOW_MAX_RATIO * max(maxw, 1):
+        return "dense"
+    return "gather"
+
+
+def _point_reads_dense(row_len: int) -> bool:
+    return row_len <= _DENSE_POINT_MAX_LEN
 
 
 class SeriesMatrix:
@@ -238,6 +307,36 @@ def _gather(row2d: jax.Array, idx: jax.Array) -> jax.Array:
     return jnp.take_along_axis(row2d, idx, axis=1)
 
 
+def _read_at_dense(channels, idx: jax.Array):
+    """channels[k][s, idx[s, t]] for K channels [S, N] -> K arrays [S, T]
+    with no gather: `sum_n where(n == idx[s, t], bits(channel[s, n]), 0)`,
+    one fused compare-select-reduce over the row axis a channel. The
+    select (not a product with a one-hot) and the integer sum of the
+    value's bits make the result the gather's bit for bit: an `inf` or a
+    `NaN` elsewhere in the row adds nothing, -0.0 stays -0.0. `idx` lies
+    in [0, N), as a gather's caller clips it."""
+    N = channels[0].shape[1]
+    hit = jnp.arange(N, dtype=jnp.int32)[None, :, None] == idx[:, None, :]
+    outs = []
+    for c in channels:
+        bits = c if jnp.issubdtype(c.dtype, jnp.integer) else \
+            jax.lax.bitcast_convert_type(
+                c, jnp.int32 if c.dtype.itemsize == 4 else jnp.int64)
+        r = jnp.sum(jnp.where(hit, bits[:, :, None], 0), axis=1,
+                    dtype=bits.dtype)                       # over [S, N, T]
+        outs.append(jax.lax.bitcast_convert_type(r, c.dtype))
+    return outs
+
+
+def _read_at(channels, idx: jax.Array, row_len: int):
+    """channels[k][s, idx[s, t]] -> K arrays [S, T] in the form rows of
+    `row_len` take (a channel may be a prefix array, one longer than the
+    row): the one place a point read chooses."""
+    if _point_reads_dense(row_len):
+        return _read_at_dense(channels, idx)
+    return [_gather(c, idx) for c in channels]
+
+
 def _rebase_i64_host(ts2d, t0, step=0, nsteps=1, range_ms=0):
     """Host-validating guard against silent int64→int32 narrowing.
 
@@ -349,11 +448,14 @@ def _rac_body(ts2d, val2d, lengths, lo, hi, step_ends, range_ms, *,
     ok1 = count >= 1
     hi1 = jnp.maximum(hi - 1, 0)
 
+    def take(row2d, at):        # dense or gather, by the row's length
+        return _read_at([row2d], at, L)[0]
+
     def pick_first():
-        return _gather(val2d, jnp.minimum(lo, L - 1))
+        return take(val2d, jnp.minimum(lo, L - 1))
 
     def pick_last():
-        return _gather(val2d, hi1)
+        return take(val2d, hi1)
 
     if op in ("count_over_time", "present_over_time"):
         if op == "present_over_time":
@@ -365,7 +467,7 @@ def _rac_body(ts2d, val2d, lengths, lo, hi, step_ends, range_ms, *,
         vz = jnp.where(valid, val2d, 0).astype(fv)
         cs = jnp.cumsum(vz, axis=1)
         csp = jnp.concatenate([jnp.zeros((S, 1), fv), cs], axis=1)
-        wsum = _gather(csp, hi) - _gather(csp, lo)
+        wsum = take(csp, hi) - take(csp, lo)
         if op == "sum_over_time":
             return wsum, ok1
         cnt = jnp.maximum(count, 1).astype(fv)
@@ -374,7 +476,7 @@ def _rac_body(ts2d, val2d, lengths, lo, hi, step_ends, range_ms, *,
             return mean, ok1
         cs2 = jnp.cumsum(vz * vz, axis=1)
         cs2p = jnp.concatenate([jnp.zeros((S, 1), fv), cs2], axis=1)
-        wsq = _gather(cs2p, hi) - _gather(cs2p, lo)
+        wsq = take(cs2p, hi) - take(cs2p, lo)
         var = jnp.maximum(wsq / cnt - mean * mean, 0.0)
         if op == "stdvar_over_time":
             return var, ok1
@@ -388,12 +490,12 @@ def _rac_body(ts2d, val2d, lengths, lo, hi, step_ends, range_ms, *,
     if op in ("idelta", "irate_num"):
         ok2 = count >= 2
         last = pick_last()
-        prev = _gather(val2d, jnp.maximum(hi - 2, 0))
+        prev = take(val2d, jnp.maximum(hi - 2, 0))
         if op == "irate_num":
             # prometheus instantValue counter-reset rule: on reset
             # (last < prev) the delta is the last sample alone, at the
             # counter's own level
-            alone = last if abs2d is None else _gather(abs2d, hi1)
+            alone = last if abs2d is None else take(abs2d, hi1)
             return jnp.where(last < prev, alone, last - prev), ok2
         return last - prev, ok2
 
@@ -407,14 +509,14 @@ def _rac_body(ts2d, val2d, lengths, lo, hi, step_ends, range_ms, *,
         ci = jnp.cumsum(ind.astype(jnp.int32), axis=1)
         cip = jnp.concatenate([jnp.zeros((S, 1), jnp.int32), ci], axis=1)
         # pairs (i-1, i) with both endpoints inside [lo, hi)
-        cnt = _gather(cip, hi) - _gather(cip, jnp.minimum(lo + 1, L))
+        cnt = take(cip, hi) - take(cip, jnp.minimum(lo + 1, L))
         cnt = jnp.where(count >= 1, cnt, 0)
         return cnt.astype(fv), ok1
 
     if op in ("rate", "increase", "delta"):
         ok2 = count >= 2
-        first_t = _gather(ts2d, jnp.minimum(lo, L - 1)).astype(fv)
-        last_t = _gather(ts2d, hi1).astype(fv)
+        first_t = take(ts2d, jnp.minimum(lo, L - 1)).astype(fv)
+        last_t = take(ts2d, hi1).astype(fv)
         first_v = pick_first()
         last_v = pick_last()
         if op == "delta":
@@ -430,9 +532,9 @@ def _rac_body(ts2d, val2d, lengths, lo, hi, step_ends, range_ms, *,
                 contrib = jnp.where(pair_ok & (val2d < prev), prev,
                                     0).astype(fv)
                 adj = val2d + jnp.cumsum(contrib, axis=1)
-            raw = _gather(adj, hi1) - _gather(adj, jnp.minimum(lo, L - 1))
+            raw = take(adj, hi1) - take(adj, jnp.minimum(lo, L - 1))
             if abs2d is not None:   # the zero point is the counter's own
-                first_v = _gather(abs2d, jnp.minimum(lo, L - 1))
+                first_v = take(abs2d, jnp.minimum(lo, L - 1))
             is_counter = True
         return _extrapolate(raw, first_t, last_t, first_v, count, step_ends,
                             range_ms, op=op, is_counter=is_counter)
@@ -516,15 +618,34 @@ def _rag_body(
     t0, step, range_ms, pre_lo, pre_hi, *, op: str, nsteps: int, maxw: int,
     param: float = 0.0, param2: float = 0.0, series_block: int = 128,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Gather-path range functions: each window materializes ≤ maxw samples.
+    """The gather family of range functions, in the form the shapes ask
+    for (`window_read_path`).
+
+    Dense (min / max / deriv / predict_linear on rows of up to
+    `_DENSE_WINDOW_MAX_RATIO` x maxw samples): a window's samples are
+    those whose index lies within its bounds, so every sum, mean, min and
+    max is a masked reduction over the row axis of the row broadcast along
+    the steps. XLA fuses compare, select and reduce: nothing of [B, T, L]
+    is written, no [B, T, W] array exists and `maxw` plays no part.
+    predict_linear at [3072, 256] x 64 is 1.97 ms on a v5e where the two
+    gathers of [128, 64, 256] a block took 1,277 (PR 29).
+
+    Gather (the rest): each window materializes ≤ maxw samples; windows
+    longer than maxw are truncated to their most recent maxw samples
+    (callers size maxw from data density), processed in series blocks via
+    lax.map to bound the footprint.
 
     Row validity comes from the TS_PAD sentinel (padded slots sort last and
-    fall outside every window), so no lengths array is needed. Windows longer
-    than maxw are truncated to their most recent maxw samples (callers size
-    maxw from data density). Processed in series blocks via lax.map to bound
-    VMEM footprint."""
+    fall outside every window), so no lengths array is needed."""
     S, L = ts2d.shape
     step_ends = t0 + jnp.arange(nsteps, dtype=ts2d.dtype) * step
+    dense = window_read_path(op, L, maxw) == "dense"
+    if dense:
+        # one block where the whole selection is within the budget (no
+        # `while` on the device), else the fewest blocks of a multiple
+        # of 8 series
+        per = max(8, _DENSE_BLOCK_CELLS // (nsteps * L) // 8 * 8)
+        series_block = max(1, min(S, per))
     pad_s = (-S) % series_block
     pad_sentinel = jnp.iinfo(ts2d.dtype).max
     ts2d = jnp.pad(ts2d, ((0, pad_s), (0, 0)), constant_values=pad_sentinel)
@@ -542,17 +663,22 @@ def _rag_body(
         else:
             tsb, valb = args          # [B, L]
             lo, hi = window_bounds(tsb, step_ends, range_ms)
-        lo = jnp.maximum(lo, hi - maxw)
-        w = jnp.arange(maxw, dtype=jnp.int32)
-        widx = lo[:, :, None] + w[None, None, :]            # [B, T, W]
-        inwin = widx < hi[:, :, None]
-        widx_c = jnp.minimum(widx, L - 1)
-        vals = jnp.take_along_axis(jnp.broadcast_to(valb[:, None, :],
-                                                    (valb.shape[0], nsteps, L)),
-                                   widx_c, axis=2)
-        tvals = jnp.take_along_axis(jnp.broadcast_to(tsb[:, None, :],
-                                                     (tsb.shape[0], nsteps, L)),
-                                    widx_c, axis=2)
+        if dense:
+            idx = jnp.arange(L, dtype=jnp.int32)[None, None, :]
+            inwin = (idx >= lo[:, :, None]) & (idx < hi[:, :, None])
+            vals, tvals = valb[:, None, :], tsb[:, None, :]     # [B, 1, L]
+        else:
+            lo = jnp.maximum(lo, hi - maxw)
+            w = jnp.arange(maxw, dtype=jnp.int32)
+            widx = lo[:, :, None] + w[None, None, :]            # [B, T, W]
+            inwin = widx < hi[:, :, None]
+            widx_c = jnp.minimum(widx, L - 1)
+            vals = jnp.take_along_axis(
+                jnp.broadcast_to(valb[:, None, :],
+                                 (valb.shape[0], nsteps, L)), widx_c, axis=2)
+            tvals = jnp.take_along_axis(
+                jnp.broadcast_to(tsb[:, None, :],
+                                 (tsb.shape[0], nsteps, L)), widx_c, axis=2)
         count = (hi - lo).astype(jnp.int32)
         ok1 = count >= 1
         fv = valb.dtype
@@ -573,14 +699,15 @@ def _rag_body(
             ok2 = count >= 2
             # least squares around the window's own means: the textbook
             # n*sxy - sx*sy subtracts two float32 products that agree in
-            # their leading digits (values of 1e8 B, slope off by 1e-3)
+            # their leading digits (values of 1e8 B, slope off by 1e-3).
+            # Samples outside the window are selected away, not multiplied
+            # by 0: an `inf` beside a window is none of its business.
             t_sec = (tvals.astype(fv) - step_ends[None, :, None].astype(fv)) / 1000.0
-            m = inwin.astype(fv)
-            n = jnp.maximum(jnp.sum(m, axis=2), 1)
-            xm = jnp.sum(t_sec * m, axis=2) / n
-            ym = jnp.sum(vals * m, axis=2) / n
-            dx = (t_sec - xm[:, :, None]) * m
-            dy = (vals - ym[:, :, None]) * m
+            n = jnp.maximum(count, 1).astype(fv)
+            xm = jnp.sum(jnp.where(inwin, t_sec, 0), axis=2) / n
+            ym = jnp.sum(jnp.where(inwin, vals, 0), axis=2) / n
+            dx = jnp.where(inwin, t_sec - xm[:, :, None], 0)
+            dy = jnp.where(inwin, vals - ym[:, :, None], 0)
             sxx = jnp.sum(dx * dx, axis=2)
             sxy = jnp.sum(dx * dy, axis=2)
             slope = jnp.where(sxx != 0, sxy / jnp.where(sxx == 0, 1, sxx),
@@ -598,7 +725,10 @@ def _rag_body(
     if have_bounds:
         operands += (pre_lo.reshape(SB, series_block, nsteps),
                      pre_hi.reshape(SB, series_block, nsteps))
-    outs, oks = jax.lax.map(block, operands)
+    if SB == 1:
+        outs, oks = block(tuple(a[0] for a in operands))
+    else:
+        outs, oks = jax.lax.map(block, operands)
     out = outs.reshape(-1, nsteps)[:S]
     ok = oks.reshape(-1, nsteps)[:S]
     return out, ok
@@ -623,12 +753,17 @@ _CH_CSP, _CH_TS_PREV, _CH_TS_AT, _CH_VAL_PREV, _CH_VAL_AT, _CH_VAL_PREV2 = \
 def _gather_channels(channels, e):
     """K channels [S, L+1] at positions e [S, T_ext] -> [S, T_ext, K].
 
-    One 2-D gather per channel. A single gather over the stacked
-    [S, L+1, K] operand never returns on jax 0.9.0 / libtpu 0.0.34 for
-    some shapes (on a v5e: [4000, 513, 6] hangs, [4000, 397, 6] and
-    [4000, 1025, 6] take 3-6 ms), with or without an optimization barrier
-    and with the channel axis in either place."""
-    return jnp.stack([jnp.take_along_axis(c, e, axis=1) for c in channels],
+    Rows of up to `_DENSE_POINT_MAX_LEN` samples are read without a
+    gather (`_read_at_dense`, bit for bit the same): on a v5e the five
+    channels of `_stack_rate` at [65536, 129] x 84 take 3.95 ms so and
+    292.0 ms gathered (PR 29; the dashboard's whole-table `rate`).
+
+    Longer rows: one 2-D gather per channel. A single gather over the
+    stacked [S, L+1, K] operand never returns on jax 0.9.0 / libtpu
+    0.0.34 for some shapes (on a v5e: [4000, 513, 6] hangs, [4000, 397,
+    6] and [4000, 1025, 6] take 3-6 ms), with or without an optimization
+    barrier and with the channel axis in either place."""
+    return jnp.stack(_read_at(channels, e, channels[0].shape[1] - 1),
                      axis=-1)
 
 

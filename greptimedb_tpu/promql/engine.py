@@ -761,9 +761,11 @@ class _Eval:
 
     def _range_func(self, func: str, sel: VectorSelector,
                     param: float = 0.0, param2: float = 0.0) -> VectorVal:
+        from ..common import exec_stats
+        from ..common.telemetry import increment_counter
         from ..ops.window import (
             CUMSUM_OPS, GATHER_OPS, range_aggregate_cumsum,
-            range_aggregate_gather)
+            range_aggregate_gather, window_read_path)
 
         win = sel.range_ms
         if not win:
@@ -785,7 +787,16 @@ class _Eval:
         def kernel(dm, t0r, nsteps):
             matrix = dm.matrix
 
+            row_len = int(matrix.max_len)
+            maxw = max(row_len, 2)      # a gathered window: the whole row
+
             def run(op):
+                # how the kernels about to be called read their samples:
+                # they choose by the same function of the same shapes
+                path = window_read_path(op, row_len, maxw)
+                if path is not None:
+                    increment_counter("promql_window_reads", path=path)
+                    exec_stats.record("window.launch", path=path)
                 if op in CUMSUM_OPS and not dm.on_host \
                         and self._aligned_ok(win, nsteps):
                     return self._aligned_eval(dm, t0r, win, nsteps).eval(op)
@@ -801,8 +812,8 @@ class _Eval:
                 if op in GATHER_OPS:
                     return range_aggregate_gather(
                         dm.ts2d, val2d, t0r, self.step, win, op=op,
-                        nsteps=nsteps, maxw=max(int(matrix.max_len), 2),
-                        param=param, param2=param2, bounds=bounds)
+                        nsteps=nsteps, maxw=maxw, param=param,
+                        param2=param2, bounds=bounds)
                 raise UnsupportedError(
                     f"range function {func} not implemented")
 
