@@ -3,9 +3,7 @@
 
 PY ?= python
 
-.PHONY: check lint typecheck test test-slow race baseline bench bench-qps \
-	bench-index bench-distagg bench-trace bench-promql bench-prof \
-	bench-replica prof
+.PHONY: check lint typecheck test test-slow race baseline prof
 
 check: lint typecheck test
 
@@ -23,12 +21,14 @@ typecheck:
 	  && $(PY) -m mypy --config-file mypy.ini \
 	  || echo "mypy not installed; skipping typecheck (see mypy.ini)"
 
-# tier-1 suite: the ROADMAP.md verify command (lock-order detector is
-# auto-enabled under pytest; greptlint runs inside as tests/test_greptlint.py)
+# tier-1 suite as the driver runs it: six xdist workers, a file to a
+# worker, under 1,470 s (lock-order detector is auto-enabled under
+# pytest; greptlint runs inside as tests/test_greptlint.py)
 test:
-	JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m 'not slow' \
+	timeout -k 10 1470 env JAX_PLATFORMS=cpu ALLOW_MULTIPLE_LIBTPU_LOAD=1 \
+	  $(PY) -m pytest tests/ -q -m 'not slow' \
 	  --continue-on-collection-errors -p no:cacheprovider \
-	  -p no:xdist -p no:randomly
+	  -p xdist -n 6 --dist loadfile -p no:randomly
 
 test-slow:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q \
@@ -50,39 +50,6 @@ baseline:
 	$(PY) -m greptimedb_tpu.devtools.greptlint greptimedb_tpu/ \
 	  --write-baseline
 
-bench:
-	JAX_PLATFORMS=cpu $(PY) bench.py
-
-# only the ISSUE 12 front-door metric: 1000-logical-client mixed
-# workload QPS × p99 + the WAL group-commit on/off differential
-bench-qps:
-	JAX_PLATFORMS=cpu GREPTIME_BENCH_ONLY=concurrent_qps $(PY) bench.py
-
-# only the ISSUE 13 metric: high-cardinality point/IN query throughput
-# on a ~100k-series, >=16-SST region with the per-SST secondary index
-# on vs `SET sst_index = 0` (asserts the >=3x differential)
-bench-index:
-	JAX_PLATFORMS=cpu GREPTIME_BENCH_ONLY=index $(PY) bench.py
-
-# only the ISSUE 15 metric: bulk-ingest + point-query differential with
-# the durable trace store's sink at sample ratio 1.0 / 0.01 vs off
-# (asserts <3% overhead at the default 0.01 ratio)
-bench-trace:
-	JAX_PLATFORMS=cpu GREPTIME_BENCH_ONLY=trace $(PY) bench.py
-
-# only the ISSUE 14 metric: 4-datanode GROUP BY with
-# count/count-distinct/p95 through the sketch partial pushdown vs the
-# raw-row fallback (`SET dist_partial_agg = 0`); asserts the >=3x
-# wire-byte reduction
-bench-distagg:
-	JAX_PLATFORMS=cpu GREPTIME_BENCH_ONLY=distagg $(PY) bench.py
-
-# only the ISSUE 17 metric: mixed bulk-ingest + point-query throughput
-# with the continuous profiler sampling at the default 19 Hz vs off
-# (asserts <3% overhead)
-bench-prof:
-	JAX_PLATFORMS=cpu GREPTIME_BENCH_ONLY=prof $(PY) bench.py
-
 # quick continuous-profiling demo: boots a standalone frontend with
 # `SET profiling = 1`, runs a short mixed workload and prints the
 # ADMIN SHOW PROFILE 'last' tree (ISSUE 17)
@@ -90,16 +57,3 @@ prof:
 	JAX_PLATFORMS=cpu $(PY) -m pytest \
 	  tests/test_profiler.py -q -k standalone_end_to_end \
 	  -p no:cacheprovider -p no:xdist -p no:randomly
-
-# only the ISSUE 19 metric: read QPS at 1/2/3 region replicas under
-# SET read_replica = 'follower', plus the leader kill -9 promotion
-# handoff window and the acked-loss/dup counts (asserted zero)
-bench-replica:
-	JAX_PLATFORMS=cpu GREPTIME_BENCH_ONLY=replica $(PY) bench.py
-
-# only the ISSUE 16 metric: 4-datanode PromQL range query
-# `sum by (hostname) (rate(...))` through the plan-IR pushdown vs the
-# raw-pull row path (`SET dist_partial_agg = 0`); asserts the >=3x
-# speedup and publishes the wire-byte ratio
-bench-promql:
-	JAX_PLATFORMS=cpu GREPTIME_BENCH_ONLY=promql $(PY) bench.py

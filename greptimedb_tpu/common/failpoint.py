@@ -30,7 +30,8 @@ Action grammar (``parse_action``)::
 
 Zero overhead when inactive: every entry point checks the module-level
 ``_ACTIVE`` bool first — one global load + branch per instrumented call,
-no dict lookup, no lock (bench.py asserts the ingest differential).
+no dict lookup, no lock (tests/test_fault_injection.py
+``test_inactive_is_noop_and_zero_cost_guard``).
 Evaluation while armed takes a lock; failpoints are a test/debug surface,
 never a production hot path.
 """

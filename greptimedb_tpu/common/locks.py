@@ -27,7 +27,8 @@ storage layer's ~10 locks are wrapped in :func:`TrackedLock` /
 Zero overhead in production, same pattern as ``common/failpoint.py``:
 :func:`TrackedLock` is a **factory** that returns a plain
 ``threading.Lock`` unless the detector is enabled, so the inactive mode
-costs literally nothing per acquire (bench.py asserts the differential).
+costs literally nothing per acquire (tests/test_locks.py
+``TestInactiveMode`` holds the type).
 Enablement is decided at import: ``GREPTIME_LOCK_CHECK=1`` forces on,
 ``GREPTIME_LOCK_CHECK=0`` forces off, and otherwise the detector turns
 itself on when running under pytest (``pytest`` already imported).

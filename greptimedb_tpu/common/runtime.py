@@ -51,7 +51,7 @@ def dist_fanout() -> int:
 
 def configure_dist_fanout(n: int) -> None:
     """SET dist_fanout — 1 serializes the scatter (the pre-parallel
-    behavior, kept for differential benchmarks and debugging)."""
+    behavior; the sqlness dist_scan golden pins it)."""
     with _lock:
         _DIST_FANOUT[0] = max(1, int(n))
 
@@ -115,6 +115,32 @@ def new_thread(target: Callable, *, name: Optional[str] = None,
         target = propagate(target)
     return threading.Thread(target=target, name=name, daemon=daemon,
                             args=args)
+
+
+class ServesInBackground:
+    """For a Flight server (mixed in before `FlightServerBase`):
+    `serve_in_background()`, and a `shutdown()` that waits for it.
+    Arrow's `serve()` of a server just shut down is still winding down in
+    its thread when `shutdown()` returns, and a server of the same process
+    that starts to serve meanwhile is taken down with it: its listener is
+    gone some tens of milliseconds later and the next dial is refused. So
+    a server counts as stopped only once its `serve()` has returned."""
+
+    _serve_name = "flight"
+    _serve_thread: Optional[threading.Thread] = None
+
+    def serve_in_background(self) -> threading.Thread:
+        self._serve_thread = new_thread(
+            self.serve, daemon=True, name=self._serve_name,
+            propagate_context=False)
+        self._serve_thread.start()
+        return self._serve_thread
+
+    def shutdown(self) -> None:
+        super().shutdown()
+        t = self._serve_thread
+        if t is not None and t is not threading.current_thread():
+            t.join(timeout=30)
 
 
 def transient_executor(max_workers: int,

@@ -67,8 +67,7 @@ class DatanodeOptions:
     #: self-monitoring scrape cadence (metrics + region heat →
     #: greptime_private system tables); same pytest/0 rules as the flow
     #: tick. 30s keeps the history fine-grained enough for the region
-    #: split/migrate decisions ROADMAP item 1 needs without measurable
-    #: ingest overhead (<3%, see bench.py self_monitoring_overhead)
+    #: split/migrate decisions ROADMAP item 1 needs
     self_monitor_interval_s: float = 30.0
 
 

@@ -3,8 +3,8 @@
 ``tracked_state(obj, name)`` wraps a dict/list/set/OrderedDict in a
 subclass whose accesses flow through :func:`detector.record_access`.
 When the detector is off it returns ``obj`` unchanged — the
-TrackedLock/failpoint zero-overhead factory pattern (bench.py's
-``greptsan_inactive_overhead`` asserts the differential is noise).
+TrackedLock/failpoint zero-overhead factory pattern
+(tests/test_greptsan.py ``TestInactiveMode`` holds the identity).
 
 Granularity (what counts as "the same variable"):
 

@@ -184,9 +184,6 @@ class DistTable(Table):
         #: refresh — the StaleRouteError surfaces after the retries
         self.meta = meta
         self._warned_remote_regions = False
-        #: per-node wall latency of the most recent scatter on this
-        #: frontend ({label: ms}; bench.py's scatter profile reads it)
-        self.last_scatter_node_ms: Dict[str, float] = {}
 
     # ---- stale-route refresh (elastic regions) ----
     def refresh_route(self) -> bool:
@@ -691,8 +688,7 @@ class DistTable(Table):
 
     def _record_node_vector(self, rows: int, node_ms: list) -> None:
         """The per-node latency vector (not just its max) — rendered in
-        the dist_scatter detail, kept on the table for bench.py's
-        scatter profile JSON line. String values: a statement that
+        the dist_scatter detail. String values: a statement that
         scatters twice must not SUM its latencies (numeric details
         accumulate in ExecStats)."""
         slowest = max((ms for _, ms in node_ms), default=0.0)
@@ -700,7 +696,6 @@ class DistTable(Table):
             f"{label}:{ms:.1f}" for label, ms in sorted(
                 node_ms, key=lambda kv: exec_stats.node_sort_key(kv[0]))
         ) or "-"
-        self.last_scatter_node_ms = {label: ms for label, ms in node_ms}
         exec_stats.record("dist_scatter", rows=rows,
                           slowest_node_ms=f"{slowest:.2f}",
                           node_ms=vector)

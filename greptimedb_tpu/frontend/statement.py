@@ -313,7 +313,7 @@ def apply_admin_maintenance(catalog: CatalogManager, stmt: ast.Admin,
     """Shared ADMIN FLUSH/COMPACT TABLE handler: force the table's
     regions through a flush (memtables → indexed L0 SSTs) or a manual
     compaction. One function for both frontends; the sqlness goldens
-    and the index bench use it to pin the on-disk SST layout."""
+    and tests/test_sst_index.py use it to pin the on-disk SST layout."""
     catalog_name, schema_name, name = ctx.resolve(stmt.table)
     table = catalog.table(catalog_name, schema_name, name)
     if table is None:
@@ -392,8 +392,8 @@ def apply_set_variable(stmt: ast.SetVariable, ctx: QueryContext) -> Output:
             configure_retry(base_ms=value)
     elif name == "dist_fanout":
         # per-statement bound on concurrently in-flight datanode RPCs
-        # in the distributed scatter-gather (1 = serial, the pre-
-        # parallel behavior — the bench differential uses it)
+        # in the distributed scatter-gather (1 = serial: the sqlness
+        # dist_scan golden and tests/test_dist_scatter.py pin it)
         from ..common.runtime import configure_dist_fanout
         configure_dist_fanout(_int_setting(stmt))
     elif name in ("dist_rpc_max_retries", "dist_rpc_retry_base_ms"):
@@ -425,7 +425,7 @@ def apply_set_variable(stmt: ast.SetVariable, ctx: QueryContext) -> Output:
     elif name in ("wal_group_commit", "wal_group_max_wait_us",
                   "wal_group_max_batch"):
         # WAL group-commit knobs: concurrent sync_on_write writers share
-        # one fsync; the toggle is the bench differential's kill switch
+        # one fsync; wal_group_commit = 0 is an fsync per append
         from ..storage.wal import configure_group_commit
         value = _int_setting(stmt)
         try:
@@ -437,16 +437,13 @@ def apply_set_variable(stmt: ast.SetVariable, ctx: QueryContext) -> Output:
                 configure_group_commit(max_batch=value)
         except ValueError as e:
             raise InvalidArgumentsError(f"SET {stmt.name}: {e}")
-    elif name in ("ingest_coalesce", "ingest_coalesce_window_ms"):
+    elif name == "ingest_coalesce_window_ms":
         # protocol-ingest coalescer (servers/coalesce.py): merge
-        # concurrent small same-table writes into shared bulk batches
+        # concurrent small same-table writes into shared bulk batches;
+        # 0 passes every write straight through
         from ..servers.coalesce import configure_coalescer
-        value = _int_setting(stmt)
         try:
-            if name == "ingest_coalesce":
-                configure_coalescer(enabled=bool(value))
-            else:
-                configure_coalescer(window_ms=value)
+            configure_coalescer(window_ms=_int_setting(stmt))
         except ValueError as e:
             raise InvalidArgumentsError(f"SET {stmt.name}: {e}")
     elif name == "exact_distinct":
@@ -466,21 +463,17 @@ def apply_set_variable(stmt: ast.SetVariable, ctx: QueryContext) -> Output:
                 f"got {stmt.value!r}")
     elif name == "dist_partial_agg":
         # distributed partial-aggregate pushdown kill switch: 0 sends
-        # GROUP BYs over DistTables through the raw-row scatter (the
-        # bench differential compares wire bytes against it)
+        # GROUP BYs over DistTables through the raw-row scatter
+        # (tests/test_sketches.py's reference answers)
         from ..query import tpu_exec
         tpu_exec.configure_partial_pushdown(
             enabled=bool(_int_setting(stmt)))
-    elif name == "scan_fusion":
-        # single-flight fusion of concurrent identical small scans of
-        # one region (query/tpu_exec.py); 0 = every scan solo
-        from ..query import tpu_exec
-        tpu_exec.configure_scan_fusion(enabled=bool(_int_setting(stmt)))
     elif name == "sst_index":
         # per-SST secondary indexes (storage/index.py): 0 disables both
         # sidecar writes and every index consult — point/IN queries then
-        # take the pre-index stats-only read path (the bench
-        # differential's kill switch; env twin GREPTIME_SST_INDEX)
+        # take the pre-index stats-only read path (the reference
+        # tests/test_sst_index.py compares against; env twin
+        # GREPTIME_SST_INDEX)
         from ..storage.index import configure_sst_index
         configure_sst_index(enabled=bool(_int_setting(stmt)))
     elif name in ("admission_max_inflight", "admission_max_queued_bytes",

@@ -152,10 +152,6 @@ class RegionBalancer:
 
     def _save(self, op: dict) -> None:
         op["updated_ms"] = int(time.time() * 1000)
-        # first-entry timestamp per state: bench.py derives the fenced
-        # handoff window (open → release) from these
-        op.setdefault("times", {}).setdefault(op["state"],
-                                              op["updated_ms"])
         self.srv.kv.put(f"{OP_PREFIX}{op['id']}",
                         json.dumps(op).encode())
 
@@ -178,7 +174,6 @@ class RegionBalancer:
         if error:
             op["error"] = error
         op["updated_ms"] = int(time.time() * 1000)
-        op.setdefault("times", {}).setdefault(state, op["updated_ms"])
         self.srv.kv.batch([
             ("put", f"{DONE_PREFIX}{op['id']}",
              json.dumps(op).encode()),
@@ -649,8 +644,6 @@ class RegionBalancer:
         route.version += 1
         op["state"] = "release"
         op["updated_ms"] = int(time.time() * 1000)
-        op.setdefault("times", {}).setdefault("release",
-                                              op["updated_ms"])
         self.srv.kv.batch([
             ("put", f"{ROUTE_PREFIX}{op['table']}",
              json.dumps(route.to_dict()).encode()),
@@ -715,7 +708,6 @@ class RegionBalancer:
         op["rule_doc"] = new_doc
         op["state"] = "apply"
         op["updated_ms"] = int(time.time() * 1000)
-        op.setdefault("times", {}).setdefault("apply", op["updated_ms"])
         self.srv.kv.batch([
             ("put", f"{ROUTE_PREFIX}{op['table']}",
              json.dumps(route.to_dict()).encode()),
@@ -781,7 +773,6 @@ class RegionBalancer:
         op["state"] = "wire"
         op["wal_tail"] = None      # bootstrapped; shrink the op doc
         op["updated_ms"] = int(time.time() * 1000)
-        op.setdefault("times", {}).setdefault("wire", op["updated_ms"])
         self.srv.kv.batch([
             ("put", f"{ROUTE_PREFIX}{op['table']}",
              json.dumps(route.to_dict()).encode()),
@@ -812,7 +803,6 @@ class RegionBalancer:
         route.version += 1
         op["state"] = "drop"
         op["updated_ms"] = int(time.time() * 1000)
-        op.setdefault("times", {}).setdefault("drop", op["updated_ms"])
         self.srv.kv.batch([
             ("put", f"{ROUTE_PREFIX}{op['table']}",
              json.dumps(route.to_dict()).encode()),

@@ -18,12 +18,15 @@ from typing import Iterator, List, Optional, Tuple
 
 import pyarrow.flight as flight
 
+from ..common.runtime import ServesInBackground
 from ..errors import GreptimeError
 from .service import (
     DatanodeStat, HeartbeatResponse, MetaSrv, Peer, TableRoute)
 
 
-class FlightMetaServer(flight.FlightServerBase):
+class FlightMetaServer(ServesInBackground, flight.FlightServerBase):
+    _serve_name = "flight-metasrv"
+
     def __init__(self, srv: MetaSrv, location: str = "grpc://127.0.0.1:0",
                  raft_node: object = None) -> None:
         super().__init__(location)
@@ -35,13 +38,6 @@ class FlightMetaServer(flight.FlightServerBase):
     def address(self) -> str:
         from ..servers.flight import _advertised_address
         return _advertised_address(self._location, self.port)
-
-    def serve_in_background(self) -> threading.Thread:
-        from ..common.runtime import new_thread
-        t = new_thread(self.serve, daemon=True, name="flight-metasrv",
-                       propagate_context=False)
-        t.start()
-        return t
 
     def do_action(self, context: object, action: "flight.Action"
                   ) -> Iterator["flight.Result"]:

@@ -600,7 +600,7 @@ def seg_len_bucket(max_len: int) -> int:
     """Static pass-count bucket for the shift-doubling kernels: the
     smallest even k with 2^k >= max_len. Even buckets bound recompiles;
     the kernels' correctness REQUIRES 2^k >= the longest segment, so
-    every caller (scan launch, benches, tests) must derive k through
+    every caller (scan launch, tests) must derive k through
     this one helper."""
     return -(-max(max_len - 1, 1).bit_length() // 2) * 2
 

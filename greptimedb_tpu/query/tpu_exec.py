@@ -34,6 +34,7 @@ from ..sql.ast import (
     Literal, Query, UnaryOp,
 )
 from ..common.failpoint import register as _fp_register
+from ..utils import env_flag as _env_flag
 from .expr import Evaluator, expr_name
 from .functions import SKETCH_AGGREGATES, TPU_AGGREGATES, parse_interval_ms
 from .planner import Analysis, _group_slot
@@ -407,18 +408,9 @@ SCAN_CACHE = _ScanCache()
 # concurrent scan fusion: single-flight over identical resident scans
 # ---------------------------------------------------------------------------
 
-#: SET scan_fusion toggles; single-slot swap (no lock needed for a read)
-from ..utils import env_flag as _env_flag  # noqa: E402
-
-_FUSION_ENABLED = [_env_flag("GREPTIME_SCAN_FUSION", True)]
 #: bounded park for a follower on the leader's pass — a dead leader
 #: degrades to a solo scan, never a hang
 _FUSION_WAIT_TIMEOUT_S = 30.0
-
-
-def configure_scan_fusion(*, enabled: Optional[bool] = None) -> None:
-    if enabled is not None:
-        _FUSION_ENABLED[0] = bool(enabled)
 
 
 class _FlightEntry:
@@ -451,10 +443,6 @@ class _ScanFlightMap:
     def execute(self, region, table, plan: "TpuPlan"):
         from ..common import exec_stats, process_list
         from ..common.telemetry import increment_counter
-        if not _FUSION_ENABLED[0]:
-            # check BEFORE fingerprinting: the opt-out must not pay the
-            # plan serialization on every region of every scan
-            return _execute_region(region, table, plan)
         key = self._key(region, plan)
         if key is None:
             return _execute_region(region, table, plan)
@@ -1104,7 +1092,8 @@ def cached_table_frame(table) -> Optional[pd.DataFrame]:
 
 #: SET dist_partial_agg — kill switch for the distributed partial
 #: pushdown: 0 routes aggregate statements over DistTables through the
-#: raw-row scatter instead (the bench differential + ops escape hatch)
+#: raw-row scatter instead (tests/test_sketches.py takes its reference
+#: answers from it)
 _PARTIAL_PUSHDOWN = [_env_flag("GREPTIME_DIST_PARTIAL_AGG", True)]
 
 
@@ -1156,8 +1145,8 @@ def frames_nbytes(frames) -> int:
     """Byte size of partial moment frames — numeric columns by their
     array width, sketch columns by their encoded frame lengths. This is
     the number the wire pays (the IPC framing adds low single-digit %),
-    so EXPLAIN ANALYZE's partial_bytes and the bench's wire-byte
-    comparison measure the same thing for local and Flight datanodes."""
+    so EXPLAIN ANALYZE's partial_bytes reads the same for local and
+    Flight datanodes."""
     total = 0
     for f in frames:
         for col in f.columns:

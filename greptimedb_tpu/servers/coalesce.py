@@ -29,7 +29,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional
 
 from ..common.locks import TrackedLock
 from ..common.process_list import check_cancelled
@@ -40,30 +40,24 @@ from ..errors import GreptimeError, InternalError
 #: insert before surfacing an error (never deadlock on a dead leader)
 _FOLLOW_TIMEOUT_S = 30.0
 
-from ..utils import env_flag as _env_flag, env_float as _env_float
-
 _CFG_LOCK = TrackedLock("servers.coalesce_config")
 
-_ENABLED = [_env_flag("GREPTIME_INGEST_COALESCE", True)]
-_WINDOW_MS = [_env_float("GREPTIME_INGEST_COALESCE_WINDOW_MS", 2.0)]
+_WINDOW_MS = [2.0]
 
 
-def configure_coalescer(*, enabled: Optional[bool] = None,
-                        window_ms: Optional[float] = None) -> None:
-    """Process-wide knobs (SET ingest_coalesce /
-    ingest_coalesce_window_ms; 0 ms behaves like off)."""
+def configure_coalescer(*, window_ms: float) -> None:
+    """Process-wide knob (SET ingest_coalesce_window_ms; 0 ms passes
+    every write straight through)."""
+    if window_ms < 0:
+        raise ValueError("ingest_coalesce_window_ms must be >= 0")
     with _CFG_LOCK:
-        if enabled is not None:
-            _ENABLED[0] = bool(enabled)
-        if window_ms is not None:
-            if window_ms < 0:
-                raise ValueError("ingest_coalesce_window_ms must be >= 0")
-            _WINDOW_MS[0] = float(window_ms)
+        _WINDOW_MS[0] = float(window_ms)
 
 
-def coalescer_settings() -> Tuple[bool, float]:
+def coalescer_settings() -> float:
+    """The accumulation window in ms."""
     with _CFG_LOCK:
-        return _ENABLED[0], _WINDOW_MS[0]
+        return _WINDOW_MS[0]
 
 
 class _Batch:
@@ -95,8 +89,8 @@ class IngestCoalescer:
         paths; returns THIS request's row count once its rows are as
         durable as a solo insert would have made them."""
         n_rows = len(columns.get(timestamp_column, ()))
-        enabled, window_ms = coalescer_settings()
-        if not enabled or window_ms <= 0:
+        window_ms = coalescer_settings()
+        if window_ms <= 0:
             return frontend.handle_row_insert(
                 table, columns, tag_columns=tag_columns,
                 timestamp_column=timestamp_column, types=types, ctx=ctx)

@@ -23,7 +23,6 @@ from __future__ import annotations
 
 import json
 import logging
-import threading
 import time
 from typing import Dict, Optional
 
@@ -31,6 +30,7 @@ import pyarrow as pa
 import pyarrow.flight as flight
 
 from ..common import exec_stats
+from ..common.runtime import ServesInBackground
 from ..common.telemetry import (
     remote_context, slow_query_threshold_ms, span)
 from ..datatypes.record_batch import RecordBatch
@@ -161,7 +161,7 @@ def _affected_stream(n: int,
 # datanode server (worker side of the distributed data plane)
 # ---------------------------------------------------------------------------
 
-class FlightDatanodeServer(flight.FlightServerBase):
+class FlightDatanodeServer(ServesInBackground, flight.FlightServerBase):
     """Serves one datanode's region data plane over Arrow Flight."""
 
     def __init__(self, datanode, location: str = "grpc://127.0.0.1:0"):
@@ -170,18 +170,11 @@ class FlightDatanodeServer(flight.FlightServerBase):
         self.datanode = datanode
         self.local = LocalDatanodeClient(datanode)
         self._location = location
+        self._serve_name = f"flight-dn{datanode.opts.node_id}"
 
     @property
     def address(self) -> str:
         return _advertised_address(self._location, self.port)
-
-    def serve_in_background(self) -> threading.Thread:
-        from ..common.runtime import new_thread
-        t = new_thread(self.serve, daemon=True,
-                       name=f"flight-dn{self.datanode.opts.node_id}",
-                       propagate_context=False)
-        t.start()
-        return t
 
     # ---- control plane: DDL / flush / describe ----
     def do_action(self, context, action):
@@ -382,7 +375,9 @@ class FlightDatanodeServer(flight.FlightServerBase):
 # GreptimeService + FlightService pair)
 # ---------------------------------------------------------------------------
 
-class FlightFrontendServer(flight.FlightServerBase):
+class FlightFrontendServer(ServesInBackground, flight.FlightServerBase):
+    _serve_name = "flight-frontend"
+
     def __init__(self, frontend, location: str = "grpc://127.0.0.1:0"):
         super().__init__(location)
         self.frontend = frontend
@@ -391,13 +386,6 @@ class FlightFrontendServer(flight.FlightServerBase):
     @property
     def address(self) -> str:
         return _advertised_address(self._location, self.port)
-
-    def serve_in_background(self) -> threading.Thread:
-        from ..common.runtime import new_thread
-        t = new_thread(self.serve, daemon=True, name="flight-frontend",
-                       propagate_context=False)
-        t.start()
-        return t
 
     def do_get(self, context, ticket):
         raw = ticket.ticket

@@ -36,7 +36,8 @@ SST page.
 
 Knobs: ``SET sst_index = 0|1`` (env twin ``GREPTIME_SST_INDEX``)
 gates both sidecar writes and every index consult; off reproduces the
-pre-index read path exactly — the bench differential's kill switch.
+pre-index read path exactly — the reference tests/test_sst_index.py
+holds indexed answers to.
 """
 
 from __future__ import annotations
@@ -70,7 +71,7 @@ _NUM_HASHES = 7
 _RG_EXACT_MAX_SIDS = 131072
 
 #: SET sst_index / GREPTIME_SST_INDEX: single-slot swap, read lock-free
-#: on the hot path (the scan_fusion knob pattern)
+#: on the hot path
 _INDEX_ENABLED = [env_flag("GREPTIME_SST_INDEX", True)]
 
 

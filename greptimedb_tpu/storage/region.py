@@ -99,8 +99,8 @@ class IngestProfile:
 class ScanProfile:
     """Stage-by-stage breakdown of the last aggregate scan over this
     region — the scan twin of IngestProfile (published via EXPLAIN
-    ANALYZE, /status and bench.py; the observability tests assert the
-    two views agree). `path` names the route taken: "resident" (scan
+    ANALYZE and /status; the observability tests assert the two views
+    agree). `path` names the route taken: "resident" (scan
     cache + device kernel) or "streamed" (cold slice streaming).
     `counters` carries path facts (slices, lean vs merged, cache hit)
     under the same names EXPLAIN ANALYZE prints."""

@@ -45,7 +45,7 @@ def _reset_front_door_knobs():
                    retry_after_s=gate_snap["retry_after_s"])
     configure_group_commit(enabled=gc_snap[0], max_wait_us=gc_snap[1],
                            max_batch=gc_snap[2])
-    configure_coalescer(enabled=co_snap[0], window_ms=co_snap[1])
+    configure_coalescer(window_ms=co_snap)
 
 
 @pytest.fixture()
@@ -472,7 +472,7 @@ class TestGroupCommit:
 
 class TestCoalescer:
     def test_concurrent_same_shape_requests_merge(self, frontend):
-        configure_coalescer(enabled=True, window_ms=25)
+        configure_coalescer(window_ms=25)
         from greptimedb_tpu.session import QueryContext
         ctx = QueryContext()
         start = threading.Barrier(5)
@@ -505,7 +505,7 @@ class TestCoalescer:
     def test_shared_error_reaches_every_member(self, frontend):
         """A cohort whose shared insert fails errors EVERY member —
         none of their rows are durable, none may be acked."""
-        configure_coalescer(enabled=True, window_ms=25)
+        configure_coalescer(window_ms=25)
         from greptimedb_tpu.session import QueryContext
         frontend.do_query(
             "CREATE TABLE co_err (host STRING, ts TIMESTAMP TIME INDEX, "
@@ -540,7 +540,7 @@ class TestCoalescer:
         """Requests whose column signatures differ stay separate, so a
         request needing a different auto-create shape cannot poison a
         stranger's ack."""
-        configure_coalescer(enabled=True, window_ms=25)
+        configure_coalescer(window_ms=25)
         from greptimedb_tpu.session import QueryContext
         ctx = QueryContext()
         start = threading.Barrier(2)
@@ -571,7 +571,7 @@ class TestCoalescer:
         assert results.get("narrow") == 1
 
     def test_disabled_coalescer_is_passthrough(self, frontend):
-        configure_coalescer(enabled=False)
+        configure_coalescer(window_ms=0)
         from greptimedb_tpu.session import QueryContext
         n = COALESCER.ingest(
             frontend, "co_direct", {"ts": [1], "v": [1.0]},
@@ -583,7 +583,7 @@ class TestCoalescer:
         """End to end over HTTP: concurrent line-protocol bodies for one
         measurement still ack 204 each and land every row."""
         from greptimedb_tpu.servers.http import HttpServer
-        configure_coalescer(enabled=True, window_ms=25)
+        configure_coalescer(window_ms=25)
         srv = HttpServer(frontend, addr="127.0.0.1:0")
         srv.start()
         try:
@@ -713,14 +713,3 @@ class TestScanFusion:
         finally:
             self._teardown(tpu_exec)
 
-    def test_fusion_disabled_by_knob(self, frontend):
-        tpu_exec = self._setup(frontend)
-        try:
-            frontend.do_query("SET scan_fusion = 0")
-            assert tpu_exec._FUSION_ENABLED[0] is False
-            out = frontend.do_query(
-                "SELECT host, max(v) FROM fuse GROUP BY host")[0]
-            assert len(list(out.batches[0].rows())) == 4
-        finally:
-            frontend.do_query("SET scan_fusion = 1")
-            self._teardown(tpu_exec)

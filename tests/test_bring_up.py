@@ -61,12 +61,12 @@ class TestCompileCachePlacement:
 
     def test_one_guarded_call_site(self):
         hits = []
-        for root in ("greptimedb_tpu", "benchmarks"):
+        for root in ("greptimedb_tpu", "benchmark"):
             for dirpath, _, files in os.walk(os.path.join(REPO, root)):
                 hits += [os.path.join(dirpath, f) for f in files
                          if f.endswith(".py")]
         hits += [os.path.join(REPO, f) for f in
-                 ("bench.py", "chip_smoke.py", "__graft_entry__.py")]
+                 ("chip_smoke.py", "__graft_entry__.py")]
         sites = []
         for path in hits:
             with open(path, encoding="utf-8") as f:

@@ -131,7 +131,9 @@ class TestClusterObservability:
 
     @pytest.fixture()
     def wire_cluster(self, tmp_path):
-        meta_srv = MetaSrv(MemKv())
+        # a lease no loaded box outlives between the set-up's one
+        # heartbeat and a test's first look; expiry is probed with `now`
+        meta_srv = MetaSrv(MemKv(), datanode_lease_secs=3600)
         meta_server = FlightMetaServer(meta_srv)
         meta_server.serve_in_background()
         _wait_port(meta_server)
@@ -169,31 +171,40 @@ class TestClusterObservability:
         datanode it touched (datanodes used to mint their own)."""
         import logging
 
+        from greptimedb_tpu.common import failpoint
         from greptimedb_tpu.common.telemetry import (
             set_slow_query_threshold_ms)
         fe, _, _ = wire_cluster
-        set_slow_query_threshold_ms(1)
+        sql = "SELECT host, count(*) AS c FROM obs GROUP BY host"
+        # slow on purpose, not by the box's mood: a datanode that holds a
+        # scan of the table and then takes new rows refreshes that scan
+        # for the next statement, and the refresh sleeps past the threshold
+        fe.do_query(sql)
+        fe.do_query("INSERT INTO obs VALUES " + ", ".join(
+            f"('h{i}', 9000, 1.0)" for i in range(8)))
+        set_slow_query_threshold_ms(20)
         try:
             with caplog.at_level(logging.WARNING,
-                                 logger="greptimedb_tpu.slow_query"):
-                fe.do_query(
-                    "SELECT host, count(*) AS c FROM obs GROUP BY host")
+                                 logger="greptimedb_tpu.slow_query"), \
+                    failpoint.cfg("scan_cache_incremental", "delay(30)"):
+                fe.do_query(sql)
         finally:
             set_slow_query_threshold_ms(None)
         import re
 
-        def traces(needle):
-            return {re.search(r"trace=(\S+)", r.getMessage()).group(1)
+        # this statement's lines only: the logger is the process's
+        def traces(needle, mine):
+            return [re.search(r"trace=(\S+)", r.getMessage()).group(1)
                     for r in caplog.records
-                    if needle in r.getMessage()}
-        fe_traces = traces("slow query:")
-        dn_traces = traces("slow datanode op:")
+                    if needle in r.getMessage() and mine in r.getMessage()]
+        fe_traces = traces("slow query:", repr(sql))
+        dn_traces = traces("slow datanode op:", "table=obs")
         assert len(fe_traces) == 1, caplog.text
-        assert dn_traces, "datanode side must log the slow op too"
-        assert dn_traces == fe_traces, \
+        assert len(dn_traces) == 2, caplog.text    # both datanodes refresh
+        assert set(dn_traces) == set(fe_traces), \
             f"trace ids diverged: fe={fe_traces} dn={dn_traces}"
         # a bare 32-hex trace id, not a whole traceparent header
-        assert "-" not in next(iter(fe_traces))
+        assert "-" not in fe_traces[0]
 
     def _analyze_rows(self, fe, sql):
         out = fe.do_query("EXPLAIN ANALYZE " + sql)[-1]

@@ -363,8 +363,7 @@ class TestProxyFidelity:
 class TestInactiveMode:
     def test_tracked_state_is_identity_when_off(self):
         """GREPTIME_RACE_CHECK=0 ⇒ tracked_state returns its argument
-        unchanged (same object, plain type) — production pays nothing
-        (bench.py greptsan_inactive_overhead asserts the wall clock)."""
+        unchanged (same object, plain type) — production pays nothing."""
         code = (
             "from greptimedb_tpu.devtools.greptsan import tracked_state,"
             " enabled\n"

@@ -154,8 +154,7 @@ class TestIoUnderLock:
 class TestInactiveMode:
     def test_disabled_factory_returns_raw_lock(self):
         """GREPTIME_LOCK_CHECK=0 ⇒ plain threading primitives, nothing
-        wrapped — production pays zero per-acquire cost (bench.py
-        asserts the ns differential)."""
+        wrapped — production pays zero per-acquire cost."""
         code = (
             "from greptimedb_tpu.common.locks import TrackedLock, "
             "TrackedRLock, enabled\n"

@@ -1,7 +1,7 @@
 """Perf-smoke: the bulk_ingest stage profiler end to end on ~1M rows.
 
-Slow-marked so tier-1 stays inside its timeout; the driver's perf bars
-are measured by benchmarks/cold_scan.py — this test only asserts the
+Slow-marked so tier-1 stays inside its timeout; speeds are the
+benchmark's (`benchmark/run.py`) — this test only asserts the
 profiling machinery the ingest stage breakdown is built from keeps working
 (stages present, times positive, rows counted, merge() accumulates).
 """

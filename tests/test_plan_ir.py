@@ -451,6 +451,13 @@ class TestPromqlExplain:
                 "sum by (host) (rate(ctr[1m]))")[0].batches)
             assert "aggregate-pushdown" in out
             assert "fan-out" in out
+            # and what executes ships partial frames, not rows
+            from greptimedb_tpu.common import exec_stats
+            stats = exec_stats.ExecStats()
+            with exec_stats.collect(stats):
+                dist.do_query("TQL EVAL (0, 790, '60s') "
+                              "sum by (host) (rate(ctr[1m]))")
+            assert stats.totals()["partial_bytes"] > 0
         finally:
             for dn in datanodes.values():
                 dn.shutdown()

@@ -401,12 +401,15 @@ class TestSetVariableUnified:
         dn.shutdown()
 
     @pytest.mark.parametrize("which", ["standalone", "distributed"])
-    def test_unknown_variable_errors_identically(self, which, fe,
+    @pytest.mark.parametrize("name", [
+        "slow_query_treshold_ms",                # typo'd
+        "scan_fusion", "ingest_coalesce"])       # removed switches
+    def test_unknown_variable_errors_identically(self, name, which, fe,
                                                  dist_fe):
         target = fe if which == "standalone" else dist_fe
         with pytest.raises(InvalidArgumentsError,
                            match="unknown session variable"):
-            target.do_query("SET slow_query_treshold_ms = 5")  # typo'd
+            target.do_query(f"SET {name} = 0")
 
     @pytest.mark.parametrize("which", ["standalone", "distributed"])
     def test_compat_and_known_knobs_accepted(self, which, fe, dist_fe):

@@ -554,6 +554,15 @@ class TestObservability:
             # ExecStats totals carry partial bytes (processes view)
             totals = fe.query_engine.last_exec_stats.totals()
             assert totals["partial_bytes"] > 0
+            # the pushdown ships at most a third of what the raw-row
+            # scatter would: the columns the statement reads, as bytes
+            table = fe.catalog.table("greptime", "public", "ob")
+            raw_bytes = sum(
+                sum(len(str(v)) for v in col.data)
+                if col.data.dtype == object else col.data.nbytes
+                for b in table.scan_batches(projection=["host", "ts", "a"])
+                for col in b.columns)
+            assert raw_bytes >= 3 * totals["partial_bytes"]
             # the information_schema view exposes the column
             out = fe.do_query("SELECT partial_bytes FROM "
                               "information_schema.processes", ctx)[-1]
