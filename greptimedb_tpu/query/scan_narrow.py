@@ -1,0 +1,249 @@
+"""The resident reduce narrowed to the selected series' row ranges.
+
+The scan cache is sorted by (series, time), so a statement whose tag
+predicates keep a few series needs a few contiguous slices of the
+resident mirrors: each series one range of rows, its time window a
+contiguous part of that, both found by binary search. The device cuts
+those slices out of its own mirrors into a compact block and runs the
+same grouped aggregate over it (`ops/kernels.py:sorted_grouped_aggregate`);
+runs, mask, fetch and collect are sized by the selected rows, not by
+the table. `scan_read_path` chooses between this and the full launch
+(`tpu_exec._launch_scan_kernel`) from what it can count.
+"""
+
+from __future__ import annotations
+
+import functools
+from dataclasses import dataclass
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..ops.kernels import shape_bucket, sorted_grouped_aggregate
+from . import tpu_exec
+
+#: The narrowed launch runs while the compact block (`padded_rows`:
+#: range bucket x length bucket) is at most 1 / this of the table.
+#: Measured on a v5e (PR 31; 4,000 series x 4,320 rows = 17.28M, the whole
+#: resident `reduce` of one statement, ms narrow / full, by table rows
+#: over padded rows): `max` of ten fields by hour over 8 h: 527x 7.6 /
+#: 179, 66x 12.0 / 179, 16x 38.0 / 183, 8.2x 65.6 / 186, 4.1x 203.6 /
+#: 195.7, 2.1x 697 / 208; `max` of five by minute (the full launch then
+#: takes the high-cardinality kernels) over 1 h: 4,219x 6.2 / 1,528, 66x
+#: 18.7 / 1,569, 8.2x 118 / 1,599 (4,000 ranges of 512); over 12 h: 33x
+#: 15.6 / 1,541, 8.2x 61.8 / 1,562, 4.1x 124 / 1,565, 2.1x 404 / 1,404.
+#: They meet at 4.1x in the first shape (8,192 groups: the low-cardinality
+#: kernels' edge windows gather 64 values a group and column); the
+#: constant is the smallest share at which narrow was at least twice as
+#: fast in every shape.
+_NARROW_MAX_SHARE = 8
+
+#: shortest compact range: shorter selections share one program
+_MIN_RANGE_LEN = 512
+
+
+def scan_read_path(n_rows: int, n_ranges: Optional[int],
+                   padded_rows: int) -> str:
+    """"narrow" or "full": how the resident reduce reads a table of
+    `n_rows` for a statement whose point / IN tag conjuncts resolved to
+    `n_ranges` row ranges (None: it has no such conjunct) that pad to
+    `padded_rows` compact rows. The one place that chooses, from shapes
+    and counts alone."""
+    if n_ranges is None:
+        return "full"
+    return "narrow" if padded_rows * _NARROW_MAX_SHARE <= n_rows else "full"
+
+
+@dataclass
+class Selection:
+    """The row ranges a statement's tag predicates and time window keep:
+    range i is rows [starts[i], starts[i] + lens[i]) of series sids[i]."""
+    sids: np.ndarray                  # int32 [k], ascending
+    starts: np.ndarray                # int64 [k], table coordinates
+    lens: np.ndarray                  # int64 [k], all > 0
+
+    @property
+    def n_ranges(self) -> int:
+        return len(self.starts)
+
+    @property
+    def rows(self) -> int:
+        return int(self.lens.sum())
+
+    @property
+    def range_bucket(self) -> int:
+        return shape_bucket(self.n_ranges, minimum=1)
+
+    @property
+    def len_bucket(self) -> int:
+        return shape_bucket(int(self.lens.max(initial=0)),
+                            minimum=_MIN_RANGE_LEN)
+
+    @property
+    def padded_rows(self) -> int:
+        return self.range_bucket * self.len_bucket
+
+
+def _lower_bound(ts: np.ndarray, lo: np.ndarray, hi: np.ndarray,
+                 value: int) -> np.ndarray:
+    """Per range [lo[i], hi[i]) of `ts` (ascending inside a range): the
+    first row whose ts >= value. Every range bisects at once, so the
+    cost is log2(longest range) passes over k, never a pass over ts."""
+    lo, hi = lo.copy(), hi.copy()
+    while True:
+        open_ = lo < hi
+        if not open_.any():
+            return lo
+        mid = (lo + hi) >> 1
+        right = open_ & (ts[np.minimum(mid, len(ts) - 1)] < value)
+        lo = np.where(right, mid + 1, lo)
+        hi = np.where(open_ & ~right, mid, hi)
+
+
+def select(scan, schema, plan) -> Optional[Selection]:
+    """The ranges the statement keeps, or None when it has no point / IN
+    tag conjunct to resolve to series (`sid_candidates_for_filters`, a
+    superset by contract; every tag predicate is then applied exactly to
+    those candidates only). No array of the table's length is built."""
+    if not plan.tag_predicates:
+        return None
+    from ..mito.engine import sid_candidates_for_filters
+    sd = scan.series_dict
+    tag_names = schema.tag_names()
+    cand = sid_candidates_for_filters(sd, tag_names, plan.tag_predicates)
+    if cand is None:
+        return None
+    if len(cand):
+        cand = cand[tpu_exec._series_keep(sd, tag_names, cand,
+                                          plan.tag_predicates)]
+    lo = np.searchsorted(scan.series_ids, cand, side="left")
+    hi = np.searchsorted(scan.series_ids, cand, side="right")
+    if plan.time_lo is not None:
+        lo = _lower_bound(scan.ts, lo, hi, plan.time_lo)
+    if plan.time_hi is not None:
+        hi = _lower_bound(scan.ts, lo, hi, plan.time_hi)
+    live = hi > lo
+    return Selection(cand[live].astype(np.int32, copy=False),
+                     lo[live].astype(np.int64), (hi - lo)[live])
+
+
+def _cut(col, at, len_b: int):
+    """[k_b * len_b]: the slices col[at[i] : at[i] + len_b], end to end.
+    Contiguous slices, not a row gather: on a v5e (PR 31, six f32 columns
+    of 17.28M rows) 8 x 4,096 rows take 1.3 ms against 5.9, 64 x 4,096
+    1.7 against 39.6, 512 x 4,096 5.5 against 307.7 (24 ns a gathered
+    value); a Python loop of `dynamic_slice`s is as fast up to 64 ranges
+    and compiles a slice a range."""
+    return jax.vmap(
+        lambda a: jax.lax.dynamic_slice(col, (a,), (len_b,)))(at).reshape(-1)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "len_b", "num_groups", "ops", "value_ix", "mask_ix", "seg_len_k"))
+def _narrow_reduce(cuts, ends, rid, row_mask, cols, *, len_b, num_groups,
+                   ops, value_ix, mask_ix, seg_len_k):
+    """Cut `cuts` out of the resident columns and reduce the compact
+    block. cuts int32 [3, k_b]: each range's slice start (clamped by the
+    host so that start + len_b stays inside the table) and the offsets
+    [lo, hi) of its live rows inside that slice; cols[0] is ts; value_ix
+    / mask_ix index cols per moment (mask -1: the column has no NULL)."""
+    at, lo, hi = cuts
+    j = jnp.arange(len_b, dtype=jnp.int32)[None, :]
+    live = ((j >= lo[:, None]) & (j < hi[:, None])).reshape(-1)
+    if row_mask is not None:
+        live = live & row_mask
+    cut = [_cut(c, at, len_b) for c in cols]
+    ts = cut[0]
+    return sorted_grouped_aggregate(
+        ts if rid is None else rid, live, ts,
+        tuple(cut[i] for i in value_ix),
+        tuple(live if i < 0 else cut[i] for i in mask_ix),
+        num_groups=num_groups, ops=ops, has_col_masks=True, ends=ends,
+        seg_len_k=seg_len_k)
+
+
+def launch(scan, schema, plan, sel: Selection, part):
+    """-> tpu_exec._Launched over the selection's runs, or None when it
+    is empty. `part(name)` times the host's steps as the full launch's
+    do: `runs`, `mask` (field filters only), `upload`, `launch`."""
+    k, total = sel.n_ranges, sel.rows
+    if k == 0:
+        return None
+    n = scan.num_rows
+    k_b, len_b = sel.range_bucket, sel.len_bucket
+    with part("runs"):
+        # compact coordinates: range i lives in [i * len_b, (i+1) * len_b),
+        # its rows from `off[i]` on (0 unless the slice was clamped at the
+        # table's end)
+        at = np.minimum(sel.starts, n - len_b)
+        off = sel.starts - at
+        first = np.cumsum(sel.lens) - sel.lens       # selected-row index
+        within = np.arange(total, dtype=np.int64) - \
+            np.repeat(first, sel.lens)
+        rows = np.repeat(sel.starts, sel.lens) + within
+        pos = np.repeat(np.arange(k, dtype=np.int64) * len_b + off,
+                        sel.lens) + within
+        flags = np.zeros(total, dtype=bool)
+        buckets = None
+        if plan.bucket is not None:
+            b = plan.bucket
+            buckets = (scan.ts[rows] - b.origin) // b.stride_ms
+            flags[1:] = buckets[1:] != buckets[:-1]
+        if plan.bucket is not None or plan.tag_groups:
+            flags[first] = True
+        flags[0] = True
+        run_rows = np.nonzero(flags)[0]
+        run_starts = pos[run_rows]
+        run_starts[0] = 0        # runs tile the block: padding joins a run
+        ops, value_ix, mask_ix, cols = _columns(scan, schema, plan)
+        nbucket, run_ends, rid, seg_len_k = tpu_exec._segment_layout(
+            run_starts, k_b * len_b, ops)
+    row_mask = None
+    if plan.field_filters:
+        with part("mask"):
+            keep = np.ones(total, dtype=bool)
+            for ff in plan.field_filters:
+                keep &= tpu_exec._field_filter_keep(scan, ff, rows)
+            row_mask = np.zeros(k_b * len_b, dtype=bool)
+            row_mask[pos] = keep
+    with part("upload"):
+        cuts = np.zeros((3, k_b), dtype=np.int32)
+        cuts[0, :k], cuts[1, :k], cuts[2, :k] = at, off, off + sel.lens
+    with part("launch"):
+        results, counts = _narrow_reduce(
+            cuts, run_ends, rid, row_mask, cols, len_b=len_b,
+            num_groups=nbucket, ops=ops, value_ix=value_ix,
+            mask_ix=mask_ix, seg_len_k=seg_len_k)
+    run_range = np.searchsorted(first, run_rows, side="right") - 1
+    # warm stays False: the dispatch floor (`_note_device_query_time`)
+    # is fed by full launches, whose fixed cost it stands for
+    return tpu_exec._Launched(
+        tuple(results), counts, len(run_starts), sel.sids[run_range],
+        buckets[run_rows] if buckets is not None else None,
+        scan.series_dict, scan.ts_base)
+
+
+def _columns(scan, schema, plan):
+    """-> (ops, value_ix, mask_ix, cols): the moments' kernel ops and the
+    resident columns they read, each column once (cols[0] is ts; a mask
+    index of -1: the column has no NULL)."""
+    cols = [scan.device_ts()]
+    index = {}
+
+    def col_ix(key, get):
+        if key not in index:
+            index[key] = len(cols)
+            cols.append(get(key[1]))
+        return index[key]
+
+    ops, value_ix, mask_ix = [], [], []
+    for op, field_read, masked_by in tpu_exec._moment_reads(schema, plan):
+        ops.append(op)
+        value_ix.append(0 if field_read is None
+                        else col_ix(("f", field_read), scan.device_field))
+        mask_ix.append(
+            -1 if masked_by is None or scan.fields[masked_by][1] is None
+            else col_ix(("v", masked_by), scan.device_valid))
+    return tuple(ops), tuple(value_ix), tuple(mask_ix), tuple(cols)

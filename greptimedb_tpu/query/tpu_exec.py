@@ -1452,8 +1452,24 @@ def _moment_frame_for_scan(scan: MergedScan, schema,
     import time as _time
 
     import jax
+
+    from ..common import exec_stats
+    from ..common.telemetry import increment_counter
+    from . import scan_narrow
     t0 = _time.perf_counter()
-    launched = _launch_scan_kernel(scan, schema, plan, _reduce_part)
+    with _reduce_part("mask"):
+        sel = scan_narrow.select(scan, schema, plan)
+    n_ranges, padded_rows = (None, 0) if sel is None \
+        else (sel.n_ranges, sel.padded_rows)
+    path = scan_narrow.scan_read_path(scan.num_rows, n_ranges, padded_rows)
+    increment_counter("scan_reads", path=path)
+    if path == "narrow":
+        exec_stats.record("reduce", path=path, narrow_rows=sel.rows,
+                          ranges=sel.n_ranges)
+        launched = scan_narrow.launch(scan, schema, plan, sel, _reduce_part)
+    else:
+        exec_stats.record("reduce", path=path)
+        launched = _launch_scan_kernel(scan, schema, plan, _reduce_part)
     if launched is None:
         return None
     with _reduce_part("fetch"):     # blocked on the device, then D2H
@@ -1499,52 +1515,20 @@ def _launch_scan_kernel(scan: MergedScan, schema, plan: TpuPlan,
         values = []
         col_masks = []
         ops = []
-        for m in plan.moments:
-            if m.op in ("min_ts", "max_ts"):
-                values.append(d_ts)
-                col_masks.append(scan.device_valid(m.column))
-                ops.append("min" if m.op == "min_ts" else "max")
-            elif m.column is None:
-                values.append(d_ts)   # dummy; count reads only the mask
-                col_masks.append(scan.device_valid_all())
-                ops.append("count")
-            else:
-                cs = schema.column_schema(m.column)
-                if cs.dtype.is_string or cs.dtype.is_binary:
-                    values.append(d_ts)
-                else:
-                    values.append(scan.device_field(m.column))
-                col_masks.append(scan.device_valid(m.column))
-                ops.append(m.op)
+        for op, field_read, masked_by in _moment_reads(schema, plan):
+            ops.append(op)
+            values.append(d_ts if field_read is None
+                          else scan.device_field(field_read))
+            col_masks.append(scan.device_valid_all() if masked_by is None
+                             else scan.device_valid(masked_by))
 
     with part("runs"):
-        nbucket = shape_bucket(nruns, minimum=256)
-        # segment ends are free on the host (run boundaries are already
-        # computed); shipping them skips the device binary search, the
-        # dominant cost at high run cardinality
-        run_ends = np.full(nbucket, n, dtype=np.int32)
-        run_ends[:nruns - 1] = run_starts[1:]
-        # with host ends the kernel reads gids for first/last (arg-extreme
-        # tie-break) and for high-cardinality min/max (the shift-doubling
-        # kernel's same-segment guard); for every other op ts stands in
-        # for shape and both the O(n) rid cumsum and its upload are
-        # skipped
-        from ..ops.kernels import _SEG_HIGH_CARD_THRESHOLD, seg_len_bucket
-        high_card = nbucket > _SEG_HIGH_CARD_THRESHOLD
-        needs_gids = any(op in ("first", "last") for op in ops) or \
-            (high_card and any(op in ("min", "max") for op in ops))
-        seg_len_k = None
-        if needs_gids:
-            if rid is None:
-                starts_mark = np.zeros(n, dtype=np.int32)
-                starts_mark[run_starts[1:]] = 1
-                rid = np.cumsum(starts_mark, dtype=np.int32)
-                scan.device[run_key] = (rid, nruns, run_starts, buckets)
-            # static ceil-log2 of the longest run, bucketized to even
-            # values so nearby layouts share one compile
-            lens = np.diff(run_starts, append=np.int64(n))
-            seg_len_k = seg_len_bucket(int(lens.max()) if len(lens) else 1)
-    if needs_gids:
+        had_rid = rid is not None
+        nbucket, run_ends, rid, seg_len_k = _segment_layout(
+            run_starts, n, ops, rid)
+        if rid is not None and not had_rid:
+            scan.device[run_key] = (rid, nruns, run_starts, buckets)
+    if rid is not None:
         with part("upload"):
             d_rid = jax.device_put(rid)
     else:
@@ -1563,6 +1547,55 @@ def _launch_scan_kernel(scan: MergedScan, schema, plan: TpuPlan,
     return _Launched(tuple(results), counts, nruns, sids[run_starts],
                      buckets[run_starts] if buckets is not None else None,
                      scan.series_dict, scan.ts_base, warm)
+
+
+def _moment_reads(schema, plan: TpuPlan):
+    """-> per moment (kernel op, the field it reads, the column whose
+    validity masks it). No field: ts stands in (a ts extreme; a count or
+    a string column, which read only the mask). No column: a row count."""
+    for m in plan.moments:
+        if m.op in ("min_ts", "max_ts"):
+            yield ("min" if m.op == "min_ts" else "max"), None, m.column
+        elif m.column is None:
+            yield "count", None, None
+        else:
+            dtype = schema.column_schema(m.column).dtype
+            yield m.op, (None if dtype.is_string or dtype.is_binary
+                         else m.column), m.column
+
+
+def _segment_layout(run_starts: np.ndarray, n: int, ops, rid=None):
+    """-> (num_groups, run_ends, rid, seg_len_k) for a launch over `n`
+    rows cut into runs at `run_starts`; `rid` (the per-row run ids, made
+    here unless handed in) and `seg_len_k` are None when no op reads
+    them."""
+    nruns = len(run_starts)
+    nbucket = shape_bucket(nruns, minimum=256)
+    # segment ends are free on the host (run boundaries are already
+    # computed); shipping them skips the device binary search, the
+    # dominant cost at high run cardinality
+    run_ends = np.full(nbucket, n, dtype=np.int32)
+    run_ends[:nruns - 1] = run_starts[1:]
+    # with host ends the kernel reads gids for first/last (arg-extreme
+    # tie-break) and for high-cardinality min/max (the shift-doubling
+    # kernel's same-segment guard); for every other op ts stands in
+    # for shape and both the O(n) rid cumsum and its upload are
+    # skipped
+    from ..ops.kernels import _SEG_HIGH_CARD_THRESHOLD, seg_len_bucket
+    high_card = nbucket > _SEG_HIGH_CARD_THRESHOLD
+    needs_gids = any(op in ("first", "last") for op in ops) or \
+        (high_card and any(op in ("min", "max") for op in ops))
+    if not needs_gids:
+        return nbucket, run_ends, None, None
+    if rid is None:
+        starts_mark = np.zeros(n, dtype=np.int32)
+        starts_mark[run_starts[1:]] = 1
+        rid = np.cumsum(starts_mark, dtype=np.int32)
+    # static ceil-log2 of the longest run, bucketized to even
+    # values so nearby layouts share one compile
+    lens = np.diff(run_starts, append=np.int64(n))
+    return nbucket, run_ends, rid, \
+        seg_len_bucket(int(lens.max()) if len(lens) else 1)
 
 
 def _scan_runs(scan: MergedScan, plan: TpuPlan):
@@ -1624,19 +1657,9 @@ def _scan_row_mask(scan: MergedScan, schema, plan: TpuPlan):
     base_mask = None
     if plan.tag_predicates:
         sd = scan.series_dict
-        S = sd.num_series
-        tag_cols = {}
-        for i, tname in enumerate(schema.tag_names()):
-            tag_cols[tname] = sd.decode_tag_column(
-                np.arange(S, dtype=np.int32), i)
-        sdf = pd.DataFrame(tag_cols)
-        ev = Evaluator(sdf)
-        smask = np.ones(S, dtype=bool)
-        for p in plan.tag_predicates:
-            m = ev.eval(p)
-            m = m.fillna(False).astype(bool).to_numpy() \
-                if isinstance(m, pd.Series) else np.full(S, bool(m))
-            smask &= m
+        smask = _series_keep(sd, schema.tag_names(),
+                             np.arange(sd.num_series, dtype=np.int32),
+                             plan.tag_predicates)
         if not smask.any():
             return _NO_ROWS
         base_mask = smask[scan.series_ids]
@@ -1658,18 +1681,44 @@ def _scan_row_mask(scan: MergedScan, schema, plan: TpuPlan):
     if plan.time_hi is not None:
         mask &= scan.ts < plan.time_hi
     for ff in plan.field_filters:
-        vals, valid = scan.fields[ff.column]
-        if vals.dtype == object:
-            raise UnsupportedError(
-                f"filter on non-numeric {ff.column}")
-        v = vals.astype(np.float64)
-        cmp = {"eq": v == ff.value, "ne": v != ff.value,
-               "lt": v < ff.value, "le": v <= ff.value,
-               "gt": v > ff.value, "ge": v >= ff.value}[ff.op]
-        if valid is not None:
-            cmp &= valid
-        mask &= cmp
+        mask &= _field_filter_keep(scan, ff)
     return mask if mask.any() else _NO_ROWS
+
+
+def _series_keep(sd, tag_names, sids: np.ndarray, predicates) -> np.ndarray:
+    """-> bool [len(sids)]: the series of `sids` that every tag predicate
+    keeps (NULL compares UNKNOWN and drops, as WHERE does)."""
+    k = len(sids)
+    read = set().union(*(_refs(p) for p in predicates))
+    sdf = pd.DataFrame({t: sd.decode_tag_column(sids, i)
+                        for i, t in enumerate(tag_names) if t in read})
+    ev = Evaluator(sdf)
+    keep = np.ones(k, dtype=bool)
+    for p in predicates:
+        m = ev.eval(p)
+        m = m.fillna(False).astype(bool).to_numpy() \
+            if isinstance(m, pd.Series) else np.full(k, bool(m))
+        keep &= m
+    return keep
+
+
+def _field_filter_keep(scan: MergedScan, ff,
+                       rows: Optional[np.ndarray] = None) -> np.ndarray:
+    """-> bool: the rows (all of the scan's, or those of `rows`) that the
+    field filter keeps; a NULL keeps nothing."""
+    vals, valid = scan.fields[ff.column]
+    if vals.dtype == object:
+        raise UnsupportedError(f"filter on non-numeric {ff.column}")
+    if rows is not None:
+        vals = vals[rows]
+        valid = valid[rows] if valid is not None else None
+    v = vals.astype(np.float64)
+    cmp = {"eq": v == ff.value, "ne": v != ff.value,
+           "lt": v < ff.value, "le": v <= ff.value,
+           "gt": v > ff.value, "ge": v >= ff.value}[ff.op]
+    if valid is not None:
+        cmp &= valid
+    return cmp
 
 
 def _collect_moment_frame(launched: _Launched, plan: TpuPlan,

@@ -1,0 +1,454 @@
+"""The resident launch narrowed to the selected series' row ranges
+(ISSUE 31, `query/scan_narrow.py`): a statement whose tag predicates keep
+a few series answers from slices of the device mirrors, with the answers
+of the full launch.
+
+The table: 24 hosts x 800 ticks (19,200 rows) in three regions, NULLs in
+`idle`, written out of order across two flushes and a memtable, with a
+few rows overwritten. `min`, `max`, `count`, `first`, `last` must equal
+the full launch bit for bit; `sum` and `avg` add fewer f32 values and
+are held to a float64 pandas reference at 1e-5 relative (PERF.md
+section 6).
+"""
+
+import contextlib
+
+import numpy as np
+import pandas as pd
+import pytest
+
+from greptimedb_tpu.common.telemetry import registry_snapshot
+from greptimedb_tpu.datanode.instance import DatanodeInstance, DatanodeOptions
+from greptimedb_tpu.frontend.instance import FrontendInstance
+from greptimedb_tpu.query import scan_narrow, tpu_exec
+
+HOSTS, TICKS, TICK_MS = 24, 800, 10_000
+T0 = 1_700_000_040_000                      # a whole minute
+AVG_RTOL = 1e-5
+
+
+def _value(h, t, gen):
+    return float((h * 37 + t * 11 + gen * 5) % 1009) / 7.0
+
+
+def _rows():
+    """(host, region, ts, usage, idle, generation): every (host, tick)
+    once, the ticks divisible by 50 a second time with another value."""
+    rows = []
+    for h in range(HOSTS):
+        for t in range(TICKS):
+            idle = None if (h + t) % 7 == 0 else float(h + t / 10)
+            rows.append((f"h{h:02d}", f"r{h % 3}", T0 + t * TICK_MS,
+                         _value(h, t, 0), idle, 0))
+            if t % 50 == 0:
+                rows.append((f"h{h:02d}", f"r{h % 3}", T0 + t * TICK_MS,
+                             _value(h, t, 1), idle, 1))
+    return rows
+
+
+def _lit(v):
+    return "NULL" if v is None else repr(v)
+
+
+class Db:
+    def __init__(self, data_home):
+        self.dn = DatanodeInstance(DatanodeOptions(
+            data_home=data_home, register_numbers_table=False))
+        self.dn.start()
+        self.fe = FrontendInstance(self.dn)
+        self.fe.start()
+        self.fe.do_query(
+            "CREATE TABLE cpu (host STRING, region STRING, "
+            "ts TIMESTAMP TIME INDEX, usage DOUBLE, idle DOUBLE, "
+            "PRIMARY KEY(host, region))")
+        rows = _rows()
+        first = [r for r in rows if r[5] == 0]
+        # three writes, none in key order: odd ticks, flush; even ticks
+        # newest first, flush; the overwrites stay in the memtable
+        for batch, flush in (
+                ([r for r in first if (r[2] // TICK_MS) % 2], True),
+                ([r for r in first if not (r[2] // TICK_MS) % 2][::-1], True),
+                ([r for r in rows if r[5] == 1], False)):
+            values = ", ".join(
+                f"('{h}', '{g}', {ts}, {_lit(u)}, {_lit(i)})"
+                for h, g, ts, u, i, _ in batch)
+            self.fe.do_query(f"INSERT INTO cpu VALUES {values}")
+            if flush:
+                self.fe.do_query("ADMIN FLUSH TABLE cpu")
+        latest = {}
+        for h, g, ts, u, i, _ in rows:
+            latest[(h, ts)] = (h, g, ts, u, i)
+        self.ref = pd.DataFrame(sorted(latest.values()),
+                                columns=["host", "region", "ts", "usage",
+                                         "idle"])
+        # a statement over every series builds the scan cache: without it
+        # a point statement answers through the SST index, off the device
+        self.sql("SELECT host, max(usage) FROM cpu GROUP BY host")
+
+    def close(self):
+        self.fe.shutdown()
+
+    def sql(self, sql: str) -> pd.DataFrame:
+        # the dispatch floor is process-global and latency-adaptive
+        self.fe.do_query("SET tpu_dispatch_min_rows = 1")
+        out = self.fe.do_query(sql)
+        out = out[-1] if isinstance(out, list) else out
+        frames = [pd.DataFrame(b.to_pydict()) for b in out.batches]
+        return pd.concat(frames, ignore_index=True) if frames else \
+            pd.DataFrame()
+
+    def stages(self, sql: str) -> dict:
+        rows = self.sql("EXPLAIN ANALYZE " + sql)
+        return {r.stage: r.detail or "" for r in rows.itertuples()}
+
+
+@pytest.fixture(scope="module")
+def db(tmp_path_factory):
+    d = Db(str(tmp_path_factory.mktemp("narrow")))
+    yield d
+    d.fe.do_query("SET tpu_dispatch_min_rows = 131072")
+    d.close()
+
+
+@pytest.fixture
+def full(monkeypatch):
+    """-> a context in which every resident launch is the full one."""
+    @contextlib.contextmanager
+    def forced():
+        with monkeypatch.context() as m:
+            m.setattr(scan_narrow, "_NARROW_MAX_SHARE", 10**12)
+            yield
+    return forced
+
+
+def counter(path: str) -> float:
+    return sum(value for name, labels, value, _ in registry_snapshot()
+               if name == "greptime_scan_reads_total"
+               and f'path="{path}"' in labels)
+
+
+def both(db, full, sql):
+    """-> (narrow answer, full answer), each by the path it names."""
+    before = counter("narrow"), counter("full")
+    narrow = db.sql(sql)
+    assert (counter("narrow"), counter("full")) == \
+        (before[0] + 1, before[1]), "the statement did not run narrow"
+    with full():
+        whole = db.sql(sql)
+    assert counter("full") == before[1] + 1
+    return narrow, whole
+
+
+def in_list(hosts):
+    return ", ".join(f"'h{h:02d}'" for h in hosts)
+
+
+LO, HI = T0 + 30 * TICK_MS, T0 + 330 * TICK_MS
+WINDOW = f"ts >= {LO} AND ts < {HI}"
+
+#: (id, WHERE, pandas filter) — every selection lies on the narrow side
+PREDICATES = [
+    ("eq", "host = 'h05'", lambda r: r.host == "h05"),
+    ("in", f"host IN ({in_list([3, 11, 20])})",
+     lambda r: r.host.isin(["h03", "h11", "h20"])),
+    ("in-and-ne", f"host IN ({in_list([3, 11, 20, 21])}) AND region != 'r2'",
+     lambda r: r.host.isin(["h03", "h11", "h20", "h21"])
+     & (r.region != "r2")),
+    ("first-and-last-series", f"host IN ({in_list([0, HOSTS - 1])})",
+     lambda r: r.host.isin(["h00", f"h{HOSTS - 1:02d}"])),
+]
+
+#: (id, SELECT list, GROUP BY, reference keys)
+GROUPINGS = [
+    ("plain", "", "", []),
+    ("by-host", "host, ", "host", ["host"]),
+    ("by-minute", "date_bin(INTERVAL '1 minute', ts) AS minute, ",
+     "minute", ["minute"]),
+    ("by-host-and-minute",
+     "host, date_bin(INTERVAL '1 minute', ts) AS minute, ",
+     "host, minute", ["host", "minute"]),
+]
+
+EXACT = ("max(usage), min(usage), count(usage), count(idle), max(idle), "
+         "first(usage), last(idle)")
+
+
+def reference(ref: pd.DataFrame, keep, keys, windowed=True) -> pd.DataFrame:
+    r = ref[keep(ref)]
+    if windowed:
+        r = r[(r.ts >= LO) & (r.ts < HI)]
+    r = r.assign(minute=r.ts // 60_000 * 60_000).sort_values(["host", "ts"])
+    aggs = dict(
+        mx=("usage", "max"), mn=("usage", "min"), n=("usage", "count"),
+        ni=("idle", "count"), mxi=("idle", "max"),
+        sm=("usage", "sum"), av=("usage", "mean"), avi=("idle", "mean"))
+    if keys:
+        return r.groupby(keys).agg(**aggs).reset_index()
+    return pd.DataFrame({k: [getattr(r[c], f)()] for k, (c, f)
+                         in aggs.items()})
+
+
+@pytest.mark.parametrize("group", GROUPINGS, ids=[g[0] for g in GROUPINGS])
+@pytest.mark.parametrize("pred", PREDICATES, ids=[p[0] for p in PREDICATES])
+def test_exact_aggregates_equal_the_full_launch(db, full, pred, group):
+    _, where, keep = pred
+    _, select, group_by, keys = group
+    sql = f"SELECT {select}{EXACT} FROM cpu WHERE {where} AND {WINDOW}"
+    if group_by:
+        sql += f" GROUP BY {group_by} ORDER BY {group_by}"
+    narrow, whole = both(db, full, sql)
+    assert len(narrow) > 0
+    pd.testing.assert_frame_equal(narrow, whole, check_exact=True)
+    want = reference(db.ref, keep, keys)
+    assert len(narrow) == len(want)
+    # f32 mirrors: the extremes are the f32 roundings of the reference's
+    assert np.array_equal(narrow["max(usage)"].to_numpy(),
+                          want.mx.to_numpy().astype(np.float32))
+    assert np.array_equal(narrow["count(usage)"].to_numpy(), want.n)
+    assert np.array_equal(narrow["count(idle)"].to_numpy(), want.ni)
+
+
+@pytest.mark.parametrize("group", GROUPINGS, ids=[g[0] for g in GROUPINGS])
+@pytest.mark.parametrize("pred", PREDICATES, ids=[p[0] for p in PREDICATES])
+def test_sum_and_avg_within_tolerance_of_float64(db, full, pred, group):
+    _, where, keep = pred
+    _, select, group_by, keys = group
+    sql = (f"SELECT {select}sum(usage), avg(usage), avg(idle) FROM cpu "
+           f"WHERE {where} AND {WINDOW}")
+    if group_by:
+        sql += f" GROUP BY {group_by} ORDER BY {group_by}"
+    narrow, whole = both(db, full, sql)
+    want = reference(db.ref, keep, keys)
+    for got in (narrow, whole):
+        np.testing.assert_allclose(got["sum(usage)"], want.sm, rtol=AVG_RTOL)
+        np.testing.assert_allclose(got["avg(usage)"], want.av, rtol=AVG_RTOL)
+        np.testing.assert_allclose(got["avg(idle)"], want.avi, rtol=AVG_RTOL)
+
+
+def test_no_time_window_and_the_clamped_slice(db, full):
+    """The last series' whole range ends at the table's last row: its
+    slice of 1,024 starts before the range (clamped), the first series'
+    at row 0."""
+    sql = (f"SELECT host, {EXACT}, avg(usage) FROM cpu WHERE host IN "
+           f"({in_list([0, HOSTS - 1])}) GROUP BY host ORDER BY host")
+    narrow, whole = both(db, full, sql)
+    pd.testing.assert_frame_equal(narrow.drop(columns="avg(usage)"),
+                                  whole.drop(columns="avg(usage)"),
+                                  check_exact=True)
+    want = reference(db.ref, lambda r: r.host.isin(["h00", "h23"]),
+                     ["host"], windowed=False)
+    assert list(narrow["count(usage)"]) == [TICKS, TICKS] == list(want.n)
+    np.testing.assert_allclose(narrow["avg(usage)"], want.av, rtol=AVG_RTOL)
+    # a window that keeps only the table's last rows
+    tail = (f"SELECT max(usage), count(usage) FROM cpu WHERE host = 'h23' "
+            f"AND ts >= {T0 + (TICKS - 3) * TICK_MS}")
+    narrow, whole = both(db, full, tail)
+    pd.testing.assert_frame_equal(narrow, whole, check_exact=True)
+    assert list(narrow["count(usage)"]) == [3]
+
+
+def test_field_filter_rides_along(db, full):
+    sql = (f"SELECT host, count(usage), max(usage), min(idle) FROM cpu "
+           f"WHERE host IN ({in_list([4, 9])}) AND usage > 70 AND {WINDOW} "
+           "GROUP BY host ORDER BY host")
+    narrow, whole = both(db, full, sql)
+    pd.testing.assert_frame_equal(narrow, whole, check_exact=True)
+    r = db.ref
+    r = r[r.host.isin(["h04", "h09"]) & (r.usage > 70) & (r.ts >= LO)
+          & (r.ts < HI)]
+    assert list(narrow["count(usage)"]) == list(r.groupby("host").usage.count())
+
+
+@pytest.mark.parametrize("where", [
+    "host = 'never-seen'",
+    f"host IN ('h02') AND region = 'r0'",       # h02 lives in r2
+    f"host = 'h07' AND ts >= {T0 + TICKS * TICK_MS}",
+    f"host IN ('h07', 'h08') AND ts < {T0}",
+], ids=["unknown-value", "predicates-disagree", "window-after", "window-before"])
+def test_empty_selection_answers_as_no_rows(db, full, where):
+    sql = (f"SELECT host, max(usage) FROM cpu WHERE {where} GROUP BY host")
+    narrow, whole = both(db, full, sql)
+    assert len(narrow) == 0 and len(whole) == 0
+    one, whole = both(db, full,
+                      f"SELECT count(usage), max(usage) FROM cpu WHERE {where}")
+    pd.testing.assert_frame_equal(one, whole, check_exact=True)
+
+
+def test_a_window_that_misses_one_host(db, full):
+    """h06 has rows in the window, h30 does not exist, and a host whose
+    rows all lie outside it drops out of the ranges."""
+    sql = (f"SELECT host, count(usage) FROM cpu WHERE host IN ('h06', 'h30') "
+           f"AND {WINDOW} GROUP BY host ORDER BY host")
+    narrow, whole = both(db, full, sql)
+    pd.testing.assert_frame_equal(narrow, whole, check_exact=True)
+    assert list(narrow.host) == ["h06"] and list(narrow.iloc[:, 1]) == [300]
+    assert "ranges=1" in db.stages(sql)["reduce"]
+
+
+@pytest.mark.parametrize("hosts, path", [(1, "narrow"), (4, "narrow"),
+                                         (5, "full"), (12, "full")])
+def test_each_side_of_the_crossover(db, full, hosts, path):
+    """19,200 rows: 4 ranges pad to 4 x 512, an eighth of 16,384, and run
+    narrow; 5 pad to 8 x 512 and run full; the answers do not care."""
+    picked = list(range(2, 2 + hosts))
+    sql = (f"SELECT host, {EXACT} FROM cpu WHERE host IN ({in_list(picked)}) "
+           f"AND {WINDOW} GROUP BY host ORDER BY host")
+    n = db.ref.shape[0]
+    sel_rows = 512 * (1 << (hosts - 1).bit_length())
+    assert scan_narrow.scan_read_path(n, hosts, sel_rows) == path
+    before = counter(path)
+    got = db.sql(sql)
+    assert counter(path) == before + 1
+    detail = db.stages(sql)["reduce"]
+    assert f"path={path}" in detail
+    if path == "narrow":
+        assert f"narrow_rows={hosts * 300}" in detail
+        assert f"ranges={hosts}" in detail
+    with full():
+        whole = db.sql(sql)
+    pd.testing.assert_frame_equal(got, whole, check_exact=True)
+    assert list(got["count(usage)"]) == [300] * hosts
+
+
+def test_scan_read_path_reads_only_counts():
+    assert scan_narrow.scan_read_path(17_280_000, None, 0) == "full"
+    assert scan_narrow.scan_read_path(17_280_000, 8, 8 * 4096) == "narrow"
+    assert scan_narrow.scan_read_path(17_280_000, 0, 512) == "narrow"
+    most = 17_280_000 // scan_narrow._NARROW_MAX_SHARE
+    assert scan_narrow.scan_read_path(17_280_000, 512, most) == "narrow"
+    assert scan_narrow.scan_read_path(17_280_000, 512, most + 1) == "full"
+
+
+def test_no_tag_predicate_is_the_full_launch(db):
+    before = counter("narrow"), counter("full")
+    db.sql(f"SELECT host, max(usage) FROM cpu WHERE {WINDOW} GROUP BY host")
+    db.sql("SELECT region, max(usage) FROM cpu WHERE region != 'r1' "
+           "GROUP BY region")       # no point / IN conjunct
+    assert (counter("narrow"), counter("full")) == (before[0], before[1] + 2)
+    sql = "SELECT max(usage) FROM cpu"
+    assert "path=full" in db.stages(sql)["reduce"]
+    assert db.stages(sql)["dispatch"] == "device-resident (scan cache)"
+
+
+def test_other_hosts_of_the_same_shape_compile_nothing(db):
+    def sql(hosts, lo):
+        return (f"SELECT date_bin(INTERVAL '1 minute', ts) AS minute, "
+                f"max(usage), max(idle) FROM cpu WHERE host IN "
+                f"({in_list(hosts)}) AND ts >= {T0 + lo * TICK_MS} AND "
+                f"ts < {T0 + (lo + 120) * TICK_MS} GROUP BY minute")
+    db.sql(sql([1, 2, 3], 0))
+    compiled = scan_narrow._narrow_reduce._cache_size()
+    for hosts, lo in (([7, 15, 22], 60), ([0, 9, 23, 12], 240), ([5, 6, 8], 6)):
+        assert len(db.sql(sql(hosts, lo))) == 20
+    assert scan_narrow._narrow_reduce._cache_size() == compiled
+
+
+def test_narrow_builds_nothing_of_the_tables_length(db, monkeypatch):
+    """The full launch's mask and run sweep are not called, and no numpy
+    array the narrow path makes is as long as the table."""
+    def never(*a, **k):
+        raise AssertionError("the narrow path swept the table")
+    monkeypatch.setattr(tpu_exec, "_scan_row_mask", never)
+    monkeypatch.setattr(tpu_exec, "_scan_runs", never)
+    monkeypatch.setattr(tpu_exec, "_launch_scan_kernel", never)
+    seen = []
+    real = scan_narrow.launch
+
+    def spy(scan, schema, plan, sel, part):
+        seen.append((scan.num_rows, sel.rows, sel.padded_rows))
+        return real(scan, schema, plan, sel, part)
+    monkeypatch.setattr(scan_narrow, "launch", spy)
+    sql = (f"SELECT host, {EXACT} FROM cpu WHERE host IN ({in_list([1, 13])}) "
+           f"AND region != 'r9' AND {WINDOW} GROUP BY host")
+    stages = db.stages(sql)
+    assert stages["dispatch"] == "device-resident (scan cache)"
+    assert "path=narrow" in stages["reduce"]
+    for part in ("reduce.mask", "reduce.runs", "reduce.upload",
+                 "reduce.launch", "reduce.fetch", "reduce.collect"):
+        assert "t0_ns=" in stages[part], part
+    assert seen == [(db.ref.shape[0], 600, 1024)]
+
+
+def test_lower_bound_is_searchsorted_per_range():
+    rng = np.random.default_rng(3)
+    lens = rng.integers(0, 40, 50)
+    ts = np.concatenate([np.sort(rng.integers(0, 100, n)) for n in lens])
+    hi = np.cumsum(lens)
+    lo = hi - lens
+    for value in (-1, 0, 37, 99, 100):
+        want = [a + np.searchsorted(ts[a:b], value, side="left")
+                for a, b in zip(lo, hi)]
+        assert list(scan_narrow._lower_bound(ts, lo, hi, value)) == want
+
+
+def test_many_runs_take_the_high_cardinality_kernels(db, monkeypatch):
+    """16 of 160 series by 10 s buckets: 16,000 runs, past the kernels'
+    high-cardinality threshold, so the compact block carries per-row run
+    ids; a synthetic scan stands in for a table of 160,000 rows."""
+    from greptimedb_tpu.storage.series import SeriesDict
+    hosts, ticks = 160, 1000
+    captured = []
+    real = tpu_exec.plan_for
+    picked = list(range(3, 80, 5))
+    with monkeypatch.context() as m:
+        m.setattr(tpu_exec, "plan_for", lambda t, a, q: captured.append(
+            (real(t, a, q), t.schema)) or captured[-1][0])
+        db.sql(f"SELECT host, date_bin(INTERVAL '10 second', ts) AS b, "
+               f"{EXACT} FROM cpu WHERE host IN ({in_list(picked)}) "
+               "GROUP BY host, b")
+    plan, schema = captured[-1]
+    sd = SeriesDict.for_schema(schema)
+    sd.encode_rows([[f"h{h:02d}" for h in range(hosts)],   # h00..h99, h100..
+                    [f"r{h % 3}" for h in range(hosts)]])
+    rng = np.random.default_rng(11)
+    n = hosts * ticks
+    scan = tpu_exec.MergedScan(
+        np.repeat(np.arange(hosts, dtype=np.int32), ticks),
+        np.tile(T0 + np.arange(ticks, dtype=np.int64) * TICK_MS, hosts),
+        {"usage": (rng.random(n) * 100, None),
+         "idle": (rng.random(n) * 100, rng.random(n) > 0.2)}, sd, T0)
+    sel = scan_narrow.select(scan, schema, plan)
+    assert (sel.n_ranges, sel.rows, sel.padded_rows) == (16, 16_000, 16_384)
+    narrow = tpu_exec._moment_frame_for_scan(scan, schema, plan)
+    monkeypatch.setattr(scan_narrow, "_NARROW_MAX_SHARE", 10**12)
+    whole = tpu_exec._moment_frame_for_scan(scan, schema, plan)
+    assert len(narrow) == 16_000
+    pd.testing.assert_frame_equal(narrow.reset_index(drop=True),
+                                  whole.reset_index(drop=True),
+                                  check_exact=True)
+
+
+def test_the_point_cell_runs_narrow_on_the_cpu_debug_run():
+    """`benchmark/run.py --workload tsbs4k-point` at its CPU debug size:
+    every family correct and device-resident, `path=narrow` on every
+    `reduce` row but the priming `lastpoint`'s."""
+    import json
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    run = subprocess.run(
+        [sys.executable, "benchmark/run.py", "--workload", "tsbs4k-point",
+         "--seed", "7", "--seconds", "3", "--trace", "1",
+         "--debug-platform", "cpu"],
+        cwd=root, capture_output=True, text=True, timeout=600,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert run.returncode == 3, run.stderr[-2000:]     # a debug run's own
+    line = json.loads(run.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True and line["failed"] == 0
+    with open(os.path.join(root, ".bench_work", "tsbs4k-point-seed7-trace1",
+                           "record.json")) as f:
+        record = json.load(f)
+    window = [s for s in record["statements"] if s["in_window"]]
+    assert len(window) >= 8 and {s["family"] for s in window} == {
+        "single-groupby-1-1-1", "single-groupby-1-1-12",
+        "single-groupby-1-8-1", "single-groupby-5-1-1",
+        "single-groupby-5-1-12", "single-groupby-5-8-1", "cpu-max-all-1",
+        "cpu-max-all-8"}
+    warm = [w for w in record["warm"]
+            if w["explain"] and w["family"] != "lastpoint"]
+    for s in window + warm:
+        stages = s["stages"]
+        assert stages["dispatch"]["detail"] == "device-resident (scan cache)"
+        assert "path=narrow" in stages["reduce"]["detail"], s["family"]
+    assert record["compiled_in_window"] in (0, None)
