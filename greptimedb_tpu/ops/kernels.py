@@ -632,6 +632,44 @@ def _seg_minmax_doubling(x, gids, starts, ends, ident, *, is_min, k_max):
     return jnp.where(ends > starts, out, ident)
 
 
+def _seg_growth_doubling(x, m, gids, starts, ends, *, k_max):
+    """Per segment the sum of x over its valid rows (m) but the first of
+    them: PromQL's raw window growth, where x holds each sample's
+    difference to the sample before it (reset-aware for a counter) and
+    the first one's reaches back before the window. Shift-doubling like
+    its neighbours: k_max passes for "has an earlier valid row in its
+    segment", k_max for the sum, one pickup a segment. No prefix over
+    the whole scan, so a segment's sum is good to float32 rounding of
+    its own few terms; no gather but the pickup (sum - first by the
+    prefix-sum and arg-extreme kernels would take six a segment, and the
+    float prefix compiles for two minutes at 46M rows). Requires
+    2^k_max >= the longest segment."""
+    n = x.shape[0]
+
+    def shifted(a, sh, fill, back):
+        pad = jnp.full((sh,), fill, a.dtype)
+        return jnp.concatenate([pad, a[:-sh]] if back else [a[sh:], pad])
+
+    # earlier[i]: a valid row lies before i in i's segment
+    earlier = (shifted(gids, 1, -1, True) == gids) & shifted(m, 1, False,
+                                                             True)
+    for k in range(k_max):
+        sh = 1 << k
+        if sh >= n:
+            break
+        earlier = earlier | ((shifted(gids, sh, -1, True) == gids)
+                             & shifted(earlier, sh, False, True))
+    # y[i] after pass k: the sum over [i, min(i + 2^(k+1), segment end))
+    y = jnp.where(m & earlier, x, 0)
+    for k in range(k_max):
+        sh = 1 << k
+        if sh >= n:
+            break
+        y = jnp.where(shifted(gids, sh, -1, False) == gids,
+                      y + shifted(y, sh, 0, False), y)
+    return jnp.where(ends > starts, y[jnp.minimum(starts, n - 1)], 0)
+
+
 def _seg_argext_doubling(key, gids, starts, ends, ident, *, is_min, k_max):
     """Segmented lexicographic arg-extreme of (key, position) by
     shift-doubling — one fused pass family carrying the (value, pos)
@@ -896,6 +934,13 @@ def _sga_body(gids, mask, ts, values, col_masks, starts, ends, bs, be,
                 results.append(_sorted_seg_minmax(
                     filled, starts, ends, bs, be, has_inner, n,
                     is_min=is_min))
+        elif op == "growth":
+            # rows of a segment lie in time order here (one series a
+            # segment), and the caller ships real run ids with seg_len_k
+            if seg_len_k is None:
+                raise ValueError("growth needs run ids and seg_len_k")
+            results.append(_seg_growth_doubling(
+                col, m, gids, starts, ends, k_max=seg_len_k))
         elif op in ("first", "last"):
             # arg-extreme by (ts, position) — same semantics as the scatter
             # twin even when ts is unsorted within a segment

@@ -25,10 +25,19 @@ Lowered shapes (everything else → row path):
 with plain equality/inequality matchers on string tags, a single
 numeric field, no @, and any offset. The inner selector/function is
 rebuilt as a per-series instant vector from the finalized moment frame
-(counter resets ride the `reset_corr` moment; extrapolation replicates
+(a window's raw growth rides the `increase` / `delta` moment, which the
+device reduces over a derived mirror of per-sample differences, so a
+month-old counter keeps its digits in f32; extrapolation replicates
 ops/window.py exactly), then the engine's ordinary host grouping
 aggregates it — outer semantics are shared with the row path by
 construction.
+
+A lowered statement's EXPLAIN ANALYZE rows: `plan`, then what SQL's
+aggregate writes (`scan_prep`, `reduce` and its parts, `finalize`), then
+`lower` (the moment frame back to the inner instant vector) with its
+part `lower.rebuild` (series codes and the [series, steps] values / ok
+arrays); `greptime_promql_lowered_windows_total` counts the (series,
+window) rows rebuilt.
 """
 
 from __future__ import annotations
@@ -49,7 +58,8 @@ LOWERABLE_AGG_OPS = frozenset({"sum", "avg", "min", "max", "count"})
 
 #: range functions with an exact moment decomposition over one
 #: tumbling window (range == step): value and ok-mask reconstruct
-#: from first/last/min_ts/max_ts/count (+ reset_corr for counters)
+#: from first/min_ts/max_ts/count + the window's growth (`increase`,
+#: reset-aware, or `delta`)
 LOWERABLE_RANGE_FUNCS = frozenset({
     "rate", "increase", "delta", "sum_over_time", "count_over_time",
     "avg_over_time", "min_over_time", "max_over_time", "last_over_time",
@@ -211,14 +221,17 @@ def try_lower(ev, e: Aggregate):
         aggs.append(("__v", "last", field))
         mspec.append(("__t", "max_ts", field))
     elif func in ("rate", "increase", "delta"):
+        # first / last / min_ts are also what folds the growth of one
+        # window across partials (tpu_exec._finalize)
         aggs += [("__first", "first", field), ("__last", "last", field)]
         mspec += [("__mnt", "min_ts", field), ("__mxt", "max_ts", field)]
-        # for delta too, which does not read it: a host-only moment
-        # keeps the whole fold in float64 on the host. last - first of
-        # the device's f32 mirrors has no digits left for a window's
-        # growth once the level is large (a gauge at 1e12 that moves by
-        # 6e4 a window came out 31% off)
-        mspec.append(("__corr", "reset_corr", field))
+        # the window's raw growth as a moment of its own: last - first
+        # of the device's f32 mirrors has no digits left once the level
+        # is large (a gauge at 1e12 that moves by 6e4 a window came out
+        # 31% off), so the device sums per-sample differences instead
+        # (tpu_exec.RUN_DIFF_MOMENT_OPS)
+        mspec.append(("__grow", "delta" if func == "delta" else "increase",
+                      field))
     elif func in ("last_over_time",):
         aggs.append(("__v", "last", field))
     elif func != "count_over_time":
@@ -248,31 +261,83 @@ def _key_str(v) -> str:
     return _label_str(v)
 
 
+def _series_codes(df, tag_names: List[str]):
+    """-> (sid per row of the frame, the series' label-value tuples in
+    sorted order). The group columns are factorised a column at a time
+    (hashing, no Python call per row) and a label is rendered once a
+    value; values that render alike (NULL and "") fall into one."""
+    import pandas as pd
+
+    from ..query.planner import _group_slot
+    n = len(df)
+    codes = np.zeros(n, dtype=np.int64)
+    columns = []                     # per tag: (code per row, its labels)
+    for t in tag_names:
+        col, values = pd.factorize(df[_group_slot(t)],
+                                   use_na_sentinel=False)
+        columns.append((col, [_key_str(v) for v in values]))
+        codes, _ = pd.factorize(codes * len(values) + col)
+    n_raw = int(codes.max()) + 1 if n else 0
+    first = np.empty(n_raw, dtype=np.int64)
+    first[codes[::-1]] = np.arange(n - 1, -1, -1)   # first row of a code
+    rendered = [[labels[c] for c in col[first].tolist()]
+                for col, labels in columns]
+    keys = list(zip(*rendered)) if rendered else [()] * n_raw
+    uniq = sorted(set(keys))
+    sid_of = {k: i for i, k in enumerate(uniq)}
+    remap = np.fromiter((sid_of[k] for k in keys), dtype=np.int64,
+                        count=n_raw)
+    return remap[codes], uniq
+
+
+def _empty_vector(T: int):
+    from .engine import VectorVal
+    return VectorVal([], np.zeros((0, T)), np.zeros((0, T), bool))
+
+
 def eval_lowered(ev, low: LoweredSelect):
     """Run the lowered plan and rebuild the inner instant vector —
     per-series values over the step grid with Prometheus staleness /
     extrapolation semantics replicated from ops/window.py."""
+    from ..common import exec_stats
+    from ..common.telemetry import increment_counter
     from ..query import ir
     from .engine import _KEEP_NAME_RANGE_FUNCS, VectorVal
 
     df = ir.execute_agg_plan(low.table, low.plan)
     T = ev.nsteps
     if df is None or not len(df):
-        return VectorVal([], np.zeros((0, T)), np.zeros((0, T), bool))
-    from ..query.planner import _group_slot
-    # buckets whose rows were all-null carry no sample: drop them so a
-    # -inf max_ts sentinel never forward-fills
-    df = df[df["__n"].to_numpy() > 0]
-    if not len(df):
-        return VectorVal([], np.zeros((0, T)), np.zeros((0, T), bool))
+        return _empty_vector(T)
+    with exec_stats.stage("lower"):
+        # buckets whose rows were all-null carry no sample: drop them so
+        # a -inf max_ts sentinel never forward-fills
+        df = df[df["__n"].to_numpy() > 0]
+        if not len(df):
+            return _empty_vector(T)
+        with exec_stats.stage("lower.rebuild"):
+            uniq, out_vals, out_ok = _rebuild(ev, low, df)
+        exec_stats.record("lower", rows=len(df))
+        increment_counter("promql_lowered_windows", len(df))
+        del df      # released inside the row: 808,000 x 4 labels are 10 ms
+        keep_name = low.func is None or low.func in _KEEP_NAME_RANGE_FUNCS
+        labels: List[Dict[str, str]] = []
+        for ukey in uniq:
+            lbl: Dict[str, str] = {}
+            if keep_name:
+                lbl["__name__"] = low.metric
+            for tn, tv in zip(low.tag_names, ukey):
+                if tv != "":
+                    lbl[tn] = tv
+            labels.append(lbl)
+        return VectorVal(labels, out_vals, out_ok)
 
-    rendered = [[_key_str(v) for v in df[_group_slot(t)]]
-                for t in low.tag_names]
-    n = len(df)
-    keys = list(zip(*rendered)) if rendered else [()] * n
-    uniq = sorted(set(keys))
-    sid_of = {k: i for i, k in enumerate(uniq)}
-    sids = np.fromiter((sid_of[k] for k in keys), dtype=np.int64, count=n)
+
+def _rebuild(ev, low: LoweredSelect, df):
+    """The moment frame, a row a (series, window), -> (the series' label
+    values, values [series, steps], ok [series, steps])."""
+    from ..query.planner import _group_slot
+    T = ev.nsteps
+    sids, uniq = _series_codes(df, low.tag_names)
     S = len(uniq)
     step = ev.step
     bv = df[_group_slot("__promql_window")].to_numpy().astype(np.int64)
@@ -317,35 +382,20 @@ def eval_lowered(ev, low: LoweredSelect):
                 rowok = cnt >= 1
         out_vals[sids[inb], k[inb]] = rowvals[inb]
         out_ok[sids[inb], k[inb]] = rowok[inb]
-
-    keep_name = low.func is None or low.func in _KEEP_NAME_RANGE_FUNCS
-    labels: List[Dict[str, str]] = []
-    for ukey in uniq:
-        lbl: Dict[str, str] = {}
-        if keep_name:
-            lbl["__name__"] = low.metric
-        for tn, tv in zip(low.tag_names, ukey):
-            if tv != "":
-                lbl[tn] = tv
-        labels.append(lbl)
-    return VectorVal(labels, out_vals, out_ok)
+    return uniq, out_vals, out_ok
 
 
 def _window_rate(df, low: LoweredSelect, k: np.ndarray, cnt: np.ndarray):
     """rate/increase/delta from per-window moments: the Prometheus
     extrapolation epilogue of ops/window.py `_extrapolate`, replicated
-    on the frontend over merged first/last/min_ts/max_ts (+ the
-    reset_corr moment for counters)."""
+    on the frontend over the window's merged growth (`increase`,
+    reset-aware, or `delta`), first value and first / last times."""
     first_v = df["__first"].to_numpy(dtype=np.float64)
-    last_v = df["__last"].to_numpy(dtype=np.float64)
     first_t = df["__mnt"].to_numpy(dtype=np.float64)
     last_t = df["__mxt"].to_numpy(dtype=np.float64)
     rng = float(low.win)
     end_abs = (low.t0 + k * low.win).astype(np.float64)
-    if low.func == "delta":
-        raw = last_v - first_v
-    else:
-        raw = last_v - first_v + df["__corr"].to_numpy(dtype=np.float64)
+    raw = df["__grow"].to_numpy(dtype=np.float64)
     dur_to_start = first_t - (end_abs - rng)
     dur_to_end = end_abs - last_t
     sampled = last_t - first_t
@@ -374,12 +424,10 @@ def try_lowered_inner(ev, e: Aggregate):
     when the executor rejects the plan — cost-based raw-pull, a
     version-skewed datanode, a sketch decode failure."""
     from ..common import exec_stats
-    from .engine import VectorVal
     with exec_stats.stage("plan"):
         low, _reason = try_lower(ev, e)
     if low is EMPTY:
-        T = ev.nsteps
-        return VectorVal([], np.zeros((0, T)), np.zeros((0, T), bool))
+        return _empty_vector(ev.nsteps)
     if low is None:
         return None
     try:

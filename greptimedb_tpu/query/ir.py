@@ -137,7 +137,7 @@ def plan_from_specs(schema, aggs: Sequence[Tuple[str, str, Optional[str]]],
     `aggs` ops use the standard vocabulary (sum/avg/min/max/count/
     first/last/stddev/variance); `moment_specs` requests raw merged
     moments (dest, moment op, column) finalized via passthrough — how
-    PromQL's rate reads min_ts/max_ts/reset_corr at the frontend.
+    PromQL's rate reads min_ts/max_ts/increase at the frontend.
     Moments are deduped across both lists, so e.g. a rate plan's
     `first` aggregate and its `min_ts` moment share slots."""
     tag_names = schema.tag_names()
@@ -241,5 +241,8 @@ def execute_agg_plan(table, plan: TpuPlan) -> pd.DataFrame:
                 "the raw-row path", e, table.name)
             raise UnsupportedError(
                 f"sketch partial failed to decode: {e}") from e
+        # dropped inside the row: releasing a wide partial frame's label
+        # columns is milliseconds that no row would show otherwise
+        del frames, merged
     exec_stats.record("finalize", rows=len(out))
     return out

@@ -40,7 +40,7 @@ from .tpu_exec import BucketGroup, FieldFilter, Moment, TagGroup, TpuPlan
 #: moment it half-understands into a wrong answer.
 KNOWN_MOMENT_OPS = frozenset({
     "sum", "sum_sq", "count", "min", "max", "first", "last",
-    "min_ts", "max_ts", "distinct", "tdigest", "reset_corr"})
+    "min_ts", "max_ts", "distinct", "tdigest", "increase", "delta"})
 KNOWN_FINAL_OPS = frozenset({
     "sum", "avg", "count", "min", "max", "first", "last", "stddev",
     "variance", "count_distinct", "approx_distinct", "approx_percentile",

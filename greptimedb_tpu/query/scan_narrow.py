@@ -241,8 +241,8 @@ def _columns(scan, schema, plan):
     ops, value_ix, mask_ix = [], [], []
     for op, field_read, masked_by in tpu_exec._moment_reads(schema, plan):
         ops.append(op)
-        value_ix.append(0 if field_read is None
-                        else col_ix(("f", field_read), scan.device_field))
+        value_ix.append(0 if field_read is None else col_ix(
+            ("f", field_read), lambda c: tpu_exec._device_column(scan, c)))
         mask_ix.append(
             -1 if masked_by is None or scan.fields[masked_by][1] is None
             else col_ix(("v", masked_by), scan.device_valid))

@@ -781,8 +781,8 @@ def _host_partial_frame(data, kept: Optional[np.ndarray], plan, sd,
         if m.column is None:             # plain row count
             frame[m.slot] = counts
             continue
-        from .tpu_exec import SKETCH_MOMENT_OPS, moment_input, \
-            sketch_run_column
+        from .tpu_exec import RUN_DIFF_MOMENT_OPS, SKETCH_MOMENT_OPS, \
+            moment_input, run_diffs, sketch_run_column
         d, vd = moment_input(m, plan, fields, sids, ts, sd, cache=mcache)
         valid = vd if mask is None else (
             mask if vd is None else (vd & mask))
@@ -838,23 +838,21 @@ def _host_partial_frame(data, kept: Optional[np.ndarray], plan, sd,
                 r = np.maximum.reduceat(
                     dv if valid is None else np.where(valid, dv, -f64max),
                     starts)
-            elif m.op == "reset_corr":
-                # PromQL counter-reset correction: for each adjacent
-                # VALID sample pair within a run where the later value
-                # is smaller, the pre-reset value contributes
-                # (ops/window.py: `where(pair_ok & (v < prev), prev, 0)`)
+            elif m.op in RUN_DIFF_MOMENT_OPS:
+                # a run's growth: the differences between its adjacent
+                # VALID samples, reset-aware for `increase`
                 if arange is None:
                     arange = np.arange(n, dtype=np.int64)
                 runid = np.repeat(np.arange(nruns, dtype=np.int64),
                                   np.diff(starts, append=n))
                 idx = arange if valid is None else np.nonzero(valid)[0]
-                drop = np.zeros(n, dtype=np.float64)
+                grow = np.zeros(n, dtype=np.float64)
                 if len(idx) > 1:
                     prev_i, cur_i = idx[:-1], idx[1:]
-                    hit = (runid[cur_i] == runid[prev_i]) & \
-                        (dv[cur_i] < dv[prev_i])
-                    drop[cur_i] = np.where(hit, dv[prev_i], 0.0)
-                r = np.add.reduceat(drop, starts)
+                    grow[cur_i] = np.where(
+                        runid[cur_i] == runid[prev_i],
+                        run_diffs(dv[cur_i], dv[prev_i], m.op), 0.0)
+                r = np.add.reduceat(grow, starts)
             else:  # pragma: no cover — planner only emits the ops above
                 from ..errors import UnsupportedError
                 raise UnsupportedError(f"host moment op {m.op!r}")
@@ -1059,7 +1057,8 @@ def stream_region_moment_frames(region, table, plan) -> List[pd.DataFrame]:
         prof.total_s = _time.perf_counter() - _t_start
         region.last_scan_profile = prof
         return []
-    from .tpu_exec import plan_needs_host, plan_scan_columns
+    from .tpu_exec import (RUN_DIFF_MOMENT_OPS, plan_needs_host,
+                           plan_scan_columns)
     needed = plan_scan_columns(plan, schema)
     sd = region.series_dict
 
@@ -1081,9 +1080,12 @@ def stream_region_moment_frames(region, table, plan) -> List[pd.DataFrame]:
                 return []
 
     mode = _COLD_REDUCE[0]
-    if plan_needs_host(plan):
-        # sketch / expression moments have no device kernel: every
-        # slice reduces on the host (same partial-frame algebra)
+    if plan_needs_host(plan) or any(m.op in RUN_DIFF_MOMENT_OPS
+                                    for m in plan.moments):
+        # sketch / expression moments have no device kernel, and a
+        # window's growth across a slice boundary is last - first of the
+        # two slices, which f32 mirrors cannot hold: every slice reduces
+        # on the host (same partial-frame algebra)
         mode = "host"
     sid_keys = mode == "host" and _sid_keyed(plan)
     launched = []

@@ -98,6 +98,45 @@ class MergedScan:
             self.device[key] = jax.device_put(np.ascontiguousarray(v))
         return self.device[key]
 
+    def device_run_diffs(self, name: str, counter: bool):
+        """The derived mirror a lowered `rate` / `increase` (`counter`) or
+        `delta` reads: each valid sample's difference to its series'
+        previous valid sample, reset-aware for a counter (`v - prev`, or
+        `v` where the counter restarted below `prev`), 0 for a series'
+        first. Made in float64 on the host, so the f32 mirror holds a
+        scrape's growth to 6e-8 of itself whatever the level: a window's
+        raw increase is the sum over its run but the run's first sample
+        (`ops/kernels.py` `growth`). last - first of
+        the plain f32 mirrors has no digits left once the level is large
+        (a counter at 1e12 that grows 6e4 a window came out 31% off).
+        Built on a field's first use by such a function, never before."""
+        import jax
+        key = f"{'c' if counter else 'g'}:{name}"
+        if key not in self.device:
+            vals, valid = self.fields[name]
+            if vals.dtype == object:
+                raise UnsupportedError(f"field {name} is not numeric")
+            v = vals.astype(np.float64, copy=False)
+            rows = None if valid is None else np.nonzero(valid)[0]
+            sids = self.series_ids if rows is None \
+                else self.series_ids[rows]
+            if rows is not None:
+                v = v[rows]
+            d = np.zeros(len(v), dtype=np.float64)
+            if len(v) > 1:
+                np.subtract(v[1:], v[:-1], out=d[1:])
+                if counter:
+                    np.copyto(d[1:], v[1:], where=v[1:] < v[:-1])
+                d[1:][sids[1:] != sids[:-1]] = 0.0
+            if rows is not None:
+                full = np.zeros(self.num_rows, dtype=np.float64)
+                full[rows] = d
+                d = full
+            if not jax.config.jax_enable_x64:
+                d = d.astype(np.float32)
+            self.device[key] = jax.device_put(d)
+        return self.device[key]
+
     def device_valid(self, name: str):
         import jax
         key = f"v:{name}"
@@ -551,13 +590,24 @@ class Moment:
 #: number — built on the host, merged by _finalize through the codec
 SKETCH_MOMENT_OPS = frozenset({"distinct", "tdigest"})
 
-#: numeric moment ops only the host reducer implements (no device
-#: kernel): `reset_corr` is PromQL's counter-reset correction — the sum
-#: of the pre-reset value over adjacent valid sample pairs within a run
-#: where the later sample is smaller (ops/window.py rate kernel:
-#: `where(pair_ok & (val < prev), prev, 0)`), so
-#: increase = last - first + reset_corr folds like any other moment
-HOST_ONLY_MOMENT_OPS = frozenset({"reset_corr"})
+#: moment ops over adjacent samples of a run, PromQL's raw window growth:
+#: `increase` sums the reset-aware differences between a run's adjacent
+#: valid samples (`v - prev`, or `v` where a counter restarted below
+#: `prev`; what rate / increase extrapolate), `delta` the plain ones
+#: (last - first, summed so that f32 keeps its digits). The device
+#: reduces them as the kernels' `growth` of `MergedScan.device_run_diffs`
+#: (the sum of a run's differences but its first sample's, which reaches
+#: back before the run); the host reducers compute them in float64. Partials of one group are
+#: time-disjoint slices of one series: they add up, plus the difference
+#: across each slice boundary (`_finalize`, which reads the companion
+#: first / last / min_ts moments the lowering always asks for)
+RUN_DIFF_MOMENT_OPS = frozenset({"increase", "delta"})
+
+
+def run_diffs(cur, prev, op: str):
+    """Adjacent-sample differences for a RUN_DIFF_MOMENT_OPS op."""
+    d = cur - prev
+    return np.where(cur < prev, cur, d) if op == "increase" else d
 
 
 @dataclass
@@ -591,8 +641,7 @@ def plan_needs_host(plan: "TpuPlan") -> bool:
     expression columns both do. The partial-frame ALGEBRA is unchanged —
     host partials fold exactly like device partials."""
     return bool(plan.field_exprs) or \
-        any(m.op in SKETCH_MOMENT_OPS or m.op in HOST_ONLY_MOMENT_OPS
-            for m in plan.moments)
+        any(m.op in SKETCH_MOMENT_OPS for m in plan.moments)
 
 
 def plan_scan_columns(plan: "TpuPlan", schema) -> List[str]:
@@ -1151,8 +1200,14 @@ def frames_nbytes(frames) -> int:
     for f in frames:
         for col in f.columns:
             s = f[col]
-            # object (bytes, sketches, pandas 2 strings) or pandas 3 `str`
-            if pd.api.types.is_string_dtype(s.dtype):
+            if isinstance(s.dtype, pd.StringDtype):
+                # pandas 3 `str` (what a tag column of a partial frame
+                # is): lengths in one pass, a missing value as 8 B; a
+                # Python loop over 808,000 x 4 labels of a lowered PromQL
+                # statement took 3.2 s of its 7.1
+                total += int(s.str.len().fillna(8).sum())
+            # object: bytes, sketches, pandas 2 strings
+            elif pd.api.types.is_string_dtype(s.dtype):
                 total += int(sum(
                     len(v) if isinstance(v, (bytes, bytearray, str))
                     else 8 for v in s))
@@ -1463,13 +1518,17 @@ def _moment_frame_for_scan(scan: MergedScan, schema,
         else (sel.n_ranges, sel.padded_rows)
     path = scan_narrow.scan_read_path(scan.num_rows, n_ranges, padded_rows)
     increment_counter("scan_reads", path=path)
+    # the rows this launch reads on the device: the table, or the ranges
+    increment_counter("scan_device_rows",
+                      sel.rows if path == "narrow" else scan.num_rows)
     if path == "narrow":
         exec_stats.record("reduce", path=path, narrow_rows=sel.rows,
                           ranges=sel.n_ranges)
         launched = scan_narrow.launch(scan, schema, plan, sel, _reduce_part)
     else:
         exec_stats.record("reduce", path=path)
-        launched = _launch_scan_kernel(scan, schema, plan, _reduce_part)
+        launched = _launch_scan_kernel(scan, schema, plan, _reduce_part,
+                                       sel)
     if launched is None:
         return None
     with _reduce_part("fetch"):     # blocked on the device, then D2H
@@ -1482,10 +1541,12 @@ def _moment_frame_for_scan(scan: MergedScan, schema,
 
 
 def _launch_scan_kernel(scan: MergedScan, schema, plan: TpuPlan,
-                        part=_untimed_part) -> Optional[_Launched]:
+                        part=_untimed_part, sel=None) -> Optional[_Launched]:
     """`part(name)` times the host's steps for the resident path's
     EXPLAIN ANALYZE: `runs` (run-id sweep), `mask`, `upload` (every
-    device_put), `launch` (the call that returns futures)."""
+    device_put), `launch` (the call that returns futures). `sel`: the
+    row ranges `scan_narrow.select` resolved the predicates to, where the
+    caller has them."""
     import jax
 
     n = scan.num_rows
@@ -1494,7 +1555,7 @@ def _launch_scan_kernel(scan: MergedScan, schema, plan: TpuPlan,
     with part("runs"):
         run_key, (rid, nruns, run_starts, buckets) = _scan_runs(scan, plan)
     with part("mask"):
-        mask = _scan_row_mask(scan, schema, plan)
+        mask = _scan_row_mask(scan, schema, plan, sel)
     if mask is _NO_ROWS:
         return None
 
@@ -1518,16 +1579,27 @@ def _launch_scan_kernel(scan: MergedScan, schema, plan: TpuPlan,
         for op, field_read, masked_by in _moment_reads(schema, plan):
             ops.append(op)
             values.append(d_ts if field_read is None
-                          else scan.device_field(field_read))
+                          else _device_column(scan, field_read))
             col_masks.append(scan.device_valid_all() if masked_by is None
                              else scan.device_valid(masked_by))
 
     with part("runs"):
-        had_rid = rid is not None
-        nbucket, run_ends, rid, seg_len_k = _segment_layout(
-            run_starts, n, ops, rid)
-        if rid is not None and not had_rid:
-            scan.device[run_key] = (rid, nruns, run_starts, buckets)
+        # cached with the runs, per set of ops that read run ids or not:
+        # at 7.7M runs the ends, the lengths and their maximum are 0.15 s
+        layout_key = "__layout:" + run_key
+        needs_gids = _ops_need_gids(ops, nruns)
+        cached = scan.device.get(layout_key)
+        if cached is not None and (not needs_gids or (
+                cached[2] is not None and rid is not None)):
+            nbucket, run_ends, seg_len_k = cached
+            if not needs_gids:
+                rid = seg_len_k = None
+        else:
+            nbucket, run_ends, rid, seg_len_k = _segment_layout(
+                run_starts, n, ops, rid)
+            scan.device[layout_key] = (nbucket, run_ends, seg_len_k)
+            if rid is not None:
+                scan.device[run_key] = (rid, nruns, run_starts, buckets)
     if rid is not None:
         with part("upload"):
             d_rid = jax.device_put(rid)
@@ -1545,23 +1617,44 @@ def _launch_scan_kernel(scan: MergedScan, schema, plan: TpuPlan,
     scan.launched.add(signature)
     sids = scan.series_ids
     return _Launched(tuple(results), counts, nruns, sids[run_starts],
-                     buckets[run_starts] if buckets is not None else None,
+                     _run_buckets(plan, buckets, run_starts),
                      scan.series_dict, scan.ts_base, warm)
 
 
 def _moment_reads(schema, plan: TpuPlan):
-    """-> per moment (kernel op, the field it reads, the column whose
-    validity masks it). No field: ts stands in (a ts extreme; a count or
-    a string column, which read only the mask). No column: a row count."""
+    """-> per moment (kernel op, the column it reads, the column whose
+    validity masks it). No column read: ts stands in (a ts extreme; a
+    count or a string column, which read only the mask). No masking
+    column: a row count. A column is a field's name or, for a
+    RUN_DIFF_MOMENT_OPS moment, (counter, field): the derived mirror of
+    `MergedScan.device_run_diffs`, whose `growth` a run is the moment."""
     for m in plan.moments:
         if m.op in ("min_ts", "max_ts"):
             yield ("min" if m.op == "min_ts" else "max"), None, m.column
         elif m.column is None:
             yield "count", None, None
+        elif m.op in RUN_DIFF_MOMENT_OPS:
+            yield "growth", (m.op == "increase", m.column), m.column
         else:
             dtype = schema.column_schema(m.column).dtype
             yield m.op, (None if dtype.is_string or dtype.is_binary
                          else m.column), m.column
+
+
+def _device_column(scan: MergedScan, column):
+    """The resident mirror a kernel op of `_moment_reads` reads."""
+    if isinstance(column, tuple):
+        return scan.device_run_diffs(column[1], column[0])
+    return scan.device_field(column)
+
+
+def _ops_need_gids(ops, nruns: int) -> bool:
+    """Whether a launch's kernel ops read per-row run ids: first / last
+    / growth always, min / max above the high-cardinality threshold."""
+    from ..ops.kernels import _SEG_HIGH_CARD_THRESHOLD
+    high_card = shape_bucket(nruns, minimum=256) > _SEG_HIGH_CARD_THRESHOLD
+    return any(op in ("first", "last", "growth") for op in ops) or \
+        (high_card and any(op in ("min", "max") for op in ops))
 
 
 def _segment_layout(run_starts: np.ndarray, n: int, ops, rid=None):
@@ -1581,11 +1674,8 @@ def _segment_layout(run_starts: np.ndarray, n: int, ops, rid=None):
     # kernel's same-segment guard); for every other op ts stands in
     # for shape and both the O(n) rid cumsum and its upload are
     # skipped
-    from ..ops.kernels import _SEG_HIGH_CARD_THRESHOLD, seg_len_bucket
-    high_card = nbucket > _SEG_HIGH_CARD_THRESHOLD
-    needs_gids = any(op in ("first", "last") for op in ops) or \
-        (high_card and any(op in ("min", "max") for op in ops))
-    if not needs_gids:
+    from ..ops.kernels import seg_len_bucket
+    if not _ops_need_gids(ops, nruns):
         return nbucket, run_ends, None, None
     if rid is None:
         starts_mark = np.zeros(n, dtype=np.int32)
@@ -1598,16 +1688,27 @@ def _segment_layout(run_starts: np.ndarray, n: int, ops, rid=None):
         seg_len_bucket(int(lens.max()) if len(lens) else 1)
 
 
+def _bucket_phase(b: BucketGroup) -> int:
+    """Where a bucket grid's edges lie within its stride: grids of one
+    phase cut the same runs, and their bucket numbers differ by the whole
+    strides between their origins."""
+    return b.origin % b.stride_ms
+
+
 def _scan_runs(scan: MergedScan, plan: TpuPlan):
     """-> (cache key, (rid, nruns, run_starts, buckets)): the run ids
-    over (series [, bucket]), cached per scan + bucket spec: dashboards
+    over (series [, bucket]), cached per scan + bucket grid: dashboards
     repeat the same grouping over a warm region, and the
-    flags/cumsum/nonzero sweep is O(n) host work per query otherwise."""
+    flags/cumsum/nonzero sweep is O(n) host work per query otherwise.
+    `buckets` number the grid from its phase (`_bucket_phase`), not from
+    the statement's origin: a panel whose end moves by whole steps from
+    one refresh to the next (a lowered PromQL range query) keeps its runs,
+    and `_run_buckets` shifts the numbers to the statement's origin."""
     n = scan.num_rows
     sids = scan.series_ids
     if plan.bucket is not None:
         b = plan.bucket
-        run_key = f"__runs:{b.stride_ms}:{b.origin}"
+        run_key = f"__runs:{b.stride_ms}:{_bucket_phase(b)}"
     elif plan.tag_groups:
         run_key = "__runs:series"
     else:
@@ -1617,7 +1718,8 @@ def _scan_runs(scan: MergedScan, plan: TpuPlan):
         return run_key, cached_runs
     if plan.bucket is not None:
         b = plan.bucket
-        buckets = ((scan.ts - b.origin) // b.stride_ms).astype(np.int64)
+        buckets = ((scan.ts - _bucket_phase(b))
+                   // b.stride_ms).astype(np.int64)
         flags = np.empty(n, dtype=bool)
         flags[0] = True
         np.not_equal(sids[1:], sids[:-1], out=flags[1:])
@@ -1641,18 +1743,41 @@ def _scan_runs(scan: MergedScan, plan: TpuPlan):
     stale = [k for k in scan.device if k.startswith("__runs:")][:-4]
     for k in stale:
         scan.device.pop(k, None)
+        scan.device.pop("__layout:" + k, None)
     return run_key, runs
+
+
+def _run_buckets(plan: TpuPlan, buckets: Optional[np.ndarray],
+                 run_starts: np.ndarray) -> Optional[np.ndarray]:
+    """Each run's bucket number from the statement's own origin."""
+    if buckets is None:
+        return None
+    b = plan.bucket
+    return buckets[run_starts] - (b.origin - _bucket_phase(b)) // b.stride_ms
 
 
 #: _scan_row_mask: the predicates leave no row (None means "every row")
 _NO_ROWS = object()
 
 
-def _scan_row_mask(scan: MergedScan, schema, plan: TpuPlan):
+def _scan_row_mask(scan: MergedScan, schema, plan: TpuPlan, sel=None):
     """-> the host row mask of the statement's predicates: a bool array,
     None when nothing filters (the cached all-true device mask serves),
-    or _NO_ROWS."""
+    or _NO_ROWS. Where `scan_narrow.select` has resolved the tag
+    predicates and the time window to row ranges (`sel`), the mask is
+    their union: no pass over the table's series ids and times (three of
+    them at 46M rows were 0.3 s of a statement, and the part of it that
+    differed most from one server process to the next)."""
     n = scan.num_rows
+    if sel is not None and not plan.field_filters and \
+            scan.valid_rows is None:
+        if sel.n_ranges == 0:
+            return _NO_ROWS
+        mask = np.zeros(n, dtype=bool)
+        for a, b in zip(sel.starts.tolist(),
+                        (sel.starts + sel.lens).tolist()):
+            mask[a:b] = True
+        return mask
     # ---- per-series tag predicate → row mask ----
     base_mask = None
     if plan.tag_predicates:
@@ -1721,6 +1846,23 @@ def _field_filter_keep(scan: MergedScan, ff,
     return cmp
 
 
+def _tag_column(sd, sids: np.ndarray, tag_index: int):
+    """A partial frame's tag column for the runs' series. String tags go
+    from the dictionary's value ids straight to the Arrow-backed `str`
+    column pandas would infer from the decoded values: a take, where the
+    decode makes a Python string a row and pandas reads each back (0.14 s
+    a column at 808,000 rows, against 0.06 s). Any other value type keeps
+    the decoded list."""
+    ids, values = sd.tag_id_column(sids, tag_index)
+    if not all(v is None or isinstance(v, str) for v in values):
+        return sd.decode_tag_column(sids, tag_index)
+    import pyarrow as pa
+    return pd.Series(pa.DictionaryArray.from_arrays(
+        pa.array(ids, type=pa.int32()),
+        pa.array(values, type=pa.string())).dictionary_decode(),
+        dtype="str")
+
+
 def _collect_moment_frame(launched: _Launched, plan: TpuPlan,
                           counts: np.ndarray,
                           res_np: List[np.ndarray]) -> Optional[pd.DataFrame]:
@@ -1732,26 +1874,29 @@ def _collect_moment_frame(launched: _Launched, plan: TpuPlan,
     live = counts > 0
     if not live.any():
         return None
+    # the live runs only: a statement over an eighth of the series
+    # leaves seven eighths of the table's runs empty, and their tags are
+    # not worth decoding
     frame: Dict[str, Any] = {}
-    run_sids = launched.run_sids
+    run_sids = launched.run_sids[live]
     sd = launched.series_dict
     for tg in plan.tag_groups:
-        frame[_group_slot(tg.name)] = sd.decode_tag_column(
-            run_sids, tg.tag_index)
+        frame[_group_slot(tg.name)] = _tag_column(sd, run_sids,
+                                                  tg.tag_index)
     if plan.bucket is not None:
         frame[_group_slot(plan.bucket.expr_key)] = \
-            launched.run_buckets * plan.bucket.stride_ms + \
+            launched.run_buckets[live] * plan.bucket.stride_ms + \
             plan.bucket.origin
     for m, r in zip(plan.moments, res_np):
+        r = r[live]
         if m.op in ("min_ts", "max_ts"):
             # device ts is region-relative (ts - ts_base, base differs per
             # region); rebase to absolute so cross-region first/last merge
             # in _finalize compares comparable timestamps
             r = r.astype(np.int64) + launched.ts_base
         frame[m.slot] = r
-    frame["__rowcount"] = counts
-    df = pd.DataFrame(frame)[live]
-    return df
+    frame["__rowcount"] = counts[live]
+    return pd.DataFrame(frame)
 
 
 def _nan_if_none(v):
@@ -1806,18 +1951,18 @@ def _finalize(df: pd.DataFrame, plan: TpuPlan) -> pd.DataFrame:
                     out[slot] = nn.loc[nn[ts_slot].idxmin(), slot]
                 else:
                     out[slot] = nn.loc[nn[ts_slot].idxmax(), slot]
-            elif m.op == "reset_corr":
+            elif m.op in RUN_DIFF_MOMENT_OPS:
                 # partials are time-disjoint slices of one series run:
-                # total correction = per-slice corrections + each slice
-                # boundary that itself crosses a counter reset
-                # (first-of-next < last-of-prev contributes the prev)
+                # their growths add, plus the difference across each
+                # slice boundary (last-of-prev to first-of-next)
                 g = group.sort_values(_ts_slot_for(m, "min_ts"),
                                       kind="stable")
                 prev = g[_ts_slot_for(m, "last")].shift()
                 cur = g[_ts_slot_for(m, "first")]
-                cross = (cur < prev) & cur.notna() & prev.notna()
+                across = pd.Series(run_diffs(cur, prev, m.op),
+                                   index=g.index)
                 out[slot] = g[slot].sum() + \
-                    prev.where(cross, 0.0).fillna(0.0).sum()
+                    across.where(cur.notna() & prev.notna(), 0.0).sum()
         return pd.Series(out)
 
     if key_cols:
@@ -1830,12 +1975,12 @@ def _finalize(df: pd.DataFrame, plan: TpuPlan) -> pd.DataFrame:
             aggs = {}
             extremes = []
             sketches = []
-            resets = []
+            diffs = []
             for slot, m in moment_cols.items():
                 if m.op in SKETCH_MOMENT_OPS:
                     sketches.append(slot)
-                elif m.op == "reset_corr":
-                    resets.append((slot, m))
+                elif m.op in RUN_DIFF_MOMENT_OPS:
+                    diffs.append((slot, m))
                 elif m.op in ("sum", "sum_sq", "count"):
                     aggs[slot] = "sum"
                 elif m.op in ("min", "min_ts"):
@@ -1859,18 +2004,18 @@ def _finalize(df: pd.DataFrame, plan: TpuPlan) -> pd.DataFrame:
                 # fold encoded partials per group through the codec
                 # (bytes in, bytes out — pandas treats bytes as scalars)
                 merged[slot] = gb[slot].agg(_merge_sketch_cells)
-            for slot, m in resets:
-                # per-group partials sorted by slice start: corrections
-                # add, plus the prev-last where a slice boundary itself
-                # crosses a reset (first-of-next < last-of-prev)
+            for slot, m in diffs:
+                # per-group partials sorted by slice start: their growths
+                # add, plus the difference across each slice boundary
                 srt = df.sort_values(_ts_slot_for(m, "min_ts"),
                                      kind="stable")
                 gs = srt.groupby(key_cols, dropna=False, sort=False)
                 prev = gs[_ts_slot_for(m, "last")].shift()
                 cur = srt[_ts_slot_for(m, "first")]
-                cross = (cur < prev) & cur.notna() & prev.notna()
-                bonus = prev.where(cross, 0.0).fillna(0.0)
-                merged[slot] = gs[slot].sum() + bonus.groupby(
+                across = pd.Series(run_diffs(cur, prev, m.op),
+                                   index=srt.index).where(
+                    cur.notna() & prev.notna(), 0.0)
+                merged[slot] = gs[slot].sum() + across.groupby(
                     [srt[k] for k in key_cols], dropna=False,
                     sort=False).sum()
             merged = merged.reset_index()
@@ -1885,7 +2030,7 @@ def _finalize(df: pd.DataFrame, plan: TpuPlan) -> pd.DataFrame:
     for slot, op, mslots in plan.finals:
         if op in ("sum", "min", "max", "first", "last", "moment"):
             # "moment": raw merged-moment passthrough — PromQL's rate
-            # finalization reads min_ts/max_ts/reset_corr directly
+            # finalization reads min_ts/max_ts/increase directly
             out[slot] = merged[mslots[0]]
         elif op == "count":
             out[slot] = merged[mslots[0]].astype(np.int64)

@@ -246,7 +246,7 @@ class TestRealFlight:
 
     def test_version_skew_degrades_to_raw(self, fe, flight_cluster,
                                           monkeypatch):
-        """An old datanode that doesn't know reset_corr rejects the
+        """An old datanode that doesn't know `increase` rejects the
         shipped plan; the frontend degrades to the raw row path and the
         answer stays correct."""
         from greptimedb_tpu.query import plan_codec
@@ -255,7 +255,7 @@ class TestRealFlight:
         flight_cluster.do_query("INSERT INTO ctr VALUES " + _seed_rows())
         monkeypatch.setattr(
             plan_codec, "KNOWN_MOMENT_OPS",
-            plan_codec.KNOWN_MOMENT_OPS - {"reset_corr"})
+            plan_codec.KNOWN_MOMENT_OPS - {"increase"})
         q = "sum by (host) (rate(ctr[1m]))"
         skewed = _vec(flight_cluster, q)
         monkeypatch.setattr(tpu_exec, "TPU_DISPATCH_MIN_ROWS", 10**9)
