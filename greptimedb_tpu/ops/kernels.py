@@ -456,17 +456,20 @@ def _block_prefix(block_sums):
     return jnp.concatenate([zero, hi]), jnp.concatenate([zero, lo])
 
 
-def _sorted_seg_sum(x, starts, ends, bs, be, has_inner, n):
+def _sorted_seg_sum(x, starts, ends, bs, be, has_inner, n, dense=True):
     """Per-segment sum of x (zeros where masked).
 
     Low cardinality: per-segment block partials + edge windows.
     High cardinality: in-block inclusive scans + the prefix over block
     sums form a global prefix P; each segment is P[end]-P[start] —
     measured 4-8x faster at 120k-1.2M groups on v5e (the edge-window
-    design is O(groups*block) random gather). Bounds always come from
-    dense integer group queries (this module's contract), so starts[g] ==
-    ends[g-1] and the prefix at starts is a shift of the prefix at ends —
-    halving the O(G) gather count, the dominant cost at 1M+ groups."""
+    design is O(groups*block) random gather). Where the bounds come from
+    dense integer group queries (`dense`), starts[g] == ends[g-1] and the
+    prefix at starts is a shift of the prefix at ends — halving the O(G)
+    gather count, the dominant cost at 1M+ groups. Segments picked out
+    of such a layout (`dense` false: the live runs of a scan) gather the
+    prefix at both bounds; on a dense layout both forms give the same
+    bits."""
     if jnp.issubdtype(x.dtype, jnp.integer):
         acc = jnp.promote_types(x.dtype, jnp.int32)  # exact int accumulation
     else:
@@ -489,14 +492,19 @@ def _sorted_seg_sum(x, starts, ends, bs, be, has_inner, n):
     inblock = jnp.cumsum(xp.reshape(nb, B), axis=1)      # inclusive scans
     hi, lo = _block_prefix(inblock[:, -1])
 
-    # exclusive global prefix at each segment end, idx ∈ [0, nb*B]
-    b = ends // B                           # b == nb only when r == 0
-    r = ends % B
-    inb = jnp.where(
-        r > 0,
-        inblock[jnp.minimum(b, nb - 1), jnp.maximum(r - 1, 0)], 0)
-    pe_hi = hi[b]
-    pe_lo = inb if lo is None else lo[b] + inb
+    def prefix_at(idx):
+        # exclusive global prefix as a pair, idx ∈ [0, nb*B]
+        b = idx // B                        # b == nb only when r == 0
+        r = idx % B
+        inb = jnp.where(
+            r > 0,
+            inblock[jnp.minimum(b, nb - 1), jnp.maximum(r - 1, 0)], 0)
+        return hi[b], (inb if lo is None else lo[b] + inb)
+
+    pe_hi, pe_lo = prefix_at(ends)
+    if not dense:
+        ps_hi, ps_lo = prefix_at(starts)
+        return (pe_hi - ps_hi) + (pe_lo - ps_lo)
 
     def seg(pe):                            # P[end] - P[start], shifted
         return pe - jnp.concatenate([jnp.zeros(1, acc), pe[:-1]])
@@ -792,7 +800,7 @@ def _sorted_seg_argext(x, starts, ends, bs, be, has_inner, n, *, is_min,
 
 def sorted_grouped_aggregate(gids, mask, ts, values, col_masks=(), *,
                              num_groups, ops, has_col_masks=False,
-                             ends=None, seg_len_k=None):
+                             ends=None, seg_len_k=None, starts=None):
     """Host-validating wrapper (mirrors grouped_aggregate; gids sorted).
 
     At high cardinality the device-side binary search for segment bounds is
@@ -800,16 +808,24 @@ def sorted_grouped_aggregate(gids, mask, ts, values, col_masks=(), *,
     v5e). Callers that know the segment layout pass `ends` (int32
     [num_groups], cumulative row count per group — the LSM scan path has
     run boundaries on the host already); otherwise host gids fall back to a
-    bincount, and device gids to the on-device binary search."""
+    bincount, and device gids to the on-device binary search.
+
+    `starts` beside `ends` (both int32 [num_groups]) picks segments out
+    of the layout `gids` numbers: group g is rows [starts[g], ends[g]),
+    ascending and disjoint, each inside one run of `gids`; a padding group
+    has starts == ends. Every [num_groups]-shaped op is then sized by the
+    segments asked for (a scan's live runs), not by the layout's."""
     check_i64_safe(ts, what="sorted_grouped_aggregate ts")
     check_i64_safe(*[v for v in values], what="sorted_grouped_aggregate values")
+    if starts is not None and ends is None:
+        raise ValueError("starts needs ends")
     if ends is None and num_groups > _SEG_HIGH_CARD_THRESHOLD \
             and isinstance(gids, np.ndarray):
         hist = np.bincount(gids, minlength=num_groups)[:num_groups]
         ends = np.cumsum(hist, dtype=np.int64).astype(np.int32)
     if ends is not None:
         return _sorted_grouped_aggregate_pre(
-            gids, mask, ts, tuple(values), tuple(col_masks), ends,
+            gids, mask, ts, tuple(values), tuple(col_masks), ends, starts,
             num_groups=num_groups, ops=tuple(ops),
             has_col_masks=has_col_masks, seg_len_k=seg_len_k)
     return _sorted_grouped_aggregate(
@@ -820,10 +836,11 @@ def sorted_grouped_aggregate(gids, mask, ts, values, col_masks=(), *,
 @functools.partial(jax.jit,
                    static_argnames=("num_groups", "ops", "has_col_masks",
                                     "seg_len_k"))
-def _sorted_grouped_aggregate_pre(gids, mask, ts, values, col_masks, ends, *,
-                                  num_groups, ops, has_col_masks=False,
-                                  seg_len_k=None):
-    """_sorted_grouped_aggregate with host-precomputed segment ends.
+def _sorted_grouped_aggregate_pre(gids, mask, ts, values, col_masks, ends,
+                                  starts=None, *, num_groups, ops,
+                                  has_col_masks=False, seg_len_k=None):
+    """_sorted_grouped_aggregate with host-precomputed segment ends, and
+    `starts` where the segments are not the dense layout's.
 
     seg_len_k (static): ceil-log2 of the longest segment, bucketized by
     the caller — enables the shift-doubling min/max + first/last kernels
@@ -831,11 +848,14 @@ def _sorted_grouped_aggregate_pre(gids, mask, ts, values, col_masks, ends, *,
     REAL run ids (the scan path ships a dummy when no op needs them).
     """
     ends = jnp.asarray(ends)
-    starts = jnp.concatenate([jnp.zeros(1, jnp.int32), ends[:-1]])
+    dense = starts is None
+    starts = jnp.concatenate([jnp.zeros(1, jnp.int32), ends[:-1]]) \
+        if dense else jnp.asarray(starts)
     bs, be, has_inner = _block_cover(starts, ends)
     return _sga_body(gids, mask, ts, values, col_masks, starts, ends, bs,
                      be, has_inner, num_groups=num_groups, ops=ops,
-                     has_col_masks=has_col_masks, seg_len_k=seg_len_k)
+                     has_col_masks=has_col_masks, seg_len_k=seg_len_k,
+                     dense=dense)
 
 
 @functools.partial(jax.jit,
@@ -858,7 +878,12 @@ def _sorted_grouped_aggregate(gids, mask, ts, values, col_masks=(), *,
 
 def _sga_body(gids, mask, ts, values, col_masks, starts, ends, bs, be,
               has_inner, *, num_groups, ops, has_col_masks,
-              seg_len_k=None):
+              seg_len_k=None, dense=True):
+    """`dense`: segment g starts where g - 1 ends and `gids` numbers the
+    segments. Otherwise the segments are picked out of the layout `gids`
+    numbers: the shift-doubling kernels still guard their passes with
+    `gids` and pick up at `starts`; what would index a per-segment result
+    by `gids` takes the form that reads the bounds alone."""
     use_doubling = seg_len_k is not None and \
         num_groups > _SEG_HIGH_CARD_THRESHOLD
     n = gids.shape[0]
@@ -867,7 +892,7 @@ def _sga_body(gids, mask, ts, values, col_masks, starts, ends, bs, be,
         return (mask & col_masks[i]) if has_col_masks else mask
 
     counts = _sorted_seg_sum(mask.astype(jnp.int32), starts, ends, bs, be,
-                             has_inner, n).astype(jnp.int32)
+                             has_inner, n, dense).astype(jnp.int32)
 
     cache = {}
 
@@ -881,14 +906,14 @@ def _sga_body(gids, mask, ts, values, col_masks, starts, ends, bs, be,
             else:
                 v = col
             cache[ck] = _sorted_seg_sum(jnp.where(m, v, 0), starts, ends, bs,
-                                        be, has_inner, n)
+                                        be, has_inner, n, dense)
         return cache[ck]
 
     def seg_count(m, key):
         ck = ("count", key if has_col_masks else -1)
         if ck not in cache:
             cache[ck] = _sorted_seg_sum(m.astype(jnp.int32), starts, ends, bs,
-                                        be, has_inner, n)
+                                        be, has_inner, n, dense)
         return cache[ck]
 
     results = []
@@ -915,8 +940,9 @@ def _sga_body(gids, mask, ts, values, col_masks, starts, ends, bs, be,
             gc = jnp.maximum(jnp.sum(c), 1)
             shift = jnp.sum(jnp.where(m, colf, 0.0)) / gc
             d = jnp.where(m, colf - shift, 0.0)
-            s = _sorted_seg_sum(d, starts, ends, bs, be, has_inner, n)
-            sq = _sorted_seg_sum(d * d, starts, ends, bs, be, has_inner, n)
+            s = _sorted_seg_sum(d, starts, ends, bs, be, has_inner, n, dense)
+            sq = _sorted_seg_sum(d * d, starts, ends, bs, be, has_inner, n,
+                                 dense)
             cc = jnp.maximum(c, 1)
             # sample variance (ddof=1, DataFusion convention); <2 rows → NaN
             var = jnp.maximum(sq - (s / cc) * s, 0.0) / jnp.maximum(c - 1, 1)
@@ -953,8 +979,8 @@ def _sga_body(gids, mask, ts, values, col_masks, starts, ends, bs, be,
                     k_max=seg_len_k)
             else:
                 ext_t, pos = _sorted_seg_argext(key, starts, ends, bs, be,
-                                                has_inner, n,
-                                                is_min=is_min, gids=gids)
+                                                has_inner, n, is_min=is_min,
+                                                gids=gids if dense else None)
             found = (ext_t != ident) & (pos >= 0)
             val = col[jnp.clip(pos, 0, n - 1)]
             empty = jnp.nan if jnp.issubdtype(fdt, jnp.floating) \

@@ -21,7 +21,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.kernels import shape_bucket, sorted_grouped_aggregate
+from ..ops.kernels import (_SEG_HIGH_CARD_THRESHOLD, shape_bucket,
+                           sorted_grouped_aggregate)
 from . import tpu_exec
 
 #: The narrowed launch runs while the compact block (`padded_rows`:
@@ -54,6 +55,67 @@ def scan_read_path(n_rows: int, n_ranges: Optional[int],
     if n_ranges is None:
         return "full"
     return "narrow" if padded_rows * _NARROW_MAX_SHARE <= n_rows else "full"
+
+
+#: The full launch's group axis is the statement's live runs (those its
+#: row ranges touch) while their bucket is at most 1 / this of the bucket
+#: of the table's runs, and the latter is past the kernels'
+#: high-cardinality threshold (below it pickups are not the cost).
+#: Measured on a v5e (PR 36; one launch's kernel time, ms on the table's
+#: axis / on the live axis, by the live bucket's share of the table's).
+#: 46.08M rows in 7.68M runs of six (bucket 8,388,608), the longrange
+#: fleet panel's ops (count, growth, first, last, min and max of ts): 1/2
+#: 2,353 / 1,333, 1/4 2,353 / 710, 1/8 2,353 / 376, 1/16 2,353 / 219;
+#: `max` alone: 394 / 321, 170, 92, 55. 17.28M rows in 2.88M runs of six
+#: (bucket 4,194,304: tsbs-cpu-4000 by minute): `max` of five fields 488 /
+#: 360 (1/2), 145 (1/4), 77 (1/8); `avg` 406 / 356, 206, 97; `count`
+#: alone 193 / 238, 130, 62: a prefix-sum pickup gathers the prefix at both
+#: bounds on the live axis where the dense axis shifts the one it gathered
+#: at the end, so at a half the integer sums lose. The constant is the
+#: largest share at which the live axis won in every shape (1.5x to 3.4x).
+_LIVE_AXIS_MAX_SHARE = 4
+
+
+def scan_group_axis(n_runs: int, n_live: Optional[int]) -> str:
+    """"live" or "table": the group axis of a full launch over a table
+    cut into `n_runs` runs, for a statement whose row ranges touch
+    `n_live` of them (None: it resolved no ranges). The one place that
+    chooses, from counts alone."""
+    if n_live is None:
+        return "table"
+    table_b = shape_bucket(n_runs, minimum=256)
+    live_b = shape_bucket(n_live, minimum=256)
+    return "live" if table_b > _SEG_HIGH_CARD_THRESHOLD and \
+        live_b * _LIVE_AXIS_MAX_SHARE <= table_b else "table"
+
+
+def run_spans(run_starts: np.ndarray, sel: "Selection"):
+    """-> (lo, hi): range i of `sel` touches runs [lo[i], hi[i]) of a
+    table cut at `run_starts`. A range that starts or ends inside a run
+    keeps that run (the row mask decides its rows). Two searches over the
+    ranges; ranges are ascending and disjoint, so the spans are too (two
+    ranges inside one run share it: the later span starts after it)."""
+    lo = np.searchsorted(run_starts, sel.starts, side="right") - 1
+    hi = np.searchsorted(run_starts, sel.starts + sel.lens, side="left")
+    lo[1:] = np.maximum(lo[1:], hi[:-1])
+    return lo, hi
+
+
+def live_layout(run_starts: np.ndarray, run_ends: np.ndarray,
+                lo: np.ndarray, hi: np.ndarray, n: int):
+    """-> (n_live, num_groups, starts, ends): the kernel's segments for
+    the live runs the spans [lo[i], hi[i]) of `run_spans` name, end to
+    end, padded to their bucket with empty groups at `n` (as the table's
+    padded runs are). Nothing of the table's run count is built."""
+    c = hi - lo
+    n_live = int(c.sum())
+    live = np.repeat(lo - (np.cumsum(c) - c), c) + np.arange(n_live)
+    num_groups = shape_bucket(n_live, minimum=256)
+    starts = np.full(num_groups, n, dtype=np.int32)
+    ends = np.full(num_groups, n, dtype=np.int32)
+    starts[:n_live] = run_starts[live]
+    ends[:n_live] = run_ends[live]
+    return n_live, num_groups, starts, ends
 
 
 @dataclass

@@ -1493,6 +1493,9 @@ class _Launched:
     #: this scan launched the same kernel over the same columns before:
     #: nothing was compiled, uploaded or swept for this launch
     warm: bool = False
+    #: the group axis is the statement's live runs (`nruns` of them) out
+    #: of this many the table has; None: the axis is the table's runs
+    table_runs: Optional[int] = None
 
 
 def _moment_frame_for_scan(scan: MergedScan, schema,
@@ -1529,6 +1532,14 @@ def _moment_frame_for_scan(scan: MergedScan, schema,
         exec_stats.record("reduce", path=path)
         launched = _launch_scan_kernel(scan, schema, plan, _reduce_part,
                                        sel)
+        if launched is not None and launched.table_runs is not None:
+            increment_counter("scan_group_axis", axis="live")
+            exec_stats.record("reduce", groups="live",
+                              live_runs=launched.nruns,
+                              table_runs=launched.table_runs)
+        else:
+            increment_counter("scan_group_axis", axis="table")
+            exec_stats.record("reduce", groups="table")
     if launched is None:
         return None
     with _reduce_part("fetch"):     # blocked on the device, then D2H
@@ -1546,8 +1557,13 @@ def _launch_scan_kernel(scan: MergedScan, schema, plan: TpuPlan,
     EXPLAIN ANALYZE: `runs` (run-id sweep), `mask`, `upload` (every
     device_put), `launch` (the call that returns futures). `sel`: the
     row ranges `scan_narrow.select` resolved the predicates to, where the
-    caller has them."""
+    caller has them: the mask is their union, and where
+    `scan_narrow.scan_group_axis` says so the kernel's group axis is the
+    runs they touch (every row is still read, under the table's run ids)
+    and everything after the launch is sized by those."""
     import jax
+
+    from . import scan_narrow
 
     n = scan.num_rows
     if n == 0:
@@ -1600,6 +1616,15 @@ def _launch_scan_kernel(scan: MergedScan, schema, plan: TpuPlan,
             scan.device[layout_key] = (nbucket, run_ends, seg_len_k)
             if rid is not None:
                 scan.device[run_key] = (rid, nruns, run_starts, buckets)
+        table_runs = live_starts = None
+        if sel is not None:
+            lo, hi = scan_narrow.run_spans(run_starts, sel)
+            if scan_narrow.scan_group_axis(
+                    nruns, int((hi - lo).sum())) == "live":
+                table_runs = nruns
+                nruns, nbucket, live_starts, run_ends = \
+                    scan_narrow.live_layout(run_starts, run_ends, lo, hi, n)
+                run_starts = live_starts[:nruns]
     if rid is not None:
         with part("upload"):
             d_rid = jax.device_put(rid)
@@ -1609,8 +1634,9 @@ def _launch_scan_kernel(scan: MergedScan, schema, plan: TpuPlan,
         results, counts = sorted_grouped_aggregate(
             d_rid, d_mask, d_ts, tuple(values), tuple(col_masks),
             num_groups=nbucket, ops=tuple(ops), has_col_masks=True,
-            ends=run_ends, seg_len_k=seg_len_k)
-    signature = (run_key, tuple((m.op, m.column) for m in plan.moments))
+            ends=run_ends, seg_len_k=seg_len_k, starts=live_starts)
+    signature = (run_key, nbucket,
+                 tuple((m.op, m.column) for m in plan.moments))
     warm = signature in scan.launched
     if len(scan.launched) >= 64:     # sweeping bucket origins never repeat
         scan.launched.clear()
@@ -1618,7 +1644,7 @@ def _launch_scan_kernel(scan: MergedScan, schema, plan: TpuPlan,
     sids = scan.series_ids
     return _Launched(tuple(results), counts, nruns, sids[run_starts],
                      _run_buckets(plan, buckets, run_starts),
-                     scan.series_dict, scan.ts_base, warm)
+                     scan.series_dict, scan.ts_base, warm, table_runs)
 
 
 def _moment_reads(schema, plan: TpuPlan):

@@ -688,3 +688,90 @@ class TestHighCardinalityPaths:
         for g in fo.index[:3000]:
             assert first[g] == np.float32(fo.loc[g]), g
             assert last[g] == np.float32(lo.loc[g]), g
+
+
+class TestSegmentsPickedOutOfALayout:
+    """`sorted_grouped_aggregate` with explicit `starts` beside `ends`
+    (ISSUE 36): the segments asked for are some of a dense layout's (a
+    scan's live runs out of the table's), `gids` stays the dense layout's
+    run ids, and every op answers what the dense launch answers at those
+    segments."""
+
+    #: (id, the dense layout's groups, segments picked; None: all of them,
+    #: with `starts` the shift of `ends`)
+    LAYOUTS = [
+        ("low", 300, 60),                 # both under the threshold
+        ("high", 40_000, 12_000),         # both above it
+        ("straddle", 20_000, 2_000),      # the layout above, the picked under
+        ("low-shift", 300, None),
+        ("high-shift", 40_000, None),
+    ]
+
+    @pytest.mark.parametrize("with_k", [True, False],
+                             ids=["seg_len_k", "no-seg_len_k"])
+    @pytest.mark.parametrize("layout", LAYOUTS, ids=[c[0] for c in LAYOUTS])
+    def test_equals_the_dense_launch_at_those_segments(self, layout, with_k):
+        from greptimedb_tpu.ops.kernels import (
+            _SEG_HIGH_CARD_THRESHOLD, seg_len_bucket,
+            sorted_grouped_aggregate)
+        name, groups, picked = layout
+        rng = np.random.default_rng(groups + bool(with_k))
+        longest = 70                  # past two 32-row blocks
+        lens = rng.integers(0, 9, groups)
+        lens[rng.integers(0, groups, 12)] = rng.integers(30, longest + 1, 12)
+        n = int(lens.sum())
+        dense_b = shape_bucket(groups, minimum=256)
+        dense_ends = np.full(dense_b, n, dtype=np.int32)
+        dense_ends[:groups] = np.cumsum(lens)
+        dense_starts = np.concatenate([[0], dense_ends[:-1]]).astype(np.int32)
+        gids = np.repeat(np.arange(groups, dtype=np.int32), lens)
+        ts = rng.integers(0, 40, n).astype(np.int32)        # ties
+        vals = (rng.random(n, dtype=np.float32) * 100) - 50
+        mask = rng.random(n) > 0.15
+        valid = rng.random(n) > 0.1
+        ops = ("count", "sum", "avg", "min", "max", "first", "last",
+               "min", "max") + (("growth",) if with_k else ())
+        values = tuple(ts if i in (7, 8) else vals for i in range(len(ops)))
+        k = seg_len_bucket(longest) if with_k else None
+
+        if picked is None:
+            live = np.arange(dense_b)
+            live_b, starts, ends = dense_b, dense_starts, dense_ends
+        else:
+            live = np.sort(rng.choice(groups, picked, replace=False))
+            # one picked segment with rows and none under the mask
+            emptied = live[np.nonzero(lens[live] > 2)[0][3]]
+            mask[dense_starts[emptied]:dense_ends[emptied]] = False
+            live_b = shape_bucket(picked, minimum=256)
+            assert live_b > picked            # padding groups past the live
+            starts = np.full(live_b, n, dtype=np.int32)
+            ends = np.full(live_b, n, dtype=np.int32)
+            starts[:picked] = dense_starts[live]
+            ends[:picked] = dense_ends[live]
+        assert (dense_b > _SEG_HIGH_CARD_THRESHOLD) == (not name.startswith(
+            "low"))
+        assert (live_b > _SEG_HIGH_CARD_THRESHOLD) == name.startswith("high")
+
+        def run(num_groups, **segments):
+            res, counts = sorted_grouped_aggregate(
+                gids, mask, ts, values, tuple(valid for _ in ops),
+                num_groups=num_groups, ops=ops, has_col_masks=True,
+                seg_len_k=k, **segments)
+            return [np.asarray(r) for r in res], np.asarray(counts)
+
+        want, want_counts = run(dense_b, ends=dense_ends)
+        got, got_counts = run(live_b, ends=ends, starts=starts)
+        m = len(live)
+        assert np.array_equal(got_counts[:m], want_counts[live])
+        assert (got_counts[m:] == 0).all()
+        if picked is not None:
+            at = int(np.searchsorted(live, emptied))
+            assert got_counts[at] == 0 and np.isnan(got[5][at])
+        for i, (op, g, w) in enumerate(zip(ops, got, want)):
+            g, w = g[:m], w[live]
+            if op in ("sum", "avg") and name == "straddle":
+                # edge windows against prefix differences: f32 rounding
+                np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-3,
+                                           err_msg=op, equal_nan=True)
+            else:
+                assert np.array_equal(g, w, equal_nan=True), (op, i)

@@ -173,6 +173,54 @@ def test_one_nodes_panel_takes_the_narrowed_launch(fleet):
     assert "narrow_rows=38784, ranges=64" in stages["reduce"][2]
 
 
+#: the group axis of each family's full launch (ISSUE 36); None: narrow
+GROUP_AXIS = {"long-cpu-util-fleet": "live", "long-cpu-by-mode-1": None,
+              "long-net-rx-fleet": "table",
+              "long-load-max-by-instance": "table",
+              "long-mem-available-fleet": "table",
+              "long-fs-avail-min": "table"}
+
+
+@pytest.mark.parametrize("name", FAMILIES)
+def test_the_full_launch_takes_the_live_runs_where_ranges_say_which(fleet,
+                                                                    name):
+    """`mode="idle"` resolves to an eighth of the series: the fleet panel's
+    launch picks up at their runs (16,240 of 155,584 here), counted once a
+    launch; a `!=` matcher or none resolves no ranges and keeps the
+    table's; either way the family answers its reference from the
+    device."""
+    warm_cpu_table(fleet)
+    fam, params = drawn(fleet, name, "explain")
+    before = {axis: metric("scan_group_axis", axis=axis)
+              for axis in ("live", "table")}
+    rows_before = metric("scan_device_rows")
+    stages = fleet.stages(fam.sql(params, fleet.ds))
+    detail = stages["reduce"][2]
+    bumped = {axis: metric("scan_group_axis", axis=axis) - before[axis]
+              for axis in ("live", "table")}
+    axis = GROUP_AXIS[name]
+    if axis is None:
+        assert "path=narrow" in detail and "groups=" not in detail
+        assert bumped == {"live": 0, "table": 0}
+    else:
+        assert f"path=full, groups={axis}" in detail, detail
+        assert bumped == {"live": int(axis == "live"),
+                          "table": int(axis == "table")}
+    found = re.search(r"live_runs=(\d+), table_runs=(\d+)", detail)
+    if axis == "live":
+        assert 0 < int(found[1]) < int(found[2]) // 4
+        # the launch still reads every row of the table on the device
+        table = fleet.fe.catalog.table("greptime", "public",
+                                       "node_cpu_seconds_total")
+        scan = tpu_exec.SCAN_CACHE.get(next(iter(table.regions.values())))
+        assert metric("scan_device_rows") - rows_before == scan.num_rows
+    else:
+        assert found is None
+    assert stages["dispatch"][2] == RESIDENT
+    res = fleet.judge(fam, params)
+    assert res["ok"] and res["rows"] > 0, res
+
+
 def test_no_moment_op_is_left_to_the_host_alone():
     assert not hasattr(tpu_exec, "HOST_ONLY_MOMENT_OPS")
     plan = tpu_exec.TpuPlan([], None, [tpu_exec.Moment(

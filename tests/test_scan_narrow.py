@@ -121,10 +121,13 @@ def full(monkeypatch):
     return forced
 
 
-def counter(path: str) -> float:
+def total(metric: str, label: str) -> float:
     return sum(value for name, labels, value, _ in registry_snapshot()
-               if name == "greptime_scan_reads_total"
-               and f'path="{path}"' in labels)
+               if name == metric and label in labels)
+
+
+def counter(path: str) -> float:
+    return total("greptime_scan_reads_total", f'path="{path}"')
 
 
 def both(db, full, sql):
@@ -416,6 +419,223 @@ def test_many_runs_take_the_high_cardinality_kernels(db, monkeypatch):
     pd.testing.assert_frame_equal(narrow.reset_index(drop=True),
                                   whole.reset_index(drop=True),
                                   check_exact=True)
+
+
+# ---------------------------------------------------------------------------
+# the full launch's group axis: the statement's live runs, or the table's
+# (ISSUE 36). By 20 s the table is 24 x 400 = 9,600 runs (bucket 16,384,
+# past the kernels' high-cardinality threshold); 5 to 8 hosts' windows are
+# 750 to 3,200 live runs (bucket 1,024 to 4,096) and run full, not narrow.
+# ---------------------------------------------------------------------------
+
+def axis_counter(axis: str) -> float:
+    return total("greptime_scan_group_axis_total", f'axis="{axis}"')
+
+
+@pytest.fixture
+def on_axis(monkeypatch):
+    """-> a context in which every full launch with a selection takes the
+    named group axis, and every resident launch is a full one."""
+    @contextlib.contextmanager
+    def forced(axis):
+        with monkeypatch.context() as m:
+            m.setattr(scan_narrow, "_NARROW_MAX_SHARE", 10**12)
+            m.setattr(scan_narrow, "scan_group_axis",
+                      lambda n_runs, n_live: axis if n_live is not None
+                      else "table")
+            yield
+    return forced
+
+
+def both_axes(db, on_axis, sql):
+    """-> (live answer, table answer), each by the axis it names."""
+    before = axis_counter("live"), axis_counter("table")
+    with on_axis("live"):
+        live = db.sql(sql)
+    assert (axis_counter("live"), axis_counter("table")) == \
+        (before[0] + 1, before[1]), "the launch did not take the live axis"
+    with on_axis("table"):
+        table = db.sql(sql)
+    assert axis_counter("table") == before[1] + 1
+    return live, table
+
+
+BY_20S = "date_bin(INTERVAL '20 second', ts) AS b"
+#: a window whose edges lie inside 20 s runs (odd ticks)
+INSIDE = f"ts >= {T0 + 31 * TICK_MS} AND ts < {T0 + 331 * TICK_MS}"
+
+#: (id, WHERE)
+LIVE_SELECTIONS = [
+    ("eq", f"host = 'h05' AND {WINDOW}"),
+    ("in-6", f"host IN ({in_list([2, 3, 8, 13, 21, 23])}) AND {WINDOW}"),
+    ("in-no-window", f"host IN ({in_list([0, 7, 9, 16, 23])})"),
+    ("edge-inside-a-run",
+     f"host IN ({in_list([1, 4, 6, 11, 17, 22])}) AND {INSIDE}"),
+    ("in-and-ne", f"host IN ({in_list(range(2, 10))}) AND region != 'r2' "
+                  f"AND {WINDOW}"),
+    ("field-filter", f"host IN ({in_list(range(3, 9))}) AND usage > 70 "
+                     f"AND {INSIDE}"),
+    ("one-series-outside-the-window",
+     f"host IN ('h06', 'h10', 'h30') AND {WINDOW}"),
+]
+
+
+@pytest.mark.parametrize("group", [
+    ("by-host-and-20s", f"host, {BY_20S}, ", "host, b"),
+    ("by-20s", f"{BY_20S}, ", "b")], ids=lambda g: g[0])
+@pytest.mark.parametrize("where", LIVE_SELECTIONS,
+                         ids=[s[0] for s in LIVE_SELECTIONS])
+def test_the_live_axis_answers_as_the_tables(db, on_axis, where, group):
+    """The exact ops bit for bit; `sum` and `avg` take the edge-window
+    form on the live axis (its bucket is under the threshold) where the
+    table's takes prefix differences, whose rounding in a run of two
+    rows is what `test_kernels.py` allows the high-cardinality form."""
+    _, select, group_by = group
+    sql = (f"SELECT {select}{EXACT}, sum(usage), avg(idle) FROM cpu "
+           f"WHERE {where[1]} GROUP BY {group_by} ORDER BY {group_by}")
+    live, table = both_axes(db, on_axis, sql)
+    assert len(live) > 0
+    loose = ["sum(usage)", "avg(idle)"]
+    pd.testing.assert_frame_equal(live.drop(columns=loose),
+                                  table.drop(columns=loose), check_exact=True)
+    for col in loose:
+        np.testing.assert_allclose(live[col], table[col], rtol=2e-4,
+                                   atol=1e-3)
+
+
+def test_live_runs_past_the_threshold_keep_the_prefix_form_bit_for_bit(
+        db, on_axis):
+    """20 of 24 hosts by 10 s: 16,000 live runs of 19,200, both buckets
+    past the threshold, so `sum` and `avg` are the same prefix
+    differences on either axis."""
+    sql = (f"SELECT host, date_bin(INTERVAL '10 second', ts) AS b, {EXACT}, "
+           f"sum(usage), avg(usage), avg(idle) FROM cpu WHERE host IN "
+           f"({in_list(range(2, 22))}) GROUP BY host, b ORDER BY host, b")
+    live, table = both_axes(db, on_axis, sql)
+    assert len(live) == 20 * TICKS
+    pd.testing.assert_frame_equal(live, table, check_exact=True)
+
+
+@pytest.mark.parametrize("where", [
+    "host IN ('never-seen', 'nor-this')",
+    f"host IN ({in_list(range(4, 10))}) AND ts >= {T0 + TICKS * TICK_MS}",
+], ids=["unknown-values", "window-after"])
+def test_an_empty_selection_launches_nothing_on_either_axis(db, on_axis,
+                                                            where):
+    sql = f"SELECT host, {BY_20S}, max(usage) FROM cpu WHERE {where} " \
+          "GROUP BY host, b"
+    for axis in ("live", "table"):
+        before = axis_counter("live")
+        with on_axis(axis):
+            assert len(db.sql(sql)) == 0
+            assert "groups=table" in db.stages(sql)["reduce"]
+        assert axis_counter("live") == before      # nothing was launched
+
+
+def test_scan_group_axis_reads_only_counts():
+    axis = scan_narrow.scan_group_axis
+    assert axis(7_680_000, None) == "table"        # no ranges resolved
+    assert axis(7_680_000, 816_000) == "live"      # the longrange panel
+    # a quarter of the table's bucket, and the bucket past the threshold
+    assert axis(7_680_000, 2_097_152) == "live"
+    assert axis(7_680_000, 2_097_153) == "table"
+    assert axis(16_384, 4_096) == "live"
+    assert axis(8_192, 16) == "table"              # pickups are not the cost
+    assert axis(9_600, 3_200) == "live" and axis(9_600, 4_097) == "table"
+    assert axis(1, 1) == "table"
+
+
+def test_run_spans_keep_every_run_a_range_touches():
+    run_starts = np.array([0, 4, 8, 12, 20, 21, 30])
+    sel = scan_narrow.Selection(
+        np.arange(4, dtype=np.int32),
+        np.array([0, 9, 12, 22], dtype=np.int64),       # starts
+        np.array([4, 2, 9, 3], dtype=np.int64))         # lens
+    lo, hi = scan_narrow.run_spans(run_starts, sel)
+    # [0, 4) is run 0; [9, 11) lies inside run 2; [12, 21) is runs 3 and
+    # 4; [22, 25) lies inside run 5
+    assert list(zip(lo, hi)) == [(0, 1), (2, 3), (3, 5), (5, 6)]
+    run_ends = np.append(run_starts[1:], 40).astype(np.int32)
+    n_live, groups, starts, ends = scan_narrow.live_layout(
+        run_starts, run_ends, lo, hi, 40)
+    assert (n_live, groups) == (5, 256)
+    assert list(starts[:6]) == [0, 8, 12, 20, 21, 40]
+    assert list(ends[:6]) == [4, 12, 20, 21, 30, 40]
+    # two ranges inside one run share it
+    sel = scan_narrow.Selection(np.arange(2, dtype=np.int32),
+                                np.array([13, 16], dtype=np.int64),
+                                np.array([2, 3], dtype=np.int64))
+    lo, hi = scan_narrow.run_spans(run_starts, sel)
+    assert int((hi - lo).sum()) == 1
+
+
+def test_the_live_axis_builds_nothing_of_the_tables_run_count(db,
+                                                              monkeypatch):
+    """Warm (the table's runs and layout are cached with the scan), a
+    statement on the live axis cuts no layout and hands the launch, the
+    fetch and `collect` arrays of the live runs' bucket."""
+    sql = (f"SELECT host, {BY_20S}, {EXACT} FROM cpu WHERE host IN "
+           f"({in_list([1, 5, 9, 13, 17, 21])}) AND {WINDOW} "
+           "GROUP BY host, b")
+    assert "groups=live" in db.stages(sql)["reduce"]       # warm
+
+    def never(*a, **k):
+        raise AssertionError("the live axis cut the table's runs again")
+    monkeypatch.setattr(tpu_exec, "_segment_layout", never)
+    seen = {}
+    real_layout, real_collect = scan_narrow.live_layout, \
+        tpu_exec._collect_moment_frame
+
+    def layout(run_starts, run_ends, lo, hi, n):
+        out = real_layout(run_starts, run_ends, lo, hi, n)
+        seen["table_runs"] = len(run_starts)
+        seen["layout"] = (out[0], len(out[2]), len(out[3]))
+        return out
+
+    def collect(launched, plan, counts, res_np):
+        seen["collect"] = (launched.nruns, len(launched.run_sids),
+                           len(launched.run_buckets), len(counts),
+                           {len(r) for r in res_np})
+        return real_collect(launched, plan, counts, res_np)
+    monkeypatch.setattr(scan_narrow, "live_layout", layout)
+    monkeypatch.setattr(tpu_exec, "_collect_moment_frame", collect)
+    before = axis_counter("live")
+    detail = db.stages(sql)["reduce"]
+    assert axis_counter("live") == before + 1
+    assert seen["table_runs"] == HOSTS * TICKS // 2
+    assert seen["layout"] == (900, 1024, 1024)
+    assert seen["collect"] == (900, 900, 900, 1024, {1024})
+    assert "path=full, groups=live, live_runs=900, table_runs=9600" in detail
+
+
+def test_no_tag_conjunct_keeps_the_tables_axis_and_its_program(db):
+    from greptimedb_tpu.ops.kernels import _sorted_grouped_aggregate_pre
+
+    def sql(lo):
+        return (f"SELECT host, {BY_20S}, max(usage), count(idle) FROM cpu "
+                f"WHERE region != 'r1' AND ts >= {T0 + lo * TICK_MS} AND "
+                f"ts < {T0 + (lo + 300) * TICK_MS} GROUP BY host, b")
+    db.sql(sql(30))
+    compiled = _sorted_grouped_aggregate_pre._cache_size()
+    before = axis_counter("live"), axis_counter("table")
+    assert len(db.sql(sql(100))) == 16 * 150
+    detail = db.stages(sql(200))["reduce"]
+    assert "path=full, groups=table" in detail and "live_runs" not in detail
+    assert (axis_counter("live"), axis_counter("table")) == \
+        (before[0], before[1] + 2)
+    assert _sorted_grouped_aggregate_pre._cache_size() == compiled
+    # other hosts of the same shape on the live axis: one program more,
+    # then nothing
+    def live(hosts, lo):
+        return (f"SELECT host, {BY_20S}, max(usage), count(idle) FROM cpu "
+                f"WHERE host IN ({in_list(hosts)}) AND ts >= "
+                f"{T0 + lo * TICK_MS} AND ts < {T0 + (lo + 300) * TICK_MS} "
+                "GROUP BY host, b")
+    assert "groups=live" in db.stages(live(range(6), 30))["reduce"]
+    compiled = _sorted_grouped_aggregate_pre._cache_size()
+    for hosts, lo in ((range(6, 12), 100), ([1, 3, 5, 7, 20, 23], 260)):
+        assert len(db.sql(live(hosts, lo))) == 6 * 150
+    assert _sorted_grouped_aggregate_pre._cache_size() == compiled
 
 
 def test_the_point_cell_runs_narrow_on_the_cpu_debug_run():
