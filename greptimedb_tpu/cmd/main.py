@@ -44,6 +44,9 @@ class StandaloneOptions:
     #: [logging] otlp_endpoint: OTLP/HTTP collector base URL (spans are
     #: exported to {endpoint}/v1/traces when set)
     otlp_endpoint: Optional[str] = None
+    #: --wal-sync-on-write: a write is acknowledged after the WAL's fsync
+    #: (upstream's `[wal] sync_write = true`)
+    wal_sync_on_write: bool = False
 
 
 def load_options(args) -> StandaloneOptions:
@@ -80,6 +83,7 @@ def load_options(args) -> StandaloneOptions:
         v = getattr(args, name, None)
         if v is not None:
             setattr(opts, name, v)
+    opts.wal_sync_on_write = bool(getattr(args, "wal_sync_on_write", False))
     return opts
 
 
@@ -104,8 +108,9 @@ def build_servers(opts: StandaloneOptions):
     if opts.storage and str(opts.storage.get("type", "File")) != "File":
         from ..storage.object_store import build_object_store
         store = build_object_store(opts.storage, opts.data_home)
-    dn = DatanodeInstance(DatanodeOptions(data_home=opts.data_home),
-                          store=store)
+    dn = DatanodeInstance(DatanodeOptions(
+        data_home=opts.data_home,
+        wal_sync_on_write=opts.wal_sync_on_write), store=store)
     fe = FrontendInstance(dn)
     fe.start()
     provider = NoopUserProvider()
@@ -146,11 +151,12 @@ def build_servers(opts: StandaloneOptions):
 def standalone_start(args) -> None:
     opts = load_options(args)
     from ..common.telemetry import (configure_otlp, init_logging,
-                                    install_panic_hook)
+                                    install_gc_timer, install_panic_hook)
     init_logging(opts.log_level, opts.log_dir)
     if opts.otlp_endpoint:
         configure_otlp(opts.otlp_endpoint, service_name="greptimedb")
     install_panic_hook()
+    install_gc_timer()
     _claim_device()
     fe, servers = build_servers(opts)
     for s in servers:
@@ -405,7 +411,7 @@ def frontend_start(args) -> None:
     _block_until_signal(shutdown)
 
 
-def main(argv=None) -> int:
+def build_parser() -> argparse.ArgumentParser:
     parser = argparse.ArgumentParser(
         prog="greptime", description="greptimedb_tpu CLI")
     sub = parser.add_subparsers(dest="subcommand", required=True)
@@ -421,6 +427,8 @@ def main(argv=None) -> int:
     start.add_argument("--grpc-addr")
     start.add_argument("--opentsdb-addr")
     start.add_argument("--user-provider")
+    start.add_argument("--wal-sync-on-write", action="store_true",
+                       help="fsync the WAL before acking each write")
     start.set_defaults(func=standalone_start)
 
     metasrv = sub.add_parser("metasrv")
@@ -467,8 +475,11 @@ def main(argv=None) -> int:
     attach = csub.add_parser("attach")
     attach.add_argument("--grpc-addr", default="127.0.0.1:4001")
     attach.set_defaults(func=_cli_attach)
+    return parser
 
-    args = parser.parse_args(argv)
+
+def main(argv=None) -> int:
+    args = build_parser().parse_args(argv)
     args.func(args)
     return 0
 

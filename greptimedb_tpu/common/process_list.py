@@ -135,6 +135,7 @@ class ProcessRegistry:
         self._entries: Dict[int, ProcessEntry] = tracked_state(
             {}, "process_list.entries")
         self._ids = itertools.count(1)
+        self._running = 0       # len(_entries), readable without the lock
         self.node = node
 
     def register(self, query: str, protocol: str, catalog: str,
@@ -143,11 +144,20 @@ class ProcessRegistry:
                              schema, self.node, trace_id)
         with self._lock:
             self._entries[entry.id] = entry
+            self._running = len(self._entries)
         return entry
 
     def deregister(self, entry: ProcessEntry) -> None:
         with self._lock:
             self._entries.pop(entry.id, None)
+            self._running = len(self._entries)
+
+    def busy(self) -> bool:
+        """Whether a statement is executing in this process right now:
+        one word read without the lock, a hint for code that gives way
+        to statements (`admission.AdmissionGate.give_way`) after every
+        line it parses, not a count to act on."""
+        return self._running > 0
 
     def kill(self, pid: int) -> None:
         """Trip a statement's cancel event. Unknown (or already

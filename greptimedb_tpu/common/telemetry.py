@@ -620,6 +620,68 @@ def timer(name: str) -> Iterator[None]:
 
 
 # ---------------------------------------------------------------------------
+# the interpreter's full collections
+# ---------------------------------------------------------------------------
+
+#: [count, seconds, longest] of the generation-2 collections since
+#: `install_gc_timer`
+_gc_full = [0, 0.0, 0.0]
+
+
+class _GcFullCollector:
+    """`greptime_gc_full_collection_seconds_count` / `_sum` and
+    `greptime_gc_full_collection_max_seconds` on /metrics."""
+
+    def collect(self):
+        from prometheus_client.core import (GaugeMetricFamily,
+                                            SummaryMetricFamily)
+        n, total, longest = _gc_full
+        yield SummaryMetricFamily(
+            "greptime_gc_full_collection_seconds",
+            "generation-2 collections of the interpreter",
+            count_value=n, sum_value=total)
+        yield GaugeMetricFamily(
+            "greptime_gc_full_collection_max_seconds",
+            "the longest generation-2 collection", value=longest)
+
+
+def install_gc_timer() -> None:
+    """Time the interpreter's full collections. A generation-2
+    collection walks every container object of the process with the
+    interpreter lock held: statements and acknowledgements all stand
+    still for it, and nothing else on /metrics would say why. The
+    callback takes no lock and touches no metric object (a collection can
+    start inside any allocation, one made under the metrics' lock
+    included): it adds to three numbers that a collector reads. Once a
+    process, by `standalone start` (`cmd/main.py`)."""
+    import gc
+    if any(getattr(cb, "greptime_gc_timer", False) for cb in gc.callbacks):
+        return
+    t0 = [0.0]
+
+    def on_gc(phase: str, info: dict) -> None:
+        if info.get("generation") != 2:
+            return
+        if phase == "start":
+            t0[0] = time.perf_counter()
+        elif t0[0]:
+            dt = time.perf_counter() - t0[0]
+            t0[0] = 0.0
+            _gc_full[0] += 1
+            _gc_full[1] += dt
+            if dt > _gc_full[2]:
+                _gc_full[2] = dt
+
+    on_gc.greptime_gc_timer = True
+    gc.callbacks.append(on_gc)
+    try:
+        from prometheus_client import REGISTRY
+        REGISTRY.register(_GcFullCollector())
+    except ImportError:  # pragma: no cover
+        pass
+
+
+# ---------------------------------------------------------------------------
 # latency histograms (log-bucketed; reference: the HISTOGRAM_* statics in
 # src/servers/src/metrics.rs — per-protocol request latency distributions
 # exported in Prometheus histogram text format)

@@ -942,7 +942,7 @@ class QueryEngine:
         if query.order_by:
             pairs = a.order_by if (aggregated or a.is_aggregate
                                    or a.window_calls) else query.order_by
-            sort_frame = proj.copy()
+            sort_frame = proj        # copied before it gains a column
             keys: List[str] = []
             ascs: List[bool] = []
             base_ev = Evaluator(df)
@@ -955,6 +955,8 @@ class QueryEngine:
                 if target is None:
                     target = f"__ord{i}"
                     v = base_ev.eval(e)
+                    if sort_frame is proj:
+                        sort_frame = proj.copy()
                     sort_frame[target] = v if isinstance(v, pd.Series) \
                         else pd.Series([v] * len(sort_frame),
                                        index=sort_frame.index)
@@ -966,26 +968,49 @@ class QueryEngine:
                 # Default is the Postgres rule — NULLS LAST for ASC,
                 # NULLS FIRST for DESC — overridden by NULLS FIRST/LAST.
                 nulls_spec = getattr(query, "order_nulls", [])
-                sort_cols: List[str] = []
-                sort_asc: List[bool] = []
-                for i, (target, asc) in enumerate(zip(keys, ascs)):
-                    nf = nulls_spec[i] if i < len(nulls_spec) else None
-                    if nf is None:
-                        nf = not asc
-                    flag = f"__nullord{i}"
-                    sort_frame[flag] = sort_frame[target].isna()
-                    sort_cols += [flag, target]
-                    sort_asc += [not nf, asc]
-                sort_frame = sort_frame.sort_values(sort_cols,
-                                                    ascending=sort_asc,
-                                                    kind="stable")
-                proj = proj.loc[sort_frame.index]
+                nulls_first = [
+                    nulls_spec[i] if i < len(nulls_spec)
+                    and nulls_spec[i] is not None else not asc
+                    for i, asc in enumerate(ascs)]
+                proj = proj.iloc[_sort_positions(
+                    [sort_frame[k] for k in keys], ascs, nulls_first)]
 
         if query.offset:
             proj = proj.iloc[query.offset:]
         if query.limit is not None:
             proj = proj.iloc[:query.limit]
         return proj
+
+
+def _sort_positions(columns: List[pd.Series], ascs: List[bool],
+                    nulls_first: List[bool]) -> np.ndarray:
+    """The stable ORDER BY of `columns` (first key first) as row
+    positions: one `np.lexsort` over a key a column with a NULL flag
+    ahead of a column that has one. A key is the number as it is, negated
+    for DESC; anything else (and an integer column whose negation would
+    wrap: unsigned, or holding its type's smallest value) goes by its
+    rank among the sorted distinct values. Values that do not compare
+    raise pandas' TypeError."""
+    lex: List[np.ndarray] = []
+    for col, asc, nf in zip(columns, ascs, nulls_first):
+        vals = col.to_numpy() if isinstance(col.dtype, np.dtype) else None
+        kind = vals.dtype.kind if vals is not None else "O"
+        if kind in "iu" and not asc and len(vals) and (
+                kind == "u" or vals.min() == np.iinfo(vals.dtype).min):
+            kind = "O"
+        if kind in "mM":
+            na = np.isnat(vals)
+            key = vals.view(np.int64)
+        elif kind in "iuf":
+            na = np.isnan(vals) if kind == "f" else None
+            key = vals
+        else:
+            key, _ = pd.factorize(col, sort=True)         # NULL: -1
+            na = key < 0
+        if na is not None and na.any():
+            lex.append(~na if nf else na)
+        lex.append(key if asc else -key)
+    return np.lexsort(lex[::-1])
 
 
 def stage_rows_output(stats: "exec_stats.ExecStats", plan_lines: List[str],

@@ -149,10 +149,11 @@ class Selection:
 
 
 def _lower_bound(ts: np.ndarray, lo: np.ndarray, hi: np.ndarray,
-                 value: int) -> np.ndarray:
+                 value) -> np.ndarray:
     """Per range [lo[i], hi[i]) of `ts` (ascending inside a range): the
-    first row whose ts >= value. Every range bisects at once, so the
-    cost is log2(longest range) passes over k, never a pass over ts."""
+    first row whose ts >= value (one value, or one a range). Every range
+    bisects at once, so the cost is log2(longest range) passes over k,
+    never a pass over ts."""
     lo, hi = lo.copy(), hi.copy()
     while True:
         open_ = lo < hi
@@ -180,8 +181,10 @@ def select(scan, schema, plan) -> Optional[Selection]:
     if len(cand):
         cand = cand[tpu_exec._series_keep(sd, tag_names, cand,
                                           plan.tag_predicates)]
-    lo = np.searchsorted(scan.series_ids, cand, side="left")
-    hi = np.searchsorted(scan.series_ids, cand, side="right")
+    # a padded scan repeats its last row: the ranges end at the valid rows
+    sids = scan.series_ids[:scan.valid_rows]
+    lo = np.searchsorted(sids, cand, side="left")
+    hi = np.searchsorted(sids, cand, side="right")
     if plan.time_lo is not None:
         lo = _lower_bound(scan.ts, lo, hi, plan.time_lo)
     if plan.time_hi is not None:
@@ -261,7 +264,7 @@ def launch(scan, schema, plan, sel: Selection, part):
         run_starts[0] = 0        # runs tile the block: padding joins a run
         ops, value_ix, mask_ix, cols = _columns(scan, schema, plan)
         nbucket, run_ends, rid, seg_len_k = tpu_exec._segment_layout(
-            run_starts, k_b * len_b, ops)
+            run_starts, k_b * len_b, ops, pinned=scan.pinned)
     row_mask = None
     if plan.field_filters:
         with part("mask"):
@@ -274,10 +277,13 @@ def launch(scan, schema, plan, sel: Selection, part):
         cuts = np.zeros((3, k_b), dtype=np.int32)
         cuts[0, :k], cuts[1, :k], cuts[2, :k] = at, off, off + sel.lens
     with part("launch"):
-        results, counts = _narrow_reduce(
-            cuts, run_ends, rid, row_mask, cols, len_b=len_b,
-            num_groups=nbucket, ops=ops, value_ix=value_ix,
+        out = tpu_exec._run_program(
+            scan, _narrow_reduce, cuts, run_ends, rid, row_mask, cols,
+            len_b=len_b, num_groups=nbucket, ops=ops, value_ix=value_ix,
             mask_ix=mask_ix, seg_len_k=seg_len_k)
+    if out is None:         # a stand-in tail: compiled, not run
+        return None
+    results, counts = out
     run_range = np.searchsorted(first, run_rows, side="right") - 1
     # warm stays False: the dispatch floor (`_note_device_query_time`)
     # is fed by full launches, whose fixed cost it stands for

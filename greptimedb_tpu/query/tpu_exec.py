@@ -28,7 +28,8 @@ import numpy as np
 import pandas as pd
 
 from ..errors import UnsupportedError
-from ..ops.kernels import merge_dedup_numpy, shape_bucket, sorted_grouped_aggregate
+from ..ops.kernels import (_sorted_grouped_aggregate_pre, merge_dedup_numpy,
+                           shape_bucket)
 from ..sql.ast import (
     Between, BinaryOp, Column, Expr, FunctionCall, InList, Interval, IsNull,
     Literal, Query, UnaryOp,
@@ -64,22 +65,85 @@ class MergedScan:
     #: kernel launches this scan has made (run layout + moments): a
     #: repeat of one compiles, uploads and sweeps nothing
     launched: set = field(default_factory=set)
+    #: a tail (`_ScanCache`): the row axis is a capacity, not a count, and
+    #: every layout a launch derives from the content (the longest run)
+    #: is pinned to what the capacity allows, so that rows written later
+    #: never meet a program that was not compiled
+    pinned: bool = False
+    #: the scan cache's: its uploads count (`scan_cache_upload_bytes`)
+    count_uploads: bool = False
+    #: smallest / largest ts among the valid rows (a tail's: a statement
+    #: whose time range lies outside skips it)
+    ts_min: int = 0
+    ts_max: int = -1
+    #: a tail's fields as one float64 [valid rows, fields] (`_Rows.block`)
+    block: Optional[np.ndarray] = None
+    #: a base's: the executables its tails launch, compiled ahead of the
+    #: first write (`_warm_tail_programs`, `_run_program`)
+    tail_programs: dict = field(default_factory=dict)
+    #: a tail's: its base's `tail_programs`
+    programs: Optional[dict] = None
+    #: a tail nobody reads (`_warm_tail_programs`): its mirrors are shapes,
+    #: nothing is uploaded, and its launch is compiled, not run
+    stand_in: bool = False
 
     @property
     def num_rows(self) -> int:
         return len(self.ts)
 
-    def device_ts(self):
+    def _put(self, key: str, arr: np.ndarray, fill=None):
+        """Upload one mirror. A tail keeps its fields at their valid
+        length on the host: the padding to the row axis is made here
+        (`fill`, or the last value, as padded slices repeat their last
+        row)."""
         import jax
+        n, k = self.num_rows, len(arr)
+        if self.stand_in:
+            self.device[key] = jax.ShapeDtypeStruct((n,), arr.dtype)
+            return self.device[key]
+        if k < n:
+            out = np.empty(n, dtype=arr.dtype)
+            out[:k] = arr
+            out[k:] = (arr[-1] if k else 0) if fill is None else fill
+            arr = out
+        if self.count_uploads:
+            from ..common.telemetry import increment_counter
+            increment_counter("scan_cache_upload_bytes", int(arr.nbytes))
+        self.device[key] = jax.device_put(np.ascontiguousarray(arr))
+        return self.device[key]
+
+    def upload(self, arr: np.ndarray):
+        """A statement's own array (a row mask, run ids) on the device; a
+        stand-in's stays a shape."""
+        import jax
+        if self.stand_in:
+            return jax.ShapeDtypeStruct(arr.shape, arr.dtype)
+        return jax.device_put(arr)
+
+    def device_ts(self):
         if "__ts" not in self.device:
-            rel = self.ts - self.ts_base
-            if rel.size and (rel.max() >= 2**31 or rel.min() < 0):
+            if self.pinned:     # a tail knows its span: no pass to find it
+                lo, hi = self.ts_min, self.ts_max
+                rel = self.ts[:self.valid_rows] - self.ts_base
+            else:
+                rel = self.ts - self.ts_base
+                lo, hi = (int(rel.min()) + self.ts_base,
+                          int(rel.max()) + self.ts_base) if rel.size \
+                    else (self.ts_base, self.ts_base)
+            if hi - self.ts_base >= 2**31 or lo < self.ts_base:
                 raise UnsupportedError("region time span exceeds int32")
-            self.device["__ts"] = jax.device_put(rel.astype(np.int32))
+            self._put("__ts", rel.astype(np.int32))
         return self.device["__ts"]
 
+    def device_pad_mask(self):
+        """True on the valid rows of a padded scan."""
+        if "__pad_mask" not in self.device:
+            pm = np.zeros(self.num_rows, np.bool_)
+            pm[:self.valid_rows] = True
+            self._put("__pad_mask", pm)
+        return self.device["__pad_mask"]
+
     def device_field(self, name: str):
-        import jax
         key = f"f:{name}"
         if key not in self.device:
             vals, valid = self.fields[name]
@@ -95,7 +159,7 @@ class MergedScan:
                 # TPU has no f64: the device mirrors are f32 (documented
                 # precision tradeoff); with x64 on (CPU) keep full precision
                 v = v.astype(np.float32)
-            self.device[key] = jax.device_put(np.ascontiguousarray(v))
+            self._put(key, v)
         return self.device[key]
 
     def device_run_diffs(self, name: str, counter: bool):
@@ -134,24 +198,21 @@ class MergedScan:
                 d = full
             if not jax.config.jax_enable_x64:
                 d = d.astype(np.float32)
-            self.device[key] = jax.device_put(d)
+            self._put(key, d)
         return self.device[key]
 
     def device_valid(self, name: str):
-        import jax
         key = f"v:{name}"
         if key not in self.device:
             _, valid = self.fields[name]
             if valid is None:
                 return self.device_valid_all()
-            self.device[key] = jax.device_put(valid)
+            self._put(key, valid, fill=False)
         return self.device[key]
 
     def device_valid_all(self):
-        import jax
         if "__all_valid" not in self.device:
-            self.device["__all_valid"] = jax.device_put(
-                np.ones(self.num_rows, dtype=bool))
+            self._put("__all_valid", np.ones(self.num_rows, dtype=bool))
         return self.device["__all_valid"]
 
     @property
@@ -175,28 +236,169 @@ class MergedScan:
 
 @dataclass
 class _CacheEntry:
-    scan: MergedScan
+    scan: MergedScan                  # the base: immutable once built
     visible: int                      # sequences <= visible are merged in
     sst_names: frozenset              # SSTs whose content is merged in
     schema_version: int
     retraction_epoch: int
+    #: rows written since the base was built (None: none yet)
+    tail: Optional[MergedScan] = None
+
+    @property
+    def nbytes(self) -> int:
+        return self.scan.nbytes + \
+            (self.tail.nbytes if self.tail is not None else 0)
+
+
+#: A base's tail holds up to 1 / this of the base's rows (as a power of
+#: two, at least `_TAIL_MIN_ROWS`): its row axis, so one program a
+#: statement shape whatever was written. A launch over the tail costs
+#: that share of the base's; past it the tail merges into a new base.
+_TAIL_SHARE = 16
+_TAIL_MIN_ROWS = 4096
+
+
+def tail_capacity(base_rows: int) -> int:
+    return shape_bucket(base_rows // _TAIL_SHARE, minimum=_TAIL_MIN_ROWS)
+
+
+@dataclass
+class _Rows:
+    """Sorted, deduplicated rows on the host: a delta, or a tail's valid
+    rows. A field's validity is None where every value is valid."""
+    sids: np.ndarray
+    ts: np.ndarray
+    seq: np.ndarray
+    fields: Dict[str, Tuple[np.ndarray, Optional[np.ndarray]]]
+    #: a delta's tombstones (None: every row is a put)
+    deleted: Optional[np.ndarray] = None
+    #: float64 [n, fields] where every field is a float64 without a NULL:
+    #: `fields` then holds its columns as views, and a merge moves all of
+    #: them in one pass (what a pass costs a statement beside six writers
+    #: is a wait for the interpreter lock, not its bytes)
+    block: Optional[np.ndarray] = None
+
+    def __len__(self) -> int:
+        return len(self.ts)
+
+
+def _block_fields(names, block: np.ndarray) -> dict:
+    return {name: (block[:, j], None) for j, name in enumerate(names)}
+
+
+def _key_positions(sids: np.ndarray, ts: np.ndarray, new: _Rows):
+    """-> (pos, collide): where each row of `new` (sorted, unique keys)
+    goes among the rows (sids, ts) sorted by (series, ts): before row
+    pos[i], or onto it where `collide[i]` (the same key; None: no row
+    collides)."""
+    hi = np.searchsorted(sids, new.sids, side="right")
+    if not len(ts):
+        return hi, None
+    at = np.maximum(hi - 1, 0)
+    # what ticks give: every row comes after its series' last one
+    if ((hi == 0) | (sids[at] != new.sids) | (ts[at] < new.ts)).all():
+        return hi, None
+    from .scan_narrow import _lower_bound
+    lo = np.searchsorted(sids, new.sids, side="left")
+    pos = _lower_bound(ts, lo, hi, new.ts)      # every range at once
+    collide = (pos < hi) & (ts[np.minimum(pos, len(ts) - 1)] == new.ts)
+    return pos, collide if collide.any() else None
+
+
+def _merge_rows(old: _Rows, new: _Rows, drop_deleted: bool = True) -> _Rows:
+    """`new` merged into `old` (both sorted by (series, ts), keys unique
+    within each; every row of `new` is newer than any of `old`): a row
+    of `new` replaces the row of its key or takes its place in the
+    order; a tombstone of `new` removes itself and the row it shadows,
+    or with `drop_deleted` off stays as a tombstone of the result (for a
+    merge into older rows still to come). One search over the keys, one
+    pass a column (one for all fields of a `block`), no sort and no loop
+    over series."""
+    pos, collide = _key_positions(old.sids, old.ts, new)
+    n_old = len(old)
+    if collide is None:
+        fresh, hit, dest_hit = slice(None), None, None
+        n_fresh = len(new)
+    else:
+        fresh, hit = ~collide, collide
+        n_fresh = int(fresh.sum())
+    m = n_old + n_fresh
+    dest_fresh = pos[fresh] + np.arange(n_fresh)
+    is_fresh = np.zeros(m, dtype=bool)
+    is_fresh[dest_fresh] = True
+    dest_old = np.flatnonzero(~is_fresh)
+    if hit is not None:
+        dest_hit = dest_old[pos[hit]]
+    keep = None
+
+    def column(a, b, dtype=None):
+        if dtype is None:
+            dtype = object if object in (a.dtype, b.dtype) \
+                else np.result_type(a.dtype, b.dtype)
+        out = np.empty((m,) + a.shape[1:], dtype=dtype)
+        out[dest_old] = a
+        out[dest_fresh] = b[fresh]
+        if hit is not None:
+            out[dest_hit] = b[hit]
+        return out if keep is None else out[keep]
+
+    deleted = None
+    if new.deleted is not None and new.deleted.any():
+        deleted = column(np.zeros(n_old, dtype=bool), new.deleted)
+        if drop_deleted:
+            keep, deleted = ~deleted, None
+    block = None
+    if old.block is not None and new.block is not None:
+        block = column(old.block, new.block)
+        fields = _block_fields(old.fields, block)
+    else:
+        fields = {}
+        for name, (ad, av) in old.fields.items():
+            bd, bv = new.fields[name]
+            valid = None
+            if av is not None or bv is not None:
+                valid = column(
+                    av if av is not None else np.ones(n_old, bool),
+                    bv if bv is not None else np.ones(len(new), bool))
+                if valid.all():
+                    valid = None
+            fields[name] = (column(ad, bd), valid)
+    return _Rows(column(old.sids, new.sids, np.int32),
+                 column(old.ts, new.ts), column(old.seq, new.seq), fields,
+                 deleted, block)
 
 
 class _ScanCache:
-    """Per-region merged-scan cache: byte-budget LRU + incremental
-    maintenance.
+    """Per-region merged-scan cache: byte-budget LRU, refreshed by what
+    was written.
 
-    On a version bump the cache merges only the *delta* — memtable rows
-    with sequences beyond the cached watermark plus SSTs that carry such
-    rows — into the cached sorted arrays, instead of re-reading and
-    re-sorting the whole region (VERDICT round-1 weakness 5: scan prep
-    must be proportional to new data, not region size). Flushes and
-    compactions whose files only contain already-covered sequences reuse
-    the cache as-is; TTL retraction (region.retraction_epoch) and schema
-    changes force a full rebuild.
+    An entry is a *base* (the region's merged rows as they were when it
+    was built: immutable, with its device mirrors, its compiled launches
+    and its run layouts) and a *tail* (the rows written since: a second,
+    small sorted scan whose row axis is a fixed capacity,
+    `tail_capacity`, masked by `valid_rows`). On a version bump the cache
+    collects only the *delta* (memtable rows with sequences beyond the
+    cached watermark plus SSTs that carry such rows), sorts it, and
+    merges it into the tail: the cost follows the delta and the tail,
+    never the base, and no array or mirror of the base is touched. A
+    statement reduces both and folds the two partial frames
+    (`_execute_region`).
+
+    A tail holds only rows that come after the base's in their series
+    (or belong to a series the base has not seen): the two partials of
+    one group are then disjoint in time, as streamed slices are. A delta
+    with a tombstone, or with a row at or before its series' last
+    resident one (an overwrite, a late row), and a tail past its
+    capacity, *merge* into a new base (`_merge_rows` over every column:
+    counted, `scan_cache_merges`; the new base has a new length, so its
+    mirrors are uploaded and its programs compiled again). `get` hands
+    the callers that want one sorted scan such a merged base.
+    Flushes and compactions whose files only contain already-covered
+    sequences reuse the entry as it is; TTL retraction
+    (region.retraction_epoch) and schema changes force a full rebuild.
 
     Residency is bounded by a byte budget across regions (host arrays +
-    device mirrors): whole MergedScans evict LRU-first — never partially —
+    device mirrors): whole entries evict LRU-first — never partially —
     so a server hosting many hot regions can't grow HBM without bound
     (VERDICT round-3 weakness 5). The newest entry always stays, even
     when it alone exceeds the budget (regions that large should be
@@ -219,6 +421,34 @@ class _ScanCache:
         return getattr(self._last, "outcome", None)
 
     def get(self, region) -> MergedScan:
+        """The region's rows as ONE sorted scan: the base, after merging
+        a tail into it (the callers that walk a scan themselves: the
+        PromQL selector, flow folds, downsampling, the pandas frame)."""
+        entry = self._refresh(region)
+        if entry.tail is not None:
+            entry = self._store(region, _CacheEntry(
+                self._merged(entry.scan, _tail_rows(entry.tail)),
+                entry.visible, entry.sst_names, entry.schema_version,
+                entry.retraction_epoch))
+        return entry.scan
+
+    def get_parts(self, region, time_hi: Optional[int] = None
+                  ) -> Tuple[MergedScan, Optional[MergedScan]]:
+        """-> (base, tail or None), current as of the region's committed
+        sequence at the call for every row before `time_hi` (None: for
+        every row)."""
+        entry = self._refresh(region, time_hi)
+        return entry.scan, entry.tail
+
+    def _store(self, region, entry: _CacheEntry) -> _CacheEntry:
+        with self._lock:
+            self._entries.pop(region.uid, None)
+            self._entries[region.uid] = entry
+            self._evict_locked()
+        return entry
+
+    def _refresh(self, region, time_hi: Optional[int] = None
+                 ) -> _CacheEntry:
         from ..common.telemetry import increment_counter
         snap = region.snapshot()
         v = snap._version
@@ -230,17 +460,34 @@ class _ScanCache:
             if entry is not None:                    # LRU touch
                 self._entries.pop(region.uid)
                 self._entries[region.uid] = entry
+        if time_hi is not None and entry is not None \
+                and entry.schema_version == v.schema.version \
+                and entry.retraction_epoch == epoch \
+                and entry.visible <= visible \
+                and _unmerged_from(v, entry) >= time_hi:
+            # closed history: every row the entry has not merged (a put,
+            # an overwrite, a tombstone) carries a timestamp at or after
+            # the statement's range, so the entry answers it exactly as
+            # it stands, and stays as it is for the statement that does
+            # read those rows
+            self._last.outcome = "hit"
+            increment_counter("scan_cache_hit")
+            return entry
+        # an entry over an empty region has nothing to keep: the rows
+        # that arrive (a bulk load) are a build, not a delta
         if entry is not None and entry.schema_version == v.schema.version \
                 and entry.retraction_epoch == epoch \
-                and entry.visible <= visible:
+                and entry.visible <= visible \
+                and (entry.scan.num_rows or entry.tail is not None
+                     or entry.visible == visible):
             if entry.visible == visible and entry.sst_names == sst_names:
                 self._last.outcome = "hit"
                 increment_counter("scan_cache_hit")
-                return entry.scan
+                return entry
             try:
                 from ..common.failpoint import fail_point
                 fail_point("scan_cache_incremental")
-                scan = self._incremental(region, snap, v, entry, visible)
+                base, tail = self._incremental(region, v, entry, visible)
                 self._last.outcome = "incremental"
                 increment_counter("scan_cache_incremental")
             except Exception as e:  # noqa: BLE001 — degrade, don't fail
@@ -257,27 +504,22 @@ class _ScanCache:
                 with self._lock:
                     self._entries.pop(region.uid, None)
                 self._last.outcome = "full"
-                scan = self._full(region, snap)
+                base, tail = self._full(region, snap), None
         else:
             self._last.outcome = "full"
             increment_counter("scan_cache_miss")
-            scan = self._full(region, snap)
-        entry = _CacheEntry(scan, visible, sst_names, v.schema.version,
-                            epoch)
-        with self._lock:
-            self._entries.pop(region.uid, None)
-            self._entries[region.uid] = entry
-            self._evict_locked()
-        return scan
+            base, tail = self._full(region, snap), None
+        return self._store(region, _CacheEntry(
+            base, visible, sst_names, v.schema.version, epoch, tail))
 
     def _evict_locked(self) -> None:
         """Drop LRU entries until count and byte budgets hold (whole
-        scans only; the most recent entry is never evicted)."""
+        entries only; the most recent entry is never evicted)."""
         while len(self._entries) > max(self.capacity, 1):
             self._entries.pop(next(iter(self._entries)))
         if self.budget_bytes <= 0:
             return
-        total = {uid: e.scan.nbytes for uid, e in self._entries.items()}
+        total = {uid: e.nbytes for uid, e in self._entries.items()}
         used = sum(total.values())
         for uid in list(self._entries):
             if used <= self.budget_bytes or len(self._entries) <= 1:
@@ -295,7 +537,7 @@ class _ScanCache:
 
     def resident_bytes(self) -> int:
         with self._lock:
-            return sum(e.scan.nbytes for e in self._entries.values())
+            return sum(e.nbytes for e in self._entries.values())
 
     def configure(self, *, budget_bytes: Optional[int] = None,
                   capacity: Optional[int] = None) -> None:
@@ -321,10 +563,78 @@ class _ScanCache:
             fields = data.fields
         base = int(ts.min()) if ts.size else 0
         return MergedScan(sids.astype(np.int32), ts, fields,
-                          data.series_dict, base, seq=seq)
+                          data.series_dict, base, seq=seq,
+                          count_uploads=True)
 
-    def _incremental(self, region, snap, v, entry: _CacheEntry,
-                     visible: int) -> MergedScan:
+    def _incremental(self, region, v, entry: _CacheEntry, visible: int):
+        """-> (base, tail) with the rows in (entry.visible, visible]
+        applied. Parts of the statement's `scan_prep` row: `.delta` (the
+        rows collected and sorted), `.apply` (merged into the tail, or
+        tail and delta into a new base), `.upload` (the tail's pad mask
+        and the mirrors its predecessor had in use, whole: a tail is
+        sorted by (series, time), so a tick of every series lands in as
+        many places as there are series and no suffix of a mirror is
+        left as it was)."""
+        from ..common import exec_stats
+        from ..common.telemetry import increment_counter
+        with exec_stats.stage("scan_prep.delta"):
+            delta = self._delta(region, v, entry, visible)
+        if delta is None:
+            return entry.scan, entry.tail
+        increment_counter("scan_cache_delta_rows", len(delta))
+        exec_stats.record("scan_prep.delta", rows=len(delta))
+        base = entry.scan
+        with exec_stats.stage("scan_prep.apply"):
+            rows = None
+            if delta.deleted is None and _after_resident(base, delta):
+                rows = delta if entry.tail is None \
+                    else _merge_rows(_tail_rows(entry.tail), delta)
+                if len(rows) > tail_capacity(base.num_rows):
+                    rows = None
+            if rows is None:
+                if entry.tail is not None:
+                    # tombstones stay: they may shadow rows of the base
+                    delta = _merge_rows(_tail_rows(entry.tail), delta,
+                                        drop_deleted=False)
+                merged = self._merged(base, delta)
+                exec_stats.record("scan_prep.apply", merged=1)
+                return merged, None
+            tail = _make_tail(rows, base)
+        with exec_stats.stage("scan_prep.upload"):
+            # what the statements before this write read on the device:
+            # the next one finds its mirrors there
+            tail.device_pad_mask()
+            for key in (entry.tail.device if entry.tail is not None
+                        else ()):
+                if key == "__ts":
+                    tail.device_ts()
+                elif key == "__all_valid":     # the same ones: kept
+                    tail.device[key] = entry.tail.device[key]
+                elif key.startswith("f:"):
+                    tail.device_field(key[2:])
+                elif key.startswith("v:") and \
+                        tail.fields[key[2:]][1] is not None:
+                    tail.device_valid(key[2:])
+        return base, tail
+
+    def _merged(self, base: MergedScan, rows: _Rows) -> MergedScan:
+        """A new base: `rows` merged into the base's. Every column is
+        copied once; the result has no mirror and no compiled launch."""
+        from ..common.telemetry import increment_counter
+        increment_counter("scan_cache_merges")
+        out = _merge_rows(_Rows(
+            base.series_ids, base.ts,
+            base.seq if base.seq is not None
+            else np.zeros(base.num_rows, np.int64), base.fields), rows)
+        return MergedScan(out.sids, out.ts, out.fields, base.series_dict,
+                          int(out.ts.min()) if len(out) else 0,
+                          seq=out.seq, count_uploads=True)
+
+    def _delta(self, region, v, entry: _CacheEntry,
+               visible: int) -> Optional[_Rows]:
+        """The rows with sequences in (entry.visible, visible], from the
+        memtables and from SSTs the entry has not seen, sorted by
+        (series, ts), the newest version of a key kept."""
         from ..datatypes.vector import null_column
         schema = v.schema
         field_names = [c.name for c in schema.field_columns()]
@@ -333,10 +643,17 @@ class _ScanCache:
         # memtable rows beyond the cached watermark
         for mt in v.memtables.all_memtables():
             ms = mt.snapshot()
-            if ms.num_rows == 0:
+            if ms.num_rows == 0 or ms.seq[-1] <= lo:
                 continue
-            sel = (ms.seq > lo) & (ms.seq <= visible)
-            if not sel.any():
+            # writes are serialised and replayed in order: a memtable's
+            # sequences ascend, so the rows are one slice of it
+            sel = slice(int(np.searchsorted(ms.seq, lo, side="right")),
+                        int(np.searchsorted(ms.seq, visible,
+                                            side="right")))
+            if sel.start and ms.seq[sel.start - 1] > lo:
+                sel = np.flatnonzero((ms.seq > lo) & (ms.seq <= visible))
+            n = len(ms.ts[sel])
+            if not n:
                 continue
             fields = {}
             for name in field_names:
@@ -346,7 +663,7 @@ class _ScanCache:
                                     vd[sel] if vd is not None else None)
                 else:
                     fields[name] = null_column(
-                        schema.column_schema(name).dtype, int(sel.sum()))
+                        schema.column_schema(name).dtype, n)
             runs.append((ms.series_ids[sel], ms.ts[sel], ms.seq[sel],
                          ms.op_types[sel], fields))
         # SSTs not yet covered that carry rows beyond the watermark
@@ -366,78 +683,109 @@ class _ScanCache:
                       for n, (d, vd) in sst.fields.items()}
             runs.append((sst.series_ids[sel], sst.ts[sel], sst.seq[sel],
                          sst.op_types[sel], fields))
-
-        cached = entry.scan
         if not runs:
-            return cached
-        # sort + dedup the delta alone (small), then splice it into the
-        # already-sorted cached arrays with searchsorted + np.insert —
-        # O(delta·log + n) memcpy, no sort over the region
-        dsid = np.concatenate([r[0] for r in runs])
-        dts = np.concatenate([r[1] for r in runs])
-        dseq = np.concatenate([r[2] for r in runs])
-        dop = np.concatenate([r[3] for r in runs])
-        dorder = np.lexsort((dseq, dts, dsid))
-        dsid, dts, dseq, dop = (a[dorder] for a in (dsid, dts, dseq, dop))
-        # within-delta dedup: keep the newest version of each (sid, ts)
-        nxt_same = np.concatenate([(dsid[1:] == dsid[:-1]) &
-                                   (dts[1:] == dts[:-1]), [False]])
-        dkeep0 = ~nxt_same
-        dsel = dorder[dkeep0]
-        dsid, dts, dseq, dop = (a[dkeep0] for a in (dsid, dts, dseq, dop))
+            return None
 
-        csid, cts = cached.series_ids, cached.ts
-        n_cached = cached.num_rows
-        # two-level searchsorted: sid bounds, then ts inside each sid run
-        pos = np.empty(len(dsid), dtype=np.int64)
-        for s in np.unique(dsid):
-            m = dsid == s
-            lo = int(np.searchsorted(csid, s, side="left"))
-            hi = int(np.searchsorted(csid, s, side="right"))
-            pos[m] = lo + np.searchsorted(cts[lo:hi], dts[m], side="left")
-        # collisions: a delta key that already exists replaces (or deletes)
-        # the cached row; all delta sequences are newer by construction
-        collide = (pos < n_cached)
-        if collide.any():
-            pc = np.minimum(pos, n_cached - 1)
-            collide &= (csid[pc] == dsid) & (cts[pc] == dts)
-        ckeep = np.ones(n_cached, dtype=bool)
-        ckeep[pos[collide]] = False
-        dlive = dop == 0                      # delete tombstones vanish
-        # adjust insert positions for dropped cached rows
-        dropped_prefix = np.concatenate([[0], np.cumsum(~ckeep)])
-        adj = pos - dropped_prefix[pos]
+        def cat(parts):
+            return parts[0] if len(parts) == 1 else np.concatenate(parts)
 
-        ins = dlive
-        sids = np.insert(csid[ckeep] if not ckeep.all() else csid,
-                         adj[ins], dsid[ins]).astype(np.int32)
-        ts = np.insert(cts[ckeep] if not ckeep.all() else cts,
-                       adj[ins], dts[ins])
-        cseq = cached.seq if cached.seq is not None \
-            else np.zeros(n_cached, np.int64)
-        seq = np.insert(cseq[ckeep] if not ckeep.all() else cseq,
-                        adj[ins], dseq[ins])
-        fields = {}
+        dsid = cat([r[0] for r in runs])
+        dts = cat([r[1] for r in runs])
+        dseq = cat([r[2] for r in runs])
+        dop = cat([r[3] for r in runs])
+        order = np.lexsort((dseq, dts, dsid))
+        dsid, dts = dsid[order], dts[order]
+        # within the delta the newest version of each (sid, ts) stays
+        newest = np.ones(len(order), dtype=bool)
+        newest[:-1] = (dsid[1:] != dsid[:-1]) | (dts[1:] != dts[:-1])
+        if not newest.all():
+            order, dsid, dts = order[newest], dsid[newest], dts[newest]
+        vals = {name: cat([r[4][name][0] for r in runs])
+                for name in field_names}
+        valids = {}
         for name in field_names:
-            cd, cv = cached.fields[name]
-            dd = np.concatenate([r[4][name][0] for r in runs])[dsel]
-            dvs = [r[4][name][1] for r in runs]
-            if cv is not None or any(x is not None for x in dvs):
-                dv = np.concatenate([
-                    x if x is not None else np.ones(len(r[4][name][0]),
-                                                    dtype=bool)
-                    for x, r in zip(dvs, runs)])[dsel]
-                cvf = cv if cv is not None else np.ones(n_cached, bool)
-                valid = np.insert(cvf[ckeep] if not ckeep.all() else cvf,
-                                  adj[ins], dv[ins])
-            else:
-                valid = None
-            data = np.insert(cd[ckeep] if not ckeep.all() else cd,
-                             adj[ins], dd[ins])
-            fields[name] = (data, valid)
-        base = int(ts.min()) if ts.size else 0
-        return MergedScan(sids, ts, fields, cached.series_dict, base,
-                          seq=seq)
+            parts = [r[4][name][1] for r in runs]
+            valids[name] = None if all(x is None for x in parts) else cat(
+                [x if x is not None else np.ones(len(r[0]), dtype=bool)
+                 for x, r in zip(parts, runs)])
+        block = None
+        if field_names and \
+                all(a.dtype == np.float64 for a in vals.values()):
+            # TSBS's and a metric table's shape: every field a DOUBLE
+            given = [a for a in valids.values() if a is not None]
+            if not given or np.stack(given, axis=1).all():
+                block = np.stack([vals[n] for n in field_names],
+                                 axis=1)[order]
+        if block is not None:
+            fields = _block_fields(field_names, block)
+        else:
+            fields = {}
+            for name in field_names:
+                valid = valids[name]
+                if valid is not None:
+                    valid = valid[order]
+                    if valid.all():
+                        valid = None
+                fields[name] = (vals[name][order], valid)
+        deleted = dop[order] != 0
+        return _Rows(dsid.astype(np.int32, copy=False), dts, dseq[order],
+                     fields, deleted if deleted.any() else None, block)
+
+
+def _unmerged_from(v, entry: _CacheEntry) -> int:
+    """A lower bound of the timestamps of the rows this version holds
+    beyond the entry's watermark, from what memtables and file metas
+    record (a memtable's span covers its merged rows too: a bound, not
+    the minimum); the largest int where there is none."""
+    lo = np.iinfo(np.int64).max
+    for mt in v.memtables.all_memtables():
+        span = mt.time_range()
+        if span is not None and mt.num_rows:
+            lo = min(lo, span[0])
+    for meta in v.ssts.all_files():
+        if meta.file_name not in entry.sst_names and \
+                meta.max_sequence > entry.visible:
+            span = meta.time_range
+            lo = min(lo, span[0] if span is not None else -lo)
+    return int(lo)
+
+
+def _after_resident(base: MergedScan, delta: _Rows) -> bool:
+    """Whether every row of the delta comes after the base's last row of
+    its series (or its series is new to the base): a search a row, no
+    pass over the base."""
+    if base.num_rows == 0:
+        return True
+    hi = np.searchsorted(base.series_ids, delta.sids, side="right")
+    at = np.maximum(hi - 1, 0)
+    seen = (hi > 0) & (base.series_ids[at] == delta.sids)
+    return not (seen & (delta.ts <= base.ts[at])).any()
+
+
+def _tail_rows(tail: MergedScan) -> _Rows:
+    n = tail.valid_rows
+    return _Rows(tail.series_ids[:n], tail.ts[:n], tail.seq, tail.fields,
+                 block=tail.block)
+
+
+def _make_tail(rows: _Rows, base: MergedScan) -> MergedScan:
+    """The tail scan over `rows` for this base: series ids and times
+    padded to the base's tail capacity by repeating the last row (the
+    padding joins the last run, as a padded slice's does), fields and
+    sequences kept at their length (`MergedScan._put` pads a mirror)."""
+    n, cap = len(rows), tail_capacity(base.num_rows)
+
+    def padded(a):
+        out = np.empty(cap, dtype=a.dtype)
+        out[:n] = a
+        out[n:] = a[n - 1]
+        return out
+
+    lo, hi = int(rows.ts.min()), int(rows.ts.max())
+    return MergedScan(padded(rows.sids), padded(rows.ts), rows.fields,
+                      base.series_dict, lo, seq=rows.seq, valid_rows=n,
+                      pinned=True, count_uploads=True, ts_min=lo, ts_max=hi,
+                      block=rows.block, programs=base.tail_programs)
 
 
 SCAN_CACHE = _ScanCache()
@@ -1440,26 +1788,156 @@ def _execute_region(region, table, plan: TpuPlan) -> Optional[pd.DataFrame]:
     _t0 = _time.perf_counter()
     with span("region_scan", region=region.name, path="resident"):
         with exec_stats.stage("scan_prep"):
-            scan = SCAN_CACHE.get(region)
+            if _wants_one_scan(plan):
+                scan, tail = SCAN_CACHE.get(region), None
+            else:
+                scan, tail = SCAN_CACHE.get_parts(region, plan.time_hi)
         prof.mark("scan_prep", _time.perf_counter() - _t0)
         outcome = SCAN_CACHE.last_outcome() or "full"
         # same outcome vocabulary as ExecStats (cache=...) and the
         # scan_cache_* prometheus counters: hit / incremental / full
         prof.bump(f"cache_{outcome}")
-        prof.rows = scan.num_rows
-        exec_stats.record("scan_prep", rows=scan.num_rows, cache=outcome)
-        if scan.num_rows == 0:
+        rows = scan.num_rows + (tail.valid_rows if tail is not None else 0)
+        prof.rows = rows
+        exec_stats.record("scan_prep", rows=rows, cache=outcome)
+        if rows == 0:
             prof.total_s = _time.perf_counter() - _t0
             region.last_scan_profile = prof
             return None
         _t1 = _time.perf_counter()
         with exec_stats.stage("reduce"):
-            out = _moment_frame_for_scan(scan, table.schema, plan)
+            shape = []
+            reads_tail = tail is not None and not _outside(plan, tail)
+            out = None
+            if scan.num_rows:
+                out = _moment_frame_for_scan(scan, table.schema, plan,
+                                             shape=shape, runs=reads_tail)
+            if tail is None:
+                if scan.num_rows >= TPU_DISPATCH_MIN_ROWS and shape:
+                    _warm_tail_programs(scan, table.schema, plan,
+                                        shape[0])
+            elif not reads_tail:
+                exec_stats.record("reduce", tail="skipped")
+            else:
+                out = _base_and_tail_frame(out, _moment_frame_for_scan(
+                    tail, table.schema, plan, tail=True, runs=True), plan)
+            if out is not None and not len(out):
+                out = None
         prof.mark("reduce", _time.perf_counter() - _t1)
         prof.total_s = _time.perf_counter() - _t0
         region.last_scan_profile = prof
-        exec_stats.record("reduce", rows=scan.num_rows)
+        exec_stats.record("reduce", rows=rows)
     return out
+
+
+def _base_and_tail_frame(base: Optional["_RunPartial"],
+                         tail: Optional["_RunPartial"],
+                         plan: "TpuPlan") -> Optional[pd.DataFrame]:
+    """One partial frame of the two launches. The partials of one group
+    are disjoint in time (a tail holds what came after the base), as
+    streamed slices are; they fold by run before the frame is made, or,
+    where that cannot be, in `_finalize` like any two partials."""
+    from ..common import exec_stats
+    with exec_stats.stage("reduce.collect"):
+        parts = [p for p in (base, tail) if p is not None]
+        if len(parts) == 2:
+            folded = _fold_runs(base, tail, plan)
+            parts = [folded] if folded is not None else parts
+        frames = [_partial_frame(p, plan) for p in parts]
+        return None if not frames else frames[0] if len(frames) == 1 \
+            else pd.concat(frames, ignore_index=True)
+
+
+def _wants_one_scan(plan: "TpuPlan") -> bool:
+    """Plans that reduce the region's rows as one sorted scan, a tail
+    merged into the base first: the host reducers (sketch / expression
+    moments walk the rows), and a window's growth, whose difference
+    across the seam between base and tail the f32 `first` / `last` of
+    two partials cannot give (a counter at 1e12 keeps no digit of a
+    scrape's growth there; `device_run_diffs` makes it in float64 over
+    one scan)."""
+    return plan_needs_host(plan) or \
+        any(m.op in RUN_DIFF_MOMENT_OPS for m in plan.moments)
+
+
+def _outside(plan: "TpuPlan", tail: MergedScan) -> bool:
+    """The statement's time range holds no row of the tail."""
+    return (plan.time_hi is not None and plan.time_hi <= tail.ts_min) or \
+        (plan.time_lo is not None and plan.time_lo > tail.ts_max)
+
+
+def _warm_tail_programs(base: MergedScan, schema, plan: "TpuPlan",
+                        shape) -> None:
+    """Compile what this statement will launch over the base's tail once
+    rows are written, now, where the statement's own programs are
+    compiled (a server's warm statements come before the writes: the
+    first statement after one must not be the one that compiles). The
+    launch is laid out over a stand-in tail, one row a series of the base
+    just after the base's last (the shapes a tick of every series
+    gives), lowered and compiled for those shapes and kept in
+    `base.tail_programs`; nothing is uploaded and nothing runs, so a
+    table nobody writes holds on the device what it held before. Once a
+    base and statement shape (`shape`: what the base's launch chose,
+    path and range bucket); a base under the dispatch floor
+    (`TPU_DISPATCH_MIN_ROWS`, as the operator has set it) reached the
+    device by another road (a plan the host path cannot run) and is left
+    to compile when a tail is met."""
+    key = (shape, None if plan.bucket is None else
+           (plan.bucket.stride_ms, _bucket_phase(plan.bucket)),
+           bool(plan.tag_groups),
+           tuple((m.op, m.column) for m in plan.moments),
+           tuple(sorted((f.column, f.op) for f in plan.field_filters)))
+    warmed = base.device.setdefault("__tail_warmed", set())
+    if key in warmed:
+        return
+    warmed.add(key)
+    if plan.time_hi is not None:
+        # a range closed on the right is history to the rows written
+        # after the statement was composed: a tail of later rows is
+        # skipped (`_outside`), and one that is not compiles when met
+        return
+    after = int(base.ts.max()) + 1
+    if plan.time_lo is not None and plan.time_lo > after:
+        return
+    with _reduce_part("tail_warm"):
+        sids = base.series_ids
+        first = np.flatnonzero(np.concatenate(
+            [[True], sids[1:] != sids[:-1]]))
+        k = len(first)
+        zeros = np.zeros(k, dtype=np.float64)
+        fields = {name: ((zeros, None) if vals.dtype != object
+                         else (np.full(k, None, dtype=object),
+                               np.zeros(k, dtype=bool)))
+                  for name, (vals, _) in base.fields.items()}
+        stand_in = _make_tail(_Rows(
+            sids[first], np.full(k, after, np.int64),
+            np.zeros(k, np.int64), fields), base)
+        stand_in.count_uploads = False
+        stand_in.stand_in = True
+        _launch_for_scan(stand_in, schema, plan, _untimed_part)
+
+
+def _run_program(scan: MergedScan, fn, *args, **static):
+    """`fn(*args, **static)` of a jitted `fn`, for every scan but a tail.
+    A tail goes by its base's table of executables: the stand-in
+    (`_warm_tail_programs`) lowers and compiles `fn` for the arguments'
+    shapes, keeps the executable there and returns None; a tail calls the
+    one kept under its own arguments' shapes (no trace and no compile:
+    the first statement after a write launches as the hundredth does),
+    and `fn` itself where none is (a base under the floor, a statement
+    shape that was not warmed)."""
+    if scan.programs is None:
+        return fn(*args, **static)
+    import jax
+    leaves, tree = jax.tree_util.tree_flatten(args)
+    key = (fn, tree, tuple((tuple(x.shape), np.dtype(x.dtype))
+                           for x in leaves), tuple(sorted(static.items())))
+    if scan.stand_in:
+        if key not in scan.programs:
+            scan.programs[key] = fn.lower(*args, **static).compile()
+        return None
+    compiled = scan.programs.get(key)
+    return fn(*args, **static) if compiled is None else compiled(*args)
 
 
 def _reduce_part(name: str):
@@ -1498,8 +1976,29 @@ class _Launched:
     table_runs: Optional[int] = None
 
 
-def _moment_frame_for_scan(scan: MergedScan, schema,
-                           plan: TpuPlan) -> Optional[pd.DataFrame]:
+def _launch_for_scan(scan: MergedScan, schema, plan: TpuPlan, part):
+    """-> (launched or None, "narrow" | "full", the selection or None):
+    the resident reduce of one scan, launched."""
+    from . import scan_narrow
+    with part("mask"):
+        sel = scan_narrow.select(scan, schema, plan)
+    n_ranges, padded_rows = (None, 0) if sel is None \
+        else (sel.n_ranges, sel.padded_rows)
+    path = scan_narrow.scan_read_path(scan.num_rows, n_ranges, padded_rows)
+    if path == "narrow":
+        return scan_narrow.launch(scan, schema, plan, sel, part), path, sel
+    return _launch_scan_kernel(scan, schema, plan, part, sel), path, sel
+
+
+def _moment_frame_for_scan(scan: MergedScan, schema, plan: TpuPlan,
+                           tail: bool = False,
+                           shape: Optional[list] = None,
+                           runs: bool = False):
+    """-> the scan's partial moment frame, or with `runs` its
+    `_RunPartial` (None: no row). `tail`: the scan is the tail of the one
+    just reduced; the `reduce` row's detail says so (`tail_rows=`,
+    `tail_path=`) beside the base's. `shape`: a list that receives what
+    the launch chose (path, range bucket or None)."""
     if plan_needs_host(plan):
         # sketch / expression moments: reduce the resident merged scan
         # on the host with the same segment arithmetic the streamed
@@ -1513,25 +2012,23 @@ def _moment_frame_for_scan(scan: MergedScan, schema,
 
     from ..common import exec_stats
     from ..common.telemetry import increment_counter
-    from . import scan_narrow
     t0 = _time.perf_counter()
-    with _reduce_part("mask"):
-        sel = scan_narrow.select(scan, schema, plan)
-    n_ranges, padded_rows = (None, 0) if sel is None \
-        else (sel.n_ranges, sel.padded_rows)
-    path = scan_narrow.scan_read_path(scan.num_rows, n_ranges, padded_rows)
+    launched, path, sel = _launch_for_scan(scan, schema, plan, _reduce_part)
+    if shape is not None:
+        shape.append((path, None if sel is None else sel.range_bucket))
     increment_counter("scan_reads", path=path)
-    # the rows this launch reads on the device: the table, or the ranges
+    # the rows this launch reads on the device: the table (a tail: the
+    # rows it holds), or the ranges
+    rows = scan.num_rows if scan.valid_rows is None else scan.valid_rows
     increment_counter("scan_device_rows",
-                      sel.rows if path == "narrow" else scan.num_rows)
-    if path == "narrow":
+                      sel.rows if path == "narrow" else rows)
+    if tail:
+        exec_stats.record("reduce", tail_rows=rows, tail_path=path)
+    elif path == "narrow":
         exec_stats.record("reduce", path=path, narrow_rows=sel.rows,
                           ranges=sel.n_ranges)
-        launched = scan_narrow.launch(scan, schema, plan, sel, _reduce_part)
     else:
         exec_stats.record("reduce", path=path)
-        launched = _launch_scan_kernel(scan, schema, plan, _reduce_part,
-                                       sel)
         if launched is not None and launched.table_runs is not None:
             increment_counter("scan_group_axis", axis="live")
             exec_stats.record("reduce", groups="live",
@@ -1548,7 +2045,8 @@ def _moment_frame_for_scan(scan: MergedScan, schema,
     if launched.warm:
         _note_device_query_time(_time.perf_counter() - t0)
     with _reduce_part("collect"):
-        return _collect_moment_frame(launched, plan, counts, res_np)
+        return (_collect_runs if runs else _collect_moment_frame)(
+            launched, plan, counts, res_np)
 
 
 def _launch_scan_kernel(scan: MergedScan, schema, plan: TpuPlan,
@@ -1561,8 +2059,6 @@ def _launch_scan_kernel(scan: MergedScan, schema, plan: TpuPlan,
     `scan_narrow.scan_group_axis` says so the kernel's group axis is the
     runs they touch (every row is still read, under the table's run ids)
     and everything after the launch is sized by those."""
-    import jax
-
     from . import scan_narrow
 
     n = scan.num_rows
@@ -1583,11 +2079,11 @@ def _launch_scan_kernel(scan: MergedScan, schema, plan: TpuPlan,
         # of uploading n bool bytes per query (50 MB at 50M rows, per
         # query); padded streamed slices reuse the pre-staged padding mask
         if mask is None:
-            d_mask = scan.device["__pad_mask"] \
+            d_mask = scan.device_pad_mask() \
                 if scan.valid_rows is not None \
                 else scan.device_valid_all()
         else:
-            d_mask = jax.device_put(mask)
+            d_mask = scan.upload(mask)
 
         values = []
         col_masks = []
@@ -1612,7 +2108,7 @@ def _launch_scan_kernel(scan: MergedScan, schema, plan: TpuPlan,
                 rid = seg_len_k = None
         else:
             nbucket, run_ends, rid, seg_len_k = _segment_layout(
-                run_starts, n, ops, rid)
+                run_starts, n, ops, rid, pinned=scan.pinned)
             scan.device[layout_key] = (nbucket, run_ends, seg_len_k)
             if rid is not None:
                 scan.device[run_key] = (rid, nruns, run_starts, buckets)
@@ -1627,14 +2123,18 @@ def _launch_scan_kernel(scan: MergedScan, schema, plan: TpuPlan,
                 run_starts = live_starts[:nruns]
     if rid is not None:
         with part("upload"):
-            d_rid = jax.device_put(rid)
+            d_rid = scan.upload(rid)
     else:
         d_rid = d_ts
     with part("launch"):
-        results, counts = sorted_grouped_aggregate(
-            d_rid, d_mask, d_ts, tuple(values), tuple(col_masks),
+        out = _run_program(
+            scan, _sorted_grouped_aggregate_pre, d_rid, d_mask, d_ts,
+            tuple(values), tuple(col_masks), run_ends, live_starts,
             num_groups=nbucket, ops=tuple(ops), has_col_masks=True,
-            ends=run_ends, seg_len_k=seg_len_k, starts=live_starts)
+            seg_len_k=seg_len_k)
+    if out is None:         # a stand-in: compiled, not run
+        return None
+    results, counts = out
     signature = (run_key, nbucket,
                  tuple((m.op, m.column) for m in plan.moments))
     warm = signature in scan.launched
@@ -1683,11 +2183,13 @@ def _ops_need_gids(ops, nruns: int) -> bool:
         (high_card and any(op in ("min", "max") for op in ops))
 
 
-def _segment_layout(run_starts: np.ndarray, n: int, ops, rid=None):
+def _segment_layout(run_starts: np.ndarray, n: int, ops, rid=None,
+                    pinned: bool = False):
     """-> (num_groups, run_ends, rid, seg_len_k) for a launch over `n`
     rows cut into runs at `run_starts`; `rid` (the per-row run ids, made
     here unless handed in) and `seg_len_k` are None when no op reads
-    them."""
+    them. `pinned` (a tail): `seg_len_k` is what a run of all `n` rows
+    would need, not what the longest run has today."""
     nruns = len(run_starts)
     nbucket = shape_bucket(nruns, minimum=256)
     # segment ends are free on the host (run boundaries are already
@@ -1709,6 +2211,8 @@ def _segment_layout(run_starts: np.ndarray, n: int, ops, rid=None):
         rid = np.cumsum(starts_mark, dtype=np.int32)
     # static ceil-log2 of the longest run, bucketized to even
     # values so nearby layouts share one compile
+    if pinned:
+        return nbucket, run_ends, rid, seg_len_bucket(n)
     lens = np.diff(run_starts, append=np.int64(n))
     return nbucket, run_ends, rid, \
         seg_len_bucket(int(lens.max()) if len(lens) else 1)
@@ -1796,7 +2300,7 @@ def _scan_row_mask(scan: MergedScan, schema, plan: TpuPlan, sel=None):
     differed most from one server process to the next)."""
     n = scan.num_rows
     if sel is not None and not plan.field_filters and \
-            scan.valid_rows is None:
+            (scan.valid_rows is None or scan.pinned):
         if sel.n_ranges == 0:
             return _NO_ROWS
         mask = np.zeros(n, dtype=bool)
@@ -1820,7 +2324,7 @@ def _scan_row_mask(scan: MergedScan, schema, plan: TpuPlan, sel=None):
     # memory here) ----
     unfiltered = base_mask is None and plan.time_lo is None and \
         plan.time_hi is None and not plan.field_filters
-    if unfiltered and (scan.valid_rows is None
+    if unfiltered and (scan.valid_rows is None or scan.pinned
                        or "__pad_mask" in scan.device):
         return None
     mask = base_mask.copy() if base_mask is not None \
@@ -1869,6 +2373,10 @@ def _field_filter_keep(scan: MergedScan, ff,
            "gt": v > ff.value, "ge": v >= ff.value}[ff.op]
     if valid is not None:
         cmp &= valid
+    if rows is None and len(cmp) < scan.num_rows:
+        # a tail keeps its fields at their valid length
+        cmp = np.concatenate(
+            [cmp, np.zeros(scan.num_rows - len(cmp), dtype=bool)])
     return cmp
 
 
@@ -1889,40 +2397,128 @@ def _tag_column(sd, sids: np.ndarray, tag_index: int):
         dtype="str")
 
 
-def _collect_moment_frame(launched: _Launched, plan: TpuPlan,
-                          counts: np.ndarray,
-                          res_np: List[np.ndarray]) -> Optional[pd.DataFrame]:
+@dataclass
+class _RunPartial:
+    """One launch's moments by live run, before they become a frame: the
+    form in which the partials of a base and its tail fold (`_fold_runs`)
+    by integer keys, ahead of any label."""
+    sids: np.ndarray                  # [g] the runs' series
+    buckets: Optional[np.ndarray]     # [g] from the statement's origin
+    moments: List[np.ndarray]         # a plan moment each, [g]
+    rowcount: np.ndarray
+    series_dict: object
+
+
+def _collect_runs(launched: _Launched, plan: TpuPlan, counts: np.ndarray,
+                  res_np: List[np.ndarray]) -> Optional[_RunPartial]:
     nruns = launched.nruns
     counts = counts[:nruns]
-    res_np = [r[:nruns] for r in res_np]
-
-    # ---- host: fold runs into final groups ----
-    live = counts > 0
-    if not live.any():
-        return None
     # the live runs only: a statement over an eighth of the series
     # leaves seven eighths of the table's runs empty, and their tags are
     # not worth decoding
-    frame: Dict[str, Any] = {}
-    run_sids = launched.run_sids[live]
-    sd = launched.series_dict
-    for tg in plan.tag_groups:
-        frame[_group_slot(tg.name)] = _tag_column(sd, run_sids,
-                                                  tg.tag_index)
-    if plan.bucket is not None:
-        frame[_group_slot(plan.bucket.expr_key)] = \
-            launched.run_buckets[live] * plan.bucket.stride_ms + \
-            plan.bucket.origin
+    live = counts > 0
+    if not live.any():
+        return None
+    moments = []
     for m, r in zip(plan.moments, res_np):
-        r = r[live]
+        r = r[:nruns][live]
         if m.op in ("min_ts", "max_ts"):
             # device ts is region-relative (ts - ts_base, base differs per
             # region); rebase to absolute so cross-region first/last merge
             # in _finalize compares comparable timestamps
             r = r.astype(np.int64) + launched.ts_base
+        moments.append(r)
+    return _RunPartial(
+        launched.run_sids[live],
+        launched.run_buckets[live] if plan.bucket is not None else None,
+        moments, counts[live], launched.series_dict)
+
+
+def _partial_frame(p: _RunPartial, plan: TpuPlan) -> pd.DataFrame:
+    # ---- host: fold runs into final groups ----
+    frame: Dict[str, Any] = {}
+    for tg in plan.tag_groups:
+        frame[_group_slot(tg.name)] = _tag_column(p.series_dict, p.sids,
+                                                  tg.tag_index)
+    if plan.bucket is not None:
+        frame[_group_slot(plan.bucket.expr_key)] = \
+            p.buckets * plan.bucket.stride_ms + plan.bucket.origin
+    for m, r in zip(plan.moments, p.moments):
         frame[m.slot] = r
-    frame["__rowcount"] = counts[live]
+    frame["__rowcount"] = p.rowcount
     return pd.DataFrame(frame)
+
+
+def _collect_moment_frame(launched: _Launched, plan: TpuPlan,
+                          counts: np.ndarray,
+                          res_np: List[np.ndarray]) -> Optional[pd.DataFrame]:
+    runs = _collect_runs(launched, plan, counts, res_np)
+    return None if runs is None else _partial_frame(runs, plan)
+
+
+def _fold_runs(a: _RunPartial, b: _RunPartial,
+               plan: TpuPlan) -> Optional[_RunPartial]:
+    """The partials of a base (`a`) and its tail (`b`) as one: a run
+    (series, bucket) that both hold folds here, as `_finalize` would fold
+    its two rows (sums add, extremes compare, `first` / `last` go to the
+    valid value with the extreme companion timestamp), on integer keys
+    and before a label is decoded; the statement's frame then has a row
+    a group and `_finalize` nothing to fold. None where the keys do not
+    fit an int64 (the frames are then handed on as they are)."""
+    keyed = bool(plan.tag_groups) or plan.bucket is not None
+    ka = a.sids.astype(np.int64) if keyed else np.zeros(len(a.sids),
+                                                        np.int64)
+    kb = b.sids.astype(np.int64) if keyed else np.zeros(len(b.sids),
+                                                        np.int64)
+    if plan.bucket is not None:
+        lo = min(int(a.buckets.min()), int(b.buckets.min()))
+        if max(int(a.buckets.max()), int(b.buckets.max())) - lo >= 2**31:
+            return None
+        ka = (ka << 32) + (a.buckets - lo)
+        kb = (kb << 32) + (b.buckets - lo)
+    # a launch's runs are in row order: ascending in (series, bucket)
+    at = np.minimum(np.searchsorted(ka, kb), len(ka) - 1)
+    hit = ka[at] == kb
+    at, rest = at[hit], ~hit
+
+    def companion(m: Moment, kind: str):
+        i = next(i for i, mm in enumerate(plan.moments)
+                 if mm.op == kind and mm.column == m.column)
+        return a.moments[i][at], b.moments[i][hit]
+
+    def valid(v):
+        return ~np.isnan(v) if v.dtype.kind == "f" else np.ones(len(v), bool)
+
+    moments = []
+    for i, m in enumerate(plan.moments):
+        va, vb = a.moments[i], b.moments[i]
+        x, y = va[at], vb[hit]
+        if m.op in ("sum", "sum_sq", "count"):
+            both = np.where(valid(x) & valid(y), x + y,
+                            np.where(valid(x), x, y))
+        elif m.op in ("min", "min_ts"):
+            both = np.fmin(x, y)
+        elif m.op in ("max", "max_ts"):
+            both = np.fmax(x, y)
+        elif m.op == "first":
+            ta, tb = companion(m, "min_ts")
+            both = np.where(valid(x) & (~valid(y) | (ta <= tb)), x, y)
+        elif m.op == "last":
+            ta, tb = companion(m, "max_ts")
+            both = np.where(valid(y) & (~valid(x) | (tb >= ta)), y, x)
+        else:
+            raise UnsupportedError(f"no fold for moment {m.op}")
+        out = va.astype(np.result_type(va.dtype, vb.dtype), copy=True)
+        out[at] = both
+        moments.append(np.concatenate([out, vb[rest]]))
+    rowcount = a.rowcount.copy()
+    rowcount[at] += b.rowcount[hit]
+    return _RunPartial(
+        np.concatenate([a.sids, b.sids[rest]]),
+        None if plan.bucket is None
+        else np.concatenate([a.buckets, b.buckets[rest]]),
+        moments, np.concatenate([rowcount, b.rowcount[rest]]),
+        a.series_dict)
 
 
 def _nan_if_none(v):

@@ -331,9 +331,9 @@ class HttpServer:
             from .coalesce import COALESCER
             from ..common.telemetry import timer
             with GATE.admit_ingest(len(body)):
-                with timer("ingest_parse"):
+                with GATE.parse_turn() as give_way, timer("ingest_parse"):
                     inserts, tag_cols = influx_mod.body_to_inserts(
-                        body, precision)
+                        body, precision, give_way)
                 n = 0
                 for table, cols in inserts.items():
                     # concurrent small bodies for the same measurement

@@ -62,16 +62,21 @@ def _parse_field_value(raw: str):
         raise InvalidArgumentsError(f"bad field value {raw!r}") from e
 
 
-def parse_lines(body: str, precision: str = "ns"
+def parse_lines(body: str, precision: str = "ns", between_lines=None
                 ) -> List[Tuple[str, Dict[str, object], Dict[str, object],
                                 int]]:
-    """→ [(measurement, tags, fields, ts_ms)]"""
+    """→ [(measurement, tags, fields, ts_ms)]. `between_lines()` is
+    called after every line: whoever admitted the body decides there
+    what a pure-Python parser owes the process's other threads
+    (`common/admission.py:AdmissionGate.parse_turn`)."""
     scale = PRECISION_MS.get(precision)
     if scale is None:
         raise InvalidArgumentsError(f"bad precision {precision!r}")
     now = int(time.time() * 1000)
     out = []
     for line in body.splitlines():
+        if between_lines is not None:
+            between_lines()
         line = line.strip()
         if not line or line.startswith("#"):
             continue
@@ -101,11 +106,11 @@ def parse_lines(body: str, precision: str = "ns"
     return out
 
 
-def body_to_inserts(body: str, precision: str = "ns"):
+def body_to_inserts(body: str, precision: str = "ns", between_lines=None):
     """Line-protocol body → (per-measurement column dicts, per-
     measurement tag names) — the one-call shape the HTTP handler and
     the ingest coalescer share."""
-    return lines_to_inserts(parse_lines(body, precision))
+    return lines_to_inserts(parse_lines(body, precision, between_lines))
 
 
 def lines_to_inserts(parsed) -> Dict[str, Dict[str, list]]:

@@ -5,8 +5,9 @@ ordered by (row key, sequence, op). TPU-first redesign: writes append to
 unordered structure-of-arrays numpy buffers (series_id, ts, seq, op, fields);
 ordering/dedup happens at read or flush time via the sort-based device kernel
 (ops.kernels.sort_merge_dedup) — sorts are what the accelerator is good at,
-ordered maps are not. Snapshots are trivially consistent: buffers are
-append-only, so a snapshot is just a row count.
+ordered maps are not. Snapshots are cheap and consistent: buffers are
+append-only, so a snapshot is a row count and views, taken under the lock
+a write holds.
 """
 
 from __future__ import annotations
@@ -98,18 +99,24 @@ class Memtable:
 
     # ---- write path ----
     def write(self, seq: int, batch: WriteBatch) -> None:
-        """Apply all mutations of a WriteBatch at the given sequence."""
+        """Apply all mutations of a WriteBatch at the given sequence.
+        The columns are made first (series encoded, values cast); the
+        lock is held for the appends alone, which is all a reader's
+        `snapshot` has to wait for."""
+        staged = [self._stage(seq, m.data,
+                              OP_PUT if m.op_type == OP_PUT else OP_DELETE)
+                  for m in batch.mutations]
         with self._lock:
-            for m in batch.mutations:
-                if m.op_type == OP_PUT:
-                    self._insert(seq, m.data, OP_PUT)
-                else:
-                    self._insert(seq, m.data, OP_DELETE)
+            for columns in staged:
+                if columns is not None:
+                    self._append(columns)
 
-    def _insert(self, seq: int, rb: RecordBatch, op: int) -> None:
+    def _stage(self, seq: int, rb: RecordBatch, op: int):
+        """-> (sids, ts, seq, op, [(data, validity) a field]) for one
+        mutation's rows, or None for none."""
         n = rb.num_rows
         if n == 0:
-            return
+            return None
         schema = self.schema
         tag_names = schema.tag_names()
         if tag_names:
@@ -125,22 +132,33 @@ class Memtable:
             sids = self.series_dict.encode_zero_tags(n)
         ts_col = rb.column(schema.timestamp_column.name)
         ts = np.asarray(ts_col.data, dtype=np.int64)
-        self._series.append(sids)
-        self._ts.append(ts)
-        self._seq.append(np.full(n, seq, dtype=np.int64))
-        self._op.append(np.full(n, op, dtype=np.int8))
-        for name, (dataf, validf) in self._fields.items():
+        fields = []
+        for name, (dataf, _) in self._fields.items():
+            dtype = dataf.arr.dtype
             if op == OP_PUT and rb.schema.contains(name):
                 vec = rb.column(name)
-                dataf.append(np.asarray(vec.data, dtype=dataf.arr.dtype))
-                validf.append(vec.validity if vec.validity is not None
-                              else np.ones(n, dtype=bool))
+                fields.append((np.asarray(vec.data, dtype=dtype),
+                               vec.validity if vec.validity is not None
+                               else np.ones(n, dtype=bool)))
             else:
                 # delete rows / missing column: nulls
-                fill = np.zeros(n, dtype=dataf.arr.dtype) \
-                    if dataf.arr.dtype != object else np.full(n, None, dtype=object)
-                dataf.append(fill)
-                validf.append(np.zeros(n, dtype=bool))
+                fill = np.zeros(n, dtype=dtype) if dtype != object \
+                    else np.full(n, None, dtype=object)
+                fields.append((fill, np.zeros(n, dtype=bool)))
+        return (sids, ts, np.full(n, seq, dtype=np.int64),
+                np.full(n, op, dtype=np.int8), fields)
+
+    def _append(self, columns) -> None:
+        sids, ts, seq, op, fields = columns
+        n = len(ts)
+        self._series.append(sids)
+        self._ts.append(ts)
+        self._seq.append(seq)
+        self._op.append(op)
+        for (dataf, validf), (data, valid) in zip(self._fields.values(),
+                                                  fields):
+            dataf.append(data)
+            validf.append(valid)
         tmin, tmax = int(ts.min()), int(ts.max())
         self._min_ts = tmin if self._min_ts is None else min(self._min_ts, tmin)
         self._max_ts = tmax if self._max_ts is None else max(self._max_ts, tmax)
@@ -150,18 +168,25 @@ class Memtable:
 
     # ---- read path ----
     def snapshot(self) -> MemtableSnapshot:
-        n = self._ts.len  # append-only ⇒ first n rows are immutable
-        return MemtableSnapshot(
-            num_rows=n,
-            series_ids=self._series.view(n),
-            ts=self._ts.view(n),
-            seq=self._seq.view(n),
-            op_types=self._op.view(n),
-            fields={name: (d.view(n), v.view(n))
-                    for name, (d, v) in self._fields.items()},
-            min_ts=self._min_ts if self._min_ts is not None else 0,
-            max_ts=self._max_ts if self._max_ts is not None else -1,
-        )
+        # the length and the views under the lock: `_insert` appends
+        # column by column, and a reader between two of its appends would
+        # take a count the later columns do not have yet (or, at a
+        # growth, a view of a buffer about to be replaced). Append-only:
+        # the first n rows are immutable, so nothing is held while the
+        # caller reads them.
+        with self._lock:
+            n = self._ts.len
+            return MemtableSnapshot(
+                num_rows=n,
+                series_ids=self._series.view(n),
+                ts=self._ts.view(n),
+                seq=self._seq.view(n),
+                op_types=self._op.view(n),
+                fields={name: (d.view(n), v.view(n))
+                        for name, (d, v) in self._fields.items()},
+                min_ts=self._min_ts if self._min_ts is not None else 0,
+                max_ts=self._max_ts if self._max_ts is not None else -1,
+            )
 
 
 class MemtableVersion:

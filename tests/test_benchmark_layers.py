@@ -194,3 +194,198 @@ def test_idle_time_falls_under_the_innermost_stage_row():
     assert sum(idle.values()) == 180
     means = stage_idle.family_stage_ms(run)
     assert means["a"]["render"] == 30 and means["a"]["client_ms"] == 140
+
+
+# ---------------------------------------------------------------------------
+# the readers of a scan-cache refresh and the cell that reports them
+# (ISSUE 37: `tsbs4k-read-while-ingest`)
+# ---------------------------------------------------------------------------
+
+CELL = "tsbs4k-read-while-ingest"
+
+
+def refresh_run(refreshing=True, counters=True):
+    """`query_run` in which family a's statements refreshed the scan
+    cache (scan_prep 1 ms = delta 0.5 + apply 0.3 + upload 0.2 at scale
+    1) and b's found it current; 2 refreshes, 5,000 rows, 42 MB."""
+    run = query_run()
+    if refreshing:
+        for rec in run["statements"][:2]:
+            rec["stages"].update({
+                "scan_prep.delta": stage(0.5, 6), "scan_prep.apply":
+                stage(0.3, 6.5), "scan_prep.upload": stage(0.2, 6.8)})
+    before = {"greptime_scan_cache_incremental_total": 3.0,
+              "greptime_scan_cache_miss_total": 1.0,
+              "greptime_scan_cache_hit_total": 7.0}
+    after = {"greptime_scan_cache_incremental_total": 5.0 if refreshing
+             else 3.0, "greptime_scan_cache_miss_total": 1.0,
+             "greptime_scan_cache_hit_total": 9.0}
+    if counters:
+        before.update({"greptime_scan_cache_delta_rows_total": 1000.0,
+                       "greptime_scan_cache_upload_bytes_total": 1e6})
+        after.update({"greptime_scan_cache_delta_rows_total": 6000.0
+                      if refreshing else 1000.0,
+                      "greptime_scan_cache_upload_bytes_total": 43e6
+                      if refreshing else 1e6})
+    run["counters"] = {"before": before, "after": after}
+    return run
+
+
+REFRESH_READERS = {
+    "refresh_apply_ms": 0.8,            # family a alone refreshed
+    "refresh_upload_ms": 0.2,
+    "refresh_delta_rows": 2500.0,
+    "refresh_upload_bytes": 21e6,
+    "tail_merges": 0.0,                 # counts rows, merged nothing
+    "cache_refreshes": 2.0,
+    "launch_ms": 2 * 2,
+}
+
+
+@pytest.mark.parametrize("metric", sorted(REFRESH_READERS))
+def test_refresh_reader_reads_its_rows_and_counters(reader, metric):
+    assert reader(metric)(refresh_run()) == pytest.approx(
+        REFRESH_READERS[metric])
+
+
+@pytest.mark.parametrize("metric", [
+    "refresh_apply_ms", "refresh_upload_ms", "refresh_delta_rows",
+    "refresh_upload_bytes"])
+def test_a_window_without_a_refresh_reads_nothing(reader, metric):
+    """As each reader's docstring says: None, and the line leaves the
+    metric out; `tail_merges` and `cache_refreshes` are counts and read
+    0."""
+    run = refresh_run(refreshing=False)
+    assert reader(metric)(run) is None
+    assert reader("tail_merges")(run) == 0.0
+    assert reader("cache_refreshes")(run) == 0.0
+
+
+@pytest.mark.parametrize("metric", [
+    "refresh_apply_ms", "refresh_upload_ms", "refresh_delta_rows",
+    "refresh_upload_bytes", "tail_merges"])
+def test_refresh_reader_on_the_parent_program_reads_nothing(reader, metric):
+    """No `scan_prep.*` row and no such counter: None, never a raise."""
+    parent = refresh_run(refreshing=False, counters=False)
+    assert reader(metric)(parent) is None
+    assert reader(metric)(ingest_run()) is None
+    assert reader(metric)({}) is None
+
+
+def test_a_merge_in_the_window_is_counted(reader):
+    run = refresh_run()
+    run["counters"]["after"]["greptime_scan_cache_merges_total"] = 1.0
+    assert reader("tail_merges")(run) == 1.0
+
+
+def test_the_wait_for_the_parse_turn_is_read_beside_the_parse(reader):
+    """`ingest_parse_wait_ms`: the timer over the window's acknowledged
+    batches, as `ingest_parse_ms` beside it; a program without the timer
+    (the parent: bodies parsed side by side) and a window without writes
+    read None."""
+    read = reader("ingest_parse_wait_ms")
+    run = ingest_run()
+    assert read(run) is None
+    run["counters"]["before"]["greptime_ingest_parse_wait_seconds_sum"] = 1.0
+    run["counters"]["after"]["greptime_ingest_parse_wait_seconds_sum"] = 181.0
+    assert read(run) == pytest.approx(1800.0)       # 180 s, 100 batches
+    assert reader("ingest_parse_ms")(run) == pytest.approx(500.0)
+    assert read(query_run()) is None and read({}) is None
+    entry = next(m for m in _benchmark()["per_layer"]
+                 if m["name"] == "ingest_parse_wait_ms")
+    assert entry["workloads"] == ["tsbs4k-ingest", CELL]
+    assert (entry["moves"], entry["layer"]) == ("ingest_rows_per_s",
+                                                "write path")
+
+
+def test_full_collections_in_the_window_are_read_in_ms(reader):
+    read = reader("gc_full_ms")
+    run = refresh_run()
+    assert read(run) is None and read({}) is None       # no such timer
+    name = "greptime_gc_full_collection_seconds_sum"
+    run["counters"]["before"][name] = 0.25
+    run["counters"]["after"][name] = 0.25
+    assert read(run) == 0.0
+    run["counters"]["after"][name] = 4.5
+    assert read(run) == pytest.approx(4250.0)
+    entry = next(m for m in _benchmark()["per_layer"]
+                 if m["name"] == "gc_full_ms")
+    assert entry["workloads"] == [CELL]
+
+
+def _benchmark():
+    import json
+    with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def test_the_cell_reports_what_its_mix_says():
+    import json
+    bench = _benchmark()
+    cell = next(w for w in bench["workloads"] if w["name"] == CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == \
+        ("tsbs-cpu-4000-durable", "read-while-ingest", 1)
+    with open(os.path.join(BENCH, "traffic", "read-while-ingest.json")) as f:
+        mix = json.load(f)
+    assert mix["loop"] == "mixed" and mix["families"] == \
+        ["double-groupby-1", "lastpoint-live"]
+    with open(os.path.join(BENCH, "traffic", "read-under-ingest.json")) as f:
+        queued = json.load(f)
+    differs = {k for k in set(mix) | set(queued)
+               if mix.get(k) != queued.get(k)}
+    assert differs == {"about", "assumed", "reports", "extra_ticks"}
+    assert (mix["extra_ticks"], mix["debug_extra_ticks"], mix["workers"],
+            mix["batch_rows"], mix["prefill_batches"],
+            mix["warm_statements"], mix["max_statements"]) == \
+        (250, 1200, 6, 2500, 12, 3, 4000)
+    reported = {m["name"] for m in bench["end_to_end"]
+                if CELL in m.get("workloads", [CELL])}
+    assert reported == set(mix["reports"].values()) | {"setup_s"}
+    assert "p90_ms" not in mix["reports"]
+    bounds = {m["name"]: m["bound"] for m in bench["end_to_end"]}
+    assert [bounds[n] for n in ("stmt_geomean_ms", "stmt_per_s",
+                                "ingest_rows_per_s", "setup_s")] == \
+        [0.07, 0.06, 0.10, 0.25]
+    layers = {m["name"]: m for m in bench["per_layer"]
+              if CELL in m.get("workloads", ())}
+    assert set(REFRESH_READERS) | {
+        "compiled_in_window", "visible_lag_ms", "wal_fsync_ms",
+        "scan_prep_ms", "kernel_ms", "scan_kernels_roofline",
+        "batch_ack_ms", "region_write_ms"} <= set(layers)
+    for name, m in layers.items():
+        assert os.path.isfile(os.path.join(
+            BENCH, "layers", name.split(".", 1)[0] + ".py")), name
+        assert m["moves"] in reported, name
+    for name in REFRESH_READERS:
+        assert layers[name]["workloads"] == [CELL]
+        assert layers[name]["moves"] == "stmt_geomean_ms"
+
+
+def test_the_durable_configuration_is_tsbs_cpu_4000_plus_the_fsync():
+    import json
+    bench = _benchmark()
+    entry = next(c for c in bench["configs"]
+                 if c["name"] == "tsbs-cpu-4000-durable")
+    with open(os.path.join(os.path.dirname(BENCH), entry["file"])) as f:
+        config = json.load(f)
+    with open(os.path.join(BENCH, "configs", "tsbs-cpu-4000.json")) as f:
+        plain = json.load(f)
+    assert len(entry["source"]) < 200 and entry["source"] == config["source"]
+    assert "sync_write = true" in entry["source"]
+    assert entry["reduced"] == ["duration_s"] == list(config["reduced"])
+    assert config["server_options"] == ["--wal-sync-on-write"]
+    assert plain["server_options"] == []
+    same = ("use_case", "scale", "log_interval_s", "duration_s", "start",
+            "table", "time_index", "tags", "fields", "primary_key",
+            "load_chunk_ticks", "reduced", "debug")
+    assert {k: config[k] for k in same} == {k: plain[k] for k in same}
+    assert set(config["guarantees"]) == {"durability", "consistency",
+                                         "answers"}
+    assert config["guarantees"]["answers"] == plain["guarantees"]["answers"]
+    assert "fsync" in config["guarantees"]["durability"]
+    assert config["assumed"][:len(plain["assumed"])] == plain["assumed"]
+    # the flag is one `standalone start` takes
+    from greptimedb_tpu.cmd.main import build_parser
+    args = build_parser().parse_args(
+        ["standalone", "start", *config["server_options"]])
+    assert args.wal_sync_on_write is True

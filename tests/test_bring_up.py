@@ -150,6 +150,13 @@ def test_repeat_keeps_the_device_dispatch(tmp_path, monkeypatch):
     (The first launch compiles; fed into the floor it used to push every
     table under 7.5M rows onto the CPU path for the life of the process.)"""
     monkeypatch.setattr(tpu_exec, "_observed_min_dt", [None])
+    # the host path's assumed speed, lowered 15x: the table then stays on
+    # the device while a repeat takes under 134 ms, where at 15M rows/s
+    # one repeat descheduled past 8.96 ms (6 test workers on 8 cores:
+    # 9.5-11.2 ms on this tree and on its parent alike) sent the next
+    # statement to the CPU; a compiling first launch (0.3 s and more)
+    # fed into the floor would still do that
+    monkeypatch.setattr(tpu_exec, "_CPU_ROWS_PER_SEC", 1e6)
     dn = DatanodeInstance(DatanodeOptions(
         data_home=str(tmp_path / "d"), register_numbers_table=False))
     dn.start()

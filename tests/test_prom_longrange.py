@@ -816,8 +816,12 @@ def test_the_cell_reports_what_its_entries_say():
     (entry,) = [c for c in spec["configs"] if c["name"] == "prom-node-1k-2h"]
     assert entry["source"] == cell.config["source"]
     assert sorted(entry["reduced"]) == sorted(cell.config["reduced"])
-    assert spec["configs"][-1] is entry and \
-        spec["workloads"][-1]["name"] == CELL
+    # appended where PR 34 found the lists' ends; later PRs append after
+    configs = [c["name"] for c in spec["configs"]]
+    cells = [w["name"] for w in spec["workloads"]]
+    assert configs.index("prom-node-1k-2h") == \
+        configs.index("prom-node-1k") + 1
+    assert cells.index(CELL) == cells.index("prom1k-dashboard") + 1
 
 
 def test_the_mix_sends_six_families_of_one_shape_each():
