@@ -135,7 +135,9 @@ class ProcessRegistry:
         self._entries: Dict[int, ProcessEntry] = tracked_state(
             {}, "process_list.entries")
         self._ids = itertools.count(1)
-        self._running = 0       # len(_entries), readable without the lock
+        self._rendering = 0     # results being encoded for the wire
+        #: len(_entries) + _rendering, readable without the lock
+        self._running = 0
         self.node = node
 
     def register(self, query: str, protocol: str, catalog: str,
@@ -144,19 +146,37 @@ class ProcessRegistry:
                              schema, self.node, trace_id)
         with self._lock:
             self._entries[entry.id] = entry
-            self._running = len(self._entries)
+            self._running = len(self._entries) + self._rendering
         return entry
 
     def deregister(self, entry: ProcessEntry) -> None:
         with self._lock:
             self._entries.pop(entry.id, None)
-            self._running = len(self._entries)
+            self._running = len(self._entries) + self._rendering
+
+    @contextlib.contextmanager
+    def rendering(self) -> Iterator[None]:
+        """A statement's result being encoded for the wire
+        (servers/render.py): its entry is gone, there is nothing left to
+        list or to kill, but its client still waits, so `busy()` holds.
+        The encoders make hundreds of short Arrow and numpy calls that
+        give the interpreter lock up, like the engine's."""
+        with self._lock:
+            self._rendering += 1
+            self._running += 1
+        try:
+            yield
+        finally:
+            with self._lock:
+                self._rendering -= 1
+                self._running -= 1
 
     def busy(self) -> bool:
-        """Whether a statement is executing in this process right now:
-        one word read without the lock, a hint for code that gives way
-        to statements (`admission.AdmissionGate.give_way`) after every
-        line it parses, not a count to act on."""
+        """Whether a statement is executing in this process right now, or
+        its result being encoded: one word read without the lock, a hint
+        for code that gives way to statements
+        (`admission.AdmissionGate.give_way`) after every line it parses,
+        not a count to act on."""
         return self._running > 0
 
     def kill(self, pid: int) -> None:

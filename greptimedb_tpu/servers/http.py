@@ -27,7 +27,7 @@ from . import influxdb as influx_mod
 from . import opentsdb as tsdb_mod
 from . import prometheus as prom_mod
 from .auth import NoopUserProvider, UserProvider
-from .columnar import json_rows
+from .columnar import RouteRows, json_rows_text
 from .render import render
 
 logger = logging.getLogger(__name__)
@@ -42,30 +42,46 @@ def parse_db_param(db: Optional[str]) -> tuple:
     return DEFAULT_CATALOG_NAME, db
 
 
-def output_to_json(out: Output) -> Tuple[Dict[str, Any], int]:
-    """-> (one result of the envelope, rows of it that took the per-cell
-    path of servers/columnar.py)."""
+#: what `json.dumps` makes of the `"rows": None` that `sql_response` puts
+#: where a result's rows go. A string's quotes are escaped, so this text
+#: is in an envelope only where a key `rows` is
+_ROWS_GO_HERE = b'"rows": null'
+
+
+def output_to_json(out: Output) -> Dict[str, Any]:
+    """One result of the envelope; its rows are left out (None)."""
     if not out.is_batches:
-        return {"affectedrows": out.affected_rows or 0}, 0
+        return {"affectedrows": out.affected_rows or 0}
     schema = out.schema
     col_schemas = [{"name": c.name, "data_type": c.dtype.name}
                    for c in schema.column_schemas] if schema else []
-    rows, cell_rows = json_rows(out.batches or [])
     return {"records": {"schema": {"column_schemas": col_schemas},
-                        "rows": rows}}, cell_rows
+                        "rows": None}}
 
 
 def sql_response(outputs: List[Output], t0: float) -> web.Response:
     """The JSON envelope of a statement's results, made under the
-    `render` span: the rows, then the text."""
+    `render` span: the rows' text a column at a time
+    (`columnar.json_rows_text`), then the envelope by `json.dumps` with
+    the rows spliced in."""
     def encode(outs: List[Output], discard: bool):
-        results = [output_to_json(o) for o in outs]
-        body = json.dumps({
+        routes = RouteRows()
+        rows_texts = []
+        for o in outs:
+            if o.is_batches:
+                text, took = json_rows_text(o.batches or [])
+                rows_texts.append(text)
+                routes.update(took)
+        envelope = json.dumps({
             "code": 0,
-            "output": [result for result, _ in results],
+            "output": [output_to_json(o) for o in outs],
             "execution_time_ms": int((time.perf_counter() - t0) * 1e3),
-        }).encode()
-        return body, len(body), sum(n for _, n in results)
+        }).encode().split(_ROWS_GO_HERE)
+        pieces = [envelope[0]]
+        for text, rest in zip(rows_texts, envelope[1:]):
+            pieces += [b'"rows": ', *text, rest]
+        body = b"".join(pieces)
+        return body, len(body), routes
 
     return web.Response(body=render("http", outputs, encode),
                         content_type="application/json", charset="utf-8")

@@ -28,8 +28,8 @@ import numpy as np
 
 from ..errors import GreptimeError
 from ..session import Channel, QueryContext
-from .columnar import (MYSQL_TEXT, SlabWriter, TextColumn, cell_lengths,
-                       literal_columns, text_chunks)
+from .columnar import (COLUMNAR, MYSQL_TEXT, RouteRows, SlabWriter,
+                       TextColumn, cell_lengths, literal_columns, text_chunks)
 from .render import render
 
 logger = logging.getLogger(__name__)
@@ -338,14 +338,14 @@ class _Connection:
                 + b"\x00\x00")
 
     def send_resultset(self, names: List[str], types: List[int],
-                       chunks: Iterable[Tuple[int, List[TextColumn], bool]],
+                       chunks: Iterable[Tuple[int, List[TextColumn], str]],
                        binary: bool = False,
-                       io: Optional[PacketIO] = None) -> int:
+                       io: Optional[PacketIO] = None) -> RouteRows:
         """Column definitions, EOF, the rows of `chunks` (what
-        `columnar.text_chunks` yields), EOF, in slabs -> rows that took
-        the per-cell path."""
+        `columnar.text_chunks` yields), EOF, in slabs -> the rows each
+        route rendered."""
         io = io or self.io
-        cell_rows = 0
+        routes = RouteRows()
         with io.slab():
             io.write_packet(lenenc_int(len(names)))
             for name, t in zip(names, types):
@@ -354,11 +354,11 @@ class _Connection:
                     else CHARSET_BINARY
                 io.write_packet(self._column_def(name, t, charset))
             self.send_eof(io=io)
-            for nrows, columns, fell_back in chunks:
+            for nrows, columns, route in chunks:
                 io.write_row_packets(*_row_parts(nrows, columns, binary))
-                cell_rows += nrows * fell_back
+                routes[route] += nrows
             self.send_eof(io=io)
-        return cell_rows
+        return routes
 
     # ---- handshake ----
     def handshake(self) -> bool:
@@ -506,7 +506,7 @@ class _Connection:
             else:
                 self.send_resultset(
                     names, [T_VAR_STRING] * len(names),
-                    [(len(rows), literal_columns(rows, len(names)), False)]
+                    [(len(rows), literal_columns(rows, len(names)), COLUMNAR)]
                     if rows else [], binary=binary)
             return
         try:
@@ -527,19 +527,19 @@ class _Connection:
         def encode(outs, discard: bool):
             io = PacketIO(None) if discard else self.io
             sent = io.bytes_out
-            cell_rows = self._send_output(outs[-1], binary, io)
-            return None, io.bytes_out - sent, cell_rows
+            routes = self._send_output(outs[-1], binary, io)
+            return None, io.bytes_out - sent, routes
 
         render("mysql", outputs[-1:], encode)
 
-    def _send_output(self, out, binary: bool, io: PacketIO) -> int:
+    def _send_output(self, out, binary: bool, io: PacketIO) -> RouteRows:
         if not out.is_batches:
             self.send_ok(affected=out.affected_rows or 0, io=io)
-            return 0
+            return RouteRows()
         batches = out.batches
         if not batches:
             self.send_ok(io=io)
-            return 0
+            return RouteRows()
         schema = batches[0].schema
         names = schema.names()
         types = [_mysql_type(c.dtype) for c in schema.column_schemas]

@@ -23,8 +23,8 @@ import numpy as np
 
 from ..errors import GreptimeError
 from ..session import Channel, QueryContext
-from .columnar import (POSTGRES_TEXT, SlabWriter, TextColumn, cell_lengths,
-                       text_chunks)
+from .columnar import (POSTGRES_TEXT, RouteRows, SlabWriter, TextColumn,
+                       cell_lengths, text_chunks)
 from .render import render
 
 logger = logging.getLogger(__name__)
@@ -211,15 +211,15 @@ class _PgConnection:
                                    -1, -1, 0))
         (io or self.io).send(b"T", body)
 
-    def send_rows(self, batches, io: Optional[_MessageIO] = None) -> int:
-        """The DataRows of a result -> rows that took the per-cell path."""
+    def send_rows(self, batches, io: Optional[_MessageIO] = None
+                  ) -> RouteRows:
+        """The DataRows of a result -> the rows each route rendered."""
         io = io or self.io
-        cell_rows = 0
-        for nrows, columns, fell_back in text_chunks(batches,
-                                                     POSTGRES_TEXT):
+        routes = RouteRows()
+        for nrows, columns, route in text_chunks(batches, POSTGRES_TEXT):
             io.send_data_rows(columns, nrows)
-            cell_rows += nrows * fell_back
-        return cell_rows
+            routes[route] += nrows
+        return routes
 
     def send_result(self, sql: str, out, described: bool = False) -> None:
         """One result on the wire, under the `render` span:
@@ -229,18 +229,18 @@ class _PgConnection:
             io = _MessageIO(None) if discard else self.io
             sent = io.bytes_out
             result = outs[-1]
-            cell_rows = 0
+            routes = RouteRows()
             with io.slab():
                 if result.is_batches:
                     if result.batches:
                         if not described:
                             self.send_row_description(
                                 result.batches[0].schema, io)
-                        cell_rows = self.send_rows(result.batches, io)
+                        routes = self.send_rows(result.batches, io)
                     elif not described:
                         io.send(b"T", struct.pack("!H", 0))
                 self.send_complete(sql, result, io)
-            return None, io.bytes_out - sent, cell_rows
+            return None, io.bytes_out - sent, routes
 
         render("postgres", [out], encode)
 

@@ -6,9 +6,9 @@ hangs off the statement's `execute_stmt` span (so OTLP and the trace
 store show it in the statement's trace, after the engine's spans), the
 histogram `greptime_render_seconds{protocol}` on /metrics, and the span's
 annotation on the profiler's host timeline. The encoders make a result's
-bytes a column at a time (servers/columnar.py); the span's `path` and
-`greptime_render_rows_total{protocol, path}` say whether any row took the
-per-cell code instead.
+bytes a column at a time (servers/columnar.py); the span's `path`, the
+stage row's and `greptime_render_rows_total{protocol, path}` say which of
+its routes the rows took: `compiled`, `columnar`, or the per-cell code.
 
 `EXPLAIN ANALYZE` answers with stage rows, not with the statement's
 result, so its writer would skip the cost a client of the plain statement
@@ -23,18 +23,20 @@ from __future__ import annotations
 
 from typing import Callable, List, Tuple, TypeVar
 
+from ..common import process_list
 from ..common.exec_stats import StageStat
 from ..common.telemetry import (continue_trace, increment_counter,
                                 observe_latency, span)
 from ..datatypes.record_batch import RecordBatch
 from ..query.output import Output
+from .columnar import RouteRows, route_of
 
 T = TypeVar("T")
 
 #: encode(outputs, discard) -> (what the writer wants back, bytes made,
-#: rows that took servers/columnar.py's per-cell path); with `discard`
+#: the rows each route of servers/columnar.py rendered); with `discard`
 #: the bytes go nowhere (no socket, no sequence numbers)
-Encoder = Callable[[List[Output], bool], Tuple[T, int, int]]
+Encoder = Callable[[List[Output], bool], Tuple[T, int, RouteRows]]
 
 
 def render(protocol: str, outputs: List[Output], encode: Encoder) -> T:
@@ -49,7 +51,8 @@ def render(protocol: str, outputs: List[Output], encode: Encoder) -> T:
                 "render", rows=analyzed.num_rows,
                 elapsed_s=sp["elapsed_ms"] / 1e3, t0_ns=sp["start_unix_ns"],
                 detail={"protocol": protocol,
-                        "bytes": sp["attrs"]["bytes"]}))
+                        "bytes": sp["attrs"]["bytes"],
+                        "path": sp["attrs"]["path"]}))
     value, _ = _encode(protocol, outputs, encode, False)
     return value
 
@@ -58,13 +61,13 @@ def _encode(protocol: str, outputs: List[Output], encode: Encoder,
             discard: bool):
     rows = sum(o.num_rows for o in outputs if o.is_batches)
     with continue_trace(outputs[-1].trace if outputs else None), \
-            span("render", protocol=protocol, rows=rows) as sp:
-        value, sp["attrs"]["bytes"], cell_rows = encode(outputs, discard)
-        sp["attrs"]["path"] = "cell" if cell_rows else "columnar"
+            span("render", protocol=protocol, rows=rows) as sp, \
+            process_list.REGISTRY.rendering():
+        value, sp["attrs"]["bytes"], routes = encode(outputs, discard)
+        sp["attrs"]["path"] = route_of(routes)
     observe_latency("render", sp["elapsed_ms"] / 1e3, protocol=protocol)
-    for path, n in (("columnar", rows - cell_rows), ("cell", cell_rows)):
-        if n:
-            increment_counter("render_rows", n, protocol=protocol, path=path)
+    for path, n in routes.items():
+        increment_counter("render_rows", n, protocol=protocol, path=path)
     return value, sp
 
 

@@ -327,6 +327,99 @@ def _case_object_numbers():
                    ("p", dt.BOOLEAN, _objects([True, False, True])))]
 
 
+def _step(x, towards):
+    return float(np.nextafter(x, towards))
+
+
+#: what Arrow's cast prints otherwise than `repr` (integral values, the
+#: signed zeros, under 1e-4, from 1e10 up to 1e16), the neighbours of
+#: both edges of the band in which they print alike, and the ends of the
+#: doubles
+_FLOAT_EDGES = [
+    100.0, -100.0, 1.0, 7.0, 0.0, -0.0, 2.0 ** 53, 2.0 ** 53 + 2, 1e15,
+    -1e15, 1e16, -1e16, 999999999999999.9, 1e15 + 0.5, 1e22, 1e23, 1e100,
+    1e-4, _step(1e-4, 0), _step(1e-4, 1), 9.999e-05, 1.5e-07, 1e-5, 1e-10,
+    1e10, _step(1e10, 0), _step(1e10, 1e11), 15000000000.5, 9999999999.5,
+    123456789012.25, 5e-324, -5e-324, 2.2250738585072014e-308,
+    1.7976931348623157e308, -1.7976931348623157e308, math.inf, -math.inf,
+    math.nan, 0.1, 0.30000000000000004, 1 / 3, 2 / 3, 97.35199999999999]
+
+
+def _case_float_edges():
+    f64 = np.array(_FLOAT_EDGES + [-v for v in _FLOAT_EDGES])
+    return [_batch(("f64", dt.FLOAT64, f64))]
+
+
+def _case_float_edges_nulls():
+    """NULLs beside them, over data of every kind (a NULL's slot holds
+    whatever the engine left there)."""
+    f64 = np.array(_FLOAT_EDGES)
+    n = len(f64)
+    return [_batch(("even", dt.FLOAT64, f64, _alternate(n)),
+                   ("odd", dt.FLOAT64, f64, ~_alternate(n)),
+                   ("third", dt.FLOAT64, f64[::-1].copy(),
+                    np.arange(n) % 3 != 0),
+                   ("no_nan", dt.FLOAT64, np.where(f64 != f64, 1.5, f64),
+                    np.arange(n) % 5 != 0))]
+
+
+def _case_f32_born():
+    """Aggregates come off the device as f32 and are widened: 16-17
+    digits each, which is most of what a dashboard reads. And a FLOAT32
+    column, whose text is its double's."""
+    rng = np.random.default_rng(38)
+    with np.errstate(over="ignore"):
+        f32 = np.concatenate([
+            rng.random(300) * 100, rng.random(100), rng.random(100) * 1e6,
+            10.0 ** rng.uniform(-7, 12, 200), [0.1, 100.0, 1e-4, 1e10, 3e38,
+                                               1e-45, math.inf, math.nan],
+        ]).astype(np.float32)
+    return [_batch(("widened", dt.FLOAT64, f32.astype(np.float64)),
+                   ("f32", dt.FLOAT32, f32),
+                   ("f32_nulls", dt.FLOAT32, f32, _alternate(len(f32))))]
+
+
+#: strings `json.dumps` prints as they are between two quotes
+_PLAIN_STRINGS = ["", "host_0", "a b", "~!#$%&'()*+,-./:;<=>?@[]^_`{|}", "x" * 300]
+#: and strings it escapes: a quote, a backslash, control bytes, DEL, and
+#: what lies beyond ASCII
+_ESCAPED_STRINGS = ['"', "\\", "\n", "\x00", "\t\r\x1f", "\x7f", "é", "日本語", "☃",
+                    "\U0001f600", 'say "hi"', "C:\\dir\\file", "a\"b\\c\nd\x00"]
+
+
+def _case_strings_plain():
+    values = _PLAIN_STRINGS * 3
+    n = len(values)
+    return [_batch(("s", dt.STRING, _objects(values)),
+                   ("nulls", dt.STRING, _objects(values), _alternate(n)),
+                   ("none", dt.STRING, _objects(
+                       [None if i % 4 == 0 else v
+                        for i, v in enumerate(values)])))]
+
+
+def _case_strings_escaped():
+    values = _ESCAPED_STRINGS + _PLAIN_STRINGS
+    n = len(values)
+    return [_batch(("s", dt.STRING, _objects(values)),
+                   ("nulls", dt.STRING, _objects(values), ~_alternate(n)),
+                   ("none", dt.STRING, _objects(
+                       [None if i % 3 == 0 else v
+                        for i, v in enumerate(values)])),
+                   # one escaped string among plain ones: the whole
+                   # column goes through `json.dumps`
+                   ("one", dt.STRING, _objects(["plain"] * (n - 1) + ['"'])),
+                   ("i", dt.INT64, np.arange(n, dtype=np.int64)))]
+
+
+def _case_dashboard():
+    """A fleet panel's answer past the crossover, with NULLs and a NaN in
+    it, in two batches."""
+    first, second = _cpu_like(200, fields=10), _cpu_like(137, 200, fields=10)
+    second.columns[3].validity = _alternate(137)
+    second.columns[4].data[::7] = math.nan
+    return [first, second]
+
+
 CASES = {
     "integers": _case_integers,
     "floats": _case_floats,
@@ -341,9 +434,17 @@ CASES = {
     "cell_path": _case_cell_path,
     "binary": _case_binary,
     "object_numbers": _case_object_numbers,
+    "float_edges": _case_float_edges,
+    "float_edges_nulls": _case_float_edges_nulls,
+    "f32_born": _case_f32_born,
+    "strings_plain": _case_strings_plain,
+    "strings_escaped": _case_strings_escaped,
+    "dashboard": _case_dashboard,
 }
 #: cases of which some column takes the per-cell path
 CELL_PATH_CASES = {"cell_path", "binary", "object_numbers"}
+#: those of which a column takes it in JSON: a year is a number there
+JSON_CELL_PATH_CASES = {"cell_path", "object_numbers"}
 
 
 class RecordingSocket:
@@ -369,6 +470,14 @@ def slabs(request, monkeypatch):
     return slab
 
 
+@pytest.fixture(params=[1, 1 << 62], ids=["compiled", "columnar"])
+def route(request, monkeypatch):
+    """Every chunk on the compiled route whatever its length, then none:
+    the cases are a few rows each, and both routes print them."""
+    monkeypatch.setattr(columnar, "COMPILED_MIN_ROWS", request.param)
+    return columnar.COMPILED if request.param == 1 else columnar.COLUMNAR
+
+
 def _output(case):
     return Output.record_batches(CASES[case]())
 
@@ -378,7 +487,7 @@ def _output(case):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("case", CASES)
-def test_http_body_is_the_row_by_row_body(case, monkeypatch):
+def test_http_body_is_the_row_by_row_body(case, route, monkeypatch):
     monkeypatch.setattr(http.time, "perf_counter", lambda: 0.0)
     out = _output(case)
     if case == "binary":
@@ -389,25 +498,30 @@ def test_http_body_is_the_row_by_row_body(case, monkeypatch):
         return
     response = http.sql_response([out], 0.0)
     assert response.body == ref_http_body(out)
-    rows, cell_rows = columnar.json_rows(out.batches)
-    assert (cell_rows > 0) == (case in CELL_PATH_CASES)
-    for row in rows:
-        for v in row:
-            assert type(v) in (int, float, bool, str, bytes, type(None))
+    pieces, routes = columnar.json_rows_text(out.batches)
+    assert b"".join(pieces) == json.dumps(
+        [[None if v != v else v for v in r]
+         for r in ref_rows(out.batches)]).encode()
+    assert sum(routes.values()) == out.num_rows
+    if case in JSON_CELL_PATH_CASES:
+        assert set(routes) == {columnar.CELL}
+    elif out.num_rows:
+        assert set(routes) == {route}
 
 
 @pytest.mark.parametrize("binary", [False, True], ids=["text", "binary"])
 @pytest.mark.parametrize("case", CASES)
-def test_mysql_stream_is_the_row_by_row_stream(case, binary, slabs):
+def test_mysql_stream_is_the_row_by_row_stream(case, binary, slabs, route):
     out = _output(case)
     sock = RecordingSocket()
     conn = mysql._Connection(None, sock, 1)
     conn.io.seq = 1                      # a COM_QUERY's answer starts at 1
-    cell_rows = conn._send_output(out, binary, conn.io)
+    routes = conn._send_output(out, binary, conn.io)
     want = ref_mysql_stream(out, binary, 1)
     assert sock.stream == want
     assert conn.io.bytes_out == len(want)
-    assert (cell_rows > 0) == (case in CELL_PATH_CASES)
+    assert (columnar.CELL in routes) == (case in CELL_PATH_CASES)
+    assert sum(routes.values()) == out.num_rows
     # the discarded encoding of an EXPLAIN ANALYZE'd result: same count
     dropped = mysql.PacketIO(None)
     dropped.seq = 1
@@ -418,7 +532,7 @@ def test_mysql_stream_is_the_row_by_row_stream(case, binary, slabs):
 
 
 @pytest.mark.parametrize("case", CASES)
-def test_postgres_stream_is_the_row_by_row_stream(case, slabs):
+def test_postgres_stream_is_the_row_by_row_stream(case, slabs, route):
     out = _output(case)
     sock = RecordingSocket()
     conn = postgres._PgConnection(None, sock, 1)
@@ -526,7 +640,7 @@ def test_one_send_a_slab_and_the_span_counts_what_was_sent(
         wire, render_spans):
     out = Output.record_batches([_cpu_like(10_000, fields=10)])
     sock = RecordingSocket()
-    before = _render_rows_total(wire, "columnar")
+    before = _render_rows_total(wire, "compiled")
     if wire == "mysql":
         mysql._Connection(_Server(out), sock, 1).handle_query("SELECT 1")
     else:
@@ -538,8 +652,9 @@ def test_one_send_a_slab_and_the_span_counts_what_was_sent(
     (span,) = render_spans()
     assert span["attrs"]["bytes"] == sent
     assert span["attrs"]["rows"] == 10_000
-    assert span["attrs"]["path"] == "columnar"
-    assert _render_rows_total(wire, "columnar") - before == 10_000
+    # its ten float columns were printed by Arrow's cast
+    assert span["attrs"]["path"] == "compiled"
+    assert _render_rows_total(wire, "compiled") - before == 10_000
 
 
 def test_a_fallback_shows_on_the_span_and_the_counter(render_spans):
@@ -549,6 +664,224 @@ def test_a_fallback_shows_on_the_span_and_the_counter(render_spans):
     span = render_spans()[-1]
     assert span["attrs"]["path"] == "cell"
     assert _render_rows_total("http", "cell") - before == out.num_rows
+
+
+def _http_routes(out):
+    """(body, the `render` span's path, rows `greptime_render_rows_total`
+    gained by path) of one answer over HTTP."""
+    sink = _SpanSink()
+    held = telemetry._SPAN_SINK[0]
+    telemetry.set_span_sink(sink)
+    paths = (columnar.COMPILED, columnar.COLUMNAR, columnar.CELL)
+    before = {p: _render_rows_total("http", p) for p in paths}
+    try:
+        body = http.sql_response([out], 0.0).body
+    finally:
+        telemetry.set_span_sink(held)
+    gained = {p: _render_rows_total("http", p) - before[p] for p in paths}
+    spans = [s for s in sink.spans if s["name"] == "render"]
+    return body, [s["attrs"]["path"] for s in spans], {
+        p: n for p, n in gained.items() if n}
+
+
+def test_a_fleet_panel_takes_the_compiled_route_and_says_so(monkeypatch):
+    """48,000 x 12, double-groupby-all's answer: on the span, on the
+    counter, and on the `render` row under an EXPLAIN ANALYZE of it."""
+    monkeypatch.setattr(http.time, "perf_counter", lambda: 0.0)
+    out = Output.record_batches([_cpu_like(48_000, fields=10)])
+    body, paths, gained = _http_routes(out)
+    assert paths == ["compiled"]
+    assert gained == {"compiled": 48_000}
+    assert body == ref_http_body(out)
+
+    from greptimedb_tpu.common.exec_stats import ExecStats
+    from greptimedb_tpu.query.engine import stage_rows_output
+    explained = stage_rows_output(ExecStats(), ["plan"], out)
+    body, paths, gained = _http_routes(explained)
+    assert paths[0] == "compiled"          # the analysed result, discarded
+    rows = json.loads(body)["output"][0]["records"]["rows"]
+    stage, nrows, _, _, detail = rows[-1]
+    assert (stage, nrows) == ("render", 48_000)
+    assert "protocol=http" in detail and "path=compiled" in detail
+    assert gained["compiled"] == 48_000
+
+
+def test_a_small_answer_keeps_the_columnar_route():
+    """A host's panel: 12 rows. A dozen Arrow calls a column cost more
+    than `tolist` and `json.dumps` do for them."""
+    out = Output.record_batches([_cpu_like(12, fields=10)])
+    _, paths, gained = _http_routes(out)
+    assert paths == ["columnar"] and gained == {"columnar": 12}
+    assert 12 < columnar.COMPILED_MIN_ROWS <= 1000
+    at = columnar.COMPILED_MIN_ROWS
+    for n, want in ((at - 1, "columnar"), (at, "compiled")):
+        _, paths, _ = _http_routes(
+            Output.record_batches([_cpu_like(n, fields=2)]))
+        assert paths == [want]
+    for send in ("mysql", "postgres"):
+        for n, want in ((at - 1, "columnar"), (at, "compiled")):
+            chunks = columnar.text_chunks(
+                [_cpu_like(n, fields=2)],
+                {"mysql": columnar.MYSQL_TEXT,
+                 "postgres": columnar.POSTGRES_TEXT}[send])
+            assert [route for _, _, route in chunks] == [want]
+
+
+def test_a_chunk_boundary_leaves_the_body_as_it_was(monkeypatch):
+    """CHUNK_ROWS + 1 rows in two batches against the same rows in one:
+    three chunks there, two here, one text."""
+    monkeypatch.setattr(http.time, "perf_counter", lambda: 0.0)
+    n = columnar.CHUNK_ROWS + 1
+    whole = _cpu_like(n, fields=3)
+    cut = 5_000
+    halves = [whole.slice(0, cut), whole.slice(cut, n - cut)]
+    one = http.sql_response([Output.record_batches([whole])], 0.0).body
+    two = http.sql_response([Output.record_batches(halves)], 0.0).body
+    assert one == two == ref_http_body(Output.record_batches([whole]))
+    pieces, routes = columnar.json_rows_text([whole])
+    # "[", a chunk between its own "[" and "]", ", ", the row that is
+    # left, "]"
+    assert len(pieces) == 7 and routes == {"compiled": n - 1, "columnar": 1}
+
+
+def test_several_results_in_one_envelope(monkeypatch):
+    """`rows` are spliced where each result's belong, a column named
+    `"rows": null` or not."""
+    monkeypatch.setattr(http.time, "perf_counter", lambda: 0.0)
+    tricky = _batch(('"rows": null', dt.STRING, _objects(['"rows": null'])),
+                    ("rows", dt.INT64, np.array([1])))
+    outs = [Output.record_batches([tricky]), Output.rows(3),
+            Output.record_batches([_cpu_like(100)]),
+            Output.record_batches([])]
+    want = json.loads(ref_http_body(outs[0]))
+    want["output"] += [{"affectedrows": 3}]
+    want["output"] += json.loads(ref_http_body(outs[2]))["output"]
+    want["output"] += [{"records": {"schema": {"column_schemas": []},
+                                    "rows": []}}]
+    assert http.sql_response(outs, 0.0).body == json.dumps(want).encode()
+
+
+def test_a_chunk_too_long_for_arrow_takes_the_columnar_route(monkeypatch):
+    """Past 2 GiB of text in one chunk Arrow's join refuses (its strings
+    have 32-bit offsets); the rows then go through `json.dumps`."""
+    def refuse(*args, **kwargs):
+        raise columnar.pa.ArrowCapacityError(
+            "array cannot contain more than 2147483646 bytes")
+    monkeypatch.setattr(http.time, "perf_counter", lambda: 0.0)
+    monkeypatch.setattr(columnar.pc, "binary_join_element_wise", refuse)
+    out = Output.record_batches([_cpu_like(300, fields=2)])
+    assert out.num_rows >= columnar.COMPILED_MIN_ROWS
+    body, paths, gained = _http_routes(out)
+    assert body == ref_http_body(out)
+    assert paths == ["columnar"] and gained == {"columnar": 300}
+
+
+def test_the_process_is_busy_while_a_result_is_encoded(monkeypatch):
+    """The encoders give the interpreter lock up at every Arrow call, as
+    the engine's numpy calls do: a parser beside them has to give way
+    (`admission.give_way` asks `busy()`) until the body is whole."""
+    from greptimedb_tpu.common import process_list
+    seen = []
+    whole = columnar.json_rows_text
+
+    def watched(batches):
+        seen.append(process_list.REGISTRY.busy())
+        return whole(batches)
+    monkeypatch.setattr(http, "json_rows_text", watched)
+    assert not process_list.REGISTRY.busy()
+    http.sql_response([Output.record_batches([_cpu_like(300)])], 0.0)
+    assert seen == [True] and not process_list.REGISTRY.busy()
+    with pytest.raises(TypeError):
+        http.sql_response([_output("binary")], 0.0)
+    assert not process_list.REGISTRY.busy()
+
+
+def test_a_lone_surrogate_takes_the_columnar_route(monkeypatch):
+    """`json.dumps` escapes it; Arrow's strings are UTF-8, which has no
+    bytes for it."""
+    monkeypatch.setattr(http.time, "perf_counter", lambda: 0.0)
+    out = Output.record_batches([_batch(
+        ("s", dt.STRING, _objects(["ok", "\ud800x"] * 150)),
+        ("f", dt.FLOAT64, np.arange(300) / 7))])
+    assert out.num_rows >= columnar.COMPILED_MIN_ROWS
+    body, paths, gained = _http_routes(out)
+    assert body == ref_http_body(out)
+    assert paths == ["columnar"] and gained == {"columnar": 300}
+
+
+# ---------------------------------------------------------------------------
+# the floats' text: Arrow's cast inside the band, `repr` outside it
+# ---------------------------------------------------------------------------
+
+def _random_doubles(seed):
+    """Every exponent a double has (random bit patterns, NaNs and
+    infinities among them), magnitudes of every decade from 1e-6 to 1e18,
+    and integral values."""
+    rng = np.random.default_rng(seed)
+    bits = rng.integers(0, 2 ** 64, 20_000, dtype=np.uint64).view(np.float64)
+    decades = 10.0 ** rng.uniform(-6, 18, 24_000) \
+        * rng.choice([-1.0, 1.0], 24_000)
+    whole = np.rint(10.0 ** rng.uniform(0, 17, 4_000))
+    return np.concatenate([bits, decades, whole])
+
+
+@pytest.mark.parametrize("wire", ["http", "mysql", "postgres"])
+def test_random_doubles_print_as_repr_prints_them(wire, monkeypatch):
+    values = _random_doubles({"http": 381, "mysql": 382, "postgres": 383}[wire])
+    n = 6_000
+    out = Output.record_batches([_batch(*[
+        (f"f{k}", dt.FLOAT64, values[k * n:(k + 1) * n].copy())
+        for k in range(len(values) // n)])])
+    if wire == "http":
+        monkeypatch.setattr(http.time, "perf_counter", lambda: 0.0)
+        _, paths, _ = _http_routes(out)
+        assert paths == ["compiled"]
+        assert http.sql_response([out], 0.0).body == ref_http_body(out)
+    elif wire == "mysql":
+        sock = RecordingSocket()
+        conn = mysql._Connection(None, sock, 1)
+        conn.io.seq = 1
+        assert conn._send_output(out, False, conn.io) == {"compiled": n}
+        assert sock.stream == ref_mysql_stream(out, False, 1)
+    else:
+        sock = RecordingSocket()
+        postgres._PgConnection(None, sock, 1).send_result("SELECT 1", out)
+        assert sock.stream == ref_pg_stream(out)
+
+
+def test_the_band_in_which_the_cast_prints_what_repr_prints():
+    """`float_texts` is `repr` everywhere; inside the band the cast alone
+    makes it, and each edge is where the two part."""
+    values = _random_doubles(384)
+    assert columnar.float_texts(values).to_pylist() == list(
+        map(repr, values.tolist()))
+    cast = columnar.pc.cast(columnar.pa.array(values),
+                            columnar.pa.string()).to_pylist()
+    inside = columnar.cast_prints_repr(values)
+    assert 10_000 < inside.sum() < len(values) - 10_000
+    assert [t for t, i in zip(cast, inside) if i] == [
+        repr(v) for v, i in zip(values.tolist(), inside) if i]
+
+    low, high = columnar._CAST_LOW, columnar._CAST_HIGH
+    just_inside = np.array([low, _step(low, 1), _step(high, 0), high - 0.5,
+                            -low, -_step(high, 0), 0.5, 99.99])
+    just_outside = np.array([_step(low, 0), high + 0.5, _step(high, 2 * high),
+                             -_step(low, 0), -(high + 0.5), 100.0, -0.0,
+                             math.nan, math.inf])
+    assert columnar.cast_prints_repr(just_inside).all()
+    assert not columnar.cast_prints_repr(just_outside).any()
+    # and the band could be no wider: just outside it the cast prints
+    # something else
+    cast = columnar.pc.cast(columnar.pa.array(just_outside[:7]),
+                            columnar.pa.string()).to_pylist()
+    assert all(t != repr(v) for t, v in zip(cast, just_outside.tolist()))
+
+    # a NULL's slot is nobody's to read: whatever is there, the others
+    # print as they would
+    texts = columnar.float_texts(
+        np.array([100.0, 2.5, 3.0, math.nan]),
+        unread=np.array([True, False, False, True])).to_pylist()
+    assert texts[1:3] == ["2.5", "3.0"]
 
 
 def test_to_pylist_keeps_its_types():
