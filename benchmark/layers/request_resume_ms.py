@@ -1,0 +1,14 @@
+"""Layer: protocol servers. The `request.resume` stage row of a statement
+sent over HTTP: the hand-off back, from the last line of the submitted
+function on its executor thread to the handler's next line on the event
+loop, after `total` and before `render`
+(`servers/http.py:RequestPhases`, `HttpServer._offload`). Mean over
+families of family means; a family sent over MySQL has no such row (its
+statement runs on the connection's own thread) and is left out of the
+mean. None for a program without the row. EXPLAIN ANALYZE."""
+
+from benchlib.spanlib import mean_span_ms
+
+
+def read(run):
+    return mean_span_ms(run, "request.resume")

@@ -15,6 +15,9 @@ Stage vocabulary (shared with the scan/ingest profilers):
   ``device-resident`` / ``streamed-cold`` / ``aggregate-pushdown``
 - streamed scan: ``plan``, ``decode_reduce``, ``device_fetch``,
   ``fold`` (+ counters lean_slices / merged_slices / dedup_skip_slices)
+- the request's own frame, over HTTP, outside ``total``:
+  ``request.read`` and ``request.queue`` ahead of ``parse``,
+  ``request.resume`` between ``total`` and ``render`` (servers/http.py)
 - front: ``parse`` (the statement text, outside ``total``), ``plan``
   (analyze, resolve, rewrite checks, the aggregate plan, the dispatch
   decision)
@@ -29,10 +32,23 @@ Stage vocabulary (shared with the scan/ingest profilers):
 Every timed row is a span: `stage()` keeps the wall clock of its first
 entry (``t0_ns=`` at the end of the row's detail — the clock a device
 trace is anchored to) and is open as a profiler annotation of the same
-name (telemetry.annotation). A row named ``<parent>.<part>`` lies inside
-its parent's interval; rows without a dot that lie inside ``total`` do
-not overlap one another. The ``total`` row names the statement's
-``trace_id``, the identifier its telemetry spans carry.
+name (telemetry.annotation). In an analysed statement (EXPLAIN ANALYZE:
+a collector made with ``cpu=True``) ``cpu_ms=`` goes ahead of
+``t0_ns=``: the CPU time of the thread that ran the row
+(`time.thread_time_ns`), so that elapsed − cpu is what the thread stood
+off a processor for: asleep for the device in a row that waits for it
+(``reduce.fetch``), otherwise waiting for the interpreter lock or the
+scheduler. Nobody reads the rows of a plain statement, so its collector
+does not read that clock (a system call; 5.5 us a read on the chip's
+host and more beside other threads, where the wall clocks take 0.07).
+No ``cpu_ms`` either on a row recorded without a `Timed` (a pool
+worker's slices) and on those timed before the statement was known to
+be analysed (``parse``, the request's hand-offs). A row named
+``<parent>.<part>`` lies inside its parent's interval (``request.*`` are
+parts of the request, which has no row); rows without a dot that lie
+inside ``total`` do not overlap one another. The ``total`` row names the
+statement's ``trace_id``, the identifier its telemetry spans carry, and
+the CPU time of the statement's own thread.
 
 The collector is installed per top-level query (`collect()`), is
 thread-safe (streamed slices report from pool workers), and a missing
@@ -69,27 +85,42 @@ _tls = threading.local()
 #: shared by both sides of the protocol so they cannot drift
 EXEC_STATS_WIRE_KEY = b"gdb.exec_stats"
 
+#: the rows that lie before `total`, in their order: over HTTP the
+#: request's way from the socket to the statement's thread
+#: (servers/http.py), then the statement text
+BEFORE_TOTAL = ("request.read", "request.queue", "parse")
+
 
 class Timed:
     """One timed interval of the program: its start on the wall clock
     (`t0_ns`), its length on the monotonic clock (`elapsed_s`), and a
-    profiler annotation of the same name while it is open."""
+    profiler annotation of the same name while it is open. With `cpu`
+    also the CPU time its thread used meanwhile (`cpu_s`): two reads of
+    the thread's CPU clock, each a system call, for an interval that
+    begins and ends on one thread."""
 
-    __slots__ = ("name", "t0_ns", "elapsed_s", "_t0", "_annotation")
+    __slots__ = ("name", "t0_ns", "elapsed_s", "cpu_s", "_t0", "_cpu0",
+                 "_annotation")
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, cpu: bool = False):
         self.name = name
         self.t0_ns: Optional[int] = None
         self.elapsed_s = 0.0
+        self.cpu_s: Optional[float] = None
+        self._cpu0: Optional[int] = 0 if cpu else None
 
     def __enter__(self) -> "Timed":
         self._annotation = annotation(self.name)
         self._annotation.__enter__()
         self.t0_ns = time.time_ns()
         self._t0 = time.perf_counter()
+        if self._cpu0 is not None:
+            self._cpu0 = time.thread_time_ns()
         return self
 
     def __exit__(self, *exc: object) -> None:
+        if self._cpu0 is not None:
+            self.cpu_s = (time.thread_time_ns() - self._cpu0) / 1e9
         self.elapsed_s = time.perf_counter() - self._t0
         self._annotation.__exit__(*exc)
 
@@ -104,24 +135,36 @@ class StageStat:
     #: wall clock (unix ns) of the stage's first timed entry; None for a
     #: row that only counts
     t0_ns: Optional[int] = None
+    #: CPU seconds of the threads that timed the row; None for a row
+    #: whose thread's CPU clock nobody read
+    cpu_s: Optional[float] = None
 
     def detail_str(self, lead: str = "") -> str:
-        """`k=v, ...`, after `lead` if given, ending with `t0_ns=`."""
+        """`k=v, ...`, after `lead` if given, ending with `cpu_ms=` and
+        `t0_ns=`, in that order."""
         parts = [lead] if lead else []
         parts += [f"{k}={v}" for k, v in self.detail.items()]
+        if self.cpu_s is not None:
+            parts.append(f"cpu_ms={self.cpu_s * 1e3:.3f}")
         if self.t0_ns is not None:
             parts.append(f"t0_ns={self.t0_ns}")
         return ", ".join(parts)
 
 
 class ExecStats:
-    """Accumulates per-stage counters for one statement execution."""
+    """Accumulates per-stage counters for one statement execution. With
+    `cpu` every timed row and `total` also read their thread's CPU time:
+    for a collector whose rows somebody will read (EXPLAIN ANALYZE)."""
 
-    def __init__(self):
+    def __init__(self, cpu: bool = False):
+        self.cpu = cpu
         self._lock = threading.Lock()
         self.stages: "OrderedDict[str, StageStat]" = OrderedDict()
         self.dispatch: Optional[str] = None
         self.total_s: float = 0.0
+        #: CPU seconds of the statement's thread inside `collect()`
+        #: (with `cpu`)
+        self.total_cpu_s: Optional[float] = 0.0 if cpu else None
         #: the statement's trace (set by collect() from the active span)
         self.trace_id: Optional[str] = None
         #: node label -> {"stats": ExecStats, "wall_ms": float} — one
@@ -134,7 +177,7 @@ class ExecStats:
     # ---- recording ----
     def record(self, stage: str, *, rows: int = 0, files: int = 0,
                elapsed_s: float = 0.0, t0_ns: Optional[int] = None,
-               **detail) -> None:
+               cpu_s: Optional[float] = None, **detail) -> None:
         with self._lock:
             st = self.stages.get(stage)
             if st is None:
@@ -142,6 +185,8 @@ class ExecStats:
             st.rows += int(rows)
             st.files += int(files)
             st.elapsed_s += float(elapsed_s)
+            if cpu_s is not None:
+                st.cpu_s = (st.cpu_s or 0.0) + float(cpu_s)
             if st.t0_ns is None and t0_ns is not None:
                 st.t0_ns = int(t0_ns)
             for k, v in detail.items():
@@ -157,14 +202,15 @@ class ExecStats:
 
     @contextlib.contextmanager
     def stage(self, name: str, **detail) -> Iterator[None]:
-        t = Timed(name)
+        t = Timed(name, self.cpu)
         try:
             with t:
                 # the row takes its place now, ahead of its parts
                 self.record(name, t0_ns=t.t0_ns)
                 yield
         finally:
-            self.record(name, elapsed_s=t.elapsed_s, **detail)
+            self.record(name, elapsed_s=t.elapsed_s, cpu_s=t.cpu_s,
+                        **detail)
 
     def set_dispatch(self, decision: str) -> None:
         """First decision wins: nested subqueries must not overwrite the
@@ -197,6 +243,8 @@ class ExecStats:
                     "stage": st.stage, "rows": st.rows, "files": st.files,
                     "elapsed_ms": round(st.elapsed_s * 1e3, 3),
                     "t0_ns": st.t0_ns,
+                    "cpu_ms": None if st.cpu_s is None
+                    else round(st.cpu_s * 1e3, 3),
                     "detail": {k: _json_safe(v)
                                for k, v in st.detail.items()},
                 } for st in self.stages.values()],
@@ -208,10 +256,12 @@ class ExecStats:
         if d.get("dispatch"):
             self.set_dispatch(d["dispatch"])
         for st in d.get("stages", ()):
+            cpu_ms = st.get("cpu_ms")
             self.record(st.get("stage", "?"), rows=st.get("rows", 0),
                         files=st.get("files", 0),
                         elapsed_s=float(st.get("elapsed_ms", 0.0)) / 1e3,
                         t0_ns=st.get("t0_ns"),
+                        cpu_s=None if cpu_ms is None else cpu_ms / 1e3,
                         **(st.get("detail") or {}))
         with self._lock:
             self.remote_total_ms += float(d.get("total_ms", 0.0))
@@ -321,10 +371,10 @@ class ExecStats:
 
     def rows_table(self, plan_text: Optional[str] = None,
                    out_rows: int = 0) -> Dict[str, List]:
-        """Column dict for the EXPLAIN ANALYZE per-stage batch: `parse`
-        (outside `total`), `plan` leading with `plan_text` when the
-        caller has one, the dispatch decision, the stages in recording
-        order, `total`."""
+        """Column dict for the EXPLAIN ANALYZE per-stage batch: what
+        came before `total` (`request.read`, `request.queue`, `parse`),
+        `plan` leading with `plan_text` when the caller has one, the
+        dispatch decision, the stages in recording order, `total`."""
         cols: Dict[str, List] = {"stage": [], "rows": [], "files": [],
                                  "elapsed_ms": [], "detail": []}
 
@@ -337,11 +387,11 @@ class ExecStats:
             cols["detail"].append(detail)
 
         with self._lock:
-            lead = {"parse"}
-            parse = self.stages.get("parse")
-            if parse is not None:
-                add("parse", 0, 0, parse.elapsed_s * 1e3,
-                    parse.detail_str())
+            lead = set(BEFORE_TOTAL)
+            for name in BEFORE_TOTAL:
+                st = self.stages.get(name)
+                if st is not None:
+                    add(name, 0, 0, st.elapsed_s * 1e3, st.detail_str())
             if plan_text is not None:
                 lead.add("plan")
                 plan = self.stages.get("plan") or StageStat("plan")
@@ -363,8 +413,9 @@ class ExecStats:
                     _add_node_rows(add, node_items)
             if node_items and not nodes_emitted:
                 _add_node_rows(add, node_items)
-            add("total", 0, 0, self.total_s * 1e3,
-                f"trace_id={self.trace_id}" if self.trace_id else "")
+            total = StageStat("total", cpu_s=self.total_cpu_s, detail={
+                "trace_id": self.trace_id} if self.trace_id else {})
+            add("total", 0, 0, self.total_s * 1e3, total.detail_str())
         return cols
 
 
@@ -437,9 +488,12 @@ def collect(stats: Optional[ExecStats] = None) -> Iterator[ExecStats]:
     if entry is not None and entry.stats is None:
         entry.stats = s
     t0 = time.perf_counter()
+    cpu0 = time.thread_time_ns() if s.cpu else 0
     try:
         yield s
     finally:
+        if s.cpu:
+            s.total_cpu_s += (time.thread_time_ns() - cpu0) / 1e9
         s.total_s += time.perf_counter() - t0
         _tls.stats = prev
 

@@ -588,7 +588,7 @@ def _observe(name: str, seconds: float) -> None:
     h.observe(seconds)
 
 
-def increment_counter(name: str, value: int = 1, **labels: object) -> None:
+def increment_counter(name: str, value: float = 1, **labels: object) -> None:
     """`greptime_<name>_total{**labels}`; a counter's label NAMES are
     those of its first increment."""
     if metrics_suppressed():
@@ -610,13 +610,20 @@ def increment_counter(name: str, value: int = 1, **labels: object) -> None:
 @contextlib.contextmanager
 def timer(name: str) -> Iterator[None]:
     """reference `timer!` macro: records elapsed seconds on exit (and
-    shows as `name` on the profiler's host timeline, see annotation)."""
+    shows as `name` on the profiler's host timeline, see annotation).
+    Beside the histogram goes `greptime_<name>_cpu_seconds_total`, the
+    CPU time of the thread inside the timer: seconds less CPU seconds
+    is what it stood off a processor for (a lock, the interpreter's, a
+    sleep, the disk)."""
     t0 = time.perf_counter()
+    cpu0 = time.thread_time()
     try:
         with annotation(name):
             yield
     finally:
+        cpu = time.thread_time() - cpu0
         _observe(name, time.perf_counter() - t0)
+        increment_counter(f"{name}_cpu_seconds", cpu)
 
 
 # ---------------------------------------------------------------------------

@@ -263,7 +263,7 @@ class UntracedHandler(Rule):
     id = "GL07"
     title = ("servers/ RPC handlers must join the caller's trace: Flight "
              "do_get/do_put/do_action need remote_context, HTTP handlers "
-             "moving work off-thread need _traced_call")
+             "moving work off-thread need _offload")
 
     SCOPE = ("servers", "selftest")
     FLIGHT_METHODS = ("do_get", "do_put", "do_action", "do_exchange")
@@ -302,12 +302,13 @@ class UntracedHandler(Rule):
                         for n in ast.walk(stmt))
                     if uses_executor and not self._refs(
                             stmt, set(self.TRACE_NAMES),
-                            {"_traced_call", "_traced"}):
+                            {"_offload", "_traced"}):
                         yield mod.finding(
                             self.id, stmt,
                             f"HTTP handler {cls.name}.{stmt.name} ships "
-                            f"work to an executor without _traced_call — "
-                            f"the worker detaches from the request trace")
+                            f"work to an executor without _offload — the "
+                            f"worker detaches from the request trace and "
+                            f"the hand-offs go untimed")
 
 
 class UnlockedModuleMutation(Rule):

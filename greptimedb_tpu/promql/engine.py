@@ -317,7 +317,7 @@ class PromqlEngine:
         expression, evaluation, its result)."""
         from ..common import exec_stats
         from ..query.engine import _record_parse
-        stats = exec_stats.ExecStats()
+        stats = exec_stats.ExecStats(cpu=True)
         with exec_stats.collect(stats):
             _record_parse(ctx)
             expr, ev = self._plan(stmt, ctx)

@@ -178,7 +178,7 @@ class QueryEngine:
         stage names the storage profilers use, so this table, the
         tracing spans and Region.last_scan_profile agree (reference:
         DataFusion's EXPLAIN ANALYZE over operator metrics)."""
-        stats = exec_stats.ExecStats()
+        stats = exec_stats.ExecStats(cpu=True)
         analyzed = None
         with exec_stats.collect(stats):
             _record_parse(ctx)
@@ -1036,12 +1036,16 @@ def stage_rows_output(stats: "exec_stats.ExecStats", plan_lines: List[str],
 
 
 def _record_parse(ctx: QueryContext) -> None:
-    """The `parse` row of the statement's collector: what do_query timed
-    around the statement text, before (and outside) `total`."""
-    parsed = ctx.parse_span
-    if parsed is not None:
-        exec_stats.record("parse", elapsed_s=parsed.elapsed_s,
-                          t0_ns=parsed.t0_ns)
+    """The rows of the statement's collector that were timed before
+    (and outside) `total`: over HTTP the request's `request.read` and
+    `request.queue` (servers/http.py), then `parse`, what do_query timed
+    around the statement text."""
+    phases = ctx.request_phases
+    before = (phases.read, phases.queue) if phases is not None else ()
+    for timed in (*before, ctx.parse_span):
+        if timed is not None:
+            exec_stats.record(timed.name, elapsed_s=timed.elapsed_s,
+                              cpu_s=timed.cpu_s, t0_ns=timed.t0_ns)
 
 
 def _conjunct_list(e):

@@ -6,6 +6,15 @@ Reference behavior: src/servers/src/http.rs:434-578 — routes /v1/sql,
 Prometheus-compatible query API (src/servers/src/prom.rs) mounted under
 /api/v1. Responses use the GreptimeDB JSON envelope
 {"code": 0, "output": [...], "execution_time_ms": n}.
+
+A request's hand-offs are timed where they happen (`RequestPhases`): the
+event loop reads it (`read`), it waits for an executor thread (`queue`),
+the thread's answer waits for the loop (`resume`), the loop writes the
+response (`write`). Each is an `exec_stats.Timed`, observed on
+`greptime_http_phase_seconds{route, phase}`; a statement also shows the
+first three as the stage rows `request.read`, `request.queue` and
+`request.resume` around `parse` / `total` / `render`. How late the loop
+itself runs is `greptime_event_loop_lag_seconds`.
 """
 
 from __future__ import annotations
@@ -19,6 +28,8 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from aiohttp import web
 
+from ..common.exec_stats import Timed
+from ..common.telemetry import observe_latency, remote_context
 from ..errors import AuthError, GreptimeError, StatusCode
 from ..query.output import Output
 from ..session import Channel, QueryContext
@@ -59,11 +70,55 @@ def output_to_json(out: Output) -> Dict[str, Any]:
                         "rows": None}}
 
 
-def sql_response(outputs: List[Output], t0: float) -> web.Response:
+class RequestPhases:
+    """The hand-offs of one request, each an `exec_stats.Timed` named
+    `request.<phase>`; one record a request, kept on it under `PHASES`.
+    One phase is open at a time, or none while the executor thread
+    works: `read` from the record's making (the middleware's entry) to
+    the handler's first submit, or to its return where it submits
+    nothing; `queue` from a submit to the first line of the submitted
+    function on its thread; `resume` from that function's last line to
+    the handler's next line after its `await`; `write` while the
+    response goes out. A phase that ends is observed on
+    `greptime_http_phase_seconds{route, phase}`."""
+
+    __slots__ = ("route", "read", "queue", "resume", "write", "_open")
+
+    def __init__(self, route: Optional[str]):
+        #: the canonical route template; None where the path matched none
+        self.route = route
+        self.queue = self.resume = self.write = self._open = None
+        self.enter("read")
+
+    def enter(self, phase: Optional[str]) -> None:
+        """End the phase that is open, if one is, and open `phase`
+        (None: leave none open)."""
+        ended, self._open = self._open, None
+        if ended is not None:
+            ended.__exit__(None, None, None)
+            if self.route is not None:
+                observe_latency("http_phase", ended.elapsed_s,
+                                route=self.route,
+                                phase=ended.name.partition(".")[2])
+        if phase is not None:
+            self._open = Timed("request." + phase).__enter__()
+            setattr(self, phase, self._open)
+
+
+#: the key of a request's `RequestPhases`
+PHASES = "greptime.phases"
+
+#: seconds between two readings of the event loop's lag
+_LAG_TICK_S = 0.1
+
+
+def sql_response(outputs: List[Output], t0: float,
+                 request: Optional[web.Request] = None) -> web.Response:
     """The JSON envelope of a statement's results, made under the
     `render` span: the rows' text a column at a time
     (`columnar.json_rows_text`), then the envelope by `json.dumps` with
-    the rows spliced in."""
+    the rows spliced in. An analysed statement of `request` gets the
+    request's `resume` as a stage row ahead of `render`."""
     def encode(outs: List[Output], discard: bool):
         routes = RouteRows()
         rows_texts = []
@@ -83,7 +138,8 @@ def sql_response(outputs: List[Output], t0: float) -> web.Response:
         body = b"".join(pieces)
         return body, len(body), routes
 
-    return web.Response(body=render("http", outputs, encode),
+    resume = request[PHASES].resume if request is not None else None
+    return web.Response(body=render("http", outputs, encode, resume),
                         content_type="application/json", charset="utf-8")
 
 
@@ -166,6 +222,33 @@ class HttpServer:
     @web.middleware
     async def _error_middleware(self, request, handler):
         start = time.perf_counter()
+        resource = getattr(request.match_info.route, "resource", None)
+        phases = request[PHASES] = RequestPhases(
+            resource.canonical if resource is not None else None)
+        try:
+            response = await self._answered(request, handler, start)
+        except web.HTTPException:
+            phases.enter(None)
+            raise
+        # written from here, where aiohttp would write it once this
+        # returns (both calls do nothing the second time): the
+        # request's `write` phase
+        phases.enter("write")
+        try:
+            await response.prepare(request)
+            await response.write_eof()
+        except ConnectionError:
+            # the client has gone: aiohttp finds the same on its own
+            # attempt and drops the connection
+            logger.debug("client gone before %s was answered",
+                         request.path)
+        finally:
+            phases.enter(None)
+        return response
+
+    async def _answered(self, request, handler, start: float):
+        """The handler's response, or the error envelope of what it
+        raised."""
         try:
             return await self._observed(request, handler, start)
         except AuthError as e:
@@ -199,36 +282,51 @@ class HttpServer:
         """Per-route latency histogram (canonical route template, not
         the raw path, so /api/v1/label/{name}/values stays ONE series).
         Recorded in a finally so error responses — the requests an
-        operator most needs in the distribution — count too."""
+        operator most needs in the distribution — count too. From the
+        middleware's entry to the handler's return: the response's
+        write is the `write` phase's, not this histogram's."""
         try:
             return await handler(request)
         finally:
-            resource = getattr(request.match_info.route, "resource", None)
-            if resource is not None:
-                from ..common.telemetry import observe_latency
+            route = request[PHASES].route
+            if route is not None:
                 observe_latency("http_request",
-                                time.perf_counter() - start,
-                                route=resource.canonical)
+                                time.perf_counter() - start, route=route)
 
     def _ctx(self, request) -> QueryContext:
         self.user_provider.auth_http_basic(
             request.headers.get("Authorization"))
         db = request.query.get("db") or request.headers.get("x-greptime-db")
         catalog, schema = parse_db_param(db)
-        return QueryContext(catalog, schema, Channel.HTTP)
+        ctx = QueryContext(catalog, schema, Channel.HTTP)
+        ctx.request_phases = request[PHASES]
+        return ctx
 
-    def _traced_call(self, request, fn):
-        """Run `fn` (on the executor thread) under the request's W3C
+    @staticmethod
+    async def _offload(request, fn):
+        """`fn()` on an executor thread, awaited: the one way a handler
+        leaves the event loop. It runs under the request's W3C
         `traceparent` header, so external clients can stitch the whole
         statement — frontend span, datanode RPCs, slow-query log lines —
-        onto their own trace."""
+        onto their own trace; the two hand-offs, loop to thread and
+        back, are the request's `queue` and `resume` phases."""
         tp = request.headers.get("traceparent")
+        phases = request[PHASES]
 
         def run():
-            from ..common.telemetry import remote_context
-            with remote_context(tp):
-                return fn()
-        return run
+            phases.enter(None)
+            try:
+                with remote_context(tp):
+                    return fn()
+            finally:
+                phases.enter("resume")
+
+        phases.enter("queue")
+        try:
+            return await asyncio.get_running_loop().run_in_executor(
+                None, run)
+        finally:
+            phases.enter(None)
 
     async def _param(self, request, name: str) -> Optional[str]:
         if name in request.query:
@@ -258,12 +356,9 @@ class HttpServer:
             return web.json_response(
                 {"code": int(StatusCode.INVALID_ARGUMENTS),
                  "error": "missing 'sql' parameter"}, status=400)
-        loop = asyncio.get_running_loop()
-        outputs = await loop.run_in_executor(
-            None,
-            self._traced_call(request,
-                              lambda: self.frontend.do_query(sql, ctx)))
-        return sql_response(outputs, t0)
+        outputs = await self._offload(
+            request, lambda: self.frontend.do_query(sql, ctx))
+        return sql_response(outputs, t0, request)
 
     async def handle_promql(self, request):
         t0 = time.perf_counter()
@@ -277,12 +372,10 @@ class HttpServer:
                 {"code": int(StatusCode.INVALID_ARGUMENTS),
                  "error": "query/start/end/step are required"}, status=400)
         from ..sql.ast import Tql
-        loop = asyncio.get_running_loop()
-        out = await loop.run_in_executor(
-            None, self._traced_call(
-                request, lambda: self.frontend.execute_tql(
-                    Tql("eval", start, end, step, None, query), ctx)))
-        return sql_response([out], t0)
+        out = await self._offload(
+            request, lambda: self.frontend.execute_tql(
+                Tql("eval", start, end, step, None, query), ctx))
+        return sql_response([out], t0, request)
 
     # ---- coprocessor scripts (reference: /v1/scripts + /v1/run-script,
     # src/servers/src/http.rs:434-578 script routes) ----
@@ -304,11 +397,9 @@ class HttpServer:
                 {"code": int(StatusCode.INVALID_ARGUMENTS),
                  "error": "missing 'name' parameter"}, status=400)
         script = (await request.read()).decode()
-        loop = asyncio.get_running_loop()
         engine = self._script_engine()
-        await loop.run_in_executor(
-            None, self._traced_call(
-                request, lambda: engine.insert_script(name, script, ctx)))
+        await self._offload(
+            request, lambda: engine.insert_script(name, script, ctx))
         return web.json_response({"code": 0})
 
     async def handle_run_script(self, request):
@@ -317,12 +408,10 @@ class HttpServer:
         name = request.query.get("name")
         if request.query.get("db"):
             ctx.set_current_schema(request.query["db"])
-        loop = asyncio.get_running_loop()
         engine = self._script_engine()
         if name:
-            out = await loop.run_in_executor(
-                None, self._traced_call(
-                    request, lambda: engine.run(name, ctx=ctx)))
+            out = await self._offload(
+                request, lambda: engine.run(name, ctx=ctx))
         else:
             script = (await request.read()).decode()
             if not script:
@@ -330,17 +419,15 @@ class HttpServer:
                     {"code": int(StatusCode.INVALID_ARGUMENTS),
                      "error": "missing 'name' parameter or script body"},
                     status=400)
-            out = await loop.run_in_executor(
-                None, self._traced_call(
-                    request, lambda: engine.run(script, ctx=ctx,
-                                                is_script_text=True)))
-        return sql_response([out], t0)
+            out = await self._offload(
+                request, lambda: engine.run(script, ctx=ctx,
+                                            is_script_text=True))
+        return sql_response([out], t0, request)
 
     async def handle_influx_write(self, request):
         ctx = self._ctx_influx(request)
         precision = request.query.get("precision", "ns")
         body = (await request.read()).decode()
-        loop = asyncio.get_running_loop()
 
         def work():
             from ..common.admission import GATE
@@ -363,7 +450,7 @@ class HttpServer:
                         ctx=ctx)
                 return n
 
-        await loop.run_in_executor(None, self._traced_call(request, work))
+        await self._offload(request, work)
         return web.Response(status=204)
 
     def _ctx_influx(self, request) -> QueryContext:
@@ -389,7 +476,6 @@ class HttpServer:
     async def handle_opentsdb_put(self, request):
         ctx = self._ctx(request)
         raw = await request.read()
-        loop = asyncio.get_running_loop()
 
         def work():
             from ..common.admission import GATE
@@ -408,14 +494,12 @@ class HttpServer:
                         ctx=ctx)
                 return len(points)
 
-        n = await loop.run_in_executor(None,
-                                       self._traced_call(request, work))
+        n = await self._offload(request, work)
         return web.json_response({"success": n, "failed": 0}, status=200)
 
     async def handle_prom_write(self, request):
         ctx = self._ctx(request)
         body = await request.read()
-        loop = asyncio.get_running_loop()
 
         def work():
             from ..common.admission import GATE
@@ -429,13 +513,12 @@ class HttpServer:
                         timestamp_column=prom_mod.GREPTIME_TIMESTAMP,
                         ctx=ctx)
 
-        await loop.run_in_executor(None, self._traced_call(request, work))
+        await self._offload(request, work)
         return web.Response(status=204)
 
     async def handle_prom_read(self, request):
         ctx = self._ctx(request)
         body = await request.read()
-        loop = asyncio.get_running_loop()
 
         def work():
             queries = prom_mod.decode_read_request(body)
@@ -444,8 +527,7 @@ class HttpServer:
                 results.append(self._remote_read_query(q, ctx))
             return prom_mod.encode_read_response(results)
 
-        payload = await loop.run_in_executor(None,
-                                             self._traced_call(request, work))
+        payload = await self._offload(request, work)
         return web.Response(body=payload,
                             content_type="application/x-protobuf",
                             headers={"Content-Encoding": "snappy"})
@@ -516,9 +598,7 @@ class HttpServer:
                 "waterfall": trace_store.waterfall_rows(rows),
             }
 
-        loop = asyncio.get_running_loop()
-        tid, doc = await loop.run_in_executor(
-            None, self._traced_call(request, work))
+        tid, doc = await self._offload(request, work)
         if doc is None:
             return web.json_response(
                 {"code": int(StatusCode.INVALID_ARGUMENTS),
@@ -579,9 +659,7 @@ class HttpServer:
                 merged.extend(rows or [])
             return merged
 
-        loop = asyncio.get_running_loop()
-        rows = await loop.run_in_executor(
-            None, self._traced_call(request, work))
+        rows = await self._offload(request, work)
         from ..common import profiler as prof_mod
         if fmt == "folded":
             return web.Response(text=prof_mod.folded_text(rows),
@@ -688,7 +766,6 @@ class HttpServer:
     async def handle_flush(self, request):
         ctx = self._ctx(request)
         table_name = request.query.get("table")
-        loop = asyncio.get_running_loop()
 
         def work():
             cat = self.frontend.catalog
@@ -699,14 +776,12 @@ class HttpServer:
                 if t is not None:
                     t.flush()
 
-        await loop.run_in_executor(None,
-                                   self._traced_call(request, work))
+        await self._offload(request, work)
         return web.json_response({"code": 0})
 
     async def handle_compact(self, request):
         ctx = self._ctx(request)
         table_name = request.query.get("table")
-        loop = asyncio.get_running_loop()
 
         def work():
             cat = self.frontend.catalog
@@ -717,8 +792,7 @@ class HttpServer:
                 for region in getattr(t, "regions", {}).values():
                     region.compact()
 
-        await loop.run_in_executor(None,
-                                   self._traced_call(request, work))
+        await self._offload(request, work)
         return web.json_response({"code": 0})
 
     async def handle_failpoints(self, request):
@@ -802,7 +876,6 @@ class HttpServer:
             return web.json_response(
                 {"code": 4001, "error": "src or dst table not found"},
                 status=404)
-        loop = asyncio.get_running_loop()
 
         def work():
             total = 0
@@ -824,8 +897,7 @@ class HttpServer:
             return total
 
         try:
-            rows = await loop.run_in_executor(
-                None, self._traced_call(request, work))
+            rows = await self._offload(request, work)
         except Exception as e:  # noqa: BLE001 — surface as API error
             return web.json_response({"code": 1004, "error": str(e)},
                                      status=400)
@@ -878,7 +950,18 @@ class HttpServer:
                 self.port = self._runner.addresses[0][1]
             self._started.set()
 
+        def lag_tick(due: float) -> None:
+            """How late the loop ran this call: what a request waits
+            before the middleware sees it, and what a long step on the
+            loop's thread (a wide result's `render`) costs every other
+            connection."""
+            observe_latency("event_loop_lag",
+                            max(0.0, loop.time() - due))
+            due = loop.time() + _LAG_TICK_S
+            loop.call_at(due, lag_tick, due)
+
         loop.run_until_complete(boot())
+        loop.call_soon(lag_tick, loop.time())
         loop.run_forever()
 
     def shutdown(self) -> None:

@@ -7,7 +7,6 @@ endpoints. Query evaluation delegates to the PromQL engine.
 
 from __future__ import annotations
 
-import asyncio
 import time
 from typing import Dict, List
 
@@ -41,21 +40,20 @@ async def _eval(server, request, *, instant: bool):
                 return _error("bad_data", "start/end/step are required")
             step_ms = parse_prom_duration(step_raw)
         engine = server.frontend.promql_engine()
-        loop = asyncio.get_running_loop()
         explain = (await server._param(request, "explain")) in (
             "1", "true", "yes")
         if explain:
             # ?explain=1: render the plan the way SQL's EXPLAIN does —
             # the Prom expression tree plus the IR node each aggregate
             # lowered to (TpuAggregateExec / RawScan) and its dispatch
-            lines = await loop.run_in_executor(
-                None, lambda: engine.explain_lines(
+            lines = await server._offload(
+                request, lambda: engine.explain_lines(
                     query, start_ms, end_ms, step_ms, ctx))
             return web.json_response(
                 {"status": "success",
                  "data": {"resultType": "explain", "result": lines}})
-        result = await loop.run_in_executor(
-            None, lambda: engine.query_to_prom_json(
+        result = await server._offload(
+            request, lambda: engine.query_to_prom_json(
                 query, start_ms, end_ms, step_ms, ctx, instant=instant))
         return web.json_response({"status": "success", "data": result})
     except GreptimeError as e:

@@ -16,15 +16,18 @@ pays. The engine therefore hands the analysed statement's own `Output`
 along (`Output.analyzed`); it is encoded here with the same encoder, the
 bytes are discarded, and what that took is appended to the stage rows as
 `render` — after `total`, like PostgreSQL's EXPLAIN (ANALYZE, SERIALIZE).
-Plain statements never encode twice.
+Plain statements never encode twice. Over HTTP the row before it is
+`request.resume`: the statement's way back from its thread to the event
+loop, which the HTTP server timed (servers/http.py:RequestPhases).
 """
 
 from __future__ import annotations
 
-from typing import Callable, List, Tuple, TypeVar
+import time
+from typing import Callable, List, Optional, Tuple, TypeVar
 
 from ..common import process_list
-from ..common.exec_stats import StageStat
+from ..common.exec_stats import StageStat, Timed
 from ..common.telemetry import (continue_trace, increment_counter,
                                 observe_latency, span)
 from ..datatypes.record_batch import RecordBatch
@@ -39,45 +42,56 @@ T = TypeVar("T")
 Encoder = Callable[[List[Output], bool], Tuple[T, int, RouteRows]]
 
 
-def render(protocol: str, outputs: List[Output], encode: Encoder) -> T:
+def render(protocol: str, outputs: List[Output], encode: Encoder,
+           resumed: Optional[Timed] = None) -> T:
     """Encode `outputs` (one response) for the wire and return what
-    `encode` returns for them."""
+    `encode` returns for them. `resumed`: what the server timed between
+    the statement's end on its thread and this call."""
     for out in outputs:
         if out.analyzed is not None:
             analyzed, out.analyzed = out.analyzed, None
             analyzed.trace = out.trace
-            _, sp = _encode(protocol, [analyzed], encode, True)
-            _append_stage_row(out, StageStat(
-                "render", rows=analyzed.num_rows,
+            _, sp, cpu_s = _encode(protocol, [analyzed], encode, True)
+            rows = [] if resumed is None else [StageStat(
+                resumed.name, elapsed_s=resumed.elapsed_s,
+                cpu_s=resumed.cpu_s, t0_ns=resumed.t0_ns)]
+            _append_stage_rows(out, rows + [StageStat(
+                "render", rows=analyzed.num_rows, cpu_s=cpu_s,
                 elapsed_s=sp["elapsed_ms"] / 1e3, t0_ns=sp["start_unix_ns"],
                 detail={"protocol": protocol,
                         "bytes": sp["attrs"]["bytes"],
-                        "path": sp["attrs"]["path"]}))
-    value, _ = _encode(protocol, outputs, encode, False)
+                        "path": sp["attrs"]["path"]})])
+    value, _, _ = _encode(protocol, outputs, encode, False)
     return value
 
 
 def _encode(protocol: str, outputs: List[Output], encode: Encoder,
             discard: bool):
+    """-> (what `encode` returns, the `render` span, the CPU seconds
+    of this thread inside it: read for a discarded encoding, which
+    becomes a stage row, and None otherwise)."""
     rows = sum(o.num_rows for o in outputs if o.is_batches)
     with continue_trace(outputs[-1].trace if outputs else None), \
             span("render", protocol=protocol, rows=rows) as sp, \
             process_list.REGISTRY.rendering():
+        cpu0 = time.thread_time_ns() if discard else 0
         value, sp["attrs"]["bytes"], routes = encode(outputs, discard)
+        cpu_s = (time.thread_time_ns() - cpu0) / 1e9 if discard else None
         sp["attrs"]["path"] = route_of(routes)
     observe_latency("render", sp["elapsed_ms"] / 1e3, protocol=protocol)
     for path, n in routes.items():
         increment_counter("render_rows", n, protocol=protocol, path=path)
-    return value, sp
+    return value, sp, cpu_s
 
 
-def _append_stage_row(out: Output, st: StageStat) -> None:
-    """One more row under an EXPLAIN ANALYZE stage table."""
+def _append_stage_rows(out: Output, stats: List[StageStat]) -> None:
+    """More rows under an EXPLAIN ANALYZE stage table."""
     batch = out.batches[0]
     cols = batch.to_pydict()
-    for name, v in (("stage", st.stage), ("rows", st.rows),
-                    ("files", st.files),
-                    ("elapsed_ms", st.elapsed_s * 1e3),
-                    ("detail", st.detail_str())):
-        cols[name].append(v)
+    for st in stats:
+        for name, v in (("stage", st.stage), ("rows", st.rows),
+                        ("files", st.files),
+                        ("elapsed_ms", st.elapsed_s * 1e3),
+                        ("detail", st.detail_str())):
+            cols[name].append(v)
     out.batches = [RecordBatch.from_pydict(batch.schema, cols)]

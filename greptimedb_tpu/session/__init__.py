@@ -36,6 +36,11 @@ class QueryContext:
         #: (exec_stats.Timed, set by do_query); the query engine reports
         #: it as the `parse` stage row
         self.parse_span = None
+        #: the hand-offs of the HTTP request that carries the statement
+        #: (servers/http.py:RequestPhases); its `read` and `queue` are
+        #: the stage rows ahead of `parse`. None on the wires that run a
+        #: statement on the connection's own thread
+        self.request_phases = None
 
     def set_current_schema(self, schema: str) -> None:
         self.current_schema = schema

@@ -86,14 +86,15 @@ _VOLATILE_COLUMNS = {"elapsed_ms": "<elapsed>", "watermark": "<watermark>",
 
 #: wall-clock fragments inside EXPLAIN ANALYZE detail strings: the
 #: scatter's slowest-node latency, the per-node latency vector, the
-#: node rows' node-vs-network split, every timed row's wall-clock start
-#: and the total row's trace id
+#: node rows' node-vs-network split, every timed row's thread CPU time
+#: and wall-clock start, and the total row's trace id
 import re as _re  # noqa: E402
 
 _VOLATILE_DETAIL = [
     (_re.compile(r"slowest_node_ms=[0-9.]+"), "slowest_node_ms=<ms>"),
     (_re.compile(r"node_ms=[0-9A-Za-z:./#-]+"), "node_ms=<ms>"),
     (_re.compile(r"network_ms=[0-9.]+"), "network_ms=<ms>"),
+    (_re.compile(r"cpu_ms=[0-9.]+"), "cpu_ms=<ms>"),
     (_re.compile(r"t0_ns=[0-9]+"), "t0_ns=<ns>"),
     (_re.compile(r"trace_id=[0-9a-f]+"), "trace_id=<trace>"),
 ]

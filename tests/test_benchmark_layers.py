@@ -389,3 +389,217 @@ def test_the_durable_configuration_is_tsbs_cpu_4000_plus_the_fsync():
     args = build_parser().parse_args(
         ["standalone", "start", *config["server_options"]])
     assert args.wal_sync_on_write is True
+
+
+# ---------------------------------------------------------------------------
+# the readers of a request's frame, of the threads' CPU time and of the
+# event loop's lag (ISSUE 39)
+# ---------------------------------------------------------------------------
+
+def with_cpu(row, share):
+    """A span row as the program reports it since ISSUE 39: `cpu_ms=`
+    (`share` of its elapsed time) ahead of `t0_ns=`."""
+    lead, sep, start = row["detail"].rpartition("t0_ns=")
+    assert sep, row
+    cpu = f"cpu_ms={row['elapsed_ms'] * share:.3f}, "
+    return dict(row, detail=f"{lead}{cpu}t0_ns={start}")
+
+
+def framed_run(cpu=True, frame=True):
+    """`query_run` as the program of ISSUE 39 reports it. Every span
+    with its thread's CPU time: 3/4 of its elapsed time, `reduce.fetch`
+    (asleep for the device) a tenth, `reduce.launch` half, `total` 60%,
+    `render` 90%. Family a went over HTTP and has the request's rows
+    (read 0.25 ms, queue 0.5, resume 1.5), b over MySQL and has none.
+    /metrics: 30 `/v1/sql` responses written in 1.2 s, 500 ticks of the
+    loop 0.3 s late in all."""
+    run = query_run()
+    for rec in run["statements"]:
+        stages, scale = rec["stages"], rec["client_ms"] / 140.0
+        if cpu:
+            shares = {"reduce.fetch": 0.1, "reduce.launch": 0.5,
+                      "render": 0.9}
+            for name, row in stages.items():
+                if "t0_ns=" in row["detail"]:
+                    stages[name] = with_cpu(row, shares.get(name, 0.75))
+            stages["total"]["detail"] += f", cpu_ms={60.0 * scale:.3f}"
+        if frame and rec["family"] == "a":
+            rec["stages"] = {
+                "request.read": stage(0.25, -0.75),
+                "request.queue": stage(0.5, -0.5), **stages,
+                "request.resume": stage(1.5, 102),
+                "render": stages["render"]}
+    write = 'greptime_http_phase_seconds_{}{{phase="write",route="/v1/sql"}}'
+    lag = "greptime_event_loop_lag_seconds_{}"
+    run["counters"] = {"before": {}, "after": {}}
+    if frame:
+        run["counters"] = {
+            "before": {write.format("sum"): 0.2, write.format("count"): 10.0,
+                       lag.format("sum"): 0.5, lag.format("count"): 100.0},
+            "after": {write.format("sum"): 1.4, write.format("count"): 40.0,
+                      lag.format("sum"): 0.8, lag.format("count"): 600.0}}
+    return run
+
+
+def phased_ingest_run():
+    """`ingest_run` with the write route's phases (3 s of body reads, 1 s
+    queued, 0.5 s resuming over the 100 batches) and the timers' CPU
+    seconds (the parser computes for 45 of its 50 s, `Region.write` for
+    20 of its 100)."""
+    run = ingest_run()
+    before, after = run["counters"]["before"], run["counters"]["after"]
+    for phase, seconds in (("read", 3.0), ("queue", 1.0), ("resume", 0.5)):
+        name = ('greptime_http_phase_seconds_sum{phase="%s",'
+                'route="/v1/influxdb/write"}' % phase)
+        before[name], after[name] = 1.0, 1.0 + seconds
+    for timer, seconds in (("ingest_parse", 45.0), ("region_write", 20.0)):
+        name = f"greptime_{timer}_cpu_seconds_total"
+        before[name], after[name] = 1.0, 1.0 + seconds
+    return run
+
+
+FRAME_READERS = {
+    "request_read_ms": 0.25,        # family a alone went over HTTP
+    "request_queue_ms.point": 0.5,
+    "request_resume_ms": 1.5,
+    "request_write_ms": 40.0,
+    "loop_lag_ms.point": 0.6,
+    # total 100 - 60, render 30 - 27, less fetch 30 - 3 and launch 2 - 1
+    "host_off_cpu_ms": 2 * (40 + 3 - 27 - 1),
+}
+PHASED_INGEST_READERS = {
+    "ingest_body_read_ms": 30.0,
+    "ingest_queue_ms": 10.0,
+    "ingest_resume_ms": 5.0,
+    "ingest_parse_off_cpu_ms": 500.0 - 450.0,
+    "region_write_off_cpu_ms": 1000.0 - 200.0,
+    "loop_lag_ms.ingest": None,     # that run's /metrics has no such series
+}
+
+
+@pytest.mark.parametrize("metric", sorted(FRAME_READERS))
+def test_frame_reader_reads_its_rows_and_series(reader, metric):
+    assert reader(metric)(framed_run()) == pytest.approx(
+        FRAME_READERS[metric])
+
+
+@pytest.mark.parametrize("metric", sorted(FRAME_READERS))
+def test_frame_reader_on_the_parent_program_reads_nothing(reader, metric):
+    """No `request.*` row, no `cpu_ms=`, no such series: None, never a
+    raise, and the line leaves the metric out."""
+    read = reader(metric)
+    assert read(framed_run(cpu=False, frame=False)) is None
+    assert read(query_run(spans=False)) is None
+    assert read(ingest_run()) is None
+    assert read({"statements": []}) is None and read({}) is None
+
+
+@pytest.mark.parametrize("metric", sorted(PHASED_INGEST_READERS))
+def test_write_phase_reader_reads_its_series(reader, metric):
+    want = PHASED_INGEST_READERS[metric]
+    got = reader(metric)(phased_ingest_run())
+    assert got is None if want is None else got == pytest.approx(want)
+    if want is not None:
+        assert reader(metric)(ingest_run()) is None     # the parent
+        assert reader(metric)(query_run()) is None
+        assert reader(metric)({}) is None
+
+
+def test_the_loops_lag_is_read_in_a_write_window_too(reader):
+    run = phased_ingest_run()
+    run["counters"]["before"]["greptime_event_loop_lag_seconds_sum"] = 1.0
+    run["counters"]["before"]["greptime_event_loop_lag_seconds_count"] = 10.0
+    run["counters"]["after"]["greptime_event_loop_lag_seconds_sum"] = 3.0
+    run["counters"]["after"]["greptime_event_loop_lag_seconds_count"] = 510.0
+    assert reader("loop_lag_ms.ingest")(run) == pytest.approx(4.0)
+    run["counters"]["after"]["greptime_event_loop_lag_seconds_count"] = 10.0
+    assert reader("loop_lag_ms.ingest")(run) is None    # no tick, no mean
+
+
+def test_a_family_over_mysql_is_left_out_of_the_requests_means(reader):
+    """Both families over HTTP: the mean is over both; neither: None."""
+    run = framed_run()
+    rec = run["statements"][2]                  # family b, scale 3
+    rec["stages"]["request.queue"] = stage(2.5, -1.0)
+    assert reader("request_queue_ms")(run) == pytest.approx((0.5 + 2.5) / 2)
+    assert reader("request_read_ms")(run) == pytest.approx(0.25)
+    for rec in run["statements"]:
+        rec["stages"].pop("request.read", None)
+    assert reader("request_read_ms")(run) is None
+
+
+def test_a_statement_without_cpu_on_a_wait_row_reads_no_off_cpu(reader):
+    run = framed_run()
+    assert reader("host_off_cpu_ms")(run) is not None
+    for rec in run["statements"]:
+        rec["stages"]["reduce.fetch"] = stage(30, 27)     # a span, no cpu
+    assert reader("host_off_cpu_ms")(run) is None
+    # a PromQL statement's waits are its window rows
+    run = framed_run()
+    for rec in run["statements"]:
+        rec["stages"]["window.fetch"] = with_cpu(stage(8, 40), 0.25)
+    assert reader("host_off_cpu_ms")(run) == pytest.approx(
+        FRAME_READERS["host_off_cpu_ms"] - 6)
+
+
+@pytest.mark.parametrize("metric", ["untimed_ms", "wire_ms", "parse_ms",
+                                    "render_ms", "mask_ms"])
+def test_the_new_rows_move_no_older_reader(reader, metric):
+    """Every new row's name has a dot: none is taken for a part of
+    `total`, and the rows' details still end with `t0_ns=`."""
+    assert reader(metric)(framed_run()) == pytest.approx(
+        reader(metric)(query_run()))
+
+
+def test_idle_inside_a_statement_under_no_row_shrinks_with_the_frame(reader):
+    """`idle_unattributed_s` is `stage_idle.py`'s `statement_outside_rows`:
+    the statement of `test_idle_time_falls_under_the_innermost_stage_row`
+    (14 ms under no row: 1 before `total`, 5 inside it, 8 after `render`)
+    with 0.75 ms of request rows after `parse` and 3 after `render`."""
+    class Trace:
+        offset = 0
+        lo, hi = T0, T0 + 200_000_000
+        planes = {"/device:TPU:0": [[T0 + 30_000_000, T0 + 50_000_000]]}
+
+    read = reader("idle_unattributed_s")
+    rec = statement("a", 1)
+    assert read({"statements": [rec], "trace": Trace()}) == \
+        pytest.approx(0.014)
+    rec["stages"].update({"request.read": stage(0.25, 1.0),
+                          "request.queue": stage(0.5, 1.25),
+                          "request.resume": stage(3, 132)})
+    assert read({"statements": [rec], "trace": Trace()}) == \
+        pytest.approx(0.014 - 0.00075 - 0.003)
+    assert read({"statements": [rec]}) is None          # no trace
+    assert read(ingest_run()) is None and read({}) is None
+
+    class Busy(Trace):
+        planes = {"/device:TPU:0": [[T0, T0 + 200_000_000]]}
+    assert read({"statements": [rec], "trace": Busy()}) is None
+
+
+def test_every_metric_of_issue_39_has_its_reader_and_entry():
+    per_layer = {m["name"]: m for m in _benchmark()["per_layer"]}
+    statement_cells = ["tsbs4k-scan", "tsbs100k-groupby", "prom1k-dashboard",
+                       "prom1k-longrange", CELL]
+    layer_of = {"host_off_cpu_ms": "process start, compile cache"}
+    for name in ("request_read_ms", "request_queue_ms", "request_resume_ms",
+                 "request_write_ms", "host_off_cpu_ms", "loop_lag_ms",
+                 "idle_unattributed_s"):
+        assert os.path.isfile(os.path.join(BENCH, "layers", name + ".py"))
+        m, point = per_layer[name], per_layer[name + ".point"]
+        assert m["workloads"] == statement_cells
+        assert point["workloads"] == ["tsbs4k-point"]
+        assert (m["moves"], point["moves"]) == ("stmt_geomean_ms",
+                                                "point_geomean_ms")
+        assert m["layer"] == point["layer"] == layer_of.get(
+            name, "protocol servers")
+    lag = per_layer["loop_lag_ms.ingest"]
+    assert (lag["workloads"], lag["moves"]) == (["tsbs4k-ingest"],
+                                                "ingest_rows_per_s")
+    for name in (n for n in PHASED_INGEST_READERS if "." not in n):
+        assert os.path.isfile(os.path.join(BENCH, "layers", name + ".py"))
+        m = per_layer[name]
+        assert m["workloads"] == ["tsbs4k-ingest", CELL]
+        assert (m["moves"], m["layer"], m["source"]) == (
+            "ingest_rows_per_s", "write path", "program_counter")

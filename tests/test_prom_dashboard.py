@@ -406,14 +406,16 @@ def family_sql(fleet, name="prom-cpu-busy-all") -> str:
 def test_explain_analyze_tql_runs_the_query(fleet):
     sql = family_sql(fleet)
     stages = stage_rows(fleet.sql("EXPLAIN ANALYZE " + sql))
-    assert list(stages)[:3] == ["parse", "plan", "dispatch"]
+    # over HTTP the request's own rows come first (ISSUE 39)
+    assert list(stages)[:5] == ["request.read", "request.queue", "parse",
+                                "plan", "dispatch"]
     assert stages["dispatch"][2] == ROW_PATH_HERE
     assert stages["plan"][2].startswith("PromBinary: -")
     assert stages["plan"][0] == len(fleet.sql(sql)) > 0    # rows answered
     # the idle series of every target with a sample in the 20 min read
     assert stages["select"][0] in (8 * 20, 8 * 21, 8 * 22)
     assert stages["total"][1] > 0 and "trace_id=" in stages["total"][2]
-    assert list(stages)[-1] == "render"
+    assert list(stages)[-2:] == ["request.resume", "render"]
     assert "protocol=http" in stages["render"][2]
 
 

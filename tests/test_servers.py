@@ -292,6 +292,140 @@ class TestLatencyHistograms:
         assert 'route="/v1/sql"' in text
 
 
+def samples(server):
+    """/metrics -> {sample name with labels: value}."""
+    out = {}
+    for line in req(server, "/metrics")[1].decode().splitlines():
+        if line and not line.startswith("#"):
+            name, _, value = line.rpartition(" ")
+            out[name] = float(value)
+    return out
+
+
+class TestRequestPhases:
+    """ISSUE 39: what a request does outside its statement's rows. The
+    HTTP server's hand-offs as `greptime_http_phase_seconds{route,
+    phase}`, the event loop's lag, a timer's thread CPU seconds."""
+
+    PHASES = ("read", "queue", "resume", "write")
+
+    @staticmethod
+    def phase(route, phase, what="count"):
+        return (f'greptime_http_phase_seconds_{what}'
+                f'{{phase="{phase}",route="{route}"}}')
+
+    @staticmethod
+    def send(server, route):
+        if route == "/v1/sql":
+            sql(server, "SELECT 1")
+        else:
+            assert req(server, route, "POST", b"phases,host=a v=1 1000",
+                       params={"precision": "ms"})[0] == 204
+
+    @pytest.mark.parametrize("phase", PHASES)
+    @pytest.mark.parametrize("route", ["/v1/sql", "/v1/influxdb/write"])
+    def test_every_request_is_observed_in_each_phase(self, server, route,
+                                                     phase):
+        self.send(server, route)            # the series exist from here
+        before = samples(server)
+        for _ in range(3):
+            self.send(server, route)
+        after = samples(server)
+        name = self.phase(route, phase)
+        assert after[name] - before[name] == 3
+        total = self.phase(route, phase, "sum")
+        assert 0.0 < after[total] - before[total] < 30.0
+        assert self.phase(route, phase, 'bucket').replace(
+            '{', '{le="+Inf",') in after
+
+    @pytest.mark.parametrize("route", ["/v1/sql", "/v1/influxdb/write"])
+    def test_the_request_histogram_counts_as_before(self, server, route):
+        """One observation a request, from the middleware's entry to the
+        handler's return; the phases lie inside it but for `write`."""
+        self.send(server, route)
+        before = samples(server)
+        for _ in range(3):
+            self.send(server, route)
+        after = samples(server)
+
+        def delta(name):
+            return after[name] - before[name]
+        count = f'greptime_http_request_seconds_count{{route="{route}"}}'
+        total = f'greptime_http_request_seconds_sum{{route="{route}"}}'
+        assert delta(count) == 3
+        inside = sum(delta(self.phase(route, p, "sum"))
+                     for p in ("read", "queue", "resume"))
+        assert inside < delta(total)
+
+    def test_a_handler_that_stays_on_the_loop_has_no_handoff(self, server):
+        req(server, "/health")
+        before = samples(server)
+        req(server, "/health")
+        after = samples(server)
+        for phase in ("read", "write"):
+            name = self.phase("/health", phase)
+            assert after[name] - before[name] == 1
+        assert self.phase("/health", "queue") not in after
+        assert self.phase("/health", "resume") not in after
+
+    def test_an_error_response_is_written_under_the_write_phase(self,
+                                                                 server):
+        def bad():
+            status, body = req(server, "/v1/sql", "POST",
+                               b"sql=SELECT+*+FROM+no_such_table",
+                               {"Content-Type":
+                                "application/x-www-form-urlencoded"})
+            assert status == 400 and json.loads(body)["code"] != 0
+        bad()
+        before = samples(server)
+        bad()
+        after = samples(server)
+        for phase in self.PHASES:
+            name = self.phase("/v1/sql", phase)
+            assert after[name] - before[name] == 1, phase
+
+    def test_an_unrouted_path_is_no_series(self, server):
+        assert req(server, "/no/such/path")[0] == 404
+        assert not [n for n in samples(server) if "/no/such/path" in n]
+
+    def test_the_event_loops_lag_is_read_ten_times_a_second(self, server):
+        import time
+        before = samples(server).get(
+            "greptime_event_loop_lag_seconds_count", 0.0)
+        time.sleep(0.55)
+        after = samples(server)
+        ticks = after["greptime_event_loop_lag_seconds_count"] - before
+        # other servers of this process tick into the same series
+        assert ticks >= 3
+        assert after["greptime_event_loop_lag_seconds_sum"] >= 0.0
+
+    def test_a_timer_counts_its_threads_cpu_seconds(self, server):
+        self.send(server, "/v1/influxdb/write")
+        got = samples(server)
+        for timer in ("region_write", "ingest_parse", "wal_append"):
+            cpu = got[f"greptime_{timer}_cpu_seconds_total"]
+            wall = got[f"greptime_{timer}_seconds_sum"]
+            assert 0.0 <= cpu <= wall + 0.005 * got[
+                f"greptime_{timer}_seconds_count"], timer
+
+    def test_the_cpu_counter_tells_work_from_waiting(self):
+        import time
+        from prometheus_client import REGISTRY
+        from greptimedb_tpu.common.telemetry import timer
+
+        def cpu(name):
+            return REGISTRY.get_sample_value(
+                f"greptime_{name}_cpu_seconds_total") or 0.0
+        with timer("phases_test_sleep"):
+            time.sleep(0.05)
+        with timer("phases_test_burn"):
+            end = time.perf_counter() + 0.05
+            while time.perf_counter() < end:
+                pass
+        assert cpu("phases_test_sleep") < 0.01
+        assert 0.015 <= cpu("phases_test_burn") <= 0.06
+
+
 class TestTraceparentHeader:
     def test_sql_joins_external_trace(self, server, caplog):
         """A client-supplied W3C traceparent header threads through the

@@ -1,12 +1,16 @@
 """Every millisecond of a statement under a span of the program's own
 (ISSUE 24): EXPLAIN ANALYZE rows with wall-clock starts over HTTP and
 MySQL, the `render` row, the profiler bridge, the row-insert timers.
+And what a request does outside its rows (ISSUE 39): the HTTP server's
+hand-offs as the rows `request.read` / `.queue` / `.resume`, the thread's
+CPU time on every row.
 """
 
 import json
 import re
 import subprocess
 import sys
+import time
 import urllib.parse
 import urllib.request
 
@@ -18,11 +22,18 @@ from greptimedb_tpu.servers.http import HttpServer
 from greptimedb_tpu.servers.mysql import MysqlServer
 
 from test_mysql import MiniMysqlClient
+from test_postgres import MiniPgClient
 
 QUERY = ("SELECT host, date_bin(INTERVAL '1 minute', ts) AS minute, "
          "avg(usage), max(usage) FROM span_cpu GROUP BY host, minute "
          "ORDER BY host, minute")
 T0 = re.compile(r"t0_ns=(\d+)$")
+CPU = re.compile(r"(?:^|, )cpu_ms=(\d+\.\d{3})(?:, |$)")
+#: the request's frame over HTTP, outside `total`
+BEFORE, AFTER = ["request.read", "request.queue", "parse"], \
+    ["total", "request.resume", "render"]
+#: timed before the statement was known to be analysed: no CPU clock read
+NO_CPU = {"request.read", "request.queue", "request.resume", "parse"}
 #: rows that may carry a time without being a span of this statement's
 #: thread of execution: the dispatch decision, the whole
 UNTIMED = {"dispatch", "total"}
@@ -146,7 +157,8 @@ def test_every_timed_row_is_a_span(wires, via):
 @VIAS
 def test_parts_lie_inside_their_parent(wires, via):
     stages = wires.stages(via)
-    parts = [n for n in stages if "." in n]
+    # the request's rows are parts of no row: their whole is the client's
+    parts = [n for n in stages if "." in n and not n.startswith("request.")]
     assert len(parts) >= 8
     slack = 50_000      # two clocks: wall start, monotonic length
     for part in parts:
@@ -188,7 +200,66 @@ def test_render_reports_the_plain_statement(wires, via):
 @VIAS
 def test_total_names_the_statements_trace(wires, via):
     total = wires.stages(via)["total"]
-    assert re.fullmatch(r"trace_id=[0-9a-f]{32}", total[2]), total[2]
+    assert re.fullmatch(r"trace_id=[0-9a-f]{32}, cpu_ms=\d+\.\d{3}",
+                        total[2]), total[2]
+
+
+def test_a_request_over_http_is_framed_by_its_handoffs(wires):
+    stages = wires.stages("http")
+    names = list(stages)
+    assert names[:3] == BEFORE and names[-3:] == AFTER, names
+    chain = BEFORE + [n for n in names[3:-3] if "." not in n
+                      and n not in UNTIMED] + AFTER[1:]
+    assert chain[3:-2] == ["plan", "scan_prep", "reduce", "finalize",
+                           "project"]
+    slack = 1_000_000       # 1 ms: the wall clock against the monotonic
+    for a, b in zip(chain, chain[1:]):
+        assert interval(stages, a)[1] <= interval(stages, b)[0] + slack, \
+            f"{a} runs into {b}"
+    # nothing long lies between them: the frame is the whole request
+    whole = interval(stages, "render")[1] - interval(stages, BEFORE[0])[0]
+    covered = sum(stages[n][1] for n in BEFORE + AFTER) * 1e6
+    assert whole - covered < 20e6, (whole, covered)
+
+
+@pytest.mark.parametrize("via", ["mysql", "postgres"])
+def test_a_connections_own_thread_has_no_handoff_rows(wires, via):
+    """MySQL and Postgres run a statement on the connection's thread."""
+    if via == "mysql":
+        names = list(wires.stages(via))
+    else:
+        from greptimedb_tpu.servers.postgres import PostgresServer
+        server = PostgresServer(wires.fe)
+        server.serve_in_background()
+        try:
+            wires.fe.do_query("SET tpu_dispatch_min_rows = 1")
+            client = MiniPgClient(server.port)
+            names = [r[0] for r in client.query("EXPLAIN ANALYZE "
+                                                + QUERY)[1]]
+            client.close()
+        finally:
+            server.shutdown()
+    assert names[0] == "parse" and names[-2:] == ["total", "render"]
+    assert not [n for n in names if n.startswith("request.")]
+
+
+@pytest.mark.parametrize("via", ["http", "mysql"])
+def test_every_span_reads_its_threads_cpu_time(wires, via):
+    stages = wires.stages(via)
+    spans = [n for n in stages if T0.search(stages[n][2])]
+    assert len(spans) >= 16
+    for name in spans + ["total"]:
+        _rows, ms, detail = stages[name]
+        found = CPU.search(detail)
+        if name in NO_CPU:
+            assert found is None, f"{name} reads no CPU clock: {detail}"
+            continue
+        assert found, f"row {name!r} has no cpu_ms: {detail!r}"
+        # two clocks, and the CPU clock ticks coarsely on some kernels
+        assert 0.0 <= float(found.group(1)) <= ms + 1.0, (name, detail)
+        if name != "total":
+            assert re.search(r"cpu_ms=[0-9.]+, t0_ns=\d+$", detail), detail
+    assert "cpu_ms=" not in stages["dispatch"][2]
 
 
 def test_a_datanodes_rows_keep_their_own_start():
@@ -201,6 +272,80 @@ def test_a_datanodes_rows_keep_their_own_start():
     local.absorb(json.loads(json.dumps(remote.to_dict())))
     assert local.stages["reduce"].t0_ns == start
     assert local.stages["reduce"].detail_str() == f"t0_ns={start}"
+
+
+def _burn(seconds):
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+@pytest.mark.parametrize("work, low, high", [
+    (_burn, 0.3, 1.05), (time.sleep, 0.0, 0.2)],
+    ids=["busy-loop", "sleep"])
+def test_cpu_time_tells_work_from_waiting(work, low, high):
+    from greptimedb_tpu.common.exec_stats import ExecStats
+    stats = ExecStats(cpu=True)
+    with stats.stage("row", n=1):
+        work(0.08)
+    st = stats.stages["row"]
+    assert st.elapsed_s >= 0.08
+    assert low * st.elapsed_s <= st.cpu_s <= high * st.elapsed_s + 2e-3
+    assert st.detail_str().startswith(f"n=1, cpu_ms={st.cpu_s * 1e3:.3f}, ")
+
+
+def test_a_row_recorded_without_a_timed_interval_has_no_cpu_ms():
+    """A pool worker's slices: counted and summed, never clocked here."""
+    from greptimedb_tpu.common.exec_stats import ExecStats
+    stats = ExecStats()
+    stats.record("decode", rows=10, elapsed_s=0.004, t0_ns=123)
+    stats.record("decode", rows=5, elapsed_s=0.001)
+    assert stats.stages["decode"].cpu_s is None
+    assert stats.stages["decode"].detail_str() == "t0_ns=123"
+    assert stats.to_dict()["stages"][0]["cpu_ms"] is None
+    local = ExecStats()
+    local.absorb(json.loads(json.dumps(stats.to_dict())))
+    assert local.stages["decode"].cpu_s is None
+
+
+def test_cpu_time_adds_up_over_a_rows_entries_and_crosses_the_wire():
+    from greptimedb_tpu.common.exec_stats import ExecStats
+    remote = ExecStats(cpu=True)
+    for _ in range(2):
+        with remote.stage("reduce"):
+            _burn(0.01)
+    # two entries of 10 ms each, less what a busy machine took away
+    assert 0.004 <= remote.stages["reduce"].cpu_s <= \
+        remote.stages["reduce"].elapsed_s + 2e-3
+    local = ExecStats()
+    local.absorb(json.loads(json.dumps(remote.to_dict())))
+    assert local.stages["reduce"].cpu_s == pytest.approx(
+        remote.stages["reduce"].cpu_s, abs=1e-6)
+    assert re.fullmatch(r"cpu_ms=\d+\.\d{3}, t0_ns=\d+",
+                        local.stages["reduce"].detail_str())
+
+
+def test_only_an_analysed_statement_reads_the_cpu_clock(wires, monkeypatch):
+    """A read of the thread's CPU clock is a system call (5.5 us on the
+    chip's host): the collector of a plain statement, whose rows nobody
+    reads, makes none; `telemetry.timer`'s are `time.thread_time`."""
+    from greptimedb_tpu.common.exec_stats import ExecStats, Timed
+    reads = []
+    clock = time.thread_time_ns
+    monkeypatch.setattr(time, "thread_time_ns",
+                        lambda: reads.append(1) or clock())
+    for via in ("http", "mysql"):
+        wires.sql_raw(via, QUERY)
+    assert reads == []
+    wires.stages("http")
+    assert len(reads) >= 2 * 16
+    del reads[:]
+    with Timed("parse") as plain, ExecStats().stage("row"):
+        pass
+    assert reads == [] and plain.cpu_s is None and plain.elapsed_s > 0
+    with Timed("row", cpu=True) as timed:
+        pass
+    assert len(reads) == 2 and 0.0 <= timed.cpu_s <= timed.elapsed_s + 2e-3
 
 
 def test_telemetry_alone_does_not_import_jax():

@@ -6,6 +6,8 @@ side. Dense against gather case by case, the lowered programs, the
 choice by shape, and the counter and span detail that say which ran.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -354,7 +356,7 @@ def test_counter_and_span_say_which_form_ran(gauge, query, path, reads):
         return
     assert moved == {f'{{path="{path}"}}': float(reads)}
     detail = stages["window.launch"]
-    assert f"path={path}, t0_ns=" in detail, detail
+    assert re.search(rf"path={path}, cpu_ms=[0-9.]+, t0_ns=", detail), detail
 
 
 def test_predict_linear_on_the_dense_form_is_the_line(gauge):
