@@ -798,6 +798,89 @@ def _sorted_seg_argext(x, starts, ends, bs, be, has_inner, n, *, is_min,
     return ft, fp
 
 
+def distinct_arrays(arrays, absent):
+    """-> (the arrays each once, per input its index among them): by
+    identity, so that a kernel sees two moments over one array as one
+    parameter and can share what depends on it alone. -1 where the input
+    is `absent` (a value that is the time index itself; a validity of
+    None: the column has no NULL)."""
+    out, ix, seen = [], [], {}
+    for a in arrays:
+        if a is absent:
+            ix.append(-1)
+            continue
+        if id(a) not in seen:
+            seen[id(a)] = len(out)
+            out.append(a)
+        ix.append(seen[id(a)])
+    return tuple(out), tuple(ix)
+
+
+#: the launch's row count: the one pass every program runs, and what a
+#: count under no validity of its own is
+_ROW_COUNT = ("count", -1)
+
+
+def _moment_passes(ops, value_ix, mask_ix):
+    """Per moment the row passes its result is made of, as the keys
+    `_sga_body` computes each once under: a pass depends on the row mask,
+    one validity (`mask_ix`, -1: none) and at most one column
+    (`value_ix`, -1: the time index), and on nothing else of its moment.
+    A time extreme beside a first / last under the same validity is that
+    arg-extreme's own extreme."""
+    ext = {("argext", op == "first", mk)
+           for op, mk in zip(ops, mask_ix) if op in ("first", "last")}
+    out = []
+    for op, v, mk in zip(ops, value_ix, mask_ix):
+        count = ("count", mk)
+        ext_t = ("argext", op == "min", mk)
+        if op == "count":
+            out.append((count,))
+        elif op == "avg":
+            out.append((("sum", v, mk), count))
+        elif op in ("stddev", "variance"):
+            out.append((count, ("dev", v, mk), ("dev_sq", v, mk)))
+        elif op in ("min", "max") and v < 0 and ext_t in ext:
+            out.append((ext_t,))
+        elif op in ("first", "last"):
+            out.append((("argext", op == "first", mk),))
+        elif op in ("sum", "sum_sq", "min", "max", "growth"):
+            out.append(((op, v, mk),))
+        else:
+            raise ValueError(f"unsupported agg op: {op}")
+    return out
+
+
+def _result_keys(ops, value_ix, mask_ix):
+    """Per moment what names its result: moments of one key are one
+    array."""
+    return [("count", mk) if op == "count" else (op, v, mk)
+            for op, v, mk in zip(ops, value_ix, mask_ix)]
+
+
+def moment_sharing(ops, value_ix, mask_ix):
+    """-> (out_ix, run, shared) of a launch over these static moments.
+    `out_ix`: per moment the index of its array among the distinct
+    results the program returns (-1: the row counts it returns anyway);
+    `run`: the passes over the rows the program makes, the row count
+    among them; `shared`: the passes more that a program making every
+    moment's own would run."""
+    passes = _moment_passes(ops, value_ix, mask_ix)
+    run = len({_ROW_COUNT, *(k for p in passes for k in p)})
+    index: Dict[tuple, int] = {}
+    out_ix = tuple(-1 if k == _ROW_COUNT else index.setdefault(k, len(index))
+                   for k in _result_keys(ops, value_ix, mask_ix))
+    return out_ix, run, 1 + sum(map(len, passes)) - run
+
+
+def moment_results(distinct, counts, ops, value_ix, mask_ix):
+    """-> (a launch's results a moment, from the distinct ones its program
+    returned; the passes it ran and those it shared)."""
+    out_ix, *passes = moment_sharing(ops, value_ix, mask_ix)
+    return tuple(counts if i < 0 else distinct[i] for i in out_ix), \
+        tuple(passes)
+
+
 def sorted_grouped_aggregate(gids, mask, ts, values, col_masks=(), *,
                              num_groups, ops, has_col_masks=False,
                              ends=None, seg_len_k=None, starts=None):
@@ -814,7 +897,13 @@ def sorted_grouped_aggregate(gids, mask, ts, values, col_masks=(), *,
     of the layout `gids` numbers: group g is rows [starts[g], ends[g]),
     ascending and disjoint, each inside one run of `gids`; a padding group
     has starts == ends. Every [num_groups]-shaped op is then sized by the
-    segments asked for (a scan's live runs), not by the layout's."""
+    segments asked for (a scan's live runs), not by the layout's.
+
+    What the moments share is computed once (`moment_sharing`): a
+    validity of None says the column has no NULL, and its count is the
+    row count; moments handed the same validity array, or the same value
+    array, are told to the program as one parameter; a value that is `ts`
+    itself reads the time index."""
     check_i64_safe(ts, what="sorted_grouped_aggregate ts")
     check_i64_safe(*[v for v in values], what="sorted_grouped_aggregate values")
     if starts is not None and ends is None:
@@ -823,22 +912,28 @@ def sorted_grouped_aggregate(gids, mask, ts, values, col_masks=(), *,
             and isinstance(gids, np.ndarray):
         hist = np.bincount(gids, minlength=num_groups)[:num_groups]
         ends = np.cumsum(hist, dtype=np.int64).astype(np.int32)
+    ops = tuple(ops)
+    values, value_ix = distinct_arrays(values, ts)
+    masks, mask_ix = distinct_arrays(
+        col_masks if has_col_masks else (None,) * len(ops), None)
+    static = dict(num_groups=num_groups, ops=ops, value_ix=value_ix,
+                  mask_ix=mask_ix)
     if ends is not None:
-        return _sorted_grouped_aggregate_pre(
-            gids, mask, ts, tuple(values), tuple(col_masks), ends, starts,
-            num_groups=num_groups, ops=tuple(ops),
-            has_col_masks=has_col_masks, seg_len_k=seg_len_k)
-    return _sorted_grouped_aggregate(
-        gids, mask, ts, tuple(values), tuple(col_masks),
-        num_groups=num_groups, ops=tuple(ops), has_col_masks=has_col_masks)
+        distinct, counts = _sorted_grouped_aggregate_pre(
+            gids, mask, ts, values, masks, ends, starts,
+            seg_len_k=seg_len_k, **static)
+    else:
+        distinct, counts = _sorted_grouped_aggregate(
+            gids, mask, ts, values, masks, **static)
+    return moment_results(distinct, counts, ops, value_ix, mask_ix)[0], counts
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("num_groups", "ops", "has_col_masks",
-                                    "seg_len_k"))
+                   static_argnames=("num_groups", "ops", "value_ix",
+                                    "mask_ix", "seg_len_k"))
 def _sorted_grouped_aggregate_pre(gids, mask, ts, values, col_masks, ends,
-                                  starts=None, *, num_groups, ops,
-                                  has_col_masks=False, seg_len_k=None):
+                                  starts=None, *, num_groups, ops, value_ix,
+                                  mask_ix, seg_len_k=None):
     """_sorted_grouped_aggregate with host-precomputed segment ends, and
     `starts` where the segments are not the dense layout's.
 
@@ -854,14 +949,15 @@ def _sorted_grouped_aggregate_pre(gids, mask, ts, values, col_masks, ends,
     bs, be, has_inner = _block_cover(starts, ends)
     return _sga_body(gids, mask, ts, values, col_masks, starts, ends, bs,
                      be, has_inner, num_groups=num_groups, ops=ops,
-                     has_col_masks=has_col_masks, seg_len_k=seg_len_k,
+                     value_ix=value_ix, mask_ix=mask_ix, seg_len_k=seg_len_k,
                      dense=dense)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("num_groups", "ops", "has_col_masks"))
+                   static_argnames=("num_groups", "ops", "value_ix",
+                                    "mask_ix"))
 def _sorted_grouped_aggregate(gids, mask, ts, values, col_masks=(), *,
-                              num_groups, ops, has_col_masks=False):
+                              num_groups, ops, value_ix, mask_ix):
     """grouped_aggregate twin requiring non-decreasing gids (the natural
     order of merged LSM scans). Same semantics, scatter-free execution.
 
@@ -873,13 +969,17 @@ def _sorted_grouped_aggregate(gids, mask, ts, values, col_masks=(), *,
     starts, ends, bs, be, has_inner = _segment_bounds(gids, num_groups, n)
     return _sga_body(gids, mask, ts, values, col_masks, starts, ends, bs,
                      be, has_inner, num_groups=num_groups, ops=ops,
-                     has_col_masks=has_col_masks)
+                     value_ix=value_ix, mask_ix=mask_ix)
 
 
 def _sga_body(gids, mask, ts, values, col_masks, starts, ends, bs, be,
-              has_inner, *, num_groups, ops, has_col_masks,
+              has_inner, *, num_groups, ops, value_ix, mask_ix,
               seg_len_k=None, dense=True):
-    """`dense`: segment g starts where g - 1 ends and `gids` numbers the
+    """-> (the distinct results in `moment_sharing`'s order, the row
+    counts). `values` / `col_masks`: the launch's distinct arrays, which
+    `value_ix` / `mask_ix` (static, a moment each) index.
+
+    `dense`: segment g starts where g - 1 ends and `gids` numbers the
     segments. Otherwise the segments are picked out of the layout `gids`
     numbers: the shift-doubling kernels still guard their passes with
     `gids` and pick up at `starts`; what would index a per-segment result
@@ -887,105 +987,120 @@ def _sga_body(gids, mask, ts, values, col_masks, starts, ends, bs, be,
     use_doubling = seg_len_k is not None and \
         num_groups > _SEG_HIGH_CARD_THRESHOLD
     n = gids.shape[0]
-
-    def agg_mask(i):
-        return (mask & col_masks[i]) if has_col_masks else mask
-
-    counts = _sorted_seg_sum(mask.astype(jnp.int32), starts, ends, bs, be,
-                             has_inner, n, dense).astype(jnp.int32)
-
     cache = {}
 
-    def seg_sum(col, m, key, square=False):
-        ck = (key, square)
-        if ck not in cache:
-            if square:
-                # square in float: col*col wraps int columns past ~46k
-                colf = col.astype(jnp.promote_types(col.dtype, jnp.float32))
-                v = colf * colf
-            else:
-                v = col
-            cache[ck] = _sorted_seg_sum(jnp.where(m, v, 0), starts, ends, bs,
-                                        be, has_inner, n, dense)
-        return cache[ck]
+    def once(key, make):
+        if key not in cache:
+            cache[key] = make()
+        return cache[key]
 
-    def seg_count(m, key):
-        ck = ("count", key if has_col_masks else -1)
-        if ck not in cache:
-            cache[ck] = _sorted_seg_sum(m.astype(jnp.int32), starts, ends, bs,
-                                        be, has_inner, n, dense)
-        return cache[ck]
+    def seg_sum(x):
+        return _sorted_seg_sum(x, starts, ends, bs, be, has_inner, n, dense)
 
-    results = []
-    iota = jnp.arange(n, dtype=jnp.int32)
-    for i, op in enumerate(ops):
-        col, m = values[i], agg_mask(i)
-        fdt = col.dtype
-        if op == "count":
-            results.append(seg_count(m, i).astype(jnp.int32))
-        elif op == "sum":
-            results.append(seg_sum(col, m, i).astype(fdt))
-        elif op == "sum_sq":
-            # partial moment for distributed/merged stddev computation
-            results.append(seg_sum(col, m, i, square=True))
-        elif op == "avg":
-            s, c = seg_sum(col, m, i), seg_count(m, i)
-            results.append(jnp.where(c > 0, s / jnp.maximum(c, 1), jnp.nan))
-        elif op in ("stddev", "variance"):
-            # Shifted one-pass moments (see the scatter twin): center on
-            # the global mean before squaring — avoids int wraparound and
-            # f32 cancellation on large, tight value distributions.
+    def column(v):
+        return ts if v < 0 else values[v]
+
+    def rows(mk):
+        """The rows a moment under validity `mk` reads."""
+        return mask if mk < 0 else \
+            once(("rows", mk), lambda: mask & col_masks[mk])
+
+    def centred(v, mk):
+        # Shifted one-pass moments (see the scatter twin): center on
+        # the global mean before squaring — avoids int wraparound and
+        # f32 cancellation on large, tight value distributions.
+        col, m = column(v), rows(mk)
+        colf = col.astype(jnp.promote_types(col.dtype, jnp.float32))
+        gc = jnp.maximum(jnp.sum(run(("count", mk))), 1)
+        shift = jnp.sum(jnp.where(m, colf, 0.0)) / gc
+        return jnp.where(m, colf - shift, 0.0)
+
+    def argext(is_min, mk):
+        # arg-extreme by (ts, position) — same semantics as the scatter
+        # twin even when ts is unsorted within a segment
+        ident = _max_ident(ts.dtype) if is_min else _min_ident(ts.dtype)
+        key = jnp.where(rows(mk), ts, ident)
+        if use_doubling:
+            return _seg_argext_doubling(key, gids, starts, ends, ident,
+                                        is_min=is_min, k_max=seg_len_k)
+        return _sorted_seg_argext(key, starts, ends, bs, be, has_inner, n,
+                                  is_min=is_min,
+                                  gids=gids if dense else None)
+
+    def minmax(is_min, v, mk):
+        col = column(v)
+        ident = _max_ident(col.dtype) if is_min else _min_ident(col.dtype)
+        filled = jnp.where(rows(mk), col, ident)
+        if use_doubling:
+            return _seg_minmax_doubling(filled, gids, starts, ends, ident,
+                                        is_min=is_min, k_max=seg_len_k)
+        return _sorted_seg_minmax(filled, starts, ends, bs, be, has_inner,
+                                  n, is_min=is_min)
+
+    def compute(kind, *of):
+        if kind == "count":
+            return seg_sum(rows(*of).astype(jnp.int32))
+        if kind == "argext":
+            return argext(*of)
+        if kind in ("min", "max"):
+            return minmax(kind == "min", *of)
+        if kind in ("dev", "dev_sq"):
+            d = once(("centred",) + of, lambda: centred(*of))
+            return seg_sum(d if kind == "dev" else d * d)
+        v, mk = of
+        col, m = column(v), rows(mk)
+        if kind == "sum":
+            return seg_sum(jnp.where(m, col, 0))
+        if kind == "sum_sq":
+            # partial moment for distributed/merged stddev computation;
+            # square in float: col*col wraps int columns past ~46k
             colf = col.astype(jnp.promote_types(col.dtype, jnp.float32))
-            c = seg_count(m, i)
-            gc = jnp.maximum(jnp.sum(c), 1)
-            shift = jnp.sum(jnp.where(m, colf, 0.0)) / gc
-            d = jnp.where(m, colf - shift, 0.0)
-            s = _sorted_seg_sum(d, starts, ends, bs, be, has_inner, n, dense)
-            sq = _sorted_seg_sum(d * d, starts, ends, bs, be, has_inner, n,
-                                 dense)
+            return seg_sum(jnp.where(m, colf * colf, 0))
+        # growth: rows of a segment lie in time order here (one series a
+        # segment), and the caller ships real run ids with seg_len_k
+        if seg_len_k is None:
+            raise ValueError("growth needs run ids and seg_len_k")
+        return _seg_growth_doubling(col, m, gids, starts, ends,
+                                    k_max=seg_len_k)
+
+    def run(key):
+        return once(key, lambda: compute(*key))
+
+    counts = run(_ROW_COUNT).astype(jnp.int32)
+    results = {}
+    for op, v, passes, rk in zip(ops, value_ix,
+                                 _moment_passes(ops, value_ix, mask_ix),
+                                 _result_keys(ops, value_ix, mask_ix)):
+        if rk == _ROW_COUNT or rk in results:
+            continue
+        p = [run(k) for k in passes]
+        fdt = column(v).dtype
+        if op == "count":
+            r = p[0].astype(jnp.int32)
+        elif op == "sum":
+            r = p[0].astype(fdt)
+        elif op == "avg":
+            s, c = p
+            r = jnp.where(c > 0, s / jnp.maximum(c, 1), jnp.nan)
+        elif op in ("stddev", "variance"):
+            c, s, sq = p
             cc = jnp.maximum(c, 1)
             # sample variance (ddof=1, DataFusion convention); <2 rows → NaN
             var = jnp.maximum(sq - (s / cc) * s, 0.0) / jnp.maximum(c - 1, 1)
             var = jnp.where(c >= 2, var, jnp.nan)
-            results.append(jnp.sqrt(var) if op == "stddev" else var)
-        elif op in ("min", "max"):
-            is_min = op == "min"
-            ident = _max_ident(fdt) if is_min else _min_ident(fdt)
-            filled = jnp.where(m, col, ident)
-            if use_doubling:
-                results.append(_seg_minmax_doubling(
-                    filled, gids, starts, ends, ident, is_min=is_min,
-                    k_max=seg_len_k))
-            else:
-                results.append(_sorted_seg_minmax(
-                    filled, starts, ends, bs, be, has_inner, n,
-                    is_min=is_min))
-        elif op == "growth":
-            # rows of a segment lie in time order here (one series a
-            # segment), and the caller ships real run ids with seg_len_k
-            if seg_len_k is None:
-                raise ValueError("growth needs run ids and seg_len_k")
-            results.append(_seg_growth_doubling(
-                col, m, gids, starts, ends, k_max=seg_len_k))
+            r = jnp.sqrt(var) if op == "stddev" else var
         elif op in ("first", "last"):
-            # arg-extreme by (ts, position) — same semantics as the scatter
-            # twin even when ts is unsorted within a segment
-            is_min = op == "first"
-            ident = _max_ident(ts.dtype) if is_min else _min_ident(ts.dtype)
-            key = jnp.where(m, ts, ident)
-            if use_doubling:
-                ext_t, pos = _seg_argext_doubling(
-                    key, gids, starts, ends, ident, is_min=is_min,
-                    k_max=seg_len_k)
-            else:
-                ext_t, pos = _sorted_seg_argext(key, starts, ends, bs, be,
-                                                has_inner, n, is_min=is_min,
-                                                gids=gids if dense else None)
+            ext_t, pos = p[0]
+            ident = _max_ident(ts.dtype) if op == "first" \
+                else _min_ident(ts.dtype)
             found = (ext_t != ident) & (pos >= 0)
-            val = col[jnp.clip(pos, 0, n - 1)]
+            val = column(v)[jnp.clip(pos, 0, n - 1)]
             empty = jnp.nan if jnp.issubdtype(fdt, jnp.floating) \
                 else jnp.zeros((), fdt)
-            results.append(jnp.where(found, val, empty))
-        else:
-            raise ValueError(f"unsupported agg op: {op}")
-    return tuple(results), counts
+            r = jnp.where(found, val, empty)
+        elif passes[0][0] == "argext":      # a time extreme beside it
+            r = p[0][0]
+        else:                               # sum_sq, min, max, growth
+            r = p[0]
+        results[rk] = r
+    return tuple(results.values()), counts

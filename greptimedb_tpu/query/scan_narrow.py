@@ -21,8 +21,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.kernels import (_SEG_HIGH_CARD_THRESHOLD, shape_bucket,
-                           sorted_grouped_aggregate)
+from ..ops.kernels import (_SEG_HIGH_CARD_THRESHOLD,
+                           _sorted_grouped_aggregate_pre, distinct_arrays,
+                           moment_results, shape_bucket)
 from . import tpu_exec
 
 #: The narrowed launch runs while the compact block (`padded_rows`:
@@ -212,20 +213,21 @@ def _narrow_reduce(cuts, ends, rid, row_mask, cols, *, len_b, num_groups,
     """Cut `cuts` out of the resident columns and reduce the compact
     block. cuts int32 [3, k_b]: each range's slice start (clamped by the
     host so that start + len_b stays inside the table) and the offsets
-    [lo, hi) of its live rows inside that slice; cols[0] is ts; value_ix
-    / mask_ix index cols per moment (mask -1: the column has no NULL)."""
+    [lo, hi) of its live rows inside that slice; cols is (ts, the value
+    columns, the validities), each column once; value_ix / mask_ix index
+    the latter two per moment (value -1: ts itself; mask -1: the column
+    has no NULL). -> the distinct results and the row counts
+    (`ops/kernels.py:moment_sharing`)."""
     at, lo, hi = cuts
     j = jnp.arange(len_b, dtype=jnp.int32)[None, :]
     live = ((j >= lo[:, None]) & (j < hi[:, None])).reshape(-1)
     if row_mask is not None:
         live = live & row_mask
-    cut = [_cut(c, at, len_b) for c in cols]
-    ts = cut[0]
-    return sorted_grouped_aggregate(
-        ts if rid is None else rid, live, ts,
-        tuple(cut[i] for i in value_ix),
-        tuple(live if i < 0 else cut[i] for i in mask_ix),
-        num_groups=num_groups, ops=ops, has_col_masks=True, ends=ends,
+    ts, values, valid = jax.tree_util.tree_map(
+        lambda c: _cut(c, at, len_b), cols)
+    return _sorted_grouped_aggregate_pre(
+        ts if rid is None else rid, live, ts, values, valid, ends,
+        num_groups=num_groups, ops=ops, value_ix=value_ix, mask_ix=mask_ix,
         seg_len_k=seg_len_k)
 
 
@@ -283,35 +285,30 @@ def launch(scan, schema, plan, sel: Selection, part):
             mask_ix=mask_ix, seg_len_k=seg_len_k)
     if out is None:         # a stand-in tail: compiled, not run
         return None
-    results, counts = out
+    distinct, counts = out
+    results, passes = moment_results(distinct, counts, ops, value_ix, mask_ix)
     run_range = np.searchsorted(first, run_rows, side="right") - 1
     # warm stays False: the dispatch floor (`_note_device_query_time`)
     # is fed by full launches, whose fixed cost it stands for
     return tpu_exec._Launched(
-        tuple(results), counts, len(run_starts), sel.sids[run_range],
+        results, counts, len(run_starts), sel.sids[run_range],
         buckets[run_rows] if buckets is not None else None,
-        scan.series_dict, scan.ts_base)
+        scan.series_dict, scan.ts_base, passes)
 
 
 def _columns(scan, schema, plan):
     """-> (ops, value_ix, mask_ix, cols): the moments' kernel ops and the
-    resident columns they read, each column once (cols[0] is ts; a mask
-    index of -1: the column has no NULL)."""
-    cols = [scan.device_ts()]
-    index = {}
-
-    def col_ix(key, get):
-        if key not in index:
-            index[key] = len(cols)
-            cols.append(get(key[1]))
-        return index[key]
-
-    ops, value_ix, mask_ix = [], [], []
+    resident columns they read as (ts, values, validities), each column
+    once (a value index of -1: ts itself; a mask index of -1: the column
+    has no NULL)."""
+    d_ts = scan.device_ts()
+    ops, values, masks = [], [], []
     for op, field_read, masked_by in tpu_exec._moment_reads(schema, plan):
         ops.append(op)
-        value_ix.append(0 if field_read is None else col_ix(
-            ("f", field_read), lambda c: tpu_exec._device_column(scan, c)))
-        mask_ix.append(
-            -1 if masked_by is None or scan.fields[masked_by][1] is None
-            else col_ix(("v", masked_by), scan.device_valid))
-    return tuple(ops), tuple(value_ix), tuple(mask_ix), tuple(cols)
+        values.append(d_ts if field_read is None
+                      else tpu_exec._device_column(scan, field_read))
+        masks.append(None if masked_by is None
+                     else scan.device_valid(masked_by))
+    values, value_ix = distinct_arrays(values, d_ts)
+    masks, mask_ix = distinct_arrays(masks, None)
+    return tuple(ops), value_ix, mask_ix, (d_ts, values, masks)

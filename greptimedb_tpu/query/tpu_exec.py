@@ -28,8 +28,8 @@ import numpy as np
 import pandas as pd
 
 from ..errors import UnsupportedError
-from ..ops.kernels import (_sorted_grouped_aggregate_pre, merge_dedup_numpy,
-                           shape_bucket)
+from ..ops.kernels import (_sorted_grouped_aggregate_pre, distinct_arrays,
+                           merge_dedup_numpy, moment_results, shape_bucket)
 from ..sql.ast import (
     Between, BinaryOp, Column, Expr, FunctionCall, InList, Interval, IsNull,
     Literal, Query, UnaryOp,
@@ -202,11 +202,12 @@ class MergedScan:
         return self.device[key]
 
     def device_valid(self, name: str):
+        """A field's validity mirror; None: the field has no NULL."""
         key = f"v:{name}"
         if key not in self.device:
             _, valid = self.fields[name]
             if valid is None:
-                return self.device_valid_all()
+                return None
             self._put(key, valid, fill=False)
         return self.device[key]
 
@@ -556,8 +557,13 @@ class _ScanCache:
             sids = data.series_ids[kept]
             ts = data.ts[kept]
             seq = data.seq[kept]
-            fields = {n: (d[kept], vd[kept] if vd is not None else None)
-                      for n, (d, vd) in data.fields.items()}
+            fields = {}
+            for n, (d, vd) in data.fields.items():
+                # a memtable hands every column a validity: one that
+                # holds no NULL is None here, as a delta's is, and a
+                # launch's moments over such columns share the row count
+                vd = None if vd is None else vd[kept]
+                fields[n] = (d[kept], None if vd is None or vd.all() else vd)
         else:
             sids, ts, seq = data.series_ids, data.ts, data.seq
             fields = data.fields
@@ -608,8 +614,6 @@ class _ScanCache:
                         else ()):
                 if key == "__ts":
                     tail.device_ts()
-                elif key == "__all_valid":     # the same ones: kept
-                    tail.device[key] = entry.tail.device[key]
                 elif key.startswith("f:"):
                     tail.device_field(key[2:])
                 elif key.startswith("v:") and \
@@ -1961,13 +1965,18 @@ class _Launched:
     with futures — so callers can launch many reductions (one per
     streamed slice), let host decode overlap device compute, and fetch
     every result in ONE device round trip."""
-    results: tuple                    # device arrays, one per moment
+    #: device arrays, one per moment: moments whose result is one
+    #: (`ops/kernels.py:moment_sharing`) hold the same array, which
+    #: `device_get` copies back once
+    results: tuple
     counts: object                    # device int32 [nbucket]
     nruns: int
     run_sids: np.ndarray              # per-run series id [nruns] — only
     run_buckets: Optional[np.ndarray]  # run-level context is retained, so
     series_dict: object               # a streamed slice's full arrays are
     ts_base: int                      # freed while its reduction is in flight
+    #: the passes over the rows the program ran, and those it shared
+    passes: Tuple[int, int]
     #: this scan launched the same kernel over the same columns before:
     #: nothing was compiled, uploaded or swept for this launch
     warm: bool = False
@@ -2039,6 +2048,13 @@ def _moment_frame_for_scan(scan: MergedScan, schema, plan: TpuPlan,
             exec_stats.record("reduce", groups="table")
     if launched is None:
         return None
+    run, shared = launched.passes
+    increment_counter("scan_kernel_passes", run, kind="run")
+    increment_counter("scan_kernel_passes", shared, kind="shared")
+    if tail:
+        exec_stats.record("reduce", tail_passes=run)
+    else:
+        exec_stats.record("reduce", moments=run + shared, passes=run)
     with _reduce_part("fetch"):     # blocked on the device, then D2H
         counts, res_np = jax.device_get((launched.counts,
                                          list(launched.results)))
@@ -2092,8 +2108,13 @@ def _launch_scan_kernel(scan: MergedScan, schema, plan: TpuPlan,
             ops.append(op)
             values.append(d_ts if field_read is None
                           else _device_column(scan, field_read))
-            col_masks.append(scan.device_valid_all() if masked_by is None
+            col_masks.append(None if masked_by is None
                              else scan.device_valid(masked_by))
+        # what the moments share, told to the program statically: each
+        # mirror a parameter once, a column without a NULL no validity
+        ops = tuple(ops)
+        values, value_ix = distinct_arrays(values, d_ts)
+        col_masks, mask_ix = distinct_arrays(col_masks, None)
 
     with part("runs"):
         # cached with the runs, per set of ops that read run ids or not:
@@ -2129,12 +2150,13 @@ def _launch_scan_kernel(scan: MergedScan, schema, plan: TpuPlan,
     with part("launch"):
         out = _run_program(
             scan, _sorted_grouped_aggregate_pre, d_rid, d_mask, d_ts,
-            tuple(values), tuple(col_masks), run_ends, live_starts,
-            num_groups=nbucket, ops=tuple(ops), has_col_masks=True,
+            values, col_masks, run_ends, live_starts, num_groups=nbucket,
+            ops=ops, value_ix=value_ix, mask_ix=mask_ix,
             seg_len_k=seg_len_k)
     if out is None:         # a stand-in: compiled, not run
         return None
-    results, counts = out
+    distinct, counts = out
+    results, passes = moment_results(distinct, counts, ops, value_ix, mask_ix)
     signature = (run_key, nbucket,
                  tuple((m.op, m.column) for m in plan.moments))
     warm = signature in scan.launched
@@ -2142,9 +2164,10 @@ def _launch_scan_kernel(scan: MergedScan, schema, plan: TpuPlan,
         scan.launched.clear()
     scan.launched.add(signature)
     sids = scan.series_ids
-    return _Launched(tuple(results), counts, nruns, sids[run_starts],
+    return _Launched(results, counts, nruns, sids[run_starts],
                      _run_buckets(plan, buckets, run_starts),
-                     scan.series_dict, scan.ts_base, warm, table_runs)
+                     scan.series_dict, scan.ts_base, passes, warm,
+                     table_runs)
 
 
 def _moment_reads(schema, plan: TpuPlan):

@@ -120,7 +120,7 @@ def downsample_region(src, dst, *, stride_ms: int,
     for dest, op, col in agg_specs:
         if col is None:
             values.append(d_ts)            # count(*): mask-only reduce
-            col_masks.append(scan.device_valid_all())
+            col_masks.append(None)
         else:
             values.append(d_ts if op == "count"
                           else scan.device_field(col))

@@ -4,6 +4,7 @@ Mirrors the reference's memtable/merge/dedup semantics tests
 (src/storage/src/memtable/tests.rs, src/storage/src/read/merge.rs) and the
 PromQL function tests (src/promql/src/functions/*)."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -775,3 +776,171 @@ class TestSegmentsPickedOutOfALayout:
                                            err_msg=op, equal_nan=True)
             else:
                 assert np.array_equal(g, w, equal_nan=True), (op, i)
+
+
+class TestMomentsShareTheirPasses:
+    """ISSUE 41: a launch computes once what its moments share (a count a
+    distinct validity, the row count where a column has no NULL, one
+    arg-extreme the `first`s / `last`s and time extremes of a validity).
+    The shared program's results equal, bit for bit, those of the same
+    moments each handed copies of its arrays, which share nothing."""
+
+    #: (id, groups, segments picked out of the layout or None, seg_len_k?)
+    LAYOUTS = [
+        ("low", 300, None, False),
+        ("high", 9_000, None, False),        # above _SEG_HIGH_CARD_THRESHOLD
+        ("doubling", 9_000, None, True),     # the shift-doubling kernels
+        ("live-runs", 12_000, 9_000, True),      # dense=False with `starts`
+    ]
+    #: the validity each column reads: two columns with no NULL, one with
+    #: NULLs of its own, two under one NULL-holding validity, which leaves
+    #: one run with rows and no valid one
+    READS = (None, None, "a", "b", "b")
+
+    @pytest.mark.parametrize("layout", LAYOUTS, ids=[c[0] for c in LAYOUTS])
+    def test_equals_a_pass_a_moment_bit_for_bit(self, layout):
+        from greptimedb_tpu.ops.kernels import (
+            _SEG_HIGH_CARD_THRESHOLD, _max_ident, _min_ident,
+            distinct_arrays, moment_sharing, seg_len_bucket,
+            sorted_grouped_aggregate)
+        name, groups, picked, with_k = layout
+        reads = self.READS
+        rng = np.random.default_rng(groups)
+        longest = 70                  # past two 32-row blocks
+        lens = rng.integers(1, 9, groups)
+        lens[rng.integers(0, groups, 12)] = rng.integers(30, longest + 1, 12)
+        n = int(lens.sum())
+        nb = shape_bucket(groups, minimum=256)
+        ends = np.full(nb, n, dtype=np.int32)
+        ends[:groups] = np.cumsum(lens)
+        starts = np.concatenate([[0], ends[:-1]]).astype(np.int32)
+        gids = np.repeat(np.arange(groups, dtype=np.int32), lens)
+        ts = rng.integers(0, 40, n).astype(np.int32)        # ties
+        mask = rng.random(n) > 0.15
+        emptied = int(np.nonzero(lens > 2)[0][3])
+        valid = {"a": rng.random(n) > 0.2, "b": rng.random(n) > 0.2}
+        valid["b"][starts[emptied]:ends[emptied]] = False
+        mask[starts[emptied]:ends[emptied]] = True
+        cols = [(rng.random(n, dtype=np.float32) * 100) - 50
+                for _ in reads]
+        segments = {"ends": ends}
+        if picked is not None:
+            live = np.sort(np.append(rng.choice(
+                np.delete(np.arange(groups), emptied), picked - 1,
+                replace=False), emptied))
+            emptied = int(np.searchsorted(live, emptied))
+            nb = shape_bucket(picked, minimum=256)
+            segments = {"starts": np.full(nb, n, dtype=np.int32),
+                        "ends": np.full(nb, n, dtype=np.int32)}
+            segments["starts"][:picked] = starts[live]
+            segments["ends"][:picked] = ends[live]
+        assert (nb > _SEG_HIGH_CARD_THRESHOLD) == (name != "low")
+
+        # a column each: avg beside a count of its own, every extreme,
+        # first / last and the time extremes beside them (`standard_final`)
+        per_column = ("sum", "count", "avg", "min", "max", "stddev",
+                      "first", "last", "min", "max") + \
+            (("growth",) if with_k else ())
+        reads_ts = (8, 9)
+        float_sums = ("sum", "avg", "stddev")
+        ops, shared_v, shared_m, own_v, own_m = [], [], [], [], []
+        for col, which in zip(cols, reads):
+            for i, op in enumerate(per_column):
+                ops.append(op)
+                shared_v.append(ts if i in reads_ts else col)
+                shared_m.append(None if which is None else valid[which])
+                own_v.append((ts if i in reads_ts else col).copy())
+                own_m.append(np.ones(n, dtype=bool) if which is None
+                             else valid[which].copy())
+
+        def run(values, col_masks, only=None):
+            keep = [i for i, op in enumerate(ops)
+                    if only is None or op in only]
+            res, counts = sorted_grouped_aggregate(
+                gids, mask, ts, tuple(values[i] for i in keep),
+                tuple(col_masks[i] for i in keep), num_groups=nb,
+                ops=tuple(ops[i] for i in keep), has_col_masks=True,
+                seg_len_k=seg_len_bucket(longest) if with_k else None,
+                **segments)
+            return dict(zip(keep, map(np.asarray, res))), np.asarray(counts)
+
+        want, want_counts = run(own_v, own_m)
+        got, got_counts = run(shared_v, shared_m)
+        assert np.array_equal(got_counts, want_counts)
+        for i, op in enumerate(ops):
+            g, w = got[i], want[i]
+            assert g.dtype == w.dtype, (i, op)
+            if op in float_sums:
+                # the same numbers added; XLA:CPU picks the order inside a
+                # block by what it fuses around the sum, so two compiled
+                # programs may differ in a last bit (seen at low
+                # cardinality): exact below, primitive by primitive
+                np.testing.assert_allclose(g, w, rtol=2e-5, atol=1e-4,
+                                           err_msg=f"{i} {op}")
+            else:
+                assert np.array_equal(g, w, equal_nan=True), (i, op)
+        with jax.disable_jit():
+            want_sums, _ = run(own_v, own_m, only=float_sums)
+            got_sums, _ = run(shared_v, shared_m, only=float_sums)
+        for i, g in got_sums.items():
+            assert np.array_equal(g, want_sums[i], equal_nan=True), \
+                (i, ops[i])
+
+        def sharing(values, col_masks):
+            return moment_sharing(tuple(ops),
+                                  distinct_arrays(values, ts)[1],
+                                  distinct_arrays(col_masks, None)[1])
+
+        m = len(per_column)
+        own_passes = 1 + len(cols) * (m + 3)     # avg 2, stddev 3
+        assert sharing(own_v, own_m)[1:] == (own_passes, 0)
+        # what a column keeps to itself: sum, min, max, the two centred
+        # sums (and growth); beside them a count and two arg-extremes a
+        # distinct validity (none: the row count)
+        out_ix, run_passes, shared_passes = sharing(shared_v, shared_m)
+        assert run_passes == 1 + len(cols) * (m - 5) + 2 + 2 * 3
+        assert run_passes + shared_passes == own_passes
+
+        count_of = [got[c * m + 1] for c in range(len(cols))]
+        for which, count, ix in zip(reads, count_of, out_ix[1::m]):
+            if which is None:   # no NULL: its count is the row count
+                assert ix == -1 and np.array_equal(count, got_counts)
+            else:               # NULLs: a count of that validity's own
+                assert (count <= got_counts).all() and \
+                    (count < got_counts).any()
+        assert out_ix[3 * m + 1] == out_ix[4 * m + 1] != out_ix[2 * m + 1]
+        assert not np.array_equal(count_of[2], count_of[3])
+        for c in (3, 4):        # rows, and none valid under "b"
+            assert got_counts[emptied] > 0 and count_of[c][emptied] == 0
+            assert np.isnan(got[c * m + 6][emptied]) and \
+                np.isnan(got[c * m + 7][emptied])
+            assert got[c * m + 8][emptied] == _max_ident(jnp.int32) and \
+                got[c * m + 9][emptied] == _min_ident(jnp.int32)
+
+
+@pytest.mark.parametrize("moments, want", [
+    # avg of ten all-valid columns: the row count and ten sums
+    ([("sum", c, -1) for c in range(10)] +
+     [("count", c, -1) for c in range(10)], (11, 10)),
+    # one of them holds NULLs: a count of its own
+    ([("sum", c, 0 if c == 3 else -1) for c in range(10)] +
+     [("count", c, 0 if c == 3 else -1) for c in range(10)], (12, 9)),
+    # lastpoint: last(c) + max_ts(c)
+    ([("last", 0, -1), ("max", -1, -1)], (2, 1)),
+    # a time extreme with no first / last beside it keeps its pass
+    ([("sum", 0, -1), ("max", -1, -1)], (3, 0)),
+    # ten lasts and their time extremes under no validity
+    ([("last", c, -1) for c in range(10)] + [("max", -1, -1)] * 10,
+     (2, 19)),
+], ids=["avg-10", "avg-10-one-null", "lastpoint", "max_ts-alone",
+        "last-10"])
+def test_moment_sharing_counts_passes(moments, want):
+    from greptimedb_tpu.ops.kernels import moment_sharing
+    ops, value_ix, mask_ix = zip(*moments)
+    out_ix, run, shared = moment_sharing(ops, value_ix, mask_ix)
+    assert (run, shared) == want
+    # moments of one result are handed one array; a count under no
+    # validity is the row counts (-1)
+    assert len(out_ix) == len(moments)
+    assert all((i == -1) == (m[0] == "count" and m[2] < 0)
+               for i, m in zip(out_ix, moments))

@@ -740,6 +740,46 @@ def test_writes_of_any_size_after_the_warm_statements_compile_nothing(big):
     assert now is base and tail.valid_rows == 400 * 6 + 7 + 7 * 150
 
 
+def test_a_tail_that_meets_a_null_answers_and_takes_one_program_more(big):
+    """ISSUE 41: which columns hold a NULL is part of a program's
+    signature. The stand-in warms the pattern of a tail without one; the
+    first NULL written brings a count of that column's own, one compile,
+    and the right answer."""
+    fe, table = big
+    count = ("SELECT host, count(usage) AS c, last(usage) AS l FROM big "
+             "WHERE host IN ('b010', 'b011') GROUP BY host")
+    whole = "SELECT host, count(usage) AS c, last(usage) AS l FROM big " \
+        "GROUP BY host"
+
+    def answer(sql):
+        fe.do_query("SET tpu_dispatch_min_rows = 131072")
+        out = fe.do_query(sql)[-1]
+        return pd.concat([pd.DataFrame(b.to_pydict())
+                          for b in out.batches]).set_index("host")
+
+    before = answer(whole)
+    answer(whole)
+    t = T0 + (BIG_TICKS + 1000) * TICK_MS      # after every other write
+    table.insert({"host": ["b010", "b011"], "ts": [t, t],
+                  "usage": [0.25, 0.5]})
+    compiled = _sorted_grouped_aggregate_pre._cache_size()
+    got = answer(whole)        # a tail without a NULL: the warmed program
+    assert _sorted_grouped_aggregate_pre._cache_size() == compiled
+    assert got.c["b010"] == before.c["b010"] + 1 and got.l["b011"] == 0.5
+    table.insert({"host": ["b010", "b011"], "ts": [t + TICK_MS] * 2,
+                  "usage": [None, 0.75]})
+    got = answer(whole)
+    assert _sorted_grouped_aggregate_pre._cache_size() == compiled + 1
+    # the NULL is no value: b010 keeps its count and its last
+    assert got.c["b010"] == before.c["b010"] + 1 and got.l["b010"] == 0.25
+    assert got.c["b011"] == before.c["b011"] + 2 and got.l["b011"] == 0.75
+    assert got.c.drop(["b010", "b011"]).equals(
+        before.c.drop(["b010", "b011"]))
+    narrow = answer(count)
+    assert list(narrow.c) == [got.c["b010"], got.c["b011"]]
+    assert list(narrow.l) == [0.25, 0.75]
+
+
 # ---------------------------------------------------------------------------
 # Memtable.snapshot under the lock; an empty table, then a bulk load
 # ---------------------------------------------------------------------------

@@ -638,6 +638,104 @@ def test_no_tag_conjunct_keeps_the_tables_axis_and_its_program(db):
     assert _sorted_grouped_aggregate_pre._cache_size() == compiled
 
 
+H0 = T0 - T0 % 3_600_000                    # a whole hour
+
+
+def passes_counter(kind: str) -> float:
+    return total("greptime_scan_kernel_passes_total", f'kind="{kind}"')
+
+
+@pytest.fixture(scope="module")
+def wide(tmp_path_factory):
+    """TSBS's shape, ten DOUBLE fields, 6 hosts x 3 h of one row a
+    minute, twice: `wide` holds no NULL, `holes` one, in c3."""
+    dn = DatanodeInstance(DatanodeOptions(
+        data_home=str(tmp_path_factory.mktemp("wide")),
+        register_numbers_table=False))
+    dn.start()
+    fe = FrontendInstance(dn)
+    fe.start()
+    for table in ("wide", "holes"):
+        fe.do_query(f"CREATE TABLE {table} (hostname STRING, ts TIMESTAMP "
+                    "TIME INDEX, " +
+                    ", ".join(f"c{i} DOUBLE" for i in range(10)) +
+                    ", PRIMARY KEY(hostname))")
+        fe.do_query(f"INSERT INTO {table} VALUES " + ", ".join(
+            f"('h{h}', {H0 + t * 60_000}, " + ", ".join(
+                "NULL" if (table, h, t, c) == ("holes", 2, 70, 3)
+                else repr(_wide_value(h, t, c)) for c in range(10))
+            + ")" for h in range(6) for t in range(180)))
+    db = Db.__new__(Db)
+    db.dn, db.fe = dn, fe
+    yield db
+    fe.do_query("SET tpu_dispatch_min_rows = 131072")
+    db.close()
+
+
+def _wide_value(h, t, c):
+    return float((h + 3 * t + 7 * c) % 101)
+
+
+def _avg_by_hour(table):
+    avgs = ", ".join(f"avg(c{i})" for i in range(10))
+    return (f"SELECT hostname, date_bin(INTERVAL '1 hour', ts) AS hour, "
+            f"{avgs} FROM {table} GROUP BY hostname, hour")
+
+
+def test_a_launch_runs_a_pass_a_distinct_validity(wide):
+    """ISSUE 41: avg of ten columns without a NULL is the row count and
+    ten sums (11 passes over the rows where a pass a moment is 21); a
+    column that holds a NULL keeps a count of its own."""
+    sql = _avg_by_hour("wide")
+    before = passes_counter("run"), passes_counter("shared")
+    detail = wide.stages(sql)["reduce"]
+    assert "path=full" in detail and "moments=21, passes=11" in detail
+    assert (passes_counter("run"), passes_counter("shared")) == \
+        (before[0] + 11, before[1] + 10)
+    got = wide.sql(sql)
+    assert len(got) == 6 * 3
+    h2 = got[got["hostname"] == "h2"].sort_values("hour")
+    for c in (3, 4):
+        assert list(h2[f"avg(c{c})"]) == [
+            float(np.mean([_wide_value(2, t, c) for t in range(lo, lo + 60)]))
+            for lo in (0, 60, 120)]
+    # two lasts and their time extremes: the row count and one arg-extreme
+    assert "moments=5, passes=2" in wide.stages(
+        "SELECT hostname, last(c0), last(c1) FROM wide GROUP BY hostname"
+    )["reduce"]
+
+    before = passes_counter("run"), passes_counter("shared")
+    assert "moments=21, passes=12" in wide.stages(
+        _avg_by_hour("holes"))["reduce"]
+    assert (passes_counter("run"), passes_counter("shared")) == \
+        (before[0] + 12, before[1] + 9)
+    holes = wide.sql(_avg_by_hour("holes"))
+    pd.testing.assert_frame_equal(
+        holes.drop(columns="avg(c3)"), got.drop(columns="avg(c3)"),
+        check_exact=True)
+    differs = holes["avg(c3)"] != got["avg(c3)"]
+    assert list(holes[differs]["hostname"]) == ["h2"]
+    assert holes[differs]["avg(c3)"].iloc[0] == float(np.mean(
+        [_wide_value(2, t, 3) for t in range(60, 120) if t != 70]))
+
+
+def test_a_tail_that_meets_a_null_keeps_that_columns_count(wide):
+    """The base holds no NULL and the row written after it one: the
+    tail's launch counts c3 by itself, the base's does not."""
+    sql = _avg_by_hour("wide")
+    wide.sql(sql)
+    wide.fe.do_query(
+        f"INSERT INTO wide VALUES ('h2', {H0 + 180 * 60_000}, " +
+        ", ".join("NULL" if c == 3 else "1.0" for c in range(10)) + ")")
+    detail = wide.stages(sql)["reduce"]
+    assert "moments=21, passes=11" in detail and "tail_rows=1" in detail \
+        and "tail_passes=12" in detail
+    got = wide.sql(sql)
+    row = got[got["hostname"] == "h2"].sort_values("hour").iloc[-1]
+    assert row["avg(c0)"] == 1.0 and row["avg(c9)"] == 1.0
+    assert pd.isna(row["avg(c3)"])
+
+
 def test_the_point_cell_runs_narrow_on_the_cpu_debug_run():
     """`benchmark/run.py --workload tsbs4k-point` at its CPU debug size:
     every family correct and device-resident, `path=narrow` on every
