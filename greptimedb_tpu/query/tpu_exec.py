@@ -288,34 +288,40 @@ def _block_fields(names, block: np.ndarray) -> dict:
 
 
 def _key_positions(sids: np.ndarray, ts: np.ndarray, new: _Rows):
-    """-> (pos, collide): where each row of `new` (sorted, unique keys)
-    goes among the rows (sids, ts) sorted by (series, ts): before row
-    pos[i], or onto it where `collide[i]` (the same key; None: no row
-    collides)."""
+    """-> (pos, collide, behind): where each row of `new` (sorted, unique
+    keys) goes among the rows (sids, ts) sorted by (series, ts): before
+    row pos[i], or onto it where `collide[i]` (the same key; None: no row
+    collides). `behind[i]`: the row lies at or before its series' last
+    row here (a late row, or with `collide` an overwrite; None: every row
+    comes after its series' last one)."""
     hi = np.searchsorted(sids, new.sids, side="right")
     if not len(ts):
-        return hi, None
+        return hi, None, None
     at = np.maximum(hi - 1, 0)
     # what ticks give: every row comes after its series' last one
-    if ((hi == 0) | (sids[at] != new.sids) | (ts[at] < new.ts)).all():
-        return hi, None
+    behind = (hi > 0) & (sids[at] == new.sids) & (ts[at] >= new.ts)
+    if not behind.any():
+        return hi, None, None
     from .scan_narrow import _lower_bound
     lo = np.searchsorted(sids, new.sids, side="left")
     pos = _lower_bound(ts, lo, hi, new.ts)      # every range at once
     collide = (pos < hi) & (ts[np.minimum(pos, len(ts) - 1)] == new.ts)
-    return pos, collide if collide.any() else None
+    return pos, collide if collide.any() else None, behind
 
 
-def _merge_rows(old: _Rows, new: _Rows, drop_deleted: bool = True) -> _Rows:
+def _merge_rows(old: _Rows, new: _Rows, drop_deleted: bool = True,
+                at=None) -> _Rows:
     """`new` merged into `old` (both sorted by (series, ts), keys unique
     within each; every row of `new` is newer than any of `old`): a row
     of `new` replaces the row of its key or takes its place in the
-    order; a tombstone of `new` removes itself and the row it shadows,
-    or with `drop_deleted` off stays as a tombstone of the result (for a
-    merge into older rows still to come). One search over the keys, one
-    pass a column (one for all fields of a `block`), no sort and no loop
-    over series."""
-    pos, collide = _key_positions(old.sids, old.ts, new)
+    order, wherever in time that is; a tombstone of `new` removes itself
+    and the row it shadows, or with `drop_deleted` off stays as a
+    tombstone of the result (for a merge into older rows still to come).
+    One search over the keys (`at`: its (pos, collide) where the caller
+    has made it), one pass a column (one for all fields of a `block`), no
+    sort and no loop over series."""
+    pos, collide = _key_positions(old.sids, old.ts, new)[:2] \
+        if at is None else at
     n_old = len(old)
     if collide is None:
         fresh, hit, dest_hit = slice(None), None, None
@@ -369,6 +375,95 @@ def _merge_rows(old: _Rows, new: _Rows, drop_deleted: bool = True) -> _Rows:
                  deleted, block)
 
 
+def _take_rows(rows: _Rows, keep: np.ndarray) -> _Rows:
+    """The rows of a put-only `rows` that the bool `keep` names."""
+    if rows.block is not None:
+        block = rows.block[keep]
+        fields = _block_fields(rows.fields, block)
+    else:
+        block = None
+        fields = {name: (d[keep], None if v is None else v[keep])
+                  for name, (d, v) in rows.fields.items()}
+    return _Rows(rows.sids[keep], rows.ts[keep], rows.seq[keep], fields,
+                 None, block)
+
+
+def _same_values(fields, at: np.ndarray, new: _Rows,
+                 rows: np.ndarray) -> np.ndarray:
+    """-> bool [len(rows)]: row rows[i] of `new` holds in every field what
+    the resident row at[i] of `fields` holds (a NULL equals a NULL; a NaN
+    equals nothing, so such a row counts as changed)."""
+    same = np.ones(len(rows), dtype=bool)
+    for name, (rd, rv) in fields.items():
+        nd, nv = new.fields[name]
+        a_ok = np.True_ if rv is None else rv[at]
+        b_ok = np.True_ if nv is None else nv[rows]
+        same &= (a_ok == b_ok) & (~(a_ok & b_ok) | (rd[at] == nd[rows]))
+    return same
+
+
+@dataclass
+class _Settled:
+    """What `_settle` made of a delta."""
+    rows: _Rows                       # what is left to write
+    #: its (pos, collide) among the tail's rows (None: there is no tail)
+    at_tail: Optional[tuple]
+    late: int = 0                     # at or before a series' last row
+    equal: int = 0                    # re-sent: dropped
+    changed: int = 0                  # overwrites that change a value
+    #: a changed row is the base's: only a merge can write it
+    changes_base: bool = False
+
+
+def _settle(base: "MergedScan", tail: Optional[_Rows],
+            delta: _Rows) -> _Settled:
+    """Where a put-only delta's rows go, by what base and tail hold at
+    their keys (one search a row over each, no pass over the base): a row
+    whose key neither holds is left for the tail, wherever its time lies
+    (`late` counts those at or before their series' last resident row); a
+    row whose key one of them holds with the same values is a retry, and
+    is dropped here (the resident row keeps the sequence it had: nothing
+    that reads the cache sees a difference); one that changes a value
+    stays, to replace the tail's row or, where it is the base's, to make
+    the caller merge."""
+    n = len(delta)
+    drop = np.zeros(n, dtype=bool)
+    late = np.zeros(n, dtype=bool)
+    held = np.zeros(n, dtype=bool)
+    out = _Settled(delta, None)
+
+    def look(sids, ts, fields):
+        pos, collide, behind = _key_positions(sids, ts, delta)
+        changed = 0
+        if behind is not None:
+            late[:] |= behind
+        if collide is not None:
+            held[:] |= collide
+            rows = np.flatnonzero(collide)
+            same = _same_values(fields, pos[rows], delta, rows)
+            drop[rows[same]] = True
+            changed = int((~same).sum())
+        return pos, collide, changed
+
+    _pos, _collide, changed = look(base.series_ids, base.ts, base.fields)
+    out.changed, out.changes_base = changed, changed > 0
+    if tail is not None and not out.changes_base:
+        pos, collide, changed = look(tail.sids, tail.ts, tail.fields)
+        out.changed += changed
+        out.at_tail = (pos, collide)
+    late &= ~held
+    out.late, out.equal = int(late.sum()), int(drop.sum())
+    if out.equal:
+        keep = ~drop
+        out.rows = _take_rows(delta, keep)
+        if out.at_tail is not None:
+            pos, collide = out.at_tail
+            collide = None if collide is None or not collide[keep].any() \
+                else collide[keep]
+            out.at_tail = (pos[keep], collide)
+    return out
+
+
 class _ScanCache:
     """Per-region merged-scan cache: byte-budget LRU, refreshed by what
     was written.
@@ -385,15 +480,21 @@ class _ScanCache:
     statement reduces both and folds the two partial frames
     (`_execute_region`).
 
-    A tail holds only rows that come after the base's in their series
-    (or belong to a series the base has not seen): the two partials of
-    one group are then disjoint in time, as streamed slices are. A delta
-    with a tombstone, or with a row at or before its series' last
-    resident one (an overwrite, a late row), and a tail past its
-    capacity, *merge* into a new base (`_merge_rows` over every column:
-    counted, `scan_cache_merges`; the new base has a new length, so its
-    mirrors are uploaded and its programs compiled again). `get` hands
-    the callers that want one sorted scan such a merged base.
+    A tail holds the rows whose key (series, time) the base does not
+    hold, wherever in time they lie: what came after the base's last row
+    of a series, a series the base has not seen, and rows that arrive
+    late into history (a relay's queue drained behind the live ticks).
+    The two partials of one group are disjoint in keys, which is what
+    sums, counts and extremes need; `first` / `last` fold by their
+    companion times (`_fold_runs`). `_settle` decides from what base and
+    tail hold at a delta's keys: a row that re-sends a resident row's
+    values (a retry) is dropped, one that changes a tail row's replaces
+    it there; a row that changes a base row's values, a tombstone, and a
+    tail past its capacity *merge* into a new base (`_merge_rows` over
+    every column: counted, `scan_cache_merges`; the new base has a new
+    length, so its mirrors are uploaded and its programs compiled
+    again). `get` hands the callers that want one sorted scan such a
+    merged base.
     Flushes and compactions whose files only contain already-covered
     sequences reuse the entry as it is; TTL retraction
     (region.retraction_epoch) and schema changes force a full rebuild.
@@ -575,8 +676,10 @@ class _ScanCache:
     def _incremental(self, region, v, entry: _CacheEntry, visible: int):
         """-> (base, tail) with the rows in (entry.visible, visible]
         applied. Parts of the statement's `scan_prep` row: `.delta` (the
-        rows collected and sorted), `.apply` (merged into the tail, or
-        tail and delta into a new base), `.upload` (the tail's pad mask
+        rows collected and sorted), `.apply` (`_settle`: retries dropped,
+        the rest merged into the tail wherever in time they lie, or tail
+        and delta into a new base; its detail counts `late=`,
+        `equal_dropped=`, `changed=`), `.upload` (the tail's pad mask
         and the mirrors its predecessor had in use, whole: a tail is
         sorted by (series, time), so a tick of every series lands in as
         many places as there are series and no suffix of a mirror is
@@ -592,15 +695,30 @@ class _ScanCache:
         base = entry.scan
         with exec_stats.stage("scan_prep.apply"):
             rows = None
-            if delta.deleted is None and _after_resident(base, delta):
-                rows = delta if entry.tail is None \
-                    else _merge_rows(_tail_rows(entry.tail), delta)
-                if len(rows) > tail_capacity(base.num_rows):
-                    rows = None
+            tail_rows = None if entry.tail is None \
+                else _tail_rows(entry.tail)
+            if delta.deleted is None:
+                settled = _settle(base, tail_rows, delta)
+                delta = settled.rows
+                increment_counter("scan_cache_late_rows", settled.late)
+                increment_counter("scan_cache_overwrites", settled.equal,
+                                  kind="equal")
+                increment_counter("scan_cache_overwrites", settled.changed,
+                                  kind="changed")
+                exec_stats.record("scan_prep.apply", late=settled.late,
+                                  equal_dropped=settled.equal,
+                                  changed=settled.changed)
+                if not len(delta):      # retries only: nothing to write
+                    return base, entry.tail
+                if not settled.changes_base:
+                    rows = delta if tail_rows is None else _merge_rows(
+                        tail_rows, delta, at=settled.at_tail)
+                    if len(rows) > tail_capacity(base.num_rows):
+                        rows = None
             if rows is None:
-                if entry.tail is not None:
+                if tail_rows is not None:
                     # tombstones stay: they may shadow rows of the base
-                    delta = _merge_rows(_tail_rows(entry.tail), delta,
+                    delta = _merge_rows(tail_rows, delta,
                                         drop_deleted=False)
                 merged = self._merged(base, delta)
                 exec_stats.record("scan_prep.apply", merged=1)
@@ -752,18 +870,6 @@ def _unmerged_from(v, entry: _CacheEntry) -> int:
             span = meta.time_range
             lo = min(lo, span[0] if span is not None else -lo)
     return int(lo)
-
-
-def _after_resident(base: MergedScan, delta: _Rows) -> bool:
-    """Whether every row of the delta comes after the base's last row of
-    its series (or its series is new to the base): a search a row, no
-    pass over the base."""
-    if base.num_rows == 0:
-        return True
-    hi = np.searchsorted(base.series_ids, delta.sids, side="right")
-    at = np.maximum(hi - 1, 0)
-    seen = (hi > 0) & (base.series_ids[at] == delta.sids)
-    return not (seen & (delta.ts <= base.ts[at])).any()
 
 
 def _tail_rows(tail: MergedScan) -> _Rows:
@@ -1817,12 +1923,16 @@ def _execute_region(region, table, plan: TpuPlan) -> Optional[pd.DataFrame]:
                 out = _moment_frame_for_scan(scan, table.schema, plan,
                                              shape=shape, runs=reads_tail)
             if tail is None:
-                if scan.num_rows >= TPU_DISPATCH_MIN_ROWS and shape:
+                if scan.num_rows >= TPU_DISPATCH_MIN_ROWS and shape \
+                        and not _wants_one_scan(plan):
                     _warm_tail_programs(scan, table.schema, plan,
                                         shape[0])
             elif not reads_tail:
                 exec_stats.record("reduce", tail="skipped")
             else:
+                if scan.num_rows and tail.ts_min <= _last_ts(scan):
+                    # late rows: the tail reaches into the base's span
+                    exec_stats.record("reduce", tail_span="history")
                 out = _base_and_tail_frame(out, _moment_frame_for_scan(
                     tail, table.schema, plan, tail=True, runs=True), plan)
             if out is not None and not len(out):
@@ -1838,9 +1948,11 @@ def _base_and_tail_frame(base: Optional["_RunPartial"],
                          tail: Optional["_RunPartial"],
                          plan: "TpuPlan") -> Optional[pd.DataFrame]:
     """One partial frame of the two launches. The partials of one group
-    are disjoint in time (a tail holds what came after the base), as
-    streamed slices are; they fold by run before the frame is made, or,
-    where that cannot be, in `_finalize` like any two partials."""
+    are disjoint in keys (a tail holds no (series, time) its base holds),
+    not in time: a tail also holds rows that arrived late into the base's
+    span. They fold by run before the frame is made (`first` / `last` by
+    their companion times), or, where that cannot be, in `_finalize` like
+    any two partials."""
     from ..common import exec_stats
     with exec_stats.stage("reduce.collect"):
         parts = [p for p in (base, tail) if p is not None]
@@ -1864,8 +1976,16 @@ def _wants_one_scan(plan: "TpuPlan") -> bool:
         any(m.op in RUN_DIFF_MOMENT_OPS for m in plan.moments)
 
 
+def _last_ts(base: MergedScan) -> int:
+    """The base's newest timestamp: one pass, once a base."""
+    if "__ts_max" not in base.device:
+        base.device["__ts_max"] = (int(base.ts.max()),)
+    return base.device["__ts_max"][0]
+
+
 def _outside(plan: "TpuPlan", tail: MergedScan) -> bool:
-    """The statement's time range holds no row of the tail."""
+    """The statement's time range holds no row of the tail (whose span
+    is that of its rows: late rows carry it back into history)."""
     return (plan.time_hi is not None and plan.time_hi <= tail.ts_min) or \
         (plan.time_lo is not None and plan.time_lo > tail.ts_max)
 
@@ -1877,15 +1997,19 @@ def _warm_tail_programs(base: MergedScan, schema, plan: "TpuPlan",
     compiled (a server's warm statements come before the writes: the
     first statement after one must not be the one that compiles). The
     launch is laid out over a stand-in tail, one row a series of the base
-    just after the base's last (the shapes a tick of every series
-    gives), lowered and compiled for those shapes and kept in
-    `base.tail_programs`; nothing is uploaded and nothing runs, so a
-    table nobody writes holds on the device what it held before. Once a
-    base and statement shape (`shape`: what the base's launch chose,
-    path and range bucket); a base under the dispatch floor
-    (`TPU_DISPATCH_MIN_ROWS`, as the operator has set it) reached the
-    device by another road (a plan the host path cannot run) and is left
-    to compile when a tail is met."""
+    at a time the statement reads: just after the base's last where its
+    range is open there (the shapes a tick of every series gives), else
+    the last instant of its range (a range closed inside the base's span
+    meets a tail only through rows that arrive late, and those land
+    inside it). A tail's group axis is pinned (`_pinned_groups`), so the
+    rows that do arrive meet the program compiled here. Lowered and
+    compiled for those shapes and kept in `base.tail_programs`; nothing is
+    uploaded and nothing runs, so a table nobody writes holds on the
+    device what it held before. Once a base and statement shape (`shape`:
+    what the base's launch chose, path and range bucket); a base under
+    the dispatch floor (`TPU_DISPATCH_MIN_ROWS`, as the operator has set
+    it) reached the device by another road (a plan the host path cannot
+    run) and is left to compile when a tail is met."""
     key = (shape, None if plan.bucket is None else
            (plan.bucket.stride_ms, _bucket_phase(plan.bucket)),
            bool(plan.tag_groups),
@@ -1895,13 +2019,12 @@ def _warm_tail_programs(base: MergedScan, schema, plan: "TpuPlan",
     if key in warmed:
         return
     warmed.add(key)
-    if plan.time_hi is not None:
-        # a range closed on the right is history to the rows written
-        # after the statement was composed: a tail of later rows is
-        # skipped (`_outside`), and one that is not compiles when met
-        return
-    after = int(base.ts.max()) + 1
-    if plan.time_lo is not None and plan.time_lo > after:
+    at = _last_ts(base) + 1
+    if plan.time_hi is not None and plan.time_hi <= at:
+        at = plan.time_hi - 1
+    if plan.time_lo is not None and plan.time_lo > at:
+        # a statement over times the base has no row of: the tail it
+        # meets compiles when met
         return
     with _reduce_part("tail_warm"):
         sids = base.series_ids
@@ -1914,7 +2037,7 @@ def _warm_tail_programs(base: MergedScan, schema, plan: "TpuPlan",
                                np.zeros(k, dtype=bool)))
                   for name, (vals, _) in base.fields.items()}
         stand_in = _make_tail(_Rows(
-            sids[first], np.full(k, after, np.int64),
+            sids[first], np.full(k, at, np.int64),
             np.zeros(k, np.int64), fields), base)
         stand_in.count_uploads = False
         stand_in.stand_in = True
@@ -2120,7 +2243,8 @@ def _launch_scan_kernel(scan: MergedScan, schema, plan: TpuPlan,
         # cached with the runs, per set of ops that read run ids or not:
         # at 7.7M runs the ends, the lengths and their maximum are 0.15 s
         layout_key = "__layout:" + run_key
-        needs_gids = _ops_need_gids(ops, nruns)
+        min_groups = _pinned_groups(scan, plan)
+        needs_gids = _ops_need_gids(ops, _group_bucket(nruns, min_groups))
         cached = scan.device.get(layout_key)
         if cached is not None and (not needs_gids or (
                 cached[2] is not None and rid is not None)):
@@ -2129,7 +2253,8 @@ def _launch_scan_kernel(scan: MergedScan, schema, plan: TpuPlan,
                 rid = seg_len_k = None
         else:
             nbucket, run_ends, rid, seg_len_k = _segment_layout(
-                run_starts, n, ops, rid, pinned=scan.pinned)
+                run_starts, n, ops, rid, pinned=scan.pinned,
+                min_groups=min_groups)
             scan.device[layout_key] = (nbucket, run_ends, seg_len_k)
             if rid is not None:
                 scan.device[run_key] = (rid, nruns, run_starts, buckets)
@@ -2197,24 +2322,46 @@ def _device_column(scan: MergedScan, column):
     return scan.device_field(column)
 
 
-def _ops_need_gids(ops, nruns: int) -> bool:
-    """Whether a launch's kernel ops read per-row run ids: first / last
-    / growth always, min / max above the high-cardinality threshold."""
+def _group_bucket(nruns: int, min_groups: int = 0) -> int:
+    """A launch's group axis: the runs' power of two, at least 256."""
+    return shape_bucket(nruns, minimum=max(256, min_groups))
+
+
+def _pinned_groups(scan: MergedScan, plan: TpuPlan) -> int:
+    """The least group axis of a full launch over a tail (0 for any other
+    scan): as a tail's row axis is a capacity, its group axis is what the
+    region's series give, so that the runs a write adds meet a compiled
+    program. Runs of whole series: one a series. Runs cut by a time
+    bucket too: two a series, which holds the live flow (every series in
+    one bucket) beside late rows of any share of the series in another,
+    or the live flow across a bucket's edge; a tail that cuts more runs
+    takes the next power of two, and compiles it once."""
+    if not scan.pinned or (plan.bucket is None and not plan.tag_groups):
+        return 0
+    k = max(int(scan.series_dict.num_series), 1)
+    return shape_bucket(k if plan.bucket is None else 2 * k, minimum=256)
+
+
+def _ops_need_gids(ops, num_groups: int) -> bool:
+    """Whether a launch's kernel ops read per-row run ids, by its group
+    axis (`_group_bucket`): first / last / growth always, min / max above
+    the high-cardinality threshold."""
     from ..ops.kernels import _SEG_HIGH_CARD_THRESHOLD
-    high_card = shape_bucket(nruns, minimum=256) > _SEG_HIGH_CARD_THRESHOLD
     return any(op in ("first", "last", "growth") for op in ops) or \
-        (high_card and any(op in ("min", "max") for op in ops))
+        (num_groups > _SEG_HIGH_CARD_THRESHOLD
+         and any(op in ("min", "max") for op in ops))
 
 
 def _segment_layout(run_starts: np.ndarray, n: int, ops, rid=None,
-                    pinned: bool = False):
+                    pinned: bool = False, min_groups: int = 0):
     """-> (num_groups, run_ends, rid, seg_len_k) for a launch over `n`
     rows cut into runs at `run_starts`; `rid` (the per-row run ids, made
     here unless handed in) and `seg_len_k` are None when no op reads
     them. `pinned` (a tail): `seg_len_k` is what a run of all `n` rows
-    would need, not what the longest run has today."""
+    would need, not what the longest run has today, and the group axis
+    is at least `min_groups` (`_pinned_groups`)."""
     nruns = len(run_starts)
-    nbucket = shape_bucket(nruns, minimum=256)
+    nbucket = _group_bucket(nruns, min_groups)
     # segment ends are free on the host (run boundaries are already
     # computed); shipping them skips the device binary search, the
     # dominant cost at high run cardinality
@@ -2226,7 +2373,7 @@ def _segment_layout(run_starts: np.ndarray, n: int, ops, rid=None,
     # for shape and both the O(n) rid cumsum and its upload are
     # skipped
     from ..ops.kernels import seg_len_bucket
-    if not _ops_need_gids(ops, nruns):
+    if not _ops_need_gids(ops, nbucket):
         return nbucket, run_ends, None, None
     if rid is None:
         starts_mark = np.zeros(n, dtype=np.int32)

@@ -380,7 +380,7 @@ def ttl_retraction(db):
 #: (the case, tails kept / merges / rebuilds of `cpu`'s entry it leaves)
 CASES = [
     (appended, "tail"),
-    (older_rows, "merge"),
+    (older_rows, "tail"),
     (overwrite, "merge"),
     (delete, "merge"),
     (new_series, "tail"),
