@@ -816,6 +816,17 @@ def distinct_arrays(arrays, absent):
     return tuple(out), tuple(ix)
 
 
+def open_window(dtype=np.int32):
+    """The time window that keeps every row of a time index of `dtype`
+    (as the device holds it: an int64 is an int32 there without x64):
+    its extremes, as the 0-d arrays `_sorted_grouped_aggregate_pre`
+    takes."""
+    dtype = jax.dtypes.canonicalize_dtype(dtype)
+    lo, hi = (-np.inf, np.inf) if np.issubdtype(dtype, np.floating) \
+        else (np.iinfo(dtype).min, np.iinfo(dtype).max)
+    return np.asarray(lo, dtype), np.asarray(hi, dtype)
+
+
 #: the launch's row count: the one pass every program runs, and what a
 #: count under no validity of its own is
 _ROW_COUNT = ("count", -1)
@@ -920,8 +931,8 @@ def sorted_grouped_aggregate(gids, mask, ts, values, col_masks=(), *,
                   mask_ix=mask_ix)
     if ends is not None:
         distinct, counts = _sorted_grouped_aggregate_pre(
-            gids, mask, ts, values, masks, ends, starts,
-            seg_len_k=seg_len_k, **static)
+            gids, mask, ts, open_window(ts.dtype), values, masks, ends,
+            starts, seg_len_k=seg_len_k, **static)
     else:
         distinct, counts = _sorted_grouped_aggregate(
             gids, mask, ts, values, masks, **static)
@@ -931,17 +942,25 @@ def sorted_grouped_aggregate(gids, mask, ts, values, col_masks=(), *,
 @functools.partial(jax.jit,
                    static_argnames=("num_groups", "ops", "value_ix",
                                     "mask_ix", "seg_len_k"))
-def _sorted_grouped_aggregate_pre(gids, mask, ts, values, col_masks, ends,
-                                  starts=None, *, num_groups, ops, value_ix,
-                                  mask_ix, seg_len_k=None):
+def _sorted_grouped_aggregate_pre(gids, mask, ts, window, values, col_masks,
+                                  ends, starts=None, *, num_groups, ops,
+                                  value_ix, mask_ix, seg_len_k=None):
     """_sorted_grouped_aggregate with host-precomputed segment ends, and
     `starts` where the segments are not the dense layout's.
+
+    `window`: (lo, hi), two traced scalars of `ts`'s dtype: a row counts
+    where `mask` holds and lo <= ts <= hi. A statement's time range
+    reaches the program so, as values and never as shapes: one program a
+    statement shape whatever the range, and no row mask made of it on the
+    host (`open_window`: the range that keeps every row).
 
     seg_len_k (static): ceil-log2 of the longest segment, bucketized by
     the caller — enables the shift-doubling min/max + first/last kernels
     at high cardinality. Callers must only pass it when `gids` holds
     REAL run ids (the scan path ships a dummy when no op needs them).
     """
+    lo, hi = window
+    mask = mask & (ts >= lo) & (ts <= hi)
     ends = jnp.asarray(ends)
     dense = starts is None
     starts = jnp.concatenate([jnp.zeros(1, jnp.int32), ends[:-1]]) \

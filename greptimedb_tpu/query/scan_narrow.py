@@ -23,7 +23,7 @@ import numpy as np
 
 from ..ops.kernels import (_SEG_HIGH_CARD_THRESHOLD,
                            _sorted_grouped_aggregate_pre, distinct_arrays,
-                           moment_results, shape_bucket)
+                           moment_results, open_window, shape_bucket)
 from . import tpu_exec
 
 #: The narrowed launch runs while the compact block (`padded_rows`:
@@ -216,7 +216,8 @@ def _narrow_reduce(cuts, ends, rid, row_mask, cols, *, len_b, num_groups,
     [lo, hi) of its live rows inside that slice; cols is (ts, the value
     columns, the validities), each column once; value_ix / mask_ix index
     the latter two per moment (value -1: ts itself; mask -1: the column
-    has no NULL). -> the distinct results and the row counts
+    has no NULL). The time window is open: the live offsets already hold
+    the statement's. -> the distinct results and the row counts
     (`ops/kernels.py:moment_sharing`)."""
     at, lo, hi = cuts
     j = jnp.arange(len_b, dtype=jnp.int32)[None, :]
@@ -226,9 +227,9 @@ def _narrow_reduce(cuts, ends, rid, row_mask, cols, *, len_b, num_groups,
     ts, values, valid = jax.tree_util.tree_map(
         lambda c: _cut(c, at, len_b), cols)
     return _sorted_grouped_aggregate_pre(
-        ts if rid is None else rid, live, ts, values, valid, ends,
-        num_groups=num_groups, ops=ops, value_ix=value_ix, mask_ix=mask_ix,
-        seg_len_k=seg_len_k)
+        ts if rid is None else rid, live, ts, open_window(ts.dtype), values,
+        valid, ends, num_groups=num_groups, ops=ops, value_ix=value_ix,
+        mask_ix=mask_ix, seg_len_k=seg_len_k)
 
 
 def launch(scan, schema, plan, sel: Selection, part):

@@ -993,7 +993,10 @@ def _load_slice(snap, dim: str, lo: int, hi: int, unit, needed_fields,
     # narrowing logic; anything else falls back to lazy upload at launch.
     try:
         rel = ts - base
-        if not rel.size or int(rel.max()) < 2 ** 31:
+        last = int(rel.max())
+        # the slice's newest time, for `tpu_exec._last_ts`
+        scan.device["__ts_max"] = (base + last,)
+        if last < 2 ** 31:
             scan.device["__ts"] = jax.device_put(rel.astype(np.int32))
         for name, (d2, v2) in fields.items():
             if d2.dtype in (np.float32, np.bool_, np.int32) or \

@@ -1983,11 +1983,34 @@ def _last_ts(base: MergedScan) -> int:
     return base.device["__ts_max"][0]
 
 
-def _outside(plan: "TpuPlan", tail: MergedScan) -> bool:
-    """The statement's time range holds no row of the tail (whose span
-    is that of its rows: late rows carry it back into history)."""
-    return (plan.time_hi is not None and plan.time_hi <= tail.ts_min) or \
-        (plan.time_lo is not None and plan.time_lo > tail.ts_max)
+def _outside(plan: "TpuPlan", scan: MergedScan) -> bool:
+    """The statement's time range lies outside the span of the scan's
+    rows: no pass over them. A tail's span is that of its rows (late rows
+    carry it back into history); any other scan starts at its `ts_base`
+    and ends at `_last_ts`."""
+    first = scan.ts_min if scan.pinned else scan.ts_base
+    if plan.time_hi is not None and plan.time_hi <= first:
+        return True
+    return plan.time_lo is not None and plan.time_lo > (
+        scan.ts_max if scan.pinned else _last_ts(scan))
+
+
+def _device_window(plan: "TpuPlan", scan: MergedScan):
+    """The statement's time range [time_lo, time_hi) as the kernel takes
+    it (`ops/kernels.py:_sorted_grouped_aggregate_pre`): inclusive bounds
+    in the coordinates of `scan.device_ts()`, two 0-d int32 arrays. The
+    upper one is made inclusive before the clip, so that a row at
+    relative time 2**31 - 1 is kept by a range that ends beyond it; an
+    open side is that extreme of an int32 (`ops/kernels.py:open_window`).
+    Exact for a range that `_outside` has not turned away (one that
+    starts past the int32 span starts past the scan's last row)."""
+    i32 = np.iinfo(np.int32)
+    lo, hi = i32.min, i32.max
+    if plan.time_lo is not None:
+        lo = min(max(int(plan.time_lo) - scan.ts_base, lo), hi)
+    if plan.time_hi is not None:
+        hi = min(max(int(plan.time_hi) - 1 - scan.ts_base, i32.min), hi)
+    return np.asarray(lo, np.int32), np.asarray(hi, np.int32)
 
 
 def _warm_tail_programs(base: MergedScan, schema, plan: "TpuPlan",
@@ -2106,6 +2129,8 @@ class _Launched:
     #: the group axis is the statement's live runs (`nruns` of them) out
     #: of this many the table has; None: the axis is the table's runs
     table_runs: Optional[int] = None
+    #: the host built and uploaded a row mask of the scan's length
+    host_mask: bool = False
 
 
 def _launch_for_scan(scan: MergedScan, schema, plan: TpuPlan, part):
@@ -2154,8 +2179,13 @@ def _moment_frame_for_scan(scan: MergedScan, schema, plan: TpuPlan,
     rows = scan.num_rows if scan.valid_rows is None else scan.valid_rows
     increment_counter("scan_device_rows",
                       sel.rows if path == "narrow" else rows)
+    # whether the host built and uploaded a row mask of the scan's length
+    # (tag predicates, field filters: a time range alone makes none)
+    made = "host" if launched is not None and launched.host_mask else "none"
+    increment_counter("scan_row_mask", made=made)
     if tail:
-        exec_stats.record("reduce", tail_rows=rows, tail_path=path)
+        exec_stats.record("reduce", tail_rows=rows, tail_path=path,
+                          tail_mask=made)
     elif path == "narrow":
         exec_stats.record("reduce", path=path, narrow_rows=sel.rows,
                           ranges=sel.n_ranges)
@@ -2169,6 +2199,7 @@ def _moment_frame_for_scan(scan: MergedScan, schema, plan: TpuPlan,
         else:
             increment_counter("scan_group_axis", axis="table")
             exec_stats.record("reduce", groups="table")
+        exec_stats.record("reduce", mask=made)
     if launched is None:
         return None
     run, shared = launched.passes
@@ -2191,10 +2222,12 @@ def _moment_frame_for_scan(scan: MergedScan, schema, plan: TpuPlan,
 def _launch_scan_kernel(scan: MergedScan, schema, plan: TpuPlan,
                         part=_untimed_part, sel=None) -> Optional[_Launched]:
     """`part(name)` times the host's steps for the resident path's
-    EXPLAIN ANALYZE: `runs` (run-id sweep), `mask`, `upload` (every
-    device_put), `launch` (the call that returns futures). `sel`: the
-    row ranges `scan_narrow.select` resolved the predicates to, where the
-    caller has them: the mask is their union, and where
+    EXPLAIN ANALYZE: `runs` (run-id sweep), `mask` (the predicates only
+    the host can apply; the time range goes to the program as two
+    scalars, `_device_window`), `upload` (every device_put), `launch`
+    (the call that returns futures). `sel`: the row ranges
+    `scan_narrow.select` resolved the predicates to, where the caller
+    has them: the mask is their union, and where
     `scan_narrow.scan_group_axis` says so the kernel's group axis is the
     runs they touch (every row is still read, under the table's run ids)
     and everything after the launch is sized by those."""
@@ -2214,9 +2247,11 @@ def _launch_scan_kernel(scan: MergedScan, schema, plan: TpuPlan,
     # queries with the same moment signature + shape bucket) ----
     with part("upload"):
         d_ts = scan.device_ts()
-        # unfiltered queries reuse the cached all-true device mask instead
-        # of uploading n bool bytes per query (50 MB at 50M rows, per
-        # query); padded streamed slices reuse the pre-staged padding mask
+        # a statement that nothing but time filters starts from the scan's
+        # resident mask, all true or true on the valid rows of a padded
+        # scan or a tail, and uploads none (n bool bytes a statement: 17 MB
+        # at 17M rows); its time range is `window`
+        window = _device_window(plan, scan)
         if mask is None:
             d_mask = scan.device_pad_mask() \
                 if scan.valid_rows is not None \
@@ -2274,7 +2309,7 @@ def _launch_scan_kernel(scan: MergedScan, schema, plan: TpuPlan,
         d_rid = d_ts
     with part("launch"):
         out = _run_program(
-            scan, _sorted_grouped_aggregate_pre, d_rid, d_mask, d_ts,
+            scan, _sorted_grouped_aggregate_pre, d_rid, d_mask, d_ts, window,
             values, col_masks, run_ends, live_starts, num_groups=nbucket,
             ops=ops, value_ix=value_ix, mask_ix=mask_ix,
             seg_len_k=seg_len_k)
@@ -2292,7 +2327,7 @@ def _launch_scan_kernel(scan: MergedScan, schema, plan: TpuPlan,
     return _Launched(results, counts, nruns, sids[run_starts],
                      _run_buckets(plan, buckets, run_starts),
                      scan.series_dict, scan.ts_base, passes, warm,
-                     table_runs)
+                     table_runs, mask is not None)
 
 
 def _moment_reads(schema, plan: TpuPlan):
@@ -2461,14 +2496,20 @@ _NO_ROWS = object()
 
 
 def _scan_row_mask(scan: MergedScan, schema, plan: TpuPlan, sel=None):
-    """-> the host row mask of the statement's predicates: a bool array,
-    None when nothing filters (the cached all-true device mask serves),
-    or _NO_ROWS. Where `scan_narrow.select` has resolved the tag
-    predicates and the time window to row ranges (`sel`), the mask is
-    their union: no pass over the table's series ids and times (three of
-    them at 46M rows were 0.3 s of a statement, and the part of it that
-    differed most from one server process to the next)."""
+    """-> the host row mask of what only the host can apply of the
+    statement's predicates: a bool array, None when nothing but time
+    filters (the scan's resident mask serves: the time range is the
+    program's, `_device_window`), or _NO_ROWS. Where `scan_narrow.select`
+    has resolved the tag predicates and the time window to row ranges
+    (`sel`), the mask is their union: no pass over the table's series ids
+    (with the two over its times, 0.3 s of a statement at 46M rows, and
+    the part of it that differed most from one server process to the
+    next). No pass over the times on any road: a range outside the scan's
+    span is turned away by its ends (`_outside`), one inside it that holds
+    no row launches and comes back with every count 0."""
     n = scan.num_rows
+    if _outside(plan, scan):
+        return _NO_ROWS
     if sel is not None and not plan.field_filters and \
             (scan.valid_rows is None or scan.pinned):
         if sel.n_ranges == 0:
@@ -2478,33 +2519,20 @@ def _scan_row_mask(scan: MergedScan, schema, plan: TpuPlan, sel=None):
                         (sel.starts + sel.lens).tolist()):
             mask[a:b] = True
         return mask
-    # ---- per-series tag predicate → row mask ----
-    base_mask = None
-    if plan.tag_predicates:
+    if not plan.tag_predicates and not plan.field_filters:
+        return None
+    if plan.tag_predicates:     # per-series tag predicate → row mask
         sd = scan.series_dict
         smask = _series_keep(sd, schema.tag_names(),
                              np.arange(sd.num_series, dtype=np.int32),
                              plan.tag_predicates)
         if not smask.any():
             return _NO_ROWS
-        base_mask = smask[scan.series_ids]
-
-    # ---- row mask (cheap elementwise, skipped entirely for the
-    # unfiltered case so unpadded/pre-staged scans touch no O(n) host
-    # memory here) ----
-    unfiltered = base_mask is None and plan.time_lo is None and \
-        plan.time_hi is None and not plan.field_filters
-    if unfiltered and (scan.valid_rows is None or scan.pinned
-                       or "__pad_mask" in scan.device):
-        return None
-    mask = base_mask.copy() if base_mask is not None \
-        else np.ones(n, dtype=bool)
+        mask = smask[scan.series_ids]
+    else:
+        mask = np.ones(n, dtype=bool)
     if scan.valid_rows is not None and scan.valid_rows < n:
         mask[scan.valid_rows:] = False   # shape-bucket padding rows
-    if plan.time_lo is not None:
-        mask &= scan.ts >= plan.time_lo
-    if plan.time_hi is not None:
-        mask &= scan.ts < plan.time_hi
     for ff in plan.field_filters:
         mask &= _field_filter_keep(scan, ff)
     return mask if mask.any() else _NO_ROWS

@@ -945,5 +945,12 @@ def test_the_row_mask_from_ranges_is_the_mask_over_every_row(fleet, matchers,
     by_ranges = tpu_exec._scan_row_mask(scan, table.schema, plan, sel)
     if by_rows is tpu_exec._NO_ROWS:
         assert by_ranges is tpu_exec._NO_ROWS
-    else:
-        assert by_rows.sum() > 0 and np.array_equal(by_rows, by_ranges)
+        return
+    # the rows a launch keeps: the host's mask under the program's window.
+    # The ranges hold the window already; the mask over every row leaves
+    # it to the program
+    lo, hi = tpu_exec._device_window(plan, scan)
+    rel = scan.ts - scan.ts_base
+    window = (rel >= lo) & (rel <= hi)
+    assert by_ranges.sum() > 0 and not (by_ranges & ~window).any()
+    assert np.array_equal(by_rows & window, by_ranges)

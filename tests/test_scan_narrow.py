@@ -638,6 +638,65 @@ def test_no_tag_conjunct_keeps_the_tables_axis_and_its_program(db):
     assert _sorted_grouped_aggregate_pre._cache_size() == compiled
 
 
+# ---------------------------------------------------------------------------
+# the full launch's time window: two scalars of its program (ISSUE 43). The
+# host makes a row mask of the table's length where a tag predicate or a
+# field filter needs one, never for the window.
+# ---------------------------------------------------------------------------
+
+def masks_made() -> tuple:
+    return tuple(total("greptime_scan_row_mask_total", f'made="{made}"')
+                 for made in ("none", "host"))
+
+
+#: (id, WHERE beside the window, pandas filter, the mask the host makes)
+WINDOWED = [
+    ("time-only", "", lambda r: r.usage == r.usage, "none"),
+    ("ne-tag", "region != 'r1' AND ", lambda r: r.region != "r1", "host"),
+    ("field-filter", "usage > 60 AND ", lambda r: r.usage > 60, "host"),
+    ("in-tag-run-full", f"host IN ({in_list(range(0, HOSTS, 2))}) AND ",
+     lambda r: r.host.isin([f"h{h:02d}" for h in range(0, HOSTS, 2)]),
+     "host"),
+]
+
+
+@pytest.mark.parametrize("case", WINDOWED, ids=[c[0] for c in WINDOWED])
+def test_a_windowed_full_launch_answers_the_reference_and_names_its_mask(
+        db, case):
+    _, where, keep, made = case
+    sql = (f"SELECT host, date_bin(INTERVAL '1 minute', ts) AS minute, "
+           f"{EXACT}, sum(usage), avg(idle) FROM cpu WHERE {where}{WINDOW} "
+           "GROUP BY host, minute ORDER BY host, minute")
+    before = masks_made(), counter("full")
+    got = db.sql(sql)
+    assert counter("full") == before[1] + 1
+    assert masks_made() == (before[0][0] + (made == "none"),
+                            before[0][1] + (made == "host"))
+    want = reference(db.ref, keep, ["host", "minute"])
+    assert len(got) == len(want) > 0
+    assert list(got.host) == list(want.host)
+    assert np.array_equal(got["max(usage)"].to_numpy(),
+                          want.mx.to_numpy().astype(np.float32))
+    assert np.array_equal(got["min(usage)"].to_numpy(),
+                          want.mn.to_numpy().astype(np.float32))
+    assert np.array_equal(got["count(usage)"].to_numpy(), want.n)
+    assert np.array_equal(got["count(idle)"].to_numpy(), want.ni)
+    np.testing.assert_allclose(got["sum(usage)"], want.sm, rtol=AVG_RTOL)
+    np.testing.assert_allclose(got["avg(idle)"], want.avi, rtol=AVG_RTOL)
+    detail = db.stages(sql)["reduce"]
+    assert f"path=full, groups=table, mask={made}" in detail
+
+
+def test_a_narrow_launch_makes_no_mask_of_the_tables_length(db):
+    sql = (f"SELECT host, max(usage) FROM cpu WHERE host = 'h05' AND "
+           f"usage > 10 AND {WINDOW} GROUP BY host")
+    before = masks_made(), counter("narrow")
+    assert len(db.sql(sql)) == 1
+    assert counter("narrow") == before[1] + 1
+    assert masks_made() == (before[0][0] + 1, before[0][1])
+    assert "mask=" not in db.stages(sql)["reduce"]
+
+
 H0 = T0 - T0 % 3_600_000                    # a whole hour
 
 
