@@ -157,6 +157,7 @@ def standalone_start(args) -> None:
         configure_otlp(opts.otlp_endpoint, service_name="greptimedb")
     install_panic_hook()
     install_gc_timer()
+    _pin_allocator()
     _claim_device()
     fe, servers = build_servers(opts)
     for s in servers:
@@ -176,6 +177,17 @@ def standalone_start(args) -> None:
     for s in servers:
         s.shutdown()
     fe.shutdown()
+
+
+def _pin_allocator() -> None:
+    """The roles that hold tables: malloc's thresholds fixed before the
+    device is claimed (`common/runtime.py:pin_allocator`), and said in
+    the log."""
+    from ..common.runtime import pin_allocator
+    pinned = pin_allocator()
+    logging.info("allocator: %s", "as the environment has it"
+                 if pinned is None else ", ".join(
+                     f"{k}={v >> 20} MiB" for k, v in pinned.items()))
 
 
 def _claim_device() -> None:
@@ -325,6 +337,7 @@ def datanode_start(args) -> None:
     from ..servers.flight import FlightDatanodeServer
 
     init_logging(args.log_level or "info")
+    _pin_allocator()
     _claim_device()
     # buffer-role trace sink: this process cannot decide tail-sampling
     # verdicts (it sees only its fragments of each trace) and cannot

@@ -27,7 +27,7 @@ __all__ = ["RepeatedTask", "spawn_bg", "spawn_read", "spawn_write",
            "bg_runtime", "read_runtime", "write_runtime", "dist_runtime",
            "dist_fanout", "configure_dist_fanout", "env_int",
            "shutdown_runtimes", "new_thread", "transient_executor",
-           "spawn_on"]
+           "spawn_on", "pin_allocator"]
 
 _lock = threading.Lock()
 _pools = {}
@@ -234,3 +234,50 @@ def _bounded_ordered(pool: concurrent.futures.Executor, fn: Callable,
         # futures finish; their results are dropped)
         for f in pending:
             f.cancel()
+
+
+#: glibc's `mallopt` parameters (malloc.h), and what `pin_allocator` sets
+_M_TRIM_THRESHOLD, _M_TOP_PAD, _M_MMAP_THRESHOLD = -1, -2, -3
+ALLOCATOR_PINS = (("mmap_threshold", _M_MMAP_THRESHOLD, 32 << 20),
+                  ("trim_threshold", _M_TRIM_THRESHOLD, 1 << 30),
+                  ("top_pad", _M_TOP_PAD, 64 << 20))
+
+
+def pin_allocator() -> Optional[dict]:
+    """Fix glibc malloc's thresholds for the life of a server process.
+    -> what was set (bytes by name), or None where nothing was (another
+    libc; an operator who set `MALLOC_*_` or `GLIBC_TUNABLES` keeps it).
+
+    Left alone, glibc moves its mmap threshold up to the largest block
+    the process has freed so far (128 KiB at start, 32 MiB at most) and
+    its trim threshold with it. A statement's arrays are 1 to 30 MB each
+    (a partial frame's columns at 808,000 groups, a device result, a row
+    mask), so whether they are cut from memory the process kept or mapped
+    anew and faulted in a page at a time hangs on what the process
+    happened to free before: what set-up allocated, the order of the
+    statements, which thread's arena. The same statement on the same
+    data then costs 140 ms of `reduce.collect` in one process, or in one
+    part of a window, and 245 in another, all of it CPU time of the
+    statement's thread (PERF.md, PR 44), and two server processes of one
+    commit differ by more than a bound of the benchmark. Pinned at the
+    ceiling of glibc's own rule, with a gigabyte kept before a heap is
+    trimmed and a thread's arena keeping its heaps (`top_pad`), a block
+    under 32 MiB comes from memory the process holds, from the first
+    statement on. What it costs: the process gives memory back to the system a
+    gigabyte late; a block over 32 MiB (a table's column) is cut from
+    the kept memory where that has room and mapped, and returned when
+    freed, where not, as before. Once a process, by the roles that hold
+    tables (`cmd/main.py`), before they claim the device."""
+    import ctypes
+    if any(k == "GLIBC_TUNABLES" or (k.startswith("MALLOC_")
+                                     and k.endswith("_"))
+           for k in os.environ):
+        return None
+    try:
+        libc = ctypes.CDLL(None)
+        libc.gnu_get_libc_version       # glibc alone reads these numbers
+        mallopt = libc.mallopt
+    except (OSError, AttributeError):
+        return None
+    return {name: value for name, param, value in ALLOCATOR_PINS
+            if mallopt(param, value) == 1} or None

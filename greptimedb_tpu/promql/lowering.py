@@ -537,7 +537,10 @@ def select_series(engine, sel: VectorSelector, lo_ms: int, hi_ms: int,
     The `select` row of the statement's collector, with its parts:
     `.scan` (the region's rows as host arrays), `.filter` (matchers to a
     series mask, then to the rows kept), `.labels` (the kept series'
-    label sets) and `.matrix` (the [series, samples] matrix built)."""
+    label sets), `.matrix` (the [series, samples] matrix built) and,
+    where rows were written since the scan cache's base was built,
+    `.tail` (what the selection takes from them: `ScanParts`; the row's
+    detail says `tail_rows=`)."""
     from ..common import exec_stats
     from ..common.telemetry import increment_counter
     with exec_stats.stage("select"):
@@ -622,18 +625,24 @@ def _select_series(engine, sel: VectorSelector, lo_ms: int, hi_ms: int,
                 keep &= _matcher_keep(tag_strs[tag_names.index(m.name)], m)
             if not keep.any():
                 continue
+        scans = scan.parts if isinstance(scan, ScanParts) else [scan]
         if len(regions) == 1 and not multi_field:
-            with stage("select.matrix"):
-                direct = _matrix_from_runs(scan, fields[0], keep, sid_set,
-                                           lo_ms, hi_ms)
+            direct = _matrix_from_runs(scans, fields[0], keep, sid_set,
+                                       lo_ms, hi_ms)
             if direct is not None:
                 return _selection_from_runs(direct, sd, metric, tag_names,
                                             tag_strs)
+        kept_rows = []
+        for i, part in enumerate(scans):
+            with stage("select.tail" if i else "select.filter"):
+                kept_rows.append(_rows_kept(part, keep, sid_set, lo_ms,
+                                            hi_ms))
+        if not sum(map(len, kept_rows)):
+            continue
         with stage("select.filter"):
-            rows = _rows_kept(scan, keep, sid_set, lo_ms, hi_ms)
-            if not len(rows):
-                continue
-            survivors = np.unique(scan.series_ids[rows]).astype(np.int32)
+            survivors = np.unique(np.concatenate(
+                [part.series_ids[rows] for part, rows
+                 in zip(scans, kept_rows)])).astype(np.int32)
 
         # decode the remaining tag columns only for surviving series
         with stage("select.labels"):
@@ -641,17 +650,19 @@ def _select_series(engine, sel: VectorSelector, lo_ms: int, hi_ms: int,
                 sd, len(tag_names), tag_strs, survivors)))
 
         for fname in fields:
-            with stage("select.filter"):
-                vals, valid = scan.fields[fname]
-                rk = rows if valid is None else rows[valid[rows]]
-                if not len(rk):
-                    continue
-                sids = scan.series_ids[rk]
-                ts = scan.ts[rk]
-                v = vals[rk].astype(np.float64)
+            taken = []
+            for i, (part, rows) in enumerate(zip(scans, kept_rows)):
+                with stage("select.tail" if i else "select.filter"):
+                    vals, valid = part.fields[fname]
+                    rk = rows if valid is None else rows[valid[rows]]
+                    if len(rk):
+                        taken.append((part.series_ids[rk], part.ts[rk],
+                                      vals[rk].astype(np.float64)))
+            if not taken:
+                continue
             # map region series → global series ids
             with stage("select.labels"):
-                uniq = np.unique(sids)
+                uniq = np.unique(np.concatenate([t[0] for t in taken]))
                 remap = np.full(S, -1, dtype=np.int32)
                 for s in uniq:
                     lbl_key = label_of[int(s)]
@@ -668,7 +679,7 @@ def _select_series(engine, sel: VectorSelector, lo_ms: int, hi_ms: int,
                             lbl["__field__"] = fname
                         glabels.append(lbl)
                     remap[s] = gid
-                parts.append((remap[sids], ts, v))
+                parts += [(remap[sids], ts, v) for sids, ts, v in taken]
 
     if not parts:
         return _Selection([], None)
@@ -720,7 +731,16 @@ def _bisect_runs(ts: np.ndarray, first: np.ndarray, end: np.ndarray,
         hi = np.where(open_ & ~right, mid, hi)
 
 
-def _matrix_from_runs(scan, field: str, keep: np.ndarray, sid_set,
+def _cut_runs(scan, sids: np.ndarray, lo_ms: int, hi_ms: int):
+    """-> (start, count): each of these series' samples in [lo_ms, hi_ms]
+    as one slice of a scan sorted by (series, time)."""
+    first = np.searchsorted(scan.series_ids, sids, side="left")
+    end = np.searchsorted(scan.series_ids, sids, side="right")
+    start = _bisect_runs(scan.ts, first, end, lo_ms, after=False)
+    return start, _bisect_runs(scan.ts, start, end, hi_ms, after=True) - start
+
+
+def _matrix_from_runs(scans, field: str, keep: np.ndarray, sid_set,
                       lo_ms: int, hi_ms: int):
     """The selection's matrix cut straight from the scan cache's rows,
     which lie sorted by series and then time: a series' samples in
@@ -729,41 +749,80 @@ def _matrix_from_runs(scan, field: str, keep: np.ndarray, sid_set,
     (a mask over every row, flat copies of the kept rows, a sort check,
     a scatter into the matrix) reads and writes the selection some
     twenty times over; a panel over a whole table is bound by exactly
-    those passes. -> (kept series ids, SeriesMatrix, first time, last
-    time), () when no series has a sample there, or None where only the
-    general path applies (rows of a cold read, a field with nulls)."""
+    those passes. `scans`: the base, and where rows were written since
+    it was built its tail (`ScanParts`), sorted the same way: a series'
+    row holds its slice of the base and then its slice of the tail
+    (`select.tail`: the tail's bisection and its cells), a series the
+    base has never seen its slice of the tail alone; the cost follows
+    the selection and the tail, never the table. -> (kept series ids,
+    SeriesMatrix, first time, last time), () when no series has a sample
+    there, or None where only the general path applies (rows of a cold
+    read, a field with nulls, a tail that reaches back before its base's
+    last sample of a selected series)."""
+    from ..common.exec_stats import stage
     from ..ops.window import TS_PAD, SeriesMatrix
     from ..query.tpu_exec import MergedScan
-    vals, valid = scan.fields[field]
-    if valid is not None or not isinstance(scan, MergedScan):
+    if any(s.fields[field][1] is not None or not isinstance(s, MergedScan)
+           for s in scans):
         return None
-    sids = np.nonzero(keep)[0] if sid_set is None else \
-        sid_set[sid_set < len(keep)]
-    sids = sids[keep[sids]]
-    first = np.searchsorted(scan.series_ids, sids, side="left")
-    end = np.searchsorted(scan.series_ids, sids, side="right")
-    start = _bisect_runs(scan.ts, first, end, lo_ms, after=False)
-    count = _bisect_runs(scan.ts, start, end, hi_ms, after=True) - start
+    scan = scans[0]
+    vals = scan.fields[field][0]
+    tail = scans[1] if len(scans) > 1 else None
+    with stage("select.matrix"):
+        sids = np.nonzero(keep)[0] if sid_set is None else \
+            sid_set[sid_set < len(keep)]
+        sids = sids[keep[sids]]
+        start, count = _cut_runs(scan, sids, lo_ms, hi_ms)
     has = count > 0
+    if tail is not None:
+        with stage("select.tail"):
+            t_start, t_count = _cut_runs(tail, sids, lo_ms, hi_ms)
+            both = has & (t_count > 0)
+            if (tail.ts[t_start[both]]
+                    <= scan.ts[(start + count - 1)[both]]).any():
+                return None     # a late row: the general path sorts
+            has = has | (t_count > 0)
+            t_start, t_count = t_start[has], t_count[has]
     if not has.any():
         return ()
-    sids, start, count = sids[has], start[has], count[has]
-    width = int(count.max())
-    width = 1 << (width - 1).bit_length() if width > 1 else 1
-    rows = series_bucket(len(sids))
-    lengths = np.zeros(rows, dtype=np.int32)
-    lengths[:len(sids)] = count
-    begin = np.zeros(rows, dtype=np.int64)
-    begin[:len(sids)] = start
-    cell = np.arange(width)[None, :]
-    pad = cell >= lengths[:, None]
-    at = np.minimum(begin[:, None] + cell, len(scan.ts) - 1)
-    ts2d = scan.ts[at]
-    ts2d[pad] = TS_PAD
-    val2d = vals[at].astype(np.float64, copy=False)
-    val2d[pad] = 0.0
-    return (sids, SeriesMatrix(ts2d, val2d, lengths),
-            int(scan.ts[start].min()), int(scan.ts[start + count - 1].max()))
+    with stage("select.matrix"):
+        sids, start, count = sids[has], start[has], count[has]
+        total = count if tail is None else count + t_count
+        width = int(total.max())
+        width = 1 << (width - 1).bit_length() if width > 1 else 1
+        rows = series_bucket(len(sids))
+        lengths = np.zeros(rows, dtype=np.int32)
+        lengths[:len(sids)] = total
+        begin = np.zeros(rows, dtype=np.int64)
+        begin[:len(sids)] = start
+        cell = np.arange(width)[None, :]
+        pad = cell >= lengths[:, None]
+        at = np.minimum(begin[:, None] + cell, len(scan.ts) - 1)
+        ts2d = scan.ts[at]
+        ts2d[pad] = TS_PAD
+        val2d = vals[at].astype(np.float64, copy=False)
+        val2d[pad] = 0.0
+        in_base = count > 0
+        data_min = scan.ts[start[in_base]].min(initial=np.iinfo(np.int64).max)
+        data_max = scan.ts[(start + count - 1)[in_base]].max(
+            initial=np.iinfo(np.int64).min)
+    if tail is not None:
+        with stage("select.tail"):
+            # a row's cells from its base count on are the tail's
+            k = len(sids)
+            off = cell - count[:, None]
+            mine = (off >= 0) & (off < t_count[:, None])
+            at = (t_start[:, None] + off)[mine]
+            ts2d[:k][mine] = tail.ts[at]
+            val2d[:k][mine] = tail.fields[field][0][at]
+            in_tail = t_count > 0
+            data_min = min(data_min, tail.ts[t_start[in_tail]].min(
+                initial=np.iinfo(np.int64).max))
+            data_max = max(data_max, tail.ts[
+                (t_start + t_count - 1)[in_tail]].max(
+                    initial=np.iinfo(np.int64).min))
+    return sids, SeriesMatrix(ts2d, val2d, lengths), int(data_min), \
+        int(data_max)
 
 
 def _selection_from_runs(direct, sd, metric: str, tag_names: List[str],
@@ -944,19 +1003,43 @@ def matcher_sids(region, tag_names, eq_matchers):
     return cand
 
 
+class ScanParts:
+    """A resident region's rows as the scan cache holds them after a
+    write: the base, and the rows written since as a second scan sorted
+    the same way (`query/tpu_exec.py:_ScanCache.get_parts`), cut to its
+    valid rows. The selector reads both and merges neither."""
+
+    def __init__(self, base, tail):
+        from ..query.tpu_exec import MergedScan
+        n = tail.valid_rows
+        self.parts = [base, MergedScan(
+            tail.series_ids[:n], tail.ts[:n], tail.fields,
+            tail.series_dict, tail.ts_base)]
+        self.series_dict = base.series_dict
+        self.num_rows = base.num_rows + n
+
+
 def region_scan(region, fields: List[str], lo_ms: int, hi_ms: int,
                 sid_set=None):
     """Rows for one region: the device-resident scan cache for warm
-    regions; a window-bounded streamed cold read for regions past the
-    streaming threshold. Both shapes expose
-    series_ids/ts/fields/series_dict."""
+    regions (a `MergedScan`, or `ScanParts` where it holds a tail); a
+    window-bounded streamed cold read for regions past the streaming
+    threshold. All expose series_ids/ts/fields/series_dict, `ScanParts`
+    a scan at a time."""
     from ..common.telemetry import increment_counter
     from ..common.time import TimestampRange
     from ..query.tpu_exec import SCAN_CACHE, region_streams_cold
 
     if not region_streams_cold(region):
         increment_counter("promql_select_resident")
-        return SCAN_CACHE.get(region)
+        base, tail = SCAN_CACHE.get_parts(region, hi_ms + 1)
+        increment_counter("promql_select_parts",
+                          tail="no" if tail is None else "yes")
+        if tail is None:
+            return base
+        from ..common import exec_stats
+        exec_stats.record("select", tail_rows=tail.valid_rows)
+        return ScanParts(base, tail)
     # cold path: merged host read of only the selector's window and
     # fields — proportional to the window, never enters the scan
     # cache, leaves no device residency behind

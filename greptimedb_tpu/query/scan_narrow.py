@@ -103,20 +103,64 @@ def run_spans(run_starts: np.ndarray, sel: "Selection"):
 
 
 def live_layout(run_starts: np.ndarray, run_ends: np.ndarray,
-                lo: np.ndarray, hi: np.ndarray, n: int):
+                lo: np.ndarray, hi: np.ndarray, n: int, min_groups: int = 0):
     """-> (n_live, num_groups, starts, ends): the kernel's segments for
     the live runs the spans [lo[i], hi[i]) of `run_spans` name, end to
-    end, padded to their bucket with empty groups at `n` (as the table's
-    padded runs are). Nothing of the table's run count is built."""
+    end, padded to their bucket (at least `min_groups`: a tail's axis is
+    pinned, `tpu_exec._tail_groups`) with empty groups at `n` (as the
+    table's padded runs are). Nothing of the table's run count is built."""
     c = hi - lo
     n_live = int(c.sum())
     live = np.repeat(lo - (np.cumsum(c) - c), c) + np.arange(n_live)
-    num_groups = shape_bucket(n_live, minimum=256)
-    starts = np.full(num_groups, n, dtype=np.int32)
-    ends = np.full(num_groups, n, dtype=np.int32)
-    starts[:n_live] = run_starts[live]
-    ends[:n_live] = run_ends[live]
-    return n_live, num_groups, starts, ends
+    return (n_live,) + padded_layout(run_starts[live], run_ends[live], n,
+                                     min_groups)
+
+
+def selection_runs(ts: np.ndarray, sel: "Selection", origin: int,
+                   stride: int):
+    """-> (starts, ends, buckets): the runs a bucket grid (`origin`,
+    `stride`) cuts inside the ranges of `sel`, as rows of the table, and
+    each run's bucket number from `origin`. One pass over the selected
+    rows and none over the table: what a statement pays whose grid the
+    scan holds no layout of (`tpu_exec._selection_layout`). A run ends
+    with its range; the rows of its (series, bucket) outside the range
+    are the row mask's to drop, as on the table's runs."""
+    first = np.cumsum(sel.lens) - sel.lens
+    rows = np.repeat(sel.starts - first, sel.lens) + \
+        np.arange(sel.rows, dtype=np.int64)
+    buckets = (ts[rows] - origin) // stride
+    flags = np.empty(len(rows), dtype=bool)
+    np.not_equal(buckets[1:], buckets[:-1], out=flags[1:])
+    flags[first] = True
+    at = np.nonzero(flags)[0]
+    starts = rows[at]
+    ends = np.empty_like(starts)
+    ends[:-1] = rows[at[1:] - 1] + 1
+    ends[-1] = rows[-1] + 1
+    return starts, ends, buckets[at]
+
+
+def padded_layout(starts: np.ndarray, ends: np.ndarray, n: int,
+                  min_groups: int = 0):
+    """-> (num_groups, starts, ends): the kernel's segments padded to
+    their bucket (at least `min_groups`) with empty groups at `n`, as the
+    table's padded runs are."""
+    num_groups = shape_bucket(len(starts), minimum=max(256, min_groups))
+    out = np.full((2, num_groups), n, dtype=np.int32)
+    out[0, :len(starts)], out[1, :len(starts)] = starts, ends
+    return num_groups, out[0], out[1]
+
+
+@jax.jit
+def run_labels(sids, ts, grid):
+    """int32 [n]: a label a row that two rows share exactly where they
+    share a series and a bucket of the grid (`grid`: its edge at or
+    before the first row in `ts`'s coordinates, its stride, the buckets
+    a series can lie in; traced, so one program a scan's length).
+    Stands in for run ids wherever a kernel compares them and indexes
+    nothing by them: the live axis (`_sga_body`, `dense=False`)."""
+    edge, stride, per_series = grid
+    return sids * per_series + jax.lax.div(ts - edge, stride)
 
 
 @dataclass
@@ -241,6 +285,9 @@ def launch(scan, schema, plan, sel: Selection, part):
         return None
     n = scan.num_rows
     k_b, len_b = sel.range_bucket, sel.len_bucket
+    reads = list(tpu_exec._moment_reads(schema, plan,
+                                        seams=scan.base is not None))
+    tpu_exec._make_seams(scan, reads, part)
     with part("runs"):
         # compact coordinates: range i lives in [i * len_b, (i+1) * len_b),
         # its rows from `off[i]` on (0 unless the slice was clamped at the
@@ -265,7 +312,7 @@ def launch(scan, schema, plan, sel: Selection, part):
         run_rows = np.nonzero(flags)[0]
         run_starts = pos[run_rows]
         run_starts[0] = 0        # runs tile the block: padding joins a run
-        ops, value_ix, mask_ix, cols = _columns(scan, schema, plan)
+        ops, value_ix, mask_ix, cols = _columns(scan, schema, plan, reads)
         nbucket, run_ends, rid, seg_len_k = tpu_exec._segment_layout(
             run_starts, k_b * len_b, ops, pinned=scan.pinned)
     row_mask = None
@@ -297,14 +344,14 @@ def launch(scan, schema, plan, sel: Selection, part):
         scan.series_dict, scan.ts_base, passes)
 
 
-def _columns(scan, schema, plan):
+def _columns(scan, schema, plan, reads):
     """-> (ops, value_ix, mask_ix, cols): the moments' kernel ops and the
     resident columns they read as (ts, values, validities), each column
     once (a value index of -1: ts itself; a mask index of -1: the column
     has no NULL)."""
     d_ts = scan.device_ts()
     ops, values, masks = [], [], []
-    for op, field_read, masked_by in tpu_exec._moment_reads(schema, plan):
+    for op, field_read, masked_by in reads:
         ops.append(op)
         values.append(d_ts if field_read is None
                       else tpu_exec._device_column(scan, field_read))

@@ -22,7 +22,8 @@ from __future__ import annotations
 import contextlib
 import threading
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import (Any, Dict, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import numpy as np
 import pandas as pd
@@ -83,9 +84,16 @@ class MergedScan:
     tail_programs: dict = field(default_factory=dict)
     #: a tail's: its base's `tail_programs`
     programs: Optional[dict] = None
+    #: a base's: what its launch of a statement shape chose last
+    #: (`_LaunchShape` by `_statement_shape`), for the launch over its
+    #: tail to follow (`_base_launch`) and `_warm_tail_programs` to key by
+    launch_shapes: dict = field(default_factory=dict)
     #: a tail nobody reads (`_warm_tail_programs`): its mirrors are shapes,
     #: nothing is uploaded, and its launch is compiled, not run
     stand_in: bool = False
+    #: a tail's: the base it follows (a series' first difference here
+    #: reaches back to its last sample there: `device_run_diffs`)
+    base: Optional["MergedScan"] = None
 
     @property
     def num_rows(self) -> int:
@@ -135,6 +143,13 @@ class MergedScan:
             self._put("__ts", rel.astype(np.int32))
         return self.device["__ts"]
 
+    def device_sids(self):
+        """The series id a row: with the times, what a run label is made
+        from where no layout holds run ids (`scan_narrow.run_labels`)."""
+        if "__sids" not in self.device:
+            self._put("__sids", self.series_ids)
+        return self.device["__sids"]
+
     def device_pad_mask(self):
         """True on the valid rows of a padded scan."""
         if "__pad_mask" not in self.device:
@@ -173,16 +188,23 @@ class MergedScan:
         (`ops/kernels.py` `growth`). last - first of
         the plain f32 mirrors has no digits left once the level is large
         (a counter at 1e12 that grows 6e4 a window came out 31% off).
-        Built on a field's first use by such a function, never before."""
+        Built on a field's first use by such a function, never before.
+
+        A tail's mirror is made across the seam: a series' first sample
+        here takes its difference from the series' last sample in the
+        base (`_seam`), so the two scans' differences are those of one
+        scan and a window that lies across them is the sum of its two
+        parts (`_fold_runs`)."""
         import jax
-        key = f"{'c' if counter else 'g'}:{name}"
+        key = _run_diffs_key(name, counter)
         if key not in self.device:
             vals, valid = self.fields[name]
             if vals.dtype == object:
                 raise UnsupportedError(f"field {name} is not numeric")
             v = vals.astype(np.float64, copy=False)
+            n = len(v)          # a tail's fields end at its valid rows
             rows = None if valid is None else np.nonzero(valid)[0]
-            sids = self.series_ids if rows is None \
+            sids = self.series_ids[:n] if rows is None \
                 else self.series_ids[rows]
             if rows is not None:
                 v = v[rows]
@@ -192,8 +214,10 @@ class MergedScan:
                 if counter:
                     np.copyto(d[1:], v[1:], where=v[1:] < v[:-1])
                 d[1:][sids[1:] != sids[:-1]] = 0.0
+            if self.base is not None and not self.stand_in and len(v):
+                _seam(self.base, name, counter, sids, v, d)
             if rows is not None:
-                full = np.zeros(self.num_rows, dtype=np.float64)
+                full = np.zeros(n, dtype=np.float64)
                 full[rows] = d
                 d = full
             if not jax.config.jax_enable_x64:
@@ -233,6 +257,11 @@ class MergedScan:
             else:
                 total += getattr(v, "nbytes", 0)
         return total
+
+
+def _run_diffs_key(name: str, counter: bool) -> str:
+    """Where a scan keeps `device_run_diffs(name, counter)`."""
+    return f"{'c' if counter else 'g'}:{name}"
 
 
 @dataclass
@@ -486,9 +515,9 @@ class _ScanCache:
     late into history (a relay's queue drained behind the live ticks).
     The two partials of one group are disjoint in keys, which is what
     sums, counts and extremes need; `first` / `last` fold by their
-    companion times (`_fold_runs`). `_settle` decides from what base and
-    tail hold at a delta's keys: a row that re-sends a resident row's
-    values (a retry) is dropped, one that changes a tail row's replaces
+    companion times, a window's growth by the seam (`_fold_runs`).
+    `_settle` decides from what base and tail hold at a delta's keys: a
+    row that re-sends a resident row's values (a retry) is dropped, one that changes a tail row's replaces
     it there; a row that changes a base row's values, a tombstone, and a
     tail past its capacity *merge* into a new base (`_merge_rows` over
     every column: counted, `scan_cache_merges`; the new base has a new
@@ -895,7 +924,60 @@ def _make_tail(rows: _Rows, base: MergedScan) -> MergedScan:
     return MergedScan(padded(rows.sids), padded(rows.ts), rows.fields,
                       base.series_dict, lo, seq=rows.seq, valid_rows=n,
                       pinned=True, count_uploads=True, ts_min=lo, ts_max=hi,
-                      block=rows.block, programs=base.tail_programs)
+                      block=rows.block, programs=base.tail_programs,
+                      base=base)
+
+
+def _series_firsts(sids: np.ndarray) -> np.ndarray:
+    """The first row of every series of a sorted series-id column."""
+    return np.flatnonzero(np.concatenate([[True], sids[1:] != sids[:-1]]))
+
+
+def _base_lasts(base: MergedScan, sids: np.ndarray):
+    """-> (at, has): the base's last row of each of these series, and
+    whether the base holds the series at all. One search a series."""
+    hi = np.searchsorted(base.series_ids, sids, side="right")
+    at = np.maximum(hi - 1, 0)
+    return at, (hi > 0) & (base.series_ids[at] == sids) \
+        if base.num_rows else np.zeros(len(sids), dtype=bool)
+
+
+def _seam(base: MergedScan, name: str, counter: bool, sids: np.ndarray,
+          v: np.ndarray, d: np.ndarray) -> None:
+    """A tail's per-sample differences `d` (of its values `v`, sorted by
+    `sids` then time) made those of one scan with its base: a series'
+    first sample here takes its difference from the series' last sample
+    in the base, with the reset rule, in float64 (a counter at 2.6e14
+    keeps its scrape's growth; the f32 `first` / `last` of two partials
+    would not). One pair a series the base holds; a series the base has
+    never seen keeps 0, as a scan's first sample does."""
+    from ..common.telemetry import increment_counter
+    first = _series_firsts(sids)
+    at, has = _base_lasts(base, sids[first])
+    first, at = first[has], at[has]
+    prev = base.fields[name][0][at].astype(np.float64, copy=False)
+    d[first] = run_diffs(v[first], prev, "increase" if counter else "delta")
+    increment_counter("scan_seam_pairs", len(first))
+
+
+def _seam_fits(base: MergedScan, tail: MergedScan, plan: "TpuPlan") -> bool:
+    """Whether a window's growth over `base` and `tail` is the sum of two
+    launches and a seam: every row of the tail comes after its series'
+    last row in the base (a row that arrived late into the base's history
+    lies between two of its samples, whose difference the base's mirror
+    already holds), and the fields the plan differences hold no NULL on
+    either side of the seam. Found once a tail."""
+    key = "__after_base"
+    if key not in tail.device:
+        n = tail.valid_rows
+        first = _series_firsts(tail.series_ids[:n])
+        at, has = _base_lasts(base, tail.series_ids[first])
+        tail.device[key] = (bool(
+            (tail.ts[first][has] > base.ts[at[has]]).all()),)
+    return tail.device[key][0] and all(
+        scan.fields[m.column][1] is None
+        for m in plan.moments if m.op in RUN_DIFF_MOMENT_OPS
+        for scan in (base, tail))
 
 
 SCAN_CACHE = _ScanCache()
@@ -1902,6 +1984,13 @@ def _execute_region(region, table, plan: TpuPlan) -> Optional[pd.DataFrame]:
                 scan, tail = SCAN_CACHE.get(region), None
             else:
                 scan, tail = SCAN_CACHE.get_parts(region, plan.time_hi)
+                if tail is not None and _grows(plan) \
+                        and not _outside(plan, tail) \
+                        and not _seam_fits(scan, tail, plan):
+                    # a late row under a window's growth: one scan
+                    # (counted: `scan_cache_merges`)
+                    exec_stats.record("scan_prep", seam="merged")
+                    scan, tail = SCAN_CACHE.get(region), None
         prof.mark("scan_prep", _time.perf_counter() - _t0)
         outcome = SCAN_CACHE.last_outcome() or "full"
         # same outcome vocabulary as ExecStats (cache=...) and the
@@ -1916,17 +2005,15 @@ def _execute_region(region, table, plan: TpuPlan) -> Optional[pd.DataFrame]:
             return None
         _t1 = _time.perf_counter()
         with exec_stats.stage("reduce"):
-            shape = []
             reads_tail = tail is not None and not _outside(plan, tail)
             out = None
             if scan.num_rows:
                 out = _moment_frame_for_scan(scan, table.schema, plan,
-                                             shape=shape, runs=reads_tail)
+                                             runs=reads_tail)
             if tail is None:
-                if scan.num_rows >= TPU_DISPATCH_MIN_ROWS and shape \
+                if scan.num_rows >= TPU_DISPATCH_MIN_ROWS \
                         and not _wants_one_scan(plan):
-                    _warm_tail_programs(scan, table.schema, plan,
-                                        shape[0])
+                    _warm_tail_programs(scan, table.schema, plan)
             elif not reads_tail:
                 exec_stats.record("reduce", tail="skipped")
             else:
@@ -1967,13 +2054,15 @@ def _base_and_tail_frame(base: Optional["_RunPartial"],
 def _wants_one_scan(plan: "TpuPlan") -> bool:
     """Plans that reduce the region's rows as one sorted scan, a tail
     merged into the base first: the host reducers (sketch / expression
-    moments walk the rows), and a window's growth, whose difference
-    across the seam between base and tail the f32 `first` / `last` of
-    two partials cannot give (a counter at 1e12 keeps no digit of a
-    scrape's growth there; `device_run_diffs` makes it in float64 over
-    one scan)."""
-    return plan_needs_host(plan) or \
-        any(m.op in RUN_DIFF_MOMENT_OPS for m in plan.moments)
+    moments walk the rows)."""
+    return plan_needs_host(plan)
+
+
+def _grows(plan: "TpuPlan") -> bool:
+    """The plan holds a window's growth (`RUN_DIFF_MOMENT_OPS`): over a
+    base and its tail it is the sum of the two launches' and the seam's
+    (`MergedScan.device_run_diffs`, `_fold_runs`), where `_seam_fits`."""
+    return any(m.op in RUN_DIFF_MOMENT_OPS for m in plan.moments)
 
 
 def _last_ts(base: MergedScan) -> int:
@@ -2013,8 +2102,7 @@ def _device_window(plan: "TpuPlan", scan: MergedScan):
     return np.asarray(lo, np.int32), np.asarray(hi, np.int32)
 
 
-def _warm_tail_programs(base: MergedScan, schema, plan: "TpuPlan",
-                        shape) -> None:
+def _warm_tail_programs(base: MergedScan, schema, plan: "TpuPlan") -> None:
     """Compile what this statement will launch over the base's tail once
     rows are written, now, where the statement's own programs are
     compiled (a server's warm statements come before the writes: the
@@ -2028,16 +2116,15 @@ def _warm_tail_programs(base: MergedScan, schema, plan: "TpuPlan",
     rows that do arrive meet the program compiled here. Lowered and
     compiled for those shapes and kept in `base.tail_programs`; nothing is
     uploaded and nothing runs, so a table nobody writes holds on the
-    device what it held before. Once a base and statement shape (`shape`:
-    what the base's launch chose, path and range bucket); a base under
+    device what it held before. Once a base and statement shape (with
+    what the base's launch chose: path, range bucket, group axis and its
+    size, which the tail's launch follows: `_base_launch`); a base under
     the dispatch floor (`TPU_DISPATCH_MIN_ROWS`, as the operator has set
     it) reached the device by another road (a plan the host path cannot
     run) and is left to compile when a tail is met."""
-    key = (shape, None if plan.bucket is None else
-           (plan.bucket.stride_ms, _bucket_phase(plan.bucket)),
-           bool(plan.tag_groups),
-           tuple((m.op, m.column) for m in plan.moments),
-           tuple(sorted((f.column, f.op) for f in plan.field_filters)))
+    shape = _statement_shape(plan)
+    key = (base.launch_shapes.get(shape), shape, None if plan.bucket is None
+           else _bucket_phase(plan.bucket))
     warmed = base.device.setdefault("__tail_warmed", set())
     if key in warmed:
         return
@@ -2051,8 +2138,7 @@ def _warm_tail_programs(base: MergedScan, schema, plan: "TpuPlan",
         return
     with _reduce_part("tail_warm"):
         sids = base.series_ids
-        first = np.flatnonzero(np.concatenate(
-            [[True], sids[1:] != sids[:-1]]))
+        first = _series_firsts(sids)
         k = len(first)
         zeros = np.zeros(k, dtype=np.float64)
         fields = {name: ((zeros, None) if vals.dtype != object
@@ -2103,6 +2189,35 @@ def _untimed_part(name: str):
     return contextlib.nullcontext()
 
 
+class _LaunchShape(NamedTuple):
+    """What a base's resident launch chose (`MergedScan.launch_shapes`),
+    for the launch over its tail to follow and `_warm_tail_programs` to
+    key by."""
+    path: str                         # "narrow" | "full"
+    range_bucket: Optional[int]       # of the selection's ranges
+    axis: Optional[str]               # a full launch's: "live" | "table"
+    groups: int                       # and its group axis (0: none)
+
+
+def _statement_shape(plan: "TpuPlan") -> tuple:
+    """What of a plan names a compiled launch, whatever its ranges and
+    its bucket grid's phase."""
+    return (None if plan.bucket is None else plan.bucket.stride_ms,
+            bool(plan.tag_groups),
+            tuple((m.op, m.column) for m in plan.moments),
+            tuple(sorted((f.column, f.op) for f in plan.field_filters)))
+
+
+def _base_launch(scan: MergedScan, plan: "TpuPlan") -> Optional[_LaunchShape]:
+    """For a tail: what its base's launch of this statement chose, which
+    ran just before it (None for any other scan). Two statements of one
+    shape and other selections that interleave on one base read each
+    other's: a tail then launches the other's program, or compiles its
+    own, and answers the same."""
+    return None if scan.base is None else \
+        scan.base.launch_shapes.get(_statement_shape(plan))
+
+
 @dataclass
 class _Launched:
     """An in-flight device reduction: device handles + host fold context.
@@ -2131,6 +2246,8 @@ class _Launched:
     table_runs: Optional[int] = None
     #: the host built and uploaded a row mask of the scan's length
     host_mask: bool = False
+    #: the program's group axis (a power of two, `nruns` of it in use)
+    num_groups: int = 0
 
 
 def _launch_for_scan(scan: MergedScan, schema, plan: TpuPlan, part):
@@ -2148,14 +2265,12 @@ def _launch_for_scan(scan: MergedScan, schema, plan: TpuPlan, part):
 
 
 def _moment_frame_for_scan(scan: MergedScan, schema, plan: TpuPlan,
-                           tail: bool = False,
-                           shape: Optional[list] = None,
-                           runs: bool = False):
+                           tail: bool = False, runs: bool = False):
     """-> the scan's partial moment frame, or with `runs` its
     `_RunPartial` (None: no row). `tail`: the scan is the tail of the one
     just reduced; the `reduce` row's detail says so (`tail_rows=`,
-    `tail_path=`) beside the base's. `shape`: a list that receives what
-    the launch chose (path, range bucket or None)."""
+    `tail_path=`) beside the base's, whose launch left what it chose in
+    `launch_shapes` for this one to follow."""
     if plan_needs_host(plan):
         # sketch / expression moments: reduce the resident merged scan
         # on the host with the same segment arithmetic the streamed
@@ -2171,8 +2286,14 @@ def _moment_frame_for_scan(scan: MergedScan, schema, plan: TpuPlan,
     from ..common.telemetry import increment_counter
     t0 = _time.perf_counter()
     launched, path, sel = _launch_for_scan(scan, schema, plan, _reduce_part)
-    if shape is not None:
-        shape.append((path, None if sel is None else sel.range_bucket))
+    if not tail:
+        axis = None if launched is None or path == "narrow" else \
+            "table" if launched.table_runs is None else "live"
+        if len(scan.launch_shapes) >= 64:
+            scan.launch_shapes.clear()
+        scan.launch_shapes[_statement_shape(plan)] = _LaunchShape(
+            path, None if sel is None else sel.range_bucket, axis,
+            launched.num_groups if axis else 0)
     increment_counter("scan_reads", path=path)
     # the rows this launch reads on the device: the table (a tail: the
     # rows it holds), or the ranges
@@ -2220,7 +2341,8 @@ def _moment_frame_for_scan(scan: MergedScan, schema, plan: TpuPlan,
 
 
 def _launch_scan_kernel(scan: MergedScan, schema, plan: TpuPlan,
-                        part=_untimed_part, sel=None) -> Optional[_Launched]:
+                        part=_untimed_part,
+                        sel=None) -> Optional[_Launched]:
     """`part(name)` times the host's steps for the resident path's
     EXPLAIN ANALYZE: `runs` (run-id sweep), `mask` (the predicates only
     the host can apply; the time range goes to the program as two
@@ -2230,18 +2352,36 @@ def _launch_scan_kernel(scan: MergedScan, schema, plan: TpuPlan,
     has them: the mask is their union, and where
     `scan_narrow.scan_group_axis` says so the kernel's group axis is the
     runs they touch (every row is still read, under the table's run ids)
-    and everything after the launch is sized by those."""
+    and everything after the launch is sized by those (`_table_layout`;
+    `_selection_layout` where the base holds no layout of the statement's
+    bucket grid: the live runs cut from the ranges themselves, the run
+    ids made on the device). A tail takes the axis its base's launch
+    took and a share of its size (`_base_launch`, `_pinned_groups`): what
+    a tail holds tomorrow must not choose another program. Its launch
+    also returns, after the plan's
+    moments, what folds a window's growth across the seam with its base
+    (`_moment_reads`, `_make_seams`: the part `seam`)."""
     from . import scan_narrow
 
     n = scan.num_rows
     if n == 0:
         return None
+    reads = list(_moment_reads(schema, plan, seams=scan.base is not None))
+    ops = tuple(op for op, _read, _masked_by in reads)
     with part("runs"):
-        run_key, (rid, nruns, run_starts, buckets) = _scan_runs(scan, plan)
+        lay = _selection_layout(scan, plan, sel, ops)
+        if lay is None:
+            lay = _table_layout(scan, plan, sel, ops)
+        elif part is _reduce_part:
+            from ..common import exec_stats
+            from ..common.telemetry import increment_counter
+            increment_counter("scan_selection_layouts")
+            exec_stats.record("reduce", runs="selection")
     with part("mask"):
         mask = _scan_row_mask(scan, schema, plan, sel)
     if mask is _NO_ROWS:
         return None
+    _make_seams(scan, reads, part)
 
     # ---- device kernel (module-level jit; compile cache shared across
     # queries with the same moment signature + shape bucket) ----
@@ -2261,82 +2401,171 @@ def _launch_scan_kernel(scan: MergedScan, schema, plan: TpuPlan,
 
         values = []
         col_masks = []
-        ops = []
-        for op, field_read, masked_by in _moment_reads(schema, plan):
-            ops.append(op)
+        for _op, field_read, masked_by in reads:
             values.append(d_ts if field_read is None
                           else _device_column(scan, field_read))
             col_masks.append(None if masked_by is None
                              else scan.device_valid(masked_by))
         # what the moments share, told to the program statically: each
         # mirror a parameter once, a column without a NULL no validity
-        ops = tuple(ops)
         values, value_ix = distinct_arrays(values, d_ts)
         col_masks, mask_ix = distinct_arrays(col_masks, None)
 
-    with part("runs"):
-        # cached with the runs, per set of ops that read run ids or not:
-        # at 7.7M runs the ends, the lengths and their maximum are 0.15 s
-        layout_key = "__layout:" + run_key
-        min_groups = _pinned_groups(scan, plan)
-        needs_gids = _ops_need_gids(ops, _group_bucket(nruns, min_groups))
-        cached = scan.device.get(layout_key)
-        if cached is not None and (not needs_gids or (
-                cached[2] is not None and rid is not None)):
-            nbucket, run_ends, seg_len_k = cached
-            if not needs_gids:
-                rid = seg_len_k = None
-        else:
-            nbucket, run_ends, rid, seg_len_k = _segment_layout(
-                run_starts, n, ops, rid, pinned=scan.pinned,
-                min_groups=min_groups)
-            scan.device[layout_key] = (nbucket, run_ends, seg_len_k)
-            if rid is not None:
-                scan.device[run_key] = (rid, nruns, run_starts, buckets)
-        table_runs = live_starts = None
-        if sel is not None:
-            lo, hi = scan_narrow.run_spans(run_starts, sel)
-            if scan_narrow.scan_group_axis(
-                    nruns, int((hi - lo).sum())) == "live":
-                table_runs = nruns
-                nruns, nbucket, live_starts, run_ends = \
-                    scan_narrow.live_layout(run_starts, run_ends, lo, hi, n)
-                run_starts = live_starts[:nruns]
-    if rid is not None:
+    if lay.grid is not None:
+        # run ids nobody laid out: a label a row, made where the rows are
+        d_rid = scan_narrow.run_labels(scan.device_sids(), d_ts, lay.grid)
+    elif lay.rid is not None:
         with part("upload"):
-            d_rid = scan.upload(rid)
+            d_rid = scan.upload(lay.rid)
     else:
         d_rid = d_ts
     with part("launch"):
         out = _run_program(
             scan, _sorted_grouped_aggregate_pre, d_rid, d_mask, d_ts, window,
-            values, col_masks, run_ends, live_starts, num_groups=nbucket,
-            ops=ops, value_ix=value_ix, mask_ix=mask_ix,
-            seg_len_k=seg_len_k)
+            values, col_masks, lay.run_ends, lay.live_starts,
+            num_groups=lay.num_groups, ops=ops, value_ix=value_ix,
+            mask_ix=mask_ix, seg_len_k=lay.seg_len_k)
     if out is None:         # a stand-in: compiled, not run
         return None
     distinct, counts = out
     results, passes = moment_results(distinct, counts, ops, value_ix, mask_ix)
-    signature = (run_key, nbucket,
+    signature = (lay.run_key, lay.num_groups,
                  tuple((m.op, m.column) for m in plan.moments))
     warm = signature in scan.launched
     if len(scan.launched) >= 64:     # sweeping bucket origins never repeat
         scan.launched.clear()
     scan.launched.add(signature)
-    sids = scan.series_ids
-    return _Launched(results, counts, nruns, sids[run_starts],
-                     _run_buckets(plan, buckets, run_starts),
+    return _Launched(results, counts, lay.nruns,
+                     scan.series_ids[lay.run_starts], lay.run_buckets,
                      scan.series_dict, scan.ts_base, passes, warm,
-                     table_runs, mask is not None)
+                     lay.table_runs, mask is not None, lay.num_groups)
 
 
-def _moment_reads(schema, plan: TpuPlan):
+class _Layout(NamedTuple):
+    """A full launch's segments, from the table's runs (`_table_layout`)
+    or from the statement's selection (`_selection_layout`)."""
+    run_key: str
+    nruns: int                        # the kernel's segments in use
+    num_groups: int                   # of this many (a power of two)
+    run_starts: np.ndarray            # [nruns] the row each starts at
+    run_buckets: Optional[np.ndarray]  # [nruns] from the plan's origin
+    run_ends: np.ndarray              # int32 [num_groups]
+    #: int32 [num_groups] where the segments are the statement's live
+    #: runs out of `table_runs`; None: the table's runs, end to end
+    live_starts: Optional[np.ndarray]
+    table_runs: Optional[int]
+    seg_len_k: Optional[int]          # None: no op reads run ids
+    rid: Optional[np.ndarray]         # the table's run ids a row, or
+    grid: Optional[tuple]             # what `run_labels` makes them from
+
+
+def _table_layout(scan: MergedScan, plan: TpuPlan, sel, ops) -> _Layout:
+    """The table's runs (`_scan_runs`) as the kernel's segments: all of
+    them, or where `scan_narrow.scan_group_axis` says so those the
+    selection's ranges touch."""
+    from . import scan_narrow
+    n = scan.num_rows
+    run_key, (rid, nruns, run_starts, buckets) = _scan_runs(scan, plan)
+    # cached with the runs, per set of ops that read run ids or not:
+    # at 7.7M runs the ends, the lengths and their maximum are 0.15 s
+    layout_key = "__layout:" + run_key
+    like = _base_launch(scan, plan)
+    min_groups = _pinned_groups(scan, plan)
+    needs_gids = _ops_need_gids(ops, _group_bucket(nruns, min_groups))
+    cached = scan.device.get(layout_key)
+    if cached is not None \
+            and cached[0] == _group_bucket(nruns, min_groups) \
+            and (not needs_gids or (
+                cached[2] is not None and rid is not None)):
+        nbucket, run_ends, seg_len_k = cached
+        if not needs_gids:
+            rid = seg_len_k = None
+    else:
+        nbucket, run_ends, rid, seg_len_k = _segment_layout(
+            run_starts, n, ops, rid, pinned=scan.pinned,
+            min_groups=min_groups)
+        scan.device[layout_key] = (nbucket, run_ends, seg_len_k)
+        if rid is not None:
+            scan.device[run_key] = (rid, nruns, run_starts, buckets)
+    table_runs = live_starts = None
+    if sel is not None:
+        lo, hi = scan_narrow.run_spans(run_starts, sel)
+        follows = like is not None and like.axis
+        if (like.axis if follows else scan_narrow.scan_group_axis(
+                nruns, int((hi - lo).sum()))) == "live":
+            table_runs = nruns
+            nruns, nbucket, live_starts, run_ends = \
+                scan_narrow.live_layout(
+                    run_starts, run_ends, lo, hi, n,
+                    _tail_groups(like) if follows else 0)
+            run_starts = live_starts[:nruns]
+    return _Layout(run_key, nruns, nbucket, run_starts,
+                   _run_buckets(plan, buckets, run_starts), run_ends,
+                   live_starts, table_runs, seg_len_k, rid, None)
+
+
+def _selection_layout(scan: MergedScan, plan: TpuPlan, sel,
+                      ops) -> Optional[_Layout]:
+    """The live axis laid out from the statement's selection, for a
+    bucket grid this base holds no layout of: a panel whose range ends
+    at any second and not at a whole step (a dashboard's "now" while its
+    table is written) brings a grid of another phase at every refresh,
+    and the table's layout for it is a pass over every row on the host
+    and the run ids of every row uploaded. Here the segments are the
+    runs the grid cuts inside the selection's ranges
+    (`scan_narrow.selection_runs`: the cost follows the selection), and
+    the run ids are labels made on the device from the resident series
+    ids and times (`scan_narrow.run_labels`: the kernels of the live
+    axis read run ids for equality alone). Taken where a grid of the
+    same stride has been laid out, whose run count stands for this one's
+    (they differ by at most a run a series), and `scan_group_axis` gives
+    the live axis by it; None: the table's layout."""
+    from . import scan_narrow
+    from ..ops.kernels import seg_len_bucket
+    b = plan.bucket
+    if sel is None or b is None or not sel.n_ranges or scan.pinned \
+            or scan.valid_rows is not None:
+        return None
+    run_key = f"__runs:{b.stride_ms}:{_bucket_phase(b)}"
+    table_runs = scan.device.get(f"__grid_runs:{b.stride_ms}")
+    if run_key in scan.device or table_runs is None:
+        return None
+    starts, ends, buckets = scan_narrow.selection_runs(
+        scan.ts, sel, b.origin, b.stride_ms)
+    if scan_narrow.scan_group_axis(table_runs[0], len(starts)) != "live":
+        return None
+    grid = seg_len_k = None
+    if _ops_need_gids(ops, _group_bucket(table_runs[0])):
+        # the grid's edge at or before the scan's first row, and the
+        # buckets a series can lie in: series x buckets must fit a label
+        edge = -((scan.ts_base - b.origin) % b.stride_ms)
+        reach = _last_ts(scan) - scan.ts_base - edge
+        per_series = reach // b.stride_ms + 1
+        if reach >= 2**31 or \
+                (int(scan.series_ids[-1]) + 1) * per_series >= 2**31:
+            return None
+        grid = tuple(np.asarray(x, np.int32)
+                     for x in (edge, b.stride_ms, per_series))
+        seg_len_k = seg_len_bucket(int((ends - starts).max()))
+    num_groups, live_starts, run_ends = scan_narrow.padded_layout(
+        starts, ends, scan.num_rows)
+    return _Layout(run_key, len(starts), num_groups, starts, buckets,
+                   run_ends, live_starts, table_runs[0], seg_len_k, None,
+                   grid)
+
+
+def _moment_reads(schema, plan: TpuPlan, seams: bool = False):
     """-> per moment (kernel op, the column it reads, the column whose
     validity masks it). No column read: ts stands in (a ts extreme; a
     count or a string column, which read only the mask). No masking
     column: a row count. A column is a field's name or, for a
     RUN_DIFF_MOMENT_OPS moment, (counter, field): the derived mirror of
-    `MergedScan.device_run_diffs`, whose `growth` a run is the moment."""
+    `MergedScan.device_run_diffs`, whose `growth` a run is the moment.
+    `seams` (a tail's launch): after the plan's moments, for each such
+    moment the `first` of that mirror a run, the difference that reaches
+    back before the run: where the run goes on from one of the base it
+    belongs to the window (`_fold_runs`). It rides the arg-extreme of the
+    `first` the lowering asks for beside a growth: no pass of its own."""
     for m in plan.moments:
         if m.op in ("min_ts", "max_ts"):
             yield ("min" if m.op == "min_ts" else "max"), None, m.column
@@ -2348,6 +2577,23 @@ def _moment_reads(schema, plan: TpuPlan):
             dtype = schema.column_schema(m.column).dtype
             yield m.op, (None if dtype.is_string or dtype.is_binary
                          else m.column), m.column
+    if seams:
+        for m in plan.moments:
+            if m.op in RUN_DIFF_MOMENT_OPS:
+                yield "first", (m.op == "increase", m.column), m.column
+
+
+def _make_seams(scan: MergedScan, reads, part) -> None:
+    """The `reduce.seam` row: a tail's derived mirrors that these reads
+    want and that are not there yet, made across the seam and uploaded."""
+    if scan.base is None or scan.stand_in:
+        return
+    wanted = {r for _op, r, _m in reads if isinstance(r, tuple)
+              and _run_diffs_key(r[1], r[0]) not in scan.device}
+    if wanted:
+        with part("seam"):
+            for counter, name in sorted(wanted):
+                scan.device_run_diffs(name, counter)
 
 
 def _device_column(scan: MergedScan, column):
@@ -2362,6 +2608,14 @@ def _group_bucket(nruns: int, min_groups: int = 0) -> int:
     return shape_bucket(nruns, minimum=max(256, min_groups))
 
 
+def _tail_groups(like) -> int:
+    """A tail's share of the group axis its base's launch took (`like`:
+    `_base_launch`): a tail holds up to an eighth of
+    its base's rows (`tail_capacity`: a sixteenth, as a power of two),
+    and at the base's rows a run that many of its runs."""
+    return like.groups // (_TAIL_SHARE // 2) if like is not None else 0
+
+
 def _pinned_groups(scan: MergedScan, plan: TpuPlan) -> int:
     """The least group axis of a full launch over a tail (0 for any other
     scan): as a tail's row axis is a capacity, its group axis is what the
@@ -2369,12 +2623,18 @@ def _pinned_groups(scan: MergedScan, plan: TpuPlan) -> int:
     program. Runs of whole series: one a series. Runs cut by a time
     bucket too: two a series, which holds the live flow (every series in
     one bucket) beside late rows of any share of the series in another,
-    or the live flow across a bucket's edge; a tail that cuts more runs
-    takes the next power of two, and compiles it once."""
+    or the live flow across a bucket's edge, and at least the tail's
+    share of its base's axis (`_tail_groups`: a panel by the minute cuts
+    a run every six scrapes, and the tail of a table scraped for hours
+    holds dozens a series); a tail that cuts more runs takes the next
+    power of two, and compiles it once."""
     if not scan.pinned or (plan.bucket is None and not plan.tag_groups):
         return 0
     k = max(int(scan.series_dict.num_series), 1)
-    return shape_bucket(k if plan.bucket is None else 2 * k, minimum=256)
+    if plan.bucket is None:
+        return shape_bucket(k, minimum=256)
+    return max(shape_bucket(2 * k, minimum=256),
+               _tail_groups(_base_launch(scan, plan)))
 
 
 def _ops_need_gids(ops, num_groups: int) -> bool:
@@ -2471,6 +2731,10 @@ def _scan_runs(scan: MergedScan, plan: TpuPlan):
     run_starts = np.nonzero(flags)[0]
     runs = (rid, len(run_starts), run_starts, buckets)
     scan.device[run_key] = runs
+    if plan.bucket is not None:
+        # what `_selection_layout` takes for any grid of this stride
+        scan.device[f"__grid_runs:{plan.bucket.stride_ms}"] = \
+            (len(run_starts),)
     # bound the per-scan run-context cache: each distinct bucket
     # spec stores O(n) host arrays, and dashboards sweeping many
     # strides over one hot region would otherwise grow host memory
@@ -2605,6 +2869,9 @@ class _RunPartial:
     moments: List[np.ndarray]         # a plan moment each, [g]
     rowcount: np.ndarray
     series_dict: object
+    #: a tail's: per RUN_DIFF_MOMENT_OPS moment (its index in the plan)
+    #: the run's first difference, which reaches back before the run
+    seams: Dict[int, np.ndarray] = field(default_factory=dict)
 
 
 def _collect_runs(launched: _Launched, plan: TpuPlan, counts: np.ndarray,
@@ -2626,10 +2893,14 @@ def _collect_runs(launched: _Launched, plan: TpuPlan, counts: np.ndarray,
             # in _finalize compares comparable timestamps
             r = r.astype(np.int64) + launched.ts_base
         moments.append(r)
+    grows = [i for i, m in enumerate(plan.moments)
+             if m.op in RUN_DIFF_MOMENT_OPS]
+    seams = {i: r[:nruns][live]
+             for i, r in zip(grows, res_np[len(plan.moments):])}
     return _RunPartial(
         launched.run_sids[live],
         launched.run_buckets[live] if plan.bucket is not None else None,
-        moments, counts[live], launched.series_dict)
+        moments, counts[live], launched.series_dict, seams)
 
 
 def _partial_frame(p: _RunPartial, plan: TpuPlan) -> pd.DataFrame:
@@ -2704,6 +2975,12 @@ def _fold_runs(a: _RunPartial, b: _RunPartial,
         elif m.op == "last":
             ta, tb = companion(m, "max_ts")
             both = np.where(valid(y) & (~valid(x) | (tb >= ta)), y, x)
+        elif m.op in RUN_DIFF_MOMENT_OPS:
+            # one window across the seam: the base's growth, the tail's,
+            # and the tail's first difference, which reaches back to the
+            # base's last sample (`MergedScan.device_run_diffs`)
+            seam = b.seams[i][hit]
+            both = x + y + np.where(valid(seam), seam, 0)
         else:
             raise UnsupportedError(f"no fold for moment {m.op}")
         out = va.astype(np.result_type(va.dtype, vb.dtype), copy=True)

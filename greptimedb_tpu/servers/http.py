@@ -503,15 +503,26 @@ class HttpServer:
 
         def work():
             from ..common.admission import GATE
+            from ..common.telemetry import timer
             from .coalesce import COALESCER
             with GATE.admit_ingest(len(body)):
-                inserts, tag_cols = prom_mod.write_request_to_inserts(body)
-                for table, cols in inserts.items():
-                    COALESCER.ingest(
-                        self.frontend, table, cols,
-                        tag_columns=tag_cols[table],
-                        timestamp_column=prom_mod.GREPTIME_TIMESTAMP,
-                        ctx=ctx)
+                # one body at a time in the pure-Python decoder, which
+                # offers the interpreter lock after every series while a
+                # statement runs (as the line-protocol parser does)
+                with GATE.parse_turn() as give_way, \
+                        timer("prom_write_decode"):
+                    inserts, tag_cols = prom_mod.write_request_to_inserts(
+                        body, give_way)
+                # the block's tables one after the other: the answer
+                # (204) follows the last one's WAL append and, with
+                # --wal-sync-on-write, its fsync
+                with timer("prom_write_insert"):
+                    for table, cols in inserts.items():
+                        COALESCER.ingest(
+                            self.frontend, table, cols,
+                            tag_columns=tag_cols[table],
+                            timestamp_column=prom_mod.GREPTIME_TIMESTAMP,
+                            ctx=ctx)
 
         await self._offload(request, work)
         return web.Response(status=204)

@@ -29,10 +29,15 @@ class TimeSeries:
     samples: List[Tuple[float, int]] = field(default_factory=list)  # (v, ts)
 
 
-def decode_write_request(body: bytes) -> List[TimeSeries]:
+def decode_write_request(body: bytes, give_way=None) -> List[TimeSeries]:
+    """`give_way`: called after every series (the admission gate's offer
+    of the interpreter lock to a running statement,
+    `common/admission.py:AdmissionGate.give_way`)."""
     raw = memoryview(decompress(body))
     series: List[TimeSeries] = []
     for fnum, wt, val in pw.iter_fields(raw):
+        if give_way is not None:
+            give_way()
         if fnum == 1 and wt == 2:                    # timeseries
             ts = TimeSeries()
             for f2, w2, v2 in pw.iter_fields(val):
@@ -85,11 +90,11 @@ def series_to_inserts(series: List[TimeSeries]):
     return result, tag_cols
 
 
-def write_request_to_inserts(body: bytes):
+def write_request_to_inserts(body: bytes, give_way=None):
     """snappy prompb.WriteRequest body → (per-metric column dicts,
     per-metric tag names) — the one-call shape the HTTP handler and the
     ingest coalescer share."""
-    return series_to_inserts(decode_write_request(body))
+    return series_to_inserts(decode_write_request(body, give_way))
 
 
 @dataclass

@@ -194,8 +194,13 @@ def test_the_full_launch_takes_the_live_runs_where_ranges_say_which(fleet,
     before = {axis: metric("scan_group_axis", axis=axis)
               for axis in ("live", "table")}
     rows_before = metric("scan_device_rows")
+    laid = metric("scan_selection_layouts")
     stages = fleet.stages(fam.sql(params, fleet.ds))
     detail = stages["reduce"][2]
+    # a panel that ends on its step finds its grid laid out over the
+    # table: never the selection's layout (`tpu_exec._selection_layout`)
+    assert metric("scan_selection_layouts") == laid
+    assert "runs=selection" not in detail
     bumped = {axis: metric("scan_group_axis", axis=axis) - before[axis]
               for axis in ("live", "table")}
     axis = GROUP_AXIS[name]
@@ -486,12 +491,35 @@ def test_span_rows_add_up_to_total(fleet, name):
     def untimed_of(stages):
         return stages["total"][1] - sum(stages[row][1] for row in TOP_LEVEL)
 
-    stages = min((fleet.stages(sql) for _ in range(3)), key=untimed_of)
+    def cpu_ms(stages, row):
+        # `outer` sums its pieces without a clock of the thread's: all
+        # of its time counts as spent
+        found = re.search(r"cpu_ms=([0-9.]+)", stages[row][2])
+        return float(found.group(1)) if found else stages[row][1]
+
+    def steady(stages):
+        """What no span covers, on the wall clock; beside five other
+        xdist workers a thread is taken off its processor between two
+        rows for longer than the limit, so a try that fails it is held
+        to the thread's CPU time instead (every row under `total`
+        carries `cpu_ms=`), which a descheduled thread does not spend."""
+        total = stages["total"][1]
+        if -0.05 <= untimed_of(stages) < max(2.0, 0.05 * total):
+            return True
+        spent = cpu_ms(stages, "total") - sum(cpu_ms(stages, row)
+                                              for row in TOP_LEVEL)
+        return untimed_of(stages) >= -0.05 and \
+            spent < max(2.0, 0.05 * cpu_ms(stages, "total"))
+
+    tries = []
+    for _ in range(8):      # the first steady one of up to eight tries
+        tries.append(fleet.stages(sql))
+        if steady(tries[-1]):
+            break
+    stages = tries[-1]
     for row in ["parse"] + TOP_LEVEL + PARTS:
         interval(stages, row)
-    untimed = untimed_of(stages)
-    assert -0.05 <= untimed < max(2.0, 0.05 * stages["total"][1]), \
-        (untimed, stages)
+    assert steady(stages), (untimed_of(stages), stages)
     assert stages["lower.rebuild"][1] <= stages["lower"][1] + 0.05
     assert re.search(r"path=(full|narrow)", stages["reduce"][2])
     # `lower` starts when `finalize` has ended, inside `total`
@@ -807,7 +835,10 @@ def test_the_cell_reports_what_its_entries_say():
             "prom_outer_ms"} <= set(layers)
     assert "scan_kernels_roofline" not in layers
     for name in NEW_READERS:
-        assert layers[name]["workloads"] == [CELL]
+        # and the cell that sends two of the panels while the agent
+        # writes (ISSUE 44)
+        assert layers[name]["workloads"] == [
+            CELL, "prom1k-remote-write-while-read"]
         assert layers[name]["moves"] == "stmt_geomean_ms"
     assert layers["lowered_scan_roofline"]["unit"] == "%"
     assert layers["lowered_scan_roofline"]["layer"] == "scan kernels"

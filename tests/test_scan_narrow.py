@@ -586,8 +586,8 @@ def test_the_live_axis_builds_nothing_of_the_tables_run_count(db,
     real_layout, real_collect = scan_narrow.live_layout, \
         tpu_exec._collect_moment_frame
 
-    def layout(run_starts, run_ends, lo, hi, n):
-        out = real_layout(run_starts, run_ends, lo, hi, n)
+    def layout(run_starts, run_ends, lo, hi, n, *pinned):
+        out = real_layout(run_starts, run_ends, lo, hi, n, *pinned)
         seen["table_runs"] = len(run_starts)
         seen["layout"] = (out[0], len(out[2]), len(out[3]))
         return out

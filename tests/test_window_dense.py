@@ -431,3 +431,21 @@ def test_the_dashboards_stack_is_fused_on_a_v5e(one_chip):
         on(one_chip, (S, L), i32), on(one_chip, (S, L), f32),
         on(one_chip, (S, L), f32), on(one_chip, (S, ext), i32)).compile()
     assert_fused(compiled, S * (L + 1) * ext)
+
+
+def test_a_46m_row_tables_run_labels_are_one_pass_on_a_v5e(one_chip):
+    """`scan_narrow.run_labels` at `prom-node-1k-2h`'s 46.08M rows: what a
+    statement off every laid-out grid launches ahead of the scan kernel
+    (`tpu_exec._selection_layout`). One fused pass: nothing gathered,
+    nothing kept but the labels."""
+    from greptimedb_tpu.query import scan_narrow
+    n, i32 = 46_080_000, jnp.int32
+    compiled = scan_narrow.run_labels.lower(
+        on(one_chip, (n,), i32), on(one_chip, (n,), i32),
+        tuple(np.asarray(x, np.int32) for x in (-13_000, 60_000, 122))
+    ).compile()
+    text = compiled.as_text()
+    assert " gather(" not in text and " while(" not in text
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == n * 4
+    assert mem.temp_size_in_bytes < n * 4 // 16
