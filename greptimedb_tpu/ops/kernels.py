@@ -357,24 +357,61 @@ def apply_cmp(op: str, col: jax.Array, a, b=None) -> jax.Array:
 # group ids are non-decreasing — group-by becomes contiguous-segment
 # reduction, with no scatter at all (XLA scatter serializes on TPU; measured
 # ~100x slower than this path on v5e). Structure per segment [s, e):
-#   inner:  whole 1024-row blocks — per-block partials (one bandwidth pass)
+#   inner:  whole blocks — per-block partials (one pass over the rows)
 #           combined by prefix-sum difference (sum family) or an RMQ sparse
 #           table over block partials (min/max family);
-#   edges:  the two partial blocks — fixed-size masked gather windows.
+#   edges:  the two partial blocks.
 # Float sums never difference a plain float32 running prefix: over a
 # resident scan of 17M rows of values near 50 the prefix reaches ~1e9,
 # whose float32 spacing is 64 — an hourly avg() came out wrong in the
 # third digit. The prefix over block sums is carried as an unevaluated
 # float32 pair (hi, lo) instead (_block_prefix), so a boundary difference
 # is good to float32 rounding of the SEGMENT sum, whatever the scan size.
-
-# Mini-block size: edge windows gather [num_groups, 2*block] elements, and
-# TPU scalar gather is ~20ns/element — small blocks keep edges cheap while
-# the sparse table over mini partials keeps inner ranges O(1) per group.
-# (Measured on v5e: block=1024 → 128 ms for a 5-col avg over 16.7M rows,
-# all in edge gathers; block=32 → gathers drop 32x and the pass is
-# bandwidth-bound.)
+#
+# Two block sizes, each for the forms that read it:
+#
+# _SEG_BLOCK, 32 rows: the forms whose edges are gathered windows of
+# [num_groups, 2 * block] elements (`_edge_windows`: the low-cardinality
+# sums and extremes, `_sorted_seg_argext`) and the in-block tables of
+# `_sorted_seg_minmax`. A TPU scalar gather is ~10-20 ns an element, so
+# the windows want a small block (measured on v5e: block=1024 → 128 ms
+# for a 5-col avg over 16.7M rows, all in edge gathers; block=32 → the
+# gathers drop 32x), and the sparse table over the mini partials keeps
+# inner ranges O(1) a group. What it costs is the row axis viewed as
+# [n / 32, 32]: a minor dimension that fills a quarter of a 128-lane
+# register, so the view is a relayout copy into four times the bytes.
+#
+# _SUM_BLOCK, 128 rows: the sum family's prefix form (`sum_form`), which
+# reads no edge window. [n / 128, 128] is the row axis as the chip tiles
+# it, so the view is free, and a block read at a bound is one row of 128
+# lanes. The probe of PR 45 (`CHANGES.md` has its table; one v5e, ms a
+# pass over the rows under `where(mask & window)`, float sum | count):
+#   17.28M rows, 65,536 dense ends: block 32, the form before   5.05 | 4.25
+#     an in-block scan kept at [n / 128, 128], one scalar read an end:
+#       by reduce_window 5.89 | 5.81; on the MXU at HIGHEST 2.37 | (5.96)
+#     the end's block read as a row of 128 | 256 | 512 | 1,024 lanes,
+#       the pair prefix as scalars     2.13 | 2.04 | 2.16 | 3.33 (1.81 at 128)
+#     as built: that row of 128 and the pair prefix as a row    1.43 | 1.81
+#   18M rows, 131,072 dense ends               7.32 | 5.47  ->  2.21 | 2.43
+#   17.28M rows, 65,536 `starts=`              6.86 | 5.32  ->  2.19 | 2.11
+#   46.08M rows, 1,048,576 `starts=`           90.4 | 61.0  ->  39.3 | 16.6
+#   8,192 rows, 1,024 groups (a count alone)          0.955 ->  0.962
+# (a count's figure holds the launch itself, about 1 ms.) In the traces
+# the two scalar gathers of the pair prefix were 1.14 ms of a pass at
+# 65,536 ends (8.7 ns a value) where the row gather is 0.29 (4.4 ns a row
+# of 512 B): hence every read at a bound a row, the pair prefix too. A
+# larger block buys nothing, and in a form that differences in-block sums
+# it costs precision; the form as built differences none.
 _SEG_BLOCK = 32
+_SUM_BLOCK = 128
+
+#: past this many groups the prefix form reads its bounds as scalars
+#: again (`_seg_sum_scalar_reads`): a gathered [G, 128] is 512 B a group,
+#: 512 MB at 1,048,576 groups (the largest shape the probe and a cell
+#: ran: the fleet panel's live runs) and 16 GB at the 33,554,432 groups of
+#: a read-back that groups by row, which the chip's compiler refuses
+#: (`tsbs4k-read-while-ingest`'s check after its restart, PR 45).
+_SUM_ROW_READS_MAX_GROUPS = 1 << 20
 
 
 def _edge_windows(x, starts, ends, bs, be, ident, n):
@@ -420,14 +457,11 @@ def _pad_block(x, ident, n):
     return x, (n + pad) // _SEG_BLOCK
 
 
-#: above this group count, segment reductions switch from exact
-#: edge-window gathers (O(groups*block), gather-bound at high
-#: cardinality) to O(groups)-gather decompositions: sums use a
-#: two-level prefix sum; min/max use in-block sparse tables + block
-#: suffix/prefix scans. The prefix-sum form can carry ~1-ulp
-#: cancellation noise into small segments, so the exact form stays for
-#: the common low-cardinality group-bys whose results users read
-#: directly.
+#: above this group count, segment reductions switch from edge-window
+#: gathers (O(groups*block), gather-bound at high cardinality) to
+#: O(groups)-read decompositions: sums read a row of `_SUM_BLOCK` lanes
+#: at each bound under the pair prefix of the block sums (`sum_form`);
+#: min/max use in-block sparse tables + block suffix/prefix scans.
 _SEG_HIGH_CARD_THRESHOLD = 8192
 
 
@@ -456,29 +490,61 @@ def _block_prefix(block_sums):
     return jnp.concatenate([zero, hi]), jnp.concatenate([zero, lo])
 
 
-def _sorted_seg_sum(x, starts, ends, bs, be, has_inner, n, dense=True):
-    """Per-segment sum of x (zeros where masked).
+def sum_form(num_groups: int) -> str:
+    """The form a program's float sums take at this many groups (static:
+    the launch's group axis): `edge` (gathered edge windows over blocks
+    of `_SEG_BLOCK`) or `prefix` (a row of `_SUM_BLOCK` lanes read at
+    each bound, the whole blocks between under the pair prefix). An
+    integer sum, so every count, takes `prefix` at any cardinality."""
+    return "prefix" if num_groups > _SEG_HIGH_CARD_THRESHOLD else "edge"
 
-    Low cardinality: per-segment block partials + edge windows.
-    High cardinality: in-block inclusive scans + the prefix over block
-    sums form a global prefix P; each segment is P[end]-P[start] —
-    measured 4-8x faster at 120k-1.2M groups on v5e (the edge-window
-    design is O(groups*block) random gather). Where the bounds come from
-    dense integer group queries (`dense`), starts[g] == ends[g-1] and the
-    prefix at starts is a shift of the prefix at ends — halving the O(G)
-    gather count, the dominant cost at 1M+ groups. Segments picked out
-    of such a layout (`dense` false: the live runs of a scan) gather the
-    prefix at both bounds; on a dense layout both forms give the same
-    bits."""
-    if jnp.issubdtype(x.dtype, jnp.integer):
-        acc = jnp.promote_types(x.dtype, jnp.int32)  # exact int accumulation
-    else:
-        acc = jnp.promote_types(x.dtype, jnp.float32)
-    B = _SEG_BLOCK
-    num_groups = starts.shape[0]
-    xp, nb = _pad_block(x.astype(acc), 0, n)
-    if num_groups <= _SEG_HIGH_CARD_THRESHOLD and \
-            not jnp.issubdtype(x.dtype, jnp.integer):
+
+def _sum_bounds(starts, ends, dense):
+    """Where the prefix form reads, made once a launch and shared by its
+    passes: per segment the block of its start and the rows of that
+    block before it (b_s, r_s), and the same of its end (b_e, r_e). On a
+    dense layout a start is the end before it, and its side a shift."""
+    B = _SUM_BLOCK
+    b_e, r_e = ends // B, ends % B
+    if dense:
+        return _shifted(b_e), _shifted(r_e), b_e, r_e
+    return starts // B, starts % B, b_e, r_e
+
+
+def _shifted(v):
+    """v[g - 1] a group, zero for the first: on a dense layout what the
+    start's side of a segment is of the end's."""
+    return jnp.concatenate([jnp.zeros(1, v.dtype), v[:-1]])
+
+
+def _sorted_seg_sum(x, starts, ends, bs, be, has_inner, n, dense, bounds):
+    """Per-segment sum of x (zeros where masked; a bool counts).
+
+    `edge` (`sum_form`; float sums at low cardinality): per-segment
+    block partials + edge windows, blocks of `_SEG_BLOCK`.
+
+    `prefix`: the rows as [n / 128, 128] (`_SUM_BLOCK`; on the chip a
+    view of the column, no copy), the block sums out of one fused read,
+    their exclusive prefix as the pair of `_block_prefix`. A segment
+    inside one block is that block read as one row of 128 lanes and
+    summed between its bounds; a longer one is the rest of its start's
+    block, the whole blocks between as a difference of the pair prefix,
+    and the rows of its end's block before the end. No running sum over
+    rows of other segments is differenced, so a segment's sum is good to
+    float32 rounding of its own terms (and of the pair prefix, ~48 bits
+    of the table's). Every read at a bound is a row of 128 lanes (the
+    pair prefix too, 32 blocks a row): on a v5e a row costs 4 ns where
+    a scalar costs 9 (probe, PR 45). On a dense layout
+    (`dense`: starts[g] == ends[g-1]) the start's side is a shift of the
+    end's and nothing is read at the starts; segments picked out of such
+    a layout (the live runs of a scan) read both bounds, and on a dense
+    layout give the same bits. `bounds`: `_sum_bounds` of the segments,
+    made once a launch."""
+    exact = x.dtype == jnp.bool_ or jnp.issubdtype(x.dtype, jnp.integer)
+    acc = jnp.promote_types(x.dtype, jnp.int32 if exact else jnp.float32)
+    if sum_form(starts.shape[0]) == "edge" and not exact:
+        B = _SEG_BLOCK
+        xp, nb = _pad_block(x.astype(acc), 0, n)
         hi, lo = _block_prefix(xp.reshape(nb, B).sum(axis=1))
         lo_b = jnp.minimum(bs, nb)
         inner = jnp.where(has_inner,
@@ -489,6 +555,75 @@ def _sorted_seg_sum(x, starts, ends, bs, be, has_inner, n, dense=True):
             jnp.where(has_inner, be, starts // B + 1), 0, n)
         return inner + edges.sum(axis=1)
 
+    if starts.shape[0] > _SUM_ROW_READS_MAX_GROUPS:
+        return _seg_sum_scalar_reads(x.astype(acc), starts, ends, n, dense)
+
+    B = _SUM_BLOCK
+    nb, left = divmod(n, B)
+    # the whole blocks, and the rows left over as one block of their own
+    whole = x[:nb * B].reshape(nb, B) if nb else None
+    rest = jnp.pad(x[nb * B:], (0, B - left))[None, :] if left else None
+    hi, lo = _block_prefix(jnp.concatenate(
+        [blocks.sum(axis=1, dtype=acc) for blocks in (whole, rest)
+         if blocks is not None]))
+    if lo is None:
+        lo = jnp.zeros_like(hi)
+    # the prefix as rows of 128 lanes, 32 blocks a row in four bands:
+    # hi[b], lo[b], hi[b + 1], lo[b + 1]
+    table = jnp.concatenate(
+        [jnp.pad(p[k:], (0, k + -p.shape[0] % 32)).reshape(-1, 32)
+         for k in (0, 1) for p in (hi, lo)], axis=1)
+    lane = jnp.arange(128, dtype=jnp.int32)[None, :]
+
+    def block_at(b):
+        """[G, B]: block b a group (past the last: masked by r == 0)."""
+        if whole is None:
+            return rest
+        rows = whole[jnp.minimum(b, nb - 1)]
+        return rows if rest is None else \
+            jnp.where((b >= nb)[:, None], rest, rows)
+
+    def between(rows, lo_lane, hi_lane):
+        keep = (lane >= lo_lane[:, None]) & (lane < hi_lane[:, None])
+        return jnp.where(keep, rows, jnp.zeros((), rows.dtype)).sum(
+            axis=1, dtype=acc)
+
+    def rest_of(rows, r):
+        """A block from a bound inside it on; none from its edge on: the
+        block is a whole one of the segment's."""
+        return between(rows, jnp.where(r > 0, r, B), jnp.full_like(r, B))
+
+    def prefix_at(b, r):
+        """The pair prefix at the first whole block from a bound on: at
+        its own block where the bound is the block's edge."""
+        rows = table[b // 32]
+        at = b % 32 + 64 * (r > 0)
+        return (between(rows, at, at + 1), between(rows, at + 32, at + 33))
+
+    b_s, r_s, b_e, r_e = bounds
+    ends_block = block_at(b_e)
+    inside = between(ends_block, r_s, r_e)      # where b_s == b_e
+    head = between(ends_block, jnp.zeros_like(r_e), r_e)
+    e_hi, e_lo = prefix_at(b_e, jnp.zeros_like(r_e))
+    if dense:
+        tail = _shifted(rest_of(ends_block, r_e))
+        s_hi, s_lo = map(_shifted, prefix_at(b_e, r_e))
+    else:
+        tail = rest_of(block_at(b_s), r_s)
+        s_hi, s_lo = prefix_at(b_s, r_s)
+    inner = (e_hi - s_hi) + (e_lo - s_lo)
+    return jnp.where(b_s == b_e, inside, (tail + head) + inner)
+
+
+def _seg_sum_scalar_reads(x, starts, ends, n, dense):
+    """The prefix form past `_SUM_ROW_READS_MAX_GROUPS`: in-block
+    inclusive scans kept in memory (blocks of `_SEG_BLOCK`) under the
+    pair prefix make a global prefix P, read as three scalars a bound;
+    each segment is P[end] - P[start]. 12 B a group where a row read is
+    512, and the form every launch past the threshold took before PR 45:
+    it carries ~1 ulp of a block's sum into a small segment."""
+    B = _SEG_BLOCK
+    xp, nb = _pad_block(x, 0, n)
     inblock = jnp.cumsum(xp.reshape(nb, B), axis=1)      # inclusive scans
     hi, lo = _block_prefix(inblock[:, -1])
 
@@ -502,14 +637,10 @@ def _sorted_seg_sum(x, starts, ends, bs, be, has_inner, n, dense=True):
         return hi[b], (inb if lo is None else lo[b] + inb)
 
     pe_hi, pe_lo = prefix_at(ends)
-    if not dense:
-        ps_hi, ps_lo = prefix_at(starts)
-        return (pe_hi - ps_hi) + (pe_lo - ps_lo)
-
-    def seg(pe):                            # P[end] - P[start], shifted
-        return pe - jnp.concatenate([jnp.zeros(1, acc), pe[:-1]])
-
-    return seg(pe_hi) + seg(pe_lo)
+    if dense:       # P[start] is a shift of P[end]
+        return (pe_hi - _shifted(pe_hi)) + (pe_lo - _shifted(pe_lo))
+    ps_hi, ps_lo = prefix_at(starts)
+    return (pe_hi - ps_hi) + (pe_lo - ps_lo)
 
 
 def _floor_log2(ln, K):
@@ -1014,7 +1145,9 @@ def _sga_body(gids, mask, ts, values, col_masks, starts, ends, bs, be,
         return cache[key]
 
     def seg_sum(x):
-        return _sorted_seg_sum(x, starts, ends, bs, be, has_inner, n, dense)
+        return _sorted_seg_sum(
+            x, starts, ends, bs, be, has_inner, n, dense,
+            once("sum_bounds", lambda: _sum_bounds(starts, ends, dense)))
 
     def column(v):
         return ts if v < 0 else values[v]
@@ -1058,7 +1191,7 @@ def _sga_body(gids, mask, ts, values, col_masks, starts, ends, bs, be,
 
     def compute(kind, *of):
         if kind == "count":
-            return seg_sum(rows(*of).astype(jnp.int32))
+            return seg_sum(rows(*of))
         if kind == "argext":
             return argext(*of)
         if kind in ("min", "max"):

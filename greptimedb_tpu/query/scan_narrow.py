@@ -341,7 +341,7 @@ def launch(scan, schema, plan, sel: Selection, part):
     return tpu_exec._Launched(
         results, counts, len(run_starts), sel.sids[run_range],
         buckets[run_rows] if buckets is not None else None,
-        scan.series_dict, scan.ts_base, passes)
+        scan.series_dict, scan.ts_base, passes, num_groups=nbucket)
 
 
 def _columns(scan, schema, plan, reads):

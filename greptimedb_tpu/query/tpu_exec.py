@@ -30,7 +30,8 @@ import pandas as pd
 
 from ..errors import UnsupportedError
 from ..ops.kernels import (_sorted_grouped_aggregate_pre, distinct_arrays,
-                           merge_dedup_numpy, moment_results, shape_bucket)
+                           merge_dedup_numpy, moment_results, shape_bucket,
+                           sum_form)
 from ..sql.ast import (
     Between, BinaryOp, Column, Expr, FunctionCall, InList, Interval, IsNull,
     Literal, Query, UnaryOp,
@@ -2326,10 +2327,14 @@ def _moment_frame_for_scan(scan: MergedScan, schema, plan: TpuPlan,
     run, shared = launched.passes
     increment_counter("scan_kernel_passes", run, kind="run")
     increment_counter("scan_kernel_passes", shared, kind="shared")
+    # the form the program's float sums take at its group count
+    sums = sum_form(launched.num_groups)
+    increment_counter("scan_sum_form", form=sums)
     if tail:
-        exec_stats.record("reduce", tail_passes=run)
+        exec_stats.record("reduce", tail_passes=run, tail_sums=sums)
     else:
-        exec_stats.record("reduce", moments=run + shared, passes=run)
+        exec_stats.record("reduce", moments=run + shared, passes=run,
+                          sums=sums)
     with _reduce_part("fetch"):     # blocked on the device, then D2H
         counts, res_np = jax.device_get((launched.counts,
                                          list(launched.results)))

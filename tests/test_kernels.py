@@ -401,6 +401,21 @@ class TestReviewRegressions:
 # sorted_grouped_aggregate (the scatter-free LSM fast path)
 # ---------------------------------------------------------------------------
 
+def block_edge_lens():
+    """Segment lengths, laid end to end from row 0, that meet the prefix
+    form's blocks of `_SUM_BLOCK` rows every way."""
+    from greptimedb_tpu.ops.kernels import _SUM_BLOCK as B
+    return [B - 1,          # shorter than a block
+            1,              # ends on the block's edge
+            B,              # exactly a block, edge to edge
+            0,              # empty, on an edge
+            B + 1,          # a block and a row of the next
+            3 * B + 5,      # over three blocks, both ends inside one
+            5,              # inside one block
+            4 * B - 11,     # over three whole blocks, ends on an edge
+            7]
+
+
 class TestSortedGroupedAggregate:
     def _mk(self, n=50_000, groups=97, skew=False, seed=3):
         rng = np.random.default_rng(seed)
@@ -706,6 +721,11 @@ class TestSegmentsPickedOutOfALayout:
         ("straddle", 20_000, 2_000),      # the layout above, the picked under
         ("low-shift", 300, None),
         ("high-shift", 40_000, None),
+        # segments that meet the prefix form's blocks every way
+        # (`block_edge_lens`), picked and dense against `starts=`
+        ("high-blocks", 40_000, 12_000),
+        ("high-blocks-shift", 40_000, None),
+        ("low-blocks-shift", 300, None),      # the counts' prefix form
     ]
 
     @pytest.mark.parametrize("with_k", [True, False],
@@ -720,6 +740,9 @@ class TestSegmentsPickedOutOfALayout:
         longest = 70                  # past two 32-row blocks
         lens = rng.integers(0, 9, groups)
         lens[rng.integers(0, groups, 12)] = rng.integers(30, longest + 1, 12)
+        if "blocks" in name:
+            edge = block_edge_lens()
+            lens[:len(edge)], longest = edge, max(edge)
         n = int(lens.sum())
         dense_b = shape_bucket(groups, minimum=256)
         dense_ends = np.full(dense_b, n, dtype=np.int32)
@@ -739,9 +762,13 @@ class TestSegmentsPickedOutOfALayout:
             live = np.arange(dense_b)
             live_b, starts, ends = dense_b, dense_starts, dense_ends
         else:
-            live = np.sort(rng.choice(groups, picked, replace=False))
+            live = rng.choice(groups, picked, replace=False)
+            if "blocks" in name:      # the segments at the blocks' edges
+                live = np.union1d(live[len(edge):], np.arange(len(edge)))
+            live, picked = np.sort(live), len(live)
             # one picked segment with rows and none under the mask
-            emptied = live[np.nonzero(lens[live] > 2)[0][3]]
+            emptied = live[np.nonzero(lens[live] > 2)[0][
+                -3 if "blocks" in name else 3]]
             mask[dense_starts[emptied]:dense_ends[emptied]] = False
             live_b = shape_bucket(picked, minimum=256)
             assert live_b > picked            # padding groups past the live
@@ -778,6 +805,129 @@ class TestSegmentsPickedOutOfALayout:
                 assert np.array_equal(g, w, equal_nan=True), (op, i)
 
 
+class TestThePrefixFormsBlocks:
+    """ISSUE 45: past `_SEG_HIGH_CARD_THRESHOLD` groups (a count: at any
+    cardinality) a sum reads its rows in blocks of `_SUM_BLOCK`: a segment
+    inside one block is that block's row summed between its bounds, a
+    longer one the rest of its first block, the whole blocks between and
+    the head of its last. Every way a segment can meet the blocks, against
+    a float64 reference at this file's tolerance for such sums, counts
+    exact, and `starts=` (all of the layout, and every other segment of
+    it) against the dense launch bit for bit."""
+
+    #: id -> the segments' lengths, laid end to end from row 0 (B: the
+    #: rows of a block); the groups past them are padding (starts == ends)
+    CASES = {
+        "shorter-than-a-block": lambda B: [B - 1, 3, B // 2, 1, B - 2],
+        "exactly-a-block": lambda B: [B, B, B],
+        "three-and-more-blocks": lambda B: [3 * B + 5, 4 * B, 7 * B + 1, 2],
+        "an-end-on-a-blocks-edge": lambda B: [B - 1, 1, 2 * B, B // 2,
+                                              B // 2, 3 * B, 7],
+        "n-no-multiple-of-the-block": lambda B: [B, B + 3, 2 * B + 17],
+        "n-under-one-block": lambda B: [5, 0, 7, B // 4],
+        "empty-segments": lambda B: [0, B, 0, 0, 5, 0, 2 * B, 0, 0, 9],
+        "one-segment-of-forty-blocks": lambda B: [40 * B + 3, 1],
+        # a gauge at 1e10 (node_memory_*) beside one at 1, in one block
+        # and across a block's edge
+        "1e10-beside-1": lambda B: [B // 2, B // 4, B // 2, B // 4, B // 8,
+                                    2 * B, B // 2, 3],
+    }
+    GROUPS = 16_384
+
+    @pytest.mark.parametrize("case", list(CASES))
+    def test_against_float64_and_dense_against_starts(self, case):
+        from greptimedb_tpu.ops.kernels import (
+            _SEG_HIGH_CARD_THRESHOLD, _SUM_BLOCK, sorted_grouped_aggregate)
+        assert self.GROUPS > _SEG_HIGH_CARD_THRESHOLD
+        lens = np.array(self.CASES[case](_SUM_BLOCK))
+        rng = np.random.default_rng(len(case))
+        n, m = int(lens.sum()), len(lens)
+        ends = np.full(self.GROUPS, n, dtype=np.int32)
+        ends[:m] = np.cumsum(lens)
+        starts = np.concatenate([[0], ends[:-1]]).astype(np.int32)
+        gids = np.repeat(np.arange(m, dtype=np.int32), lens)
+        ts = np.arange(n, dtype=np.int32)
+        if case == "1e10-beside-1":
+            vals = np.where(gids % 2 == 0, 1e10, 1.0).astype(np.float32)
+            vals *= rng.integers(1, 4, n)
+            mask = np.ones(n, bool)
+        else:
+            vals = (rng.random(n, dtype=np.float32) * 100) - 50
+            mask = rng.random(n) > 0.2
+        valid = rng.random(n) > 0.1
+        ops = ("sum", "count", "avg")
+
+        def run(**segments):
+            res, counts = sorted_grouped_aggregate(
+                gids, mask, ts, (vals,) * 3, (valid,) * 3,
+                num_groups=self.GROUPS, ops=ops, has_col_masks=True,
+                **segments)
+            return [np.asarray(r) for r in res], np.asarray(counts)
+
+        (sm, ct, av), rows = run(ends=ends)
+        # counts: exact; float sums: float64 at the file's tolerance
+        keep = mask & valid
+        v64 = np.where(keep, vals.astype(np.float64), 0)
+        at = np.concatenate([[0], np.cumsum(v64)])
+        n_at = np.concatenate([[0], np.cumsum(keep)])
+        rows_at = np.concatenate([[0], np.cumsum(mask)])
+        want_ct = n_at[ends] - n_at[starts]
+        assert np.array_equal(rows, rows_at[ends] - rows_at[starts])
+        assert np.array_equal(ct, want_ct)
+        assert sm.dtype == np.float32 and ct.dtype == np.int32
+        want = np.array([v64[s:e].sum() for s, e in zip(starts[:m],
+                                                        ends[:m])])
+        np.testing.assert_allclose(sm[:m], want, rtol=2e-4, atol=1e-3)
+        assert (sm[m:] == 0).all() and (ct[m:] == 0).all()
+        some = want_ct[:m] > 0
+        np.testing.assert_allclose(av[:m][some], (want / np.maximum(
+            want_ct[:m], 1))[some], rtol=2e-4, atol=1e-3)
+        assert np.isnan(av[:m][~some]).all() and np.isnan(av[m:]).all()
+        # `starts=` over the same layout, and over every other segment
+        for pick in (np.arange(self.GROUPS), np.arange(0, m, 2)):
+            s = np.full(self.GROUPS, n, dtype=np.int32)
+            e = np.full(self.GROUPS, n, dtype=np.int32)
+            s[:len(pick)], e[:len(pick)] = starts[pick], ends[pick]
+            (sm2, ct2, av2), rows2 = run(ends=e, starts=s)
+            for got, dense in ((sm2, sm), (ct2, ct), (av2, av),
+                               (rows2, rows)):
+                assert np.array_equal(got[:len(pick)], dense[pick],
+                                      equal_nan=True)
+
+
+def test_past_a_million_groups_a_bound_is_read_as_scalars():
+    """A read-back that groups by row has as many groups as rows: past
+    `_SUM_ROW_READS_MAX_GROUPS` a gathered [G, 128] would be 512 B a
+    group (the chip's compiler refused 16 GB at 33.5M groups), and the
+    sums read three scalars a bound, dense and `starts=` alike."""
+    from greptimedb_tpu.ops.kernels import (
+        _SUM_ROW_READS_MAX_GROUPS, sorted_grouped_aggregate)
+    groups = 2 * _SUM_ROW_READS_MAX_GROUPS
+    rng = np.random.default_rng(45)
+    lens = rng.integers(0, 3, 3_000)
+    lens[7], lens[90] = 300, 129
+    n, m = int(lens.sum()), len(lens)
+    ends = np.full(groups, n, dtype=np.int32)
+    ends[:m] = np.cumsum(lens)
+    starts = np.concatenate([[0], ends[:-1]]).astype(np.int32)
+    gids = np.repeat(np.arange(m, dtype=np.int32), lens)
+    vals = (rng.random(n, dtype=np.float32) * 100) - 50
+    mask = rng.random(n) > 0.2
+    want = np.array([vals[s:e][mask[s:e]].astype(np.float64).sum()
+                     for s, e in zip(starts[:m], ends[:m])])
+    want_n = np.array([mask[s:e].sum() for s, e in zip(starts[:m],
+                                                      ends[:m])])
+    for segments in ({"ends": ends}, {"ends": ends, "starts": starts}):
+        (sm, ct), rows = sorted_grouped_aggregate(
+            gids, mask, np.arange(n, dtype=np.int32), (vals, vals),
+            num_groups=groups, ops=("sum", "count"), **segments)
+        sm, ct = np.asarray(sm), np.asarray(ct)
+        assert np.array_equal(ct[:m], want_n) and not ct[m:].any()
+        assert np.array_equal(np.asarray(rows), ct)
+        np.testing.assert_allclose(sm[:m], want, rtol=2e-4, atol=1e-2)
+        assert not sm[m:].any()
+
+
 class TestMomentsShareTheirPasses:
     """ISSUE 41: a launch computes once what its moments share (a count a
     distinct validity, the row count where a column has no NULL, one
@@ -791,6 +941,9 @@ class TestMomentsShareTheirPasses:
         ("high", 9_000, None, False),        # above _SEG_HIGH_CARD_THRESHOLD
         ("doubling", 9_000, None, True),     # the shift-doubling kernels
         ("live-runs", 12_000, 9_000, True),      # dense=False with `starts`
+        # segments that meet the prefix form's blocks every way
+        ("high-blocks", 9_000, None, False),
+        ("live-runs-blocks", 12_000, 9_000, True),
     ]
     #: the validity each column reads: two columns with no NULL, one with
     #: NULLs of its own, two under one NULL-holding validity, which leaves
@@ -809,6 +962,9 @@ class TestMomentsShareTheirPasses:
         longest = 70                  # past two 32-row blocks
         lens = rng.integers(1, 9, groups)
         lens[rng.integers(0, groups, 12)] = rng.integers(30, longest + 1, 12)
+        if "blocks" in name:
+            edge = [ln for ln in block_edge_lens() if ln]
+            lens[:len(edge)], longest = edge, max(edge)
         n = int(lens.sum())
         nb = shape_bucket(groups, minimum=256)
         ends = np.full(nb, n, dtype=np.int32)
@@ -817,7 +973,7 @@ class TestMomentsShareTheirPasses:
         gids = np.repeat(np.arange(groups, dtype=np.int32), lens)
         ts = rng.integers(0, 40, n).astype(np.int32)        # ties
         mask = rng.random(n) > 0.15
-        emptied = int(np.nonzero(lens > 2)[0][3])
+        emptied = int(np.nonzero(lens > 2)[0][-3 if "blocks" in name else 3])
         valid = {"a": rng.random(n) > 0.2, "b": rng.random(n) > 0.2}
         valid["b"][starts[emptied]:ends[emptied]] = False
         mask[starts[emptied]:ends[emptied]] = True
@@ -825,9 +981,13 @@ class TestMomentsShareTheirPasses:
                 for _ in reads]
         segments = {"ends": ends}
         if picked is not None:
-            live = np.sort(np.append(rng.choice(
+            live = np.append(rng.choice(
                 np.delete(np.arange(groups), emptied), picked - 1,
-                replace=False), emptied))
+                replace=False), emptied)
+            if "blocks" in name:      # the segments at the blocks' edges
+                live = np.union1d(live[len(edge):], np.arange(len(edge)))
+            live = np.sort(live)
+            picked = len(live)
             emptied = int(np.searchsorted(live, emptied))
             nb = shape_bucket(picked, minimum=256)
             segments = {"starts": np.full(nb, n, dtype=np.int32),

@@ -697,6 +697,48 @@ def test_a_narrow_launch_makes_no_mask_of_the_tables_length(db):
     assert "mask=" not in db.stages(sql)["reduce"]
 
 
+# ---------------------------------------------------------------------------
+# the form of a launch's float sums (ISSUE 45, `ops/kernels.py:sum_form`):
+# the prefix read at the bounds past the high-cardinality threshold, the
+# edge windows under it. By 20 s the table is 9,600 runs (bucket 16,384).
+# ---------------------------------------------------------------------------
+
+SUM_FORMS = [
+    ("table-by-20s", "", "20 second", "path=full, groups=table", "prefix"),
+    ("table-by-hour", "", "1 hour", "path=full, groups=table", "edge"),
+    ("one-host", "WHERE host = 'h05' ", "20 second", "path=narrow", "edge"),
+]
+
+
+@pytest.mark.parametrize("case", SUM_FORMS, ids=[c[0] for c in SUM_FORMS])
+def test_the_reduce_row_names_the_form_of_its_float_sums(db, case):
+    _, where, stride, path, form = case
+    sql = (f"SELECT host, date_bin(INTERVAL '{stride}', ts) AS b, "
+           f"sum(usage), avg(idle), count(idle) FROM cpu {where}"
+           "GROUP BY host, b ORDER BY host, b")
+
+    def forms():
+        return [total("greptime_scan_sum_form_total", f'form="{f}"')
+                for f in ("prefix", "edge")]
+
+    before = forms()
+    got = db.sql(sql)
+    assert forms() == [before[0] + (form == "prefix"),
+                       before[1] + (form == "edge")]
+    detail = db.stages(sql)["reduce"]
+    assert path in detail and f"passes=4, sums={form}" in detail
+    ref = db.ref if not where else db.ref[db.ref.host == "h05"]
+    ms = {"20 second": 20_000, "1 hour": 3_600_000}[stride]
+    want = ref.assign(b=ref.ts // ms).groupby(["host", "b"]).agg(
+        sm=("usage", "sum"), avi=("idle", "mean"), ni=("idle", "count"))
+    assert len(got) == len(want) > 0
+    assert np.array_equal(got["count(idle)"].to_numpy(), want.ni)
+    np.testing.assert_allclose(got["sum(usage)"], want.sm, rtol=AVG_RTOL)
+    keep = want.ni.to_numpy() > 0
+    np.testing.assert_allclose(got["avg(idle)"][keep], want.avi[keep],
+                               rtol=AVG_RTOL)
+
+
 H0 = T0 - T0 % 3_600_000                    # a whole hour
 
 

@@ -25,6 +25,7 @@ from greptimedb_tpu.ops.kernels import (_SEG_HIGH_CARD_THRESHOLD,
                                         open_window, seg_len_bucket,
                                         shape_bucket)
 from greptimedb_tpu.query import scan_narrow, tpu_exec
+from test_kernels import block_edge_lens
 
 HOSTS, TICKS, TICK_MS = 40, 600, 10_000
 T0 = 1_700_000_400_000                      # a whole ten minutes
@@ -41,6 +42,9 @@ LAYOUTS = [
     ("high", 9_000, None, False),           # above _SEG_HIGH_CARD_THRESHOLD
     ("doubling", 9_000, None, True),        # the shift-doubling kernels
     ("live-runs", 12_000, 9_000, True),     # dense=False with `starts`
+    # segments that meet the prefix form's 128-row blocks every way
+    ("high-blocks", 9_000, None, False),
+    ("live-runs-blocks", 12_000, 9_000, True),
 ]
 
 
@@ -51,6 +55,9 @@ def test_the_programs_window_is_the_mask_made_on_the_host(layout):
     longest = 70
     lens = rng.integers(1, 9, groups)
     lens[rng.integers(0, groups, 12)] = rng.integers(30, longest + 1, 12)
+    if "blocks" in name:
+        edge = [ln for ln in block_edge_lens() if ln]
+        lens[:len(edge)], longest = edge, max(edge)
     n = int(lens.sum())
     nb = shape_bucket(groups, minimum=256)
     ends = np.full(nb, n, dtype=np.int32)
@@ -63,7 +70,10 @@ def test_the_programs_window_is_the_mask_made_on_the_host(layout):
     col = (rng.random(n, dtype=np.float32) * 100) - 50
     if picked is not None:
         first = np.concatenate([[0], ends[:-1]]).astype(np.int32)
-        live = np.sort(rng.choice(groups, picked, replace=False))
+        live = rng.choice(groups, picked, replace=False)
+        if "blocks" in name:          # the segments at the blocks' edges
+            live = np.union1d(live[len(edge):], np.arange(len(edge)))
+        live, picked = np.sort(live), len(live)
         nb = shape_bucket(picked, minimum=256)
         starts = np.full(nb, n, dtype=np.int32)
         starts[:picked] = first[live]
