@@ -218,9 +218,9 @@ def _engine_gauges(catalog_manager, catalog_name: str):
             rows.append(("greptime_flow_buckets_written", labels,
                          float(spec.stats.get("buckets_written", 0)),
                          "gauge"))
-    from ..query.tpu_exec import SCAN_CACHE
+    from ..storage import scan_cache
     rows.append(("greptime_scan_cache_resident_bytes", "",
-                 float(SCAN_CACHE.resident_bytes()), "gauge"))
+                 float(scan_cache.SCAN_CACHE.resident_bytes()), "gauge"))
     store = getattr(catalog_manager, "store", None)
     hit_ratio = getattr(store, "hit_ratio", None)
     if callable(hit_ratio):

@@ -123,9 +123,9 @@ class LocalDatanodeClient(DatanodeClient):
     def region_moments(self, catalog: str, schema: str, table: str,
                        plan, regions: Optional[Sequence[int]] = None
                        ) -> List[pd.DataFrame]:
-        from ..query.tpu_exec import region_moment_frames
+        from ..query import tpu_exec
         with self._node_ctx():
-            return region_moment_frames(
+            return tpu_exec.region_moment_frames(
                 self._table(catalog, schema, table), plan,
                 regions=regions)
 

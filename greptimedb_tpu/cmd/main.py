@@ -102,8 +102,9 @@ def build_servers(opts: StandaloneOptions):
             cold_reduce=opts.query.get("cold_reduce"))
         budget_mb = opts.query.get("scan_cache_budget_mb")
         if budget_mb is not None:
-            from ..query.tpu_exec import SCAN_CACHE
-            SCAN_CACHE.configure(budget_bytes=int(budget_mb) << 20)
+            from ..storage import scan_cache
+            scan_cache.SCAN_CACHE.configure(
+                budget_bytes=int(budget_mb) << 20)
     store = None
     if opts.storage and str(opts.storage.get("type", "File")) != "File":
         from ..storage.object_store import build_object_store

@@ -91,7 +91,8 @@ def fold_local(spec, src, dst) -> Tuple[int, int]:
     threshold never enter the scan cache — they take a window-bounded
     host fold instead (fold_region_cold), the same residency rule the
     query path applies."""
-    from ..query.tpu_exec import SCAN_CACHE, region_streams_cold
+    from ..query.tpu_exec import region_streams_cold
+    from ..storage import scan_cache
     from ..storage.downsample import downsample_region
     agg_specs = [(a.dest, a.op, a.column) for a in spec.aggs]
     written = new_total = 0
@@ -107,7 +108,7 @@ def fold_local(spec, src, dst) -> Tuple[int, int]:
             written += w
             new_total += n
             continue
-        scan = SCAN_CACHE.get(region)
+        scan = scan_cache.SCAN_CACHE.get(region)
         if scan.num_rows == 0:
             if wm.get("rows"):
                 # everything this region ever folded was deleted:

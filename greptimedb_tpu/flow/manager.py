@@ -129,7 +129,7 @@ def compile_flow(stmt: ast.CreateFlow, src_table, catalog: str,
     FlowSpec. Raises on anything the incremental fold cannot maintain."""
     from ..query.expr import expr_name
     from ..query.planner import _AGG_CANON
-    from ..query.tpu_exec import _match_bucket
+    from ..query.agg_plan import _match_bucket
 
     q = stmt.query
     if q.joins or q.where is not None or q.having is not None or \

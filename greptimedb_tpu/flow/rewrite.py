@@ -76,7 +76,7 @@ def try_rewrite(manager, table, analysis, query: ast.Query, ctx
 def _rewrite_for(flow, table, a, query: ast.Query
                  ) -> Optional[RollupRewrite]:
     from ..query.expr import expr_name
-    from ..query.tpu_exec import (_conjuncts, _match_bucket,
+    from ..query.agg_plan import (_conjuncts, _match_bucket,
                                   _match_time_pred, _refs)
 
     schema = table.schema

@@ -834,7 +834,7 @@ class DistTable(Table):
         raw rows ~2x — the GROUP BY keys are nearly unique and a
         per-group sketch carries more than the rows it summarizes."""
         from ..query import sketches
-        from ..query.tpu_exec import plan_scan_columns
+        from ..query.agg_plan import plan_scan_columns
         est = self._region_estimates(survivors)
         if not survivors or any(r not in est for r in survivors):
             return None

@@ -465,8 +465,8 @@ def apply_set_variable(stmt: ast.SetVariable, ctx: QueryContext) -> Output:
         # distributed partial-aggregate pushdown kill switch: 0 sends
         # GROUP BYs over DistTables through the raw-row scatter
         # (tests/test_sketches.py's reference answers)
-        from ..query import tpu_exec
-        tpu_exec.configure_partial_pushdown(
+        from ..query import agg_plan
+        agg_plan.configure_partial_pushdown(
             enabled=bool(_int_setting(stmt)))
     elif name == "sst_index":
         # per-SST secondary indexes (storage/index.py): 0 disables both

@@ -98,7 +98,7 @@ def _partial_aggregate(gids, mask, ts, row_idx, values, col_masks, *,
             sq = jax.lax.psum(jax.ops.segment_sum(
                 d * d, safe_gids, num_segments=seg)[:num_groups], axes)
             cc = jnp.maximum(c, 1)
-            # sample variance (ddof=1), matching the finalize in tpu_exec
+            # sample variance (ddof=1), matching moment_fold._finalize
             var = jnp.maximum(sq - (s / cc) * s, 0.0) / jnp.maximum(c - 1, 1)
             var = jnp.where(c >= 2, var, jnp.nan)
             results.append(jnp.sqrt(var) if op == "stddev" else var)

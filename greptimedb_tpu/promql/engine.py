@@ -6,7 +6,7 @@ and RangeManipulate) plus per-window scalar UDFs (functions/*.rs); the
 servers shape results to Prometheus JSON (src/servers/src/prom.rs:150-400).
 
 TPU design (original): selectors materialize a dense padded [series, time]
-matrix straight from the region scan cache (query/tpu_exec.py MergedScan —
+matrix straight from the region scan cache (storage/scan_cache.py MergedScan —
 sorted, MVCC-deduped, device-resident). Instant selection and every range
 function are single vmapped device passes over an aligned step grid
 (ops/window.py); label grouping, vector matching, and JSON shaping stay on

@@ -49,6 +49,7 @@ import numpy as np
 
 from ..errors import UnsupportedError
 from ..sql.ast import BinaryOp, Column, IsNull, Literal
+from ..storage.scan_cache import MergedScan
 from .ast import Aggregate, Call, PromExpr, VectorSelector
 
 #: outer aggregates whose inner vector we lower (topk/quantile/
@@ -120,7 +121,7 @@ def try_lower(ev, e: Aggregate):
     the IR. Returns (LoweredSelect, "") on success, (EMPTY, "") when
     the matchers statically match nothing, or (None, reason) when the
     statement keeps the row path."""
-    from ..query import ir, tpu_exec
+    from ..query import agg_plan, ir, tpu_exec
     from .engine import _matches_empty
 
     if e.op not in LOWERABLE_AGG_OPS or e.param is not None:
@@ -155,7 +156,7 @@ def try_lower(ev, e: Aggregate):
     is_dist = hasattr(table, "execute_tpu_plan")
     if not is_dist and not hasattr(table, "regions"):
         return None, f"{metric} is not a region-backed table"
-    if is_dist and not tpu_exec._PARTIAL_PUSHDOWN[0]:
+    if is_dist and not agg_plan._PARTIAL_PUSHDOWN[0]:
         return None, "SET dist_partial_agg = 0"
     if not is_dist:
         # same floor SQL's try_execute applies: small local tables are
@@ -222,14 +223,14 @@ def try_lower(ev, e: Aggregate):
         mspec.append(("__t", "max_ts", field))
     elif func in ("rate", "increase", "delta"):
         # first / last / min_ts are also what folds the growth of one
-        # window across partials (tpu_exec._finalize)
+        # window across partials (moment_fold._finalize)
         aggs += [("__first", "first", field), ("__last", "last", field)]
         mspec += [("__mnt", "min_ts", field), ("__mxt", "max_ts", field)]
         # the window's raw growth as a moment of its own: last - first
         # of the device's f32 mirrors has no digits left once the level
         # is large (a gauge at 1e12 that moves by 6e4 a window came out
         # 31% off), so the device sums per-sample differences instead
-        # (tpu_exec.RUN_DIFF_MOMENT_OPS)
+        # (agg_plan.RUN_DIFF_MOMENT_OPS)
         mspec.append(("__grow", "delta" if func == "delta" else "increase",
                       field))
     elif func in ("last_over_time",):
@@ -237,7 +238,7 @@ def try_lower(ev, e: Aggregate):
     elif func != "count_over_time":
         aggs.append(("__v", func[:-len("_over_time")], field))
 
-    from ..query.tpu_exec import BucketGroup
+    from ..query.agg_plan import BucketGroup
     plan = ir.plan_from_specs(
         schema, aggs,
         group_tags=tag_names,          # per-series: full tag key
@@ -761,7 +762,6 @@ def _matrix_from_runs(scans, field: str, keep: np.ndarray, sid_set,
     last sample of a selected series)."""
     from ..common.exec_stats import stage
     from ..ops.window import TS_PAD, SeriesMatrix
-    from ..query.tpu_exec import MergedScan
     if any(s.fields[field][1] is not None or not isinstance(s, MergedScan)
            for s in scans):
         return None
@@ -852,7 +852,6 @@ def _rows_kept(scan, keep: np.ndarray, sid_set, lo_ms: int, hi_ms: int
     (sorted by series, then time), only the candidates' runs are read:
     one node's panel then touches 64 runs of a table of 11.5M rows, not
     every row of it three times."""
-    from ..query.tpu_exec import MergedScan
     if sid_set is None or not isinstance(scan, MergedScan):
         return np.nonzero(keep[scan.series_ids] & (scan.ts >= lo_ms)
                           & (scan.ts <= hi_ms))[0]
@@ -1006,11 +1005,10 @@ def matcher_sids(region, tag_names, eq_matchers):
 class ScanParts:
     """A resident region's rows as the scan cache holds them after a
     write: the base, and the rows written since as a second scan sorted
-    the same way (`query/tpu_exec.py:_ScanCache.get_parts`), cut to its
-    valid rows. The selector reads both and merges neither."""
+    the same way (`storage/scan_cache.py:_ScanCache.get_parts`), cut to
+    its valid rows. The selector reads both and merges neither."""
 
     def __init__(self, base, tail):
-        from ..query.tpu_exec import MergedScan
         n = tail.valid_rows
         self.parts = [base, MergedScan(
             tail.series_ids[:n], tail.ts[:n], tail.fields,
@@ -1028,11 +1026,12 @@ def region_scan(region, fields: List[str], lo_ms: int, hi_ms: int,
     a scan at a time."""
     from ..common.telemetry import increment_counter
     from ..common.time import TimestampRange
-    from ..query.tpu_exec import SCAN_CACHE, region_streams_cold
+    from ..query.tpu_exec import region_streams_cold
+    from ..storage import scan_cache
 
     if not region_streams_cold(region):
         increment_counter("promql_select_resident")
-        base, tail = SCAN_CACHE.get_parts(region, hi_ms + 1)
+        base, tail = scan_cache.SCAN_CACHE.get_parts(region, hi_ms + 1)
         increment_counter("promql_select_parts",
                           tail="no" if tail is None else "yes")
         if tail is None:

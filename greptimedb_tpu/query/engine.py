@@ -34,7 +34,7 @@ from .output import Output
 from .planner import (Analysis, analyze, convert_time_literals,
                       _group_slot)
 from . import show as show_impl
-from . import tpu_exec
+from . import agg_plan, tpu_exec
 
 
 class QueryEngine:
@@ -116,7 +116,7 @@ class QueryEngine:
                                                 refresh=False)
                 if rw is not None:
                     table, pq, a, rollup_note = rw
-            plan = tpu_exec.plan_for(table, a, pq) if table else None
+            plan = agg_plan.plan_for(table, a, pq) if table else None
             if plan is not None:
                 # pin the dispatch decision (sqlness explain goldens):
                 # pushdown / cpu-small-scan / streamed-cold / resident.
@@ -284,7 +284,7 @@ class QueryEngine:
                     # LIMIT when no later stage can change which rows
                     # qualify (_run_on_frame still re-filters/limits —
                     # pushdown only sheds rows, never decides)
-                    conj = tpu_exec._conjuncts(query.where)
+                    conj = agg_plan._conjuncts(query.where)
                     push_limit = None
                     if query.limit is not None and not query.order_by \
                             and not query.distinct and not a.is_aggregate \

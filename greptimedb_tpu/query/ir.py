@@ -4,7 +4,7 @@ Reference behavior: src/query — the reference plans SQL *and* PromQL
 into one DataFusion LogicalPlan, and src/common/substrait ships that
 plan to datanodes. This build's equivalent is small and columnar:
 
-- `TpuPlan` (query/tpu_exec.py) — the aggregate node: time range, tag
+- `TpuPlan` (query/agg_plan.py) — the aggregate node: time range, tag
   predicates, group keys (tags + one time bucket), moment specs with
   sketch/expression extras. SQL (`plan_for`), PromQL
   (promql/lowering.py) and flows (flow/lowering.py) all lower into it,
@@ -48,18 +48,10 @@ import numpy as np
 import pandas as pd
 
 from ..errors import SketchCodecError, UnsupportedError
-from .tpu_exec import (
-    BucketGroup,
-    Moment,
-    TagGroup,
-    TpuPlan,
-    _aggs_desc,
-    _finalize,
-    dispatch_decision_for_pushdown,
-    frames_nbytes,
-    region_moment_frames,
-    standard_final,
-)
+from . import tpu_exec
+from .agg_plan import (BucketGroup, Moment, TagGroup, TpuPlan,
+                       standard_final)
+from .moment_fold import _aggs_desc, _finalize, frames_nbytes
 
 __all__ = [
     "BucketGroup", "Moment", "RawScan", "TagGroup", "TpuPlan",
@@ -196,7 +188,7 @@ def execute_agg_plan(table, plan: TpuPlan) -> pd.DataFrame:
         # regions, the frontend folds moment frames (_finalize).
         # The table names its own scatter (pruning + fan-out) when it
         # can, so EXPLAIN and execution print the same decision.
-        exec_stats.set_dispatch(dispatch_decision_for_pushdown(
+        exec_stats.set_dispatch(tpu_exec.dispatch_decision_for_pushdown(
             table, plan))
         with span("tpu_pushdown", table=table.name), \
                 timer("tpu_pushdown"):
@@ -205,7 +197,7 @@ def execute_agg_plan(table, plan: TpuPlan) -> pd.DataFrame:
     else:
         with span("tpu_execute", table=table.name), \
                 timer("tpu_execute"):
-            frames = region_moment_frames(table, plan)
+            frames = tpu_exec.region_moment_frames(table, plan)
     if not frames:
         cols = group_key_columns(plan)
         if cols:

@@ -29,7 +29,7 @@ from ..sql.ast import (
     Between, BinaryOp, Column, Expr, FunctionCall, InList, Interval, IsNull,
     Literal, UnaryOp,
 )
-from .tpu_exec import BucketGroup, FieldFilter, Moment, TagGroup, TpuPlan
+from .agg_plan import BucketGroup, FieldFilter, Moment, TagGroup, TpuPlan
 
 #: every moment op this build's reducers implement, and every final op
 #: _finalize knows how to render. plan_from_dict VALIDATES against these

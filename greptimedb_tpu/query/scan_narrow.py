@@ -8,7 +8,7 @@ those slices out of its own mirrors into a compact block and runs the
 same grouped aggregate over it (`ops/kernels.py:sorted_grouped_aggregate`);
 runs, mask, fetch and collect are sized by the selected rows, not by
 the table. `scan_read_path` chooses between this and the full launch
-(`tpu_exec._launch_scan_kernel`) from what it can count.
+(`scan_full._launch_scan_kernel`) from what it can count.
 """
 
 from __future__ import annotations
@@ -22,9 +22,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.kernels import (_SEG_HIGH_CARD_THRESHOLD,
-                           _sorted_grouped_aggregate_pre, distinct_arrays,
-                           moment_results, open_window, shape_bucket)
-from . import tpu_exec
+                           _sorted_grouped_aggregate_pre, moment_results,
+                           open_window, shape_bucket)
+from ..storage import scan_cache
+from . import scan_launch
 
 #: The narrowed launch runs while the compact block (`padded_rows`:
 #: range bucket x length bucket) is at most 1 / this of the table.
@@ -107,7 +108,7 @@ def live_layout(run_starts: np.ndarray, run_ends: np.ndarray,
     """-> (n_live, num_groups, starts, ends): the kernel's segments for
     the live runs the spans [lo[i], hi[i]) of `run_spans` name, end to
     end, padded to their bucket (at least `min_groups`: a tail's axis is
-    pinned, `tpu_exec._tail_groups`) with empty groups at `n` (as the
+    pinned, `scan_launch._tail_groups`) with empty groups at `n` (as the
     table's padded runs are). Nothing of the table's run count is built."""
     c = hi - lo
     n_live = int(c.sum())
@@ -122,7 +123,7 @@ def selection_runs(ts: np.ndarray, sel: "Selection", origin: int,
     `stride`) cuts inside the ranges of `sel`, as rows of the table, and
     each run's bucket number from `origin`. One pass over the selected
     rows and none over the table: what a statement pays whose grid the
-    scan holds no layout of (`tpu_exec._selection_layout`). A run ends
+    scan holds no layout of (`scan_full._selection_layout`). A run ends
     with its range; the rows of its (series, bucket) outside the range
     are the row mask's to drop, as on the table's runs."""
     first = np.cumsum(sel.lens) - sel.lens
@@ -193,23 +194,6 @@ class Selection:
         return self.range_bucket * self.len_bucket
 
 
-def _lower_bound(ts: np.ndarray, lo: np.ndarray, hi: np.ndarray,
-                 value) -> np.ndarray:
-    """Per range [lo[i], hi[i]) of `ts` (ascending inside a range): the
-    first row whose ts >= value (one value, or one a range). Every range
-    bisects at once, so the cost is log2(longest range) passes over k,
-    never a pass over ts."""
-    lo, hi = lo.copy(), hi.copy()
-    while True:
-        open_ = lo < hi
-        if not open_.any():
-            return lo
-        mid = (lo + hi) >> 1
-        right = open_ & (ts[np.minimum(mid, len(ts) - 1)] < value)
-        lo = np.where(right, mid + 1, lo)
-        hi = np.where(open_ & ~right, mid, hi)
-
-
 def select(scan, schema, plan) -> Optional[Selection]:
     """The ranges the statement keeps, or None when it has no point / IN
     tag conjunct to resolve to series (`sid_candidates_for_filters`, a
@@ -224,16 +208,16 @@ def select(scan, schema, plan) -> Optional[Selection]:
     if cand is None:
         return None
     if len(cand):
-        cand = cand[tpu_exec._series_keep(sd, tag_names, cand,
-                                          plan.tag_predicates)]
+        cand = cand[scan_launch._series_keep(sd, tag_names, cand,
+                                             plan.tag_predicates)]
     # a padded scan repeats its last row: the ranges end at the valid rows
     sids = scan.series_ids[:scan.valid_rows]
     lo = np.searchsorted(sids, cand, side="left")
     hi = np.searchsorted(sids, cand, side="right")
     if plan.time_lo is not None:
-        lo = _lower_bound(scan.ts, lo, hi, plan.time_lo)
+        lo = scan_cache._lower_bound(scan.ts, lo, hi, plan.time_lo)
     if plan.time_hi is not None:
-        hi = _lower_bound(scan.ts, lo, hi, plan.time_hi)
+        hi = scan_cache._lower_bound(scan.ts, lo, hi, plan.time_hi)
     live = hi > lo
     return Selection(cand[live].astype(np.int32, copy=False),
                      lo[live].astype(np.int64), (hi - lo)[live])
@@ -277,7 +261,7 @@ def _narrow_reduce(cuts, ends, rid, row_mask, cols, *, len_b, num_groups,
 
 
 def launch(scan, schema, plan, sel: Selection, part):
-    """-> tpu_exec._Launched over the selection's runs, or None when it
+    """-> scan_launch._Launched over the selection's runs, or None when it
     is empty. `part(name)` times the host's steps as the full launch's
     do: `runs`, `mask` (field filters only), `upload`, `launch`."""
     k, total = sel.n_ranges, sel.rows
@@ -285,9 +269,9 @@ def launch(scan, schema, plan, sel: Selection, part):
         return None
     n = scan.num_rows
     k_b, len_b = sel.range_bucket, sel.len_bucket
-    reads = list(tpu_exec._moment_reads(schema, plan,
-                                        seams=scan.base is not None))
-    tpu_exec._make_seams(scan, reads, part)
+    reads = list(scan_launch._moment_reads(schema, plan,
+                                           seams=scan.base is not None))
+    scan_launch._make_seams(scan, reads, part)
     with part("runs"):
         # compact coordinates: range i lives in [i * len_b, (i+1) * len_b),
         # its rows from `off[i]` on (0 unless the slice was clamped at the
@@ -312,22 +296,23 @@ def launch(scan, schema, plan, sel: Selection, part):
         run_rows = np.nonzero(flags)[0]
         run_starts = pos[run_rows]
         run_starts[0] = 0        # runs tile the block: padding joins a run
-        ops, value_ix, mask_ix, cols = _columns(scan, schema, plan, reads)
-        nbucket, run_ends, rid, seg_len_k = tpu_exec._segment_layout(
+        ops = tuple(op for op, _read, _masked_by in reads)
+        value_ix, mask_ix, cols = scan_launch._columns(scan, reads)
+        nbucket, run_ends, rid, seg_len_k = scan_launch._segment_layout(
             run_starts, k_b * len_b, ops, pinned=scan.pinned)
     row_mask = None
     if plan.field_filters:
         with part("mask"):
             keep = np.ones(total, dtype=bool)
             for ff in plan.field_filters:
-                keep &= tpu_exec._field_filter_keep(scan, ff, rows)
+                keep &= scan_launch._field_filter_keep(scan, ff, rows)
             row_mask = np.zeros(k_b * len_b, dtype=bool)
             row_mask[pos] = keep
     with part("upload"):
         cuts = np.zeros((3, k_b), dtype=np.int32)
         cuts[0, :k], cuts[1, :k], cuts[2, :k] = at, off, off + sel.lens
     with part("launch"):
-        out = tpu_exec._run_program(
+        out = scan_launch._run_program(
             scan, _narrow_reduce, cuts, run_ends, rid, row_mask, cols,
             len_b=len_b, num_groups=nbucket, ops=ops, value_ix=value_ix,
             mask_ix=mask_ix, seg_len_k=seg_len_k)
@@ -338,27 +323,9 @@ def launch(scan, schema, plan, sel: Selection, part):
     run_range = np.searchsorted(first, run_rows, side="right") - 1
     # warm stays False: the dispatch floor (`_note_device_query_time`)
     # is fed by full launches, whose fixed cost it stands for
-    return tpu_exec._Launched(
+    return scan_launch._Launched(
         results, counts, len(run_starts), sel.sids[run_range],
         buckets[run_rows] if buckets is not None else None,
         scan.series_dict, scan.ts_base, passes, num_groups=nbucket,
-        extremes=tpu_exec._launch_extremes(ops, value_ix, nbucket,
-                                           seg_len_k))
-
-
-def _columns(scan, schema, plan, reads):
-    """-> (ops, value_ix, mask_ix, cols): the moments' kernel ops and the
-    resident columns they read as (ts, values, validities), each column
-    once (a value index of -1: ts itself; a mask index of -1: the column
-    has no NULL)."""
-    d_ts = scan.device_ts()
-    ops, values, masks = [], [], []
-    for op, field_read, masked_by in reads:
-        ops.append(op)
-        values.append(d_ts if field_read is None
-                      else tpu_exec._device_column(scan, field_read))
-        masks.append(None if masked_by is None
-                     else scan.device_valid(masked_by))
-    values, value_ix = distinct_arrays(values, d_ts)
-    masks, mask_ix = distinct_arrays(masks, None)
-    return tuple(ops), value_ix, mask_ix, (d_ts, values, masks)
+        extremes=scan_launch._launch_extremes(ops, value_ix, nbucket,
+                                              seg_len_k))

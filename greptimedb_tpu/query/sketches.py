@@ -1,6 +1,6 @@
 """Sketch partials for distributed aggregation (ISSUE 14).
 
-The partial-state algebra of `tpu_exec` ships decomposable *moments*
+The partial-state algebra of `moment_fold` ships decomposable *moments*
 (sum/count/min/max/...) so distributed GROUP BY never moves raw rows.
 Two aggregate families break that algebra — ``count(DISTINCT x)`` and
 percentiles — because their exact state is the whole value set. This

@@ -1,6 +1,6 @@
 """Block-streamed cold scan: aggregate regions too large to cache in HBM.
 
-The cached fast path (tpu_exec.SCAN_CACHE) materializes a region's merged
+The cached fast path (storage/scan_cache.py) materializes a region's merged
 scan in host memory with device-resident mirrors — right for hot regions
 that fit, impossible for regions larger than device (or host) memory.
 This module streams instead:
@@ -18,7 +18,7 @@ This module streams instead:
    is globally exact, including overwrites and tombstones across SSTs.
 3. Each slice reduces to a partial moment frame on the device (padded to
    shape buckets so XLA compiles once, not once per slice), and
-   tpu_exec._finalize folds the partials — the same decomposable-moment
+   moment_fold._finalize folds the partials — the same decomposable-moment
    algebra that already merges partials across regions and datanodes.
 4. Host decode of slice i+1 overlaps device compute of slice i (a
    one-deep prefetch pipeline; parquet decode drops the GIL).
@@ -30,15 +30,31 @@ SURVEY §7 hard part #3 (overlapped Parquet-decode + H2D streaming).
 
 from __future__ import annotations
 
-from concurrent.futures import ThreadPoolExecutor
+import time as _time
 from typing import Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 import pandas as pd
+import pyarrow as pa
+import pyarrow.parquet as pq
 
+from ..common import exec_stats, failpoint, process_list
 from ..common.failpoint import register as _fp_register
+from ..common.runtime import parallel_map, transient_executor
+from ..common.telemetry import increment_counter, propagate, span
 from ..common.time import TimestampRange
+from ..datatypes import Vector
+from ..errors import UnsupportedError
 from ..ops.kernels import OP_PUT, merge_dedup_numpy, shape_bucket
+from ..storage.index import prune_files, sst_index_enabled
+from ..storage.region import ScanProfile
+from ..storage.scan_cache import MergedScan, run_diffs
+from . import moment_fold, scan_full
+from .agg_plan import (RUN_DIFF_MOMENT_OPS, SKETCH_MOMENT_OPS, moment_input,
+                       plan_needs_host, plan_scan_columns, sketch_run_column)
+from .expr import Evaluator
+from .planner import _group_slot
 
 # per-slice boundary of the streamed cold scan: delay(ms) makes a scan
 # deterministically slow for the KILL-cancellation tests
@@ -460,13 +476,6 @@ def _lean_chunk_frames(snap, access, files, dim: str, lo: int, hi: int,
 
     Returns (frames, rows_read), or None when any precondition fails
     and the caller must take the general scan path."""
-    import time as _time
-
-    import pyarrow as pa
-    import pyarrow.parquet as pq
-
-    from ..common import exec_stats
-
     _t0 = _time.perf_counter()
     _rows_read = 0
     _bytes_read = 0
@@ -485,7 +494,6 @@ def _lean_chunk_frames(snap, access, files, dim: str, lo: int, hi: int,
         # index tier: a pruned file's rows would all be masked out by
         # the tag predicates anyway, so the lean proof still holds on
         # the subset
-        from ..storage.index import prune_files
         files = prune_files(access.load_index, files, sid_set)[0]
         for meta in files:
             idx = access.load_index(meta)
@@ -558,8 +566,6 @@ def _lean_batch(batch, schema, needed_fields, want_types, ts_name: str,
                 need_ts: bool, nb: int) -> Optional["_LeanChunk"]:
     """numpy views over one record batch; None when a column can't be
     viewed losslessly (unexpected type) and the slice must fall back."""
-    import pyarrow as pa
-
     names = batch.schema.names
     idx = {nm: i for i, nm in enumerate(names)}
     sid_arr = batch.column(idx["__series_id"])
@@ -579,7 +585,6 @@ def _lean_batch(batch, schema, needed_fields, want_types, ts_name: str,
         if col.type != want_types[name]:
             return None
         if col.null_count:
-            from ..datatypes import Vector
             vec = Vector.from_arrow(col)
             fields[name] = (vec.data, vec.validity)
         else:
@@ -606,8 +611,6 @@ def _fold_sid_frames(frames: List[pd.DataFrame], plan, sd
     ints — ~3x the speed of the object-string fold), then a single tag
     decode pass over the folded groups. Output frames carry the standard
     label columns, so the cross-region fold is unchanged."""
-    from .planner import _group_slot
-
     df = pd.concat(frames, ignore_index=True) if len(frames) > 1 \
         else frames[0]
     keys = ["__sid"]
@@ -662,15 +665,13 @@ def _host_partial_frame(data, kept: Optional[np.ndarray], plan, sd,
                         sid_keys: bool = False
                         ) -> Optional[pd.DataFrame]:
     """One-pass vectorized host reduction of a sorted slice into the
-    same partial moment frame shape `tpu_exec._collect_moment_frame`
+    same partial moment frame shape `moment_fold._collect_moment_frame`
     emits, so `_finalize` folds host and device partials identically.
 
     Everything is segment arithmetic over the (sid [, bucket]) run
     boundaries: `np.<ufunc>.reduceat` per moment, masks folded into the
     identity element. Runs are (sid, ts)-sorted, so first/last reduce to
     the min/max valid row index per run."""
-    from .planner import _group_slot
-
     sids, ts = data.series_ids, data.ts
     fields = data.fields
     n = len(ts)
@@ -699,7 +700,6 @@ def _host_partial_frame(data, kept: Optional[np.ndarray], plan, sd,
             km[kept] = True
             and_mask(km)
     if plan.tag_predicates:
-        from .expr import Evaluator
         S = sd.num_series
         tag_cols = {}
         for i, tname in enumerate(sd.tag_names):
@@ -723,7 +723,6 @@ def _host_partial_frame(data, kept: Optional[np.ndarray], plan, sd,
     for ff in plan.field_filters:
         vals, valid = fields[ff.column]
         if vals.dtype == object:
-            from ..errors import UnsupportedError
             raise UnsupportedError(f"filter on non-numeric {ff.column}")
         v = vals.astype(np.float64, copy=False)
         cmp = {"eq": v == ff.value, "ne": v != ff.value,
@@ -781,8 +780,6 @@ def _host_partial_frame(data, kept: Optional[np.ndarray], plan, sd,
         if m.column is None:             # plain row count
             frame[m.slot] = counts
             continue
-        from .tpu_exec import RUN_DIFF_MOMENT_OPS, SKETCH_MOMENT_OPS, \
-            moment_input, run_diffs, sketch_run_column
         d, vd = moment_input(m, plan, fields, sids, ts, sd, cache=mcache)
         valid = vd if mask is None else (
             mask if vd is None else (vd & mask))
@@ -854,7 +851,6 @@ def _host_partial_frame(data, kept: Optional[np.ndarray], plan, sd,
                         run_diffs(dv[cur_i], dv[prev_i], m.op), 0.0)
                 r = np.add.reduceat(grow, starts)
             else:  # pragma: no cover — planner only emits the ops above
-                from ..errors import UnsupportedError
                 raise UnsupportedError(f"host moment op {m.op!r}")
         frame[m.slot] = r
     frame["__rowcount"] = counts
@@ -890,8 +886,6 @@ def _load_slice(snap, dim: str, lo: int, hi: int, unit, needed_fields,
     ts column is never decoded at all — on two-metric scans that cuts
     the decoded bytes by ~a quarter and the post-decode passes to the
     reduction itself."""
-    from .tpu_exec import MergedScan
-
     skip_dedup = covered = False
     lean_files: list = []
     if reduce == "host" and plan is not None:
@@ -948,7 +942,6 @@ def _load_slice(snap, dim: str, lo: int, hi: int, unit, needed_fields,
     # rows repeat the last (sid, ts) — they extend the final run, stay
     # sorted, and are masked out via valid_rows. take + device-dtype cast
     # + pad fuse into ONE pass per column (each was a full copy).
-    import jax
     x64 = jax.config.jax_enable_x64
     target = shape_bucket(n, minimum=row_bucket_min)
 
@@ -994,7 +987,7 @@ def _load_slice(snap, dim: str, lo: int, hi: int, unit, needed_fields,
     try:
         rel = ts - base
         last = int(rel.max())
-        # the slice's newest time, for `tpu_exec._last_ts`
+        # the slice's newest time, for `scan_launch._last_ts`
         scan.device["__ts_max"] = (base + last,)
         if last < 2 ** 31:
             scan.device["__ts"] = jax.device_put(rel.astype(np.int32))
@@ -1010,7 +1003,6 @@ def _load_slice(snap, dim: str, lo: int, hi: int, unit, needed_fields,
             scan.device["__pad_mask"] = jax.device_put(pm)
     except Exception:  # noqa: BLE001 — staging is an optimization; the
         # host arrays still serve the scan
-        from ..common.telemetry import increment_counter
         increment_counter("stream_device_stage_errors")
         scan.device.clear()
     return ("scan", scan, info)
@@ -1020,7 +1012,7 @@ def stream_region_moment_frames(region, table, plan) -> List[pd.DataFrame]:
     """Partial moment frames for one region via slice streaming.
 
     Returns the same frame shape tpu_exec._execute_region produces, so
-    tpu_exec._finalize folds slices exactly like regions.
+    moment_fold._finalize folds slices exactly like regions.
 
     Pipelining: XLA dispatch is asynchronous, so each slice's reduction
     is *launched* and left in flight while the next slice decodes on the
@@ -1034,15 +1026,6 @@ def stream_region_moment_frames(region, table, plan) -> List[pd.DataFrame]:
     and mirrors the same numbers into the active ExecStats collector so
     EXPLAIN ANALYZE, the profile, and the tracing spans agree.
     """
-    import time as _time
-
-    import jax
-
-    from ..common import exec_stats
-    from ..common.telemetry import propagate, span
-    from ..storage.region import ScanProfile
-    from .tpu_exec import _collect_moment_frame, _launch_scan_kernel
-
     prof = ScanProfile(path="streamed")
     _t_start = _time.perf_counter()
     snap = region.snapshot()
@@ -1060,8 +1043,6 @@ def stream_region_moment_frames(region, table, plan) -> List[pd.DataFrame]:
         prof.total_s = _time.perf_counter() - _t_start
         region.last_scan_profile = prof
         return []
-    from .tpu_exec import (RUN_DIFF_MOMENT_OPS, plan_needs_host,
-                           plan_scan_columns)
     needed = plan_scan_columns(plan, schema)
     sd = region.series_dict
 
@@ -1071,7 +1052,6 @@ def stream_region_moment_frames(region, table, plan) -> List[pd.DataFrame]:
     # full predicate set)
     sid_set = None
     if plan.tag_predicates and sd is not None and sd.tag_names:
-        from ..storage.index import sst_index_enabled
         if sst_index_enabled():
             from ..mito.engine import sid_candidates_for_filters
             sid_set = sid_candidates_for_filters(sd, sd.tag_names,
@@ -1100,8 +1080,6 @@ def stream_region_moment_frames(region, table, plan) -> List[pd.DataFrame]:
     depth = 2
     _t_stream = _time.perf_counter()
     load = propagate(_load_slice)
-    from ..common.runtime import transient_executor
-    from ..common import failpoint, process_list
     with span("stream_scan", region=region.name, slices=len(jobs),
               mode=mode), \
             transient_executor(depth, "stream-scan") as pool:
@@ -1141,7 +1119,8 @@ def stream_region_moment_frames(region, table, plan) -> List[pd.DataFrame]:
                         frames.append(payload)
                     continue
                 prof.bump("device_slices")
-                ln = _launch_scan_kernel(payload, schema, plan)
+                ln = scan_full._launch_scan_kernel(payload, schema,
+                                                    plan)
                 if ln is not None:
                     launched.append(ln)
                 del payload, res
@@ -1171,7 +1150,6 @@ def stream_region_moment_frames(region, table, plan) -> List[pd.DataFrame]:
     for ln in launched:
         flat.append(ln.counts)
         flat.extend(ln.results)
-    from ..common.telemetry import increment_counter
     for arr in flat:
         if hasattr(arr, "copy_to_host_async"):
             try:
@@ -1180,7 +1158,6 @@ def stream_region_moment_frames(region, table, plan) -> List[pd.DataFrame]:
                 # the blocking np.asarray below fetches regardless
                 increment_counter("stream_async_fetch_errors")
                 break
-    from ..common.runtime import parallel_map
     flat_np = parallel_map(np.asarray, flat,
                            max_workers=min(8, len(flat)))
     fetched = []
@@ -1190,7 +1167,8 @@ def stream_region_moment_frames(region, table, plan) -> List[pd.DataFrame]:
         fetched.append((flat_np[pos], flat_np[pos + 1:pos + 1 + k]))
         pos += 1 + k
     for ln, (counts, res_np) in zip(launched, fetched):
-        part = _collect_moment_frame(ln, plan, counts, res_np)
+        part = moment_fold._collect_moment_frame(ln, plan, counts,
+                                                 res_np)
         if part is not None and len(part):
             frames.append(part)
     prof.mark("device_fetch", _time.perf_counter() - _t_fetch)
@@ -1204,8 +1182,6 @@ def _publish_stream_stats(prof) -> None:
     """Mirror a streamed region's profile into the ExecStats collector
     (stream_scan row) and prometheus counters, so EXPLAIN ANALYZE,
     /metrics and Region.last_scan_profile tell one story."""
-    from ..common import exec_stats
-    from ..common.telemetry import increment_counter
     exec_stats.record(
         "stream_scan", rows=prof.rows,
         elapsed_s=prof.stages.get("decode_reduce", 0.0),

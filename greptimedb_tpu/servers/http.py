@@ -741,7 +741,7 @@ class HttpServer:
             p = getattr(r, "last_scan_profile", None)
             if p is not None:
                 scan = p.describe()
-        from ..query.tpu_exec import SCAN_CACHE
+        from ..storage import scan_cache
         # a routing frontend hosts no regions and owns no device: it
         # must not initialise a backend (and claim a chip) to answer
         standalone = hasattr(self.frontend, "datanode")
@@ -767,7 +767,8 @@ class HttpServer:
             "uptime_s": round(time.time() - self._start_time, 3),
             "region_count": len(regions),
             "read_cache_hit_ratio": ratio,
-            "scan_cache_resident_bytes": SCAN_CACHE.resident_bytes(),
+            "scan_cache_resident_bytes":
+                scan_cache.SCAN_CACHE.resident_bytes(),
             "last_ingest_profile": ingest,
             "last_scan_profile": scan,
             "background_errors": background_errors,

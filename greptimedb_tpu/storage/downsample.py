@@ -9,7 +9,7 @@ the bucket timestamps. The continuous-flow subsystem (flow/manager.py)
 drives the same reducer incrementally from a per-flow watermark.
 
 TPU-first data flow: the job rides the SAME device-resident merged-scan
-cache the query path uses (`query/tpu_exec.SCAN_CACHE`) — on a region
+cache the query path uses (`storage/scan_cache.py:SCAN_CACHE`) — on a region
 that has been queried (or downsampled) before, the sorted/deduped column
 arrays are already in HBM and the job ships only the run ids; on a cold
 region the cache build it pays is then amortized by every later query.
@@ -24,6 +24,8 @@ import logging
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
+
+from . import scan_cache
 
 logger = logging.getLogger(__name__)
 
@@ -64,7 +66,6 @@ def downsample_region(src, dst, *, stride_ms: int,
     import jax
 
     from ..ops.kernels import shape_bucket, sorted_grouped_aggregate
-    from ..query.tpu_exec import SCAN_CACHE
     from .write_batch import WriteBatch
 
     schema = src.schema
@@ -78,7 +79,7 @@ def downsample_region(src, dst, *, stride_ms: int,
     # merged + MVCC-deduped view, sorted by (series, ts); PUT rows only
     # (tombstones are dropped by the merge). Device mirrors of ts/fields
     # are cached per region version and shared with the query path.
-    scan = SCAN_CACHE.get(src)
+    scan = scan_cache.SCAN_CACHE.get(src)
     n = scan.num_rows
     if n == 0:
         return 0

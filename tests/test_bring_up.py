@@ -25,7 +25,7 @@ from greptimedb_tpu.common import jax_cache
 from greptimedb_tpu.datanode.instance import (
     DatanodeInstance, DatanodeOptions)
 from greptimedb_tpu.frontend.instance import FrontendInstance
-from greptimedb_tpu.query import tpu_exec
+from greptimedb_tpu.query import moment_fold, tpu_exec
 from greptimedb_tpu.session import QueryContext
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -194,8 +194,8 @@ def test_frames_nbytes_sizes_strings_by_value_under_either_dtype():
     as_object = pd.DataFrame({"h": pd.Series(["h4", "host_12"],
                                              dtype=object), "v": [1.0, 2.0]})
     inferred = pd.DataFrame({"h": ["h4", "host_12"], "v": [1.0, 2.0]})
-    assert tpu_exec.frames_nbytes([as_object]) == 9 + 16
-    assert tpu_exec.frames_nbytes([inferred]) == 9 + 16
+    assert moment_fold.frames_nbytes([as_object]) == 9 + 16
+    assert moment_fold.frames_nbytes([inferred]) == 9 + 16
 
 
 def test_native_library_is_named_by_its_source():

@@ -306,7 +306,7 @@ class TestCacheDegradation:
     def test_scan_cache_corruption_falls_back_cold(self, tmp_path):
         """Differential: a poisoned incremental scan-cache refresh must
         rebuild cold and produce the same answer."""
-        from greptimedb_tpu.query.tpu_exec import SCAN_CACHE
+        from greptimedb_tpu.storage.scan_cache import SCAN_CACHE
         rig = TortureRig(str(tmp_path))
         rig.create()
         rows = make_batch(0)
@@ -524,7 +524,7 @@ def test_background_flush_failure_retries_with_backoff(tmp_path):
     eng.close()
 
 
-def test_flush_retry_after_drop_writes_nothing(tmp_path):
+def test_flush_retry_after_drop_writes_nothing(tmp_path, monkeypatch):
     """A delayed background-flush retry firing after DROP must not
     resurrect SSTs under the destroyed region dir (nothing would ever
     collect them — a dropped region never reopens)."""
@@ -535,17 +535,25 @@ def test_flush_retry_after_drop_writes_nothing(tmp_path):
     region = eng.create_region("r", make_schema())
     region.flush_size_bytes = 1
     region_dir = region.descriptor.region_dir
+    # the retry's backoff is held here and let go once the region is
+    # dropped: whether DROP beats a 50 ms timer is the host's clock (under
+    # six workers it did not, and the retry in flight left its index file
+    # behind the delete), not what this test claims
+    delayed = []
+    monkeypatch.setattr(eng.scheduler, "submit_later",
+                        lambda key, fn, delay_s: delayed.append((key, fn)))
     with fp.cfg("flush_commit", "err"):
         wb = WriteBatch(region.schema)
         wb.put({"host": ["a"], "ts": [1000], "v": [1.0]})
         region.write(wb)               # bg flush fails, retry queued
         deadline = time.time() + 10
-        while time.time() < deadline and \
-                not region.bg_errors.get("flush"):
+        while time.time() < deadline and not delayed:
             time.sleep(0.02)
-        assert region.bg_errors.get("flush")
+        assert region.bg_errors.get("flush") and len(delayed) == 1
         eng.drop_region("r")           # destroys the region dir
-    time.sleep(0.5)                    # let any pending retry fire
+    for key, fn in delayed:            # the retry fires, runs and returns
+        eng.scheduler.submit(key, fn).wait(timeout=10)
+    eng.scheduler.wait_idle(timeout=10)
     leaked = [k for k in eng.store.list(region_dir)]
     assert not leaked, f"flush retry resurrected files: {leaked}"
     eng.close()
@@ -684,7 +692,7 @@ class TestSurfaces:
                 table.flush()
             # cold scan (streamed path) with injected read faults
             stream_exec.configure_streaming(threshold_rows=1)
-            from greptimedb_tpu.query.tpu_exec import SCAN_CACHE
+            from greptimedb_tpu.storage.scan_cache import SCAN_CACHE
             SCAN_CACHE._entries.clear()
             with fp.cfg("objstore_read", "1x3*err(transient)"):
                 out = frontend.do_query(

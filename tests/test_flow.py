@@ -430,16 +430,17 @@ class TestReviewRegressions:
         """A source region past the streaming threshold folds through
         the window-bounded host path — same answers, no scan-cache
         residency pinned by the background fold."""
-        from greptimedb_tpu.query import stream_exec, tpu_exec
+        from greptimedb_tpu.query import stream_exec
+        from greptimedb_tpu.storage import scan_cache
         _mk_cpu(fe, 600)
         fe.do_query(FLOW_SQL)
         fm = fe.datanode.flow_manager
         saved = stream_exec.stream_threshold_rows()
         try:
             stream_exec.configure_streaming(threshold_rows=1)
-            tpu_exec.SCAN_CACHE._entries.clear()
+            scan_cache.SCAN_CACHE._entries.clear()
             fm.tick()
-            assert tpu_exec.SCAN_CACHE.resident_bytes() == 0
+            assert scan_cache.SCAN_CACHE.resident_bytes() == 0
             spec = fm.flows()[0]
             assert spec.stats["rows_folded"] == 1200
             # incremental on the cold path too (ts-watermarked: refolds

@@ -1,6 +1,6 @@
 """Rows that arrive late or twice (ISSUE 42): a relay's queue drains behind
 the live ticks, a proxy re-sends a body whose acknowledgement was lost.
-The scan cache (`tpu_exec._ScanCache`) puts a late row into the tail
+The scan cache (`scan_cache._ScanCache`) puts a late row into the tail
 wherever its time lies and drops a re-sent one, at the cost of the delta
 and the tail; a statement after such a write answers as a from-scratch
 reference does, and meets no merge and no compile.
@@ -24,6 +24,7 @@ from greptimedb_tpu.datanode.instance import DatanodeInstance, DatanodeOptions
 from greptimedb_tpu.frontend.instance import FrontendInstance
 from greptimedb_tpu.ops.kernels import _sorted_grouped_aggregate_pre
 from greptimedb_tpu.query import scan_narrow, tpu_exec
+from greptimedb_tpu.storage import scan_cache
 
 TICK_MS, HOUR = 10_000, 3_600_000
 T0 = 472_223 * HOUR                         # a whole hour
@@ -296,7 +297,7 @@ def test_every_family_answers_the_reference_after_each_delta(fleet, seed):
     after each, and only a changed base row merges."""
     rng = np.random.default_rng(seed)
     check(fleet)
-    base = tpu_exec.SCAN_CACHE.get_parts(fleet.region())[0]
+    base = scan_cache.SCAN_CACHE.get_parts(fleet.region())[0]
     live, queue = TICKS, list(GAP)
     steps = ["append", "late", "resend-base", "resend-tail", "mixed",
              "change-tail", "late", "mixed", "append", "resend-tail"]
@@ -334,14 +335,14 @@ def test_every_family_answers_the_reference_after_each_delta(fleet, seed):
             written_late += queue[:n]
             queue = queue[n:]
         check(fleet)
-        now, tail = tpu_exec.SCAN_CACHE.get_parts(fleet.region())
+        now, tail = scan_cache.SCAN_CACHE.get_parts(fleet.region())
         assert now is base and tail is not None, step
         assert metric("scan_cache_merges") == merges, step
     # a row that changes a value the base holds: the one merge
     merges = metric("scan_cache_merges")
     fleet.put([(9, 100), (LATE[3], GAP[0] - 1)], changed=-2.25)
     check(fleet)
-    now, tail = tpu_exec.SCAN_CACHE.get_parts(fleet.region())
+    now, tail = scan_cache.SCAN_CACHE.get_parts(fleet.region())
     assert now is not base and tail is None
     assert metric("scan_cache_merges") == merges + 1
 
@@ -365,11 +366,11 @@ def test_a_late_row_and_a_retry_are_counted_and_said(fleet):
     assert metric("scan_cache_late_rows") == late + 8
     assert metric("scan_cache_overwrites", kind="equal") == equal + 8
     # a retry alone writes nothing: the tail stays the object it was
-    tail = tpu_exec.SCAN_CACHE.get_parts(fleet.region())[1]
+    tail = scan_cache.SCAN_CACHE.get_parts(fleet.region())[1]
     fleet.put([(h, GAP[0]) for h in LATE[:3]])
     apply = fleet.stages(DoubleGroupby1.sql)["scan_prep.apply"]
     assert "late=0" in apply and "equal_dropped=3" in apply, apply
-    assert tpu_exec.SCAN_CACHE.get_parts(fleet.region())[1] is tail
+    assert scan_cache.SCAN_CACHE.get_parts(fleet.region())[1] is tail
     # appended rows leave the tail's span where it was said to reach
     appended(fleet)
     reduce = fleet.stages(LastPoint.sql)["reduce"]
@@ -433,7 +434,7 @@ def test_late_and_resent_rows_meet_no_merge_and_no_compile(tmp_path):
         families = (DoubleGroupby1, LastPoint, CpuMaxAll8, SingleGroupby111)
         for _ in range(2):
             check(f, families)
-        base = tpu_exec.SCAN_CACHE.get_parts(f.region())[0]
+        base = scan_cache.SCAN_CACHE.get_parts(f.region())[0]
         assert base.num_rows >= tpu_exec.TPU_DISPATCH_MIN_ROWS
         assert len(base.tail_programs) == len(families)
         programs, merges = compiled(), metric("scan_cache_merges")
@@ -459,7 +460,7 @@ def test_late_and_resent_rows_meet_no_merge_and_no_compile(tmp_path):
             assert compiled() == programs, f"{what} met a new program"
             assert metric("scan_cache_merges") == merges, what
             assert metric("scan_cache_miss") == misses, what
-        now, tail = tpu_exec.SCAN_CACHE.get_parts(f.region())
+        now, tail = scan_cache.SCAN_CACHE.get_parts(f.region())
         assert now is base
         assert tail.valid_rows == 2 * f.hosts + 9 * len(LATE)
     finally:
@@ -475,13 +476,13 @@ def test_a_changed_overwrite_is_right_whichever_way_it_goes(fleet):
     appended(fleet)
     backlog(fleet, GAP[:4])
     check(fleet)
-    base = tpu_exec.SCAN_CACHE.get_parts(fleet.region())[0]
+    base = scan_cache.SCAN_CACHE.get_parts(fleet.region())[0]
     merges = metric("scan_cache_merges")
     changed = metric("scan_cache_overwrites", kind="changed")
     # of tail rows (a live one, a late one): replaced in the tail
     fleet.put([(2, TICKS + 1), (LATE[0], GAP[1])], changed=7.0)
     check(fleet)
-    assert tpu_exec.SCAN_CACHE.get_parts(fleet.region())[0] is base
+    assert scan_cache.SCAN_CACHE.get_parts(fleet.region())[0] is base
     assert metric("scan_cache_merges") == merges
     assert metric("scan_cache_overwrites", kind="changed") == changed + 2
     # of a base row, in a body that also carries late and re-sent rows
@@ -490,7 +491,7 @@ def test_a_changed_overwrite_is_right_whichever_way_it_goes(fleet):
     fleet.put([(LATE[0], GAP[1])], changed=7.0)         # now a retry
     apply = fleet.stages(LastPoint.sql)["scan_prep.apply"]
     assert "changed=1" in apply and "merged=1" in apply, apply
-    now, tail = tpu_exec.SCAN_CACHE.get_parts(fleet.region())
+    now, tail = scan_cache.SCAN_CACHE.get_parts(fleet.region())
     assert now is not base and tail is None
     assert metric("scan_cache_merges") == merges + 1
     check(fleet)
@@ -505,7 +506,7 @@ def test_a_delete_still_merges_and_is_right(fleet):
     backlog(fleet, GAP[5:7])
     check(fleet)
     assert metric("scan_cache_merges") > merges
-    assert tpu_exec.SCAN_CACHE.get_parts(fleet.region())[1] is None
+    assert scan_cache.SCAN_CACHE.get_parts(fleet.region())[1] is None
     # and the deleted late row written again is a row again
     fleet.put([(LATE[0], GAP[2])])
     check(fleet)
@@ -535,7 +536,7 @@ def test_late_rows_met_in_a_flushed_sst(fleet):
     (`_ScanCache._delta`'s second branch)."""
     fleet.flush()               # the load leaves the memtables
     check(fleet)
-    base = tpu_exec.SCAN_CACHE.get_parts(fleet.region())[0]
+    base = scan_cache.SCAN_CACHE.get_parts(fleet.region())[0]
     merges = metric("scan_cache_merges")
     appended(fleet)
     backlog(fleet, GAP[:7])
@@ -549,7 +550,7 @@ def test_late_rows_met_in_a_flushed_sst(fleet):
     assert "late=56" in stages["scan_prep.apply"], stages["scan_prep.apply"]
     assert "equal_dropped=8" in stages["scan_prep.apply"]
     check(fleet)
-    assert tpu_exec.SCAN_CACHE.get_parts(fleet.region())[0] is base
+    assert scan_cache.SCAN_CACHE.get_parts(fleet.region())[0] is base
     assert metric("scan_cache_merges") == merges
     # and across a second flush, met half in a file and half in a memtable
     backlog(fleet, GAP[7:9])

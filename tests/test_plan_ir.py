@@ -29,7 +29,7 @@ from greptimedb_tpu.errors import UnsupportedError
 from greptimedb_tpu.frontend import FrontendInstance
 from greptimedb_tpu.frontend.distributed import DistInstance, DistTable
 from greptimedb_tpu.meta import MemKv, MetaClient, MetaSrv, Peer
-from greptimedb_tpu.query import tpu_exec
+from greptimedb_tpu.query import agg_plan, tpu_exec
 from greptimedb_tpu.session import QueryContext
 
 # ---------------------------------------------------------------------------
@@ -189,7 +189,7 @@ class TestDistVsStandalone:
             q = "sum by (host) (rate(ctr[1m]))"
             _assert_close(_vec(fe, q), _vec(dist, q), rtol=2e-5)
         finally:
-            tpu_exec._PARTIAL_PUSHDOWN[0] = True
+            agg_plan._PARTIAL_PUSHDOWN[0] = True
             for dn in datanodes.values():
                 dn.shutdown()
 
@@ -397,11 +397,11 @@ class TestFlowIrFolds:
             fe.do_query("INSERT INTO ctr VALUES " + more)
             dist.do_query("INSERT INTO ctr VALUES " + more)
             fe.datanode.flow_manager.tick()
-            tpu_exec._PARTIAL_PUSHDOWN[0] = False
+            agg_plan._PARTIAL_PUSHDOWN[0] = False
             try:
                 flowering.fold_generic(spec, src, dst)
             finally:
-                tpu_exec._PARTIAL_PUSHDOWN[0] = True
+                agg_plan._PARTIAL_PUSHDOWN[0] = True
             a, b = _sink_frame(fe), _sink_frame(dist)
             for col in ("v_avg", "v_sum", "n"):
                 assert np.allclose(a[col].to_numpy(dtype=float),
@@ -471,3 +471,61 @@ class TestPromqlExplain:
         joined = "\n".join(lines)
         assert "PromSeriesScan: ctr" in joined
         assert "TpuAggregateExec:" in joined
+
+
+# ---------------------------------------------------------------------------
+# the resident read path's modules import one way (ISSUE 47)
+# ---------------------------------------------------------------------------
+
+#: bottom up: a module imports only modules above it in this list
+#: (`query/tpu_exec.py`'s docstring has the map)
+READ_PATH = ["storage.scan_cache", "query.agg_plan", "query.scan_launch",
+             "query.scan_narrow", "query.scan_full", "query.moment_fold",
+             "query.tpu_exec"]
+
+
+def _package_imports(module: str):
+    """-> [(imported module, inside a function body)] of one module of
+    the package, `greptimedb_tpu.` cut off, relative imports resolved;
+    `from . import a` names the module `a`."""
+    import ast
+    import os
+
+    import greptimedb_tpu
+    path = os.path.join(os.path.dirname(greptimedb_tpu.__file__),
+                        *module.split(".")) + ".py"
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    nested = {id(n) for fn in ast.walk(tree)
+              if isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef))
+              for n in ast.walk(fn)}
+    here = ("greptimedb_tpu." + module).split(".")[:-1]
+    out = []
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            names = [a.name for a in node.names]
+        elif isinstance(node, ast.ImportFrom):
+            base = ".".join(here[:len(here) - node.level + 1]
+                            if node.level else [])
+            base = ".".join(x for x in (base, node.module) if x)
+            names = [base] + [f"{base}.{a.name}" for a in node.names]
+        else:
+            continue
+        out += [(n[len("greptimedb_tpu."):], id(node) in nested)
+                for n in names if n.startswith("greptimedb_tpu.")]
+    return out
+
+
+def test_resident_read_path_imports_one_way():
+    """Plan, cache, launch and fold under the region executor, each
+    importing only what lies below it: the scan cache nothing of
+    `query/`, no module `tpu_exec` (so `scan_narrow` no longer reaches
+    back into its caller), `scan_narrow` not `scan_full`, and none of
+    them another inside a function body (where a cycle would hide)."""
+    for i, module in enumerate(READ_PATH):
+        for imported, in_function in _package_imports(module):
+            if module == "storage.scan_cache":
+                assert not imported.startswith("query"), imported
+            if imported in READ_PATH:
+                assert READ_PATH.index(imported) < i, (module, imported)
+                assert not in_function, (module, imported)

@@ -309,7 +309,7 @@ def test_candidate_runs_select_the_rows_the_mask_selects(fleet):
     matcher resolved (the scan cache's rows are sorted by series): the
     same rows as the mask over every row of the table."""
     from greptimedb_tpu.promql import lowering
-    from greptimedb_tpu.query.tpu_exec import SCAN_CACHE
+    from greptimedb_tpu.storage.scan_cache import SCAN_CACHE
     table = fleet.fe.catalog.table("greptime", "public",
                                    "node_cpu_seconds_total")
     (region,) = table.regions.values()

@@ -36,7 +36,8 @@ from greptimedb_tpu.datanode.instance import (  # noqa: E402
 from greptimedb_tpu.datatypes.record_batch import (  # noqa: E402
     arrow_to_ingest_columns)
 from greptimedb_tpu.frontend.instance import FrontendInstance  # noqa: E402
-from greptimedb_tpu.query import tpu_exec  # noqa: E402
+from greptimedb_tpu.query import agg_plan, moment_fold, tpu_exec  # noqa: E402
+from greptimedb_tpu.storage import scan_cache  # noqa: E402
 
 SEED = 2147483659
 CONFIG = load_json(BENCH, "configs", "prom-node-1k-2h.json")
@@ -198,7 +199,7 @@ def test_the_full_launch_takes_the_live_runs_where_ranges_say_which(fleet,
     stages = fleet.stages(fam.sql(params, fleet.ds))
     detail = stages["reduce"][2]
     # a panel that ends on its step finds its grid laid out over the
-    # table: never the selection's layout (`tpu_exec._selection_layout`)
+    # table: never the selection's layout (`scan_full._selection_layout`)
     assert metric("scan_selection_layouts") == laid
     assert "runs=selection" not in detail
     bumped = {axis: metric("scan_group_axis", axis=axis) - before[axis]
@@ -217,7 +218,7 @@ def test_the_full_launch_takes_the_live_runs_where_ranges_say_which(fleet,
         # the launch still reads every row of the table on the device
         table = fleet.fe.catalog.table("greptime", "public",
                                        "node_cpu_seconds_total")
-        scan = tpu_exec.SCAN_CACHE.get(next(iter(table.regions.values())))
+        scan = scan_cache.SCAN_CACHE.get(next(iter(table.regions.values())))
         assert metric("scan_device_rows") - rows_before == scan.num_rows
     else:
         assert found is None
@@ -227,10 +228,10 @@ def test_the_full_launch_takes_the_live_runs_where_ranges_say_which(fleet,
 
 
 def test_no_moment_op_is_left_to_the_host_alone():
-    assert not hasattr(tpu_exec, "HOST_ONLY_MOMENT_OPS")
-    plan = tpu_exec.TpuPlan([], None, [tpu_exec.Moment(
+    assert not hasattr(agg_plan, "HOST_ONLY_MOMENT_OPS")
+    plan = agg_plan.TpuPlan([], None, [agg_plan.Moment(
         "increase", "greptime_value", "__m0")], [], None, None, [], [])
-    assert not tpu_exec.plan_needs_host(plan)
+    assert not agg_plan.plan_needs_host(plan)
 
 
 # ---------------------------------------------------------------------------
@@ -562,13 +563,13 @@ def test_a_narrowed_launch_counts_the_rows_of_its_ranges(fleet):
 def test_run_diffs_are_made_in_float64_and_only_when_asked_for(counters):
     table = counters.catalog.table("greptime", "public", "c")
     region = next(iter(table.regions.values()))
-    scan = tpu_exec.SCAN_CACHE.get(region)
+    scan = scan_cache.SCAN_CACHE.get(region)
     tql(counters, 'max by (name) (max_over_time(c[1m]))')
     built = {k for k in scan.device if k[:2] in ("c:", "g:")}
     tql(counters, 'sum by (name) (rate(c{name="bytes_2_6e14_reset"}[1m]))')
-    assert {k for k in tpu_exec.SCAN_CACHE.get(region).device
+    assert {k for k in scan_cache.SCAN_CACHE.get(region).device
             if k[:2] in ("c:", "g:")} - built == {"c:greptime_value"}
-    scan = tpu_exec.SCAN_CACHE.get(region)
+    scan = scan_cache.SCAN_CACHE.get(region)
     d = np.asarray(scan.device_run_diffs("greptime_value", True))
     g = np.asarray(scan.device_run_diffs("greptime_value", False))
     assert d.dtype == np.float32
@@ -586,13 +587,13 @@ def test_growth_folds_across_partials_of_one_window():
     """Time-disjoint partials of one (series, window): their growths add,
     plus the difference across the boundary, reset-aware for a counter."""
     import pandas as pd
-    plan = tpu_exec.TpuPlan(
-        [tpu_exec.TagGroup("name", 0)], None,
-        [tpu_exec.Moment("first", "v", "f"), tpu_exec.Moment("last", "v", "l"),
-         tpu_exec.Moment("min_ts", "v", "t0"),
-         tpu_exec.Moment("max_ts", "v", "t1"),
-         tpu_exec.Moment("increase", "v", "inc"),
-         tpu_exec.Moment("delta", "v", "dlt")],
+    plan = agg_plan.TpuPlan(
+        [agg_plan.TagGroup("name", 0)], None,
+        [agg_plan.Moment("first", "v", "f"), agg_plan.Moment("last", "v", "l"),
+         agg_plan.Moment("min_ts", "v", "t0"),
+         agg_plan.Moment("max_ts", "v", "t1"),
+         agg_plan.Moment("increase", "v", "inc"),
+         agg_plan.Moment("delta", "v", "dlt")],
         [("inc", "moment", ["inc"]), ("dlt", "moment", ["dlt"])],
         None, None, [], [])
     from greptimedb_tpu.query.planner import _group_slot
@@ -605,7 +606,7 @@ def test_growth_folds_across_partials_of_one_window():
         "t0": [40, 40, 0, 0], "t1": [70, 70, 30, 30],
         "inc": [15.0, 7.0, 20.0, 20.0], "dlt": [15.0, 7.0, 20.0, 20.0],
         "__rowcount": [4, 4, 4, 4]})
-    out = tpu_exec._finalize(df, plan).set_index(key)
+    out = moment_fold._finalize(df, plan).set_index(key)
     assert out.loc["a", "inc"] == 40.0 and out.loc["a", "dlt"] == 40.0
     assert out.loc["b", "inc"] == 29.0 and out.loc["b", "dlt"] == -1.0
 
@@ -942,7 +943,7 @@ def test_partial_frames_are_sized_as_the_row_by_row_count(labels):
     assert isinstance(df["tag"].dtype, pd.StringDtype)
     by_row = sum(len(v) if isinstance(v, str) else 8 for v in labels) \
         + 4 * len(labels) + 3 * len(labels)
-    assert tpu_exec.frames_nbytes([df, df]) == 2 * by_row
+    assert moment_fold.frames_nbytes([df, df]) == 2 * by_row
 
 
 # ---------------------------------------------------------------------------
@@ -957,30 +958,32 @@ def test_partial_frames_are_sized_as_the_row_by_row_count(labels):
 ])
 def test_the_row_mask_from_ranges_is_the_mask_over_every_row(fleet, matchers,
                                                              lo_s, hi_s):
-    from greptimedb_tpu.query import scan_narrow
+    from greptimedb_tpu.query import (agg_plan, scan_full, scan_launch,
+                                      scan_narrow)
+    from greptimedb_tpu.storage import scan_cache
     from greptimedb_tpu.sql.ast import BinaryOp, Column, Literal
     warm_cpu_table(fleet)
     table = fleet.fe.catalog.table("greptime", "public",
                                    "node_cpu_seconds_total")
-    scan = tpu_exec.SCAN_CACHE.get(next(iter(table.regions.values())))
+    scan = scan_cache.SCAN_CACHE.get(next(iter(table.regions.values())))
     preds = []
     for m in matchers.split(", "):
         name, op, value = re.match(r'(\w+)(!?=)"(.*)"', m).groups()
         preds.append(BinaryOp(op, Column(name), Literal(value)))
     t0 = fleet.ds.t0_ms
-    plan = tpu_exec.TpuPlan([], None, [], [], t0 + lo_s * 1000,
+    plan = agg_plan.TpuPlan([], None, [], [], t0 + lo_s * 1000,
                             t0 + hi_s * 1000, preds, [])
     sel = scan_narrow.select(scan, table.schema, plan)
     assert sel is not None
-    by_rows = tpu_exec._scan_row_mask(scan, table.schema, plan)
-    by_ranges = tpu_exec._scan_row_mask(scan, table.schema, plan, sel)
-    if by_rows is tpu_exec._NO_ROWS:
-        assert by_ranges is tpu_exec._NO_ROWS
+    by_rows = scan_full._scan_row_mask(scan, table.schema, plan)
+    by_ranges = scan_full._scan_row_mask(scan, table.schema, plan, sel)
+    if by_rows is scan_full._NO_ROWS:
+        assert by_ranges is scan_full._NO_ROWS
         return
     # the rows a launch keeps: the host's mask under the program's window.
     # The ranges hold the window already; the mask over every row leaves
     # it to the program
-    lo, hi = tpu_exec._device_window(plan, scan)
+    lo, hi = scan_launch._device_window(plan, scan)
     rel = scan.ts - scan.ts_base
     window = (rel >= lo) & (rel <= hi)
     assert by_ranges.sum() > 0 and not (by_ranges & ~window).any()

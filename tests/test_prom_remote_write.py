@@ -8,7 +8,7 @@ reference after every block. No PromQL reader merges a written table: the
 row path cuts its selection from the scan cache's base and tail
 (`promql/lowering.py:_matrix_from_runs`), a lowered window's growth is two
 launches and a seam made in float64 on the host
-(`MergedScan.device_run_diffs`, `tpu_exec._fold_runs`). Also: a counter
+(`MergedScan.device_run_diffs`, `moment_fold._fold_runs`). Also: a counter
 at 2.6e14 and a reset exactly at the seam, a series that exists only in
 the tail, a late row under a growth plan (the counted fallback), the
 narrowed launch over a tail, the new span rows, timers and counters, and
@@ -41,7 +41,8 @@ from greptimedb_tpu.datanode.instance import (  # noqa: E402
 from greptimedb_tpu.datatypes.record_batch import (  # noqa: E402
     arrow_to_ingest_columns)
 from greptimedb_tpu.frontend.instance import FrontendInstance  # noqa: E402
-from greptimedb_tpu.query import tpu_exec  # noqa: E402
+from greptimedb_tpu.query import scan_full, scan_launch  # noqa: E402
+from greptimedb_tpu.storage import scan_cache  # noqa: E402
 from greptimedb_tpu.servers import prometheus as prom  # noqa: E402
 from greptimedb_tpu.servers.http import HttpServer  # noqa: E402
 
@@ -207,7 +208,7 @@ def test_both_tables_of_the_matching_family_hold_a_tail(written):
     fleet, _results, _moved = written
     for table in (promlive.promfam.MEM_AVAILABLE,
                   promlive.promfam.MEM_TOTAL):
-        base, tail = tpu_exec.SCAN_CACHE.get_parts(fleet.region(table))
+        base, tail = scan_cache.SCAN_CACHE.get_parts(fleet.region(table))
         assert tail is not None and tail.valid_rows > 0
         ds = fleet.ds       # the base is the load: nothing was merged
         assert base.num_rows == int((np.minimum(ds.last, ds.ticks)
@@ -331,7 +332,7 @@ def test_a_panel_off_the_minute_lays_out_its_selection_not_the_table(
     its run ids made on the device. The table's layout of the same grid
     gives the same numbers."""
     fleet, _results, _moved = written
-    base, _tail = tpu_exec.SCAN_CACHE.get_parts(
+    base, _tail = scan_cache.SCAN_CACHE.get_parts(
         fleet.region(promlive.promfam.CPU))
     assert len([k for k in base.device if k.startswith("__runs:")]) == 1
     assert "__sids" in base.device
@@ -344,7 +345,7 @@ def test_a_panel_off_the_minute_lays_out_its_selection_not_the_table(
     assert "runs=selection" in detail and "groups=live" in detail
     assert metric("scan_selection_layouts") == laid + 1
     got = fleet.query(sql)
-    monkeypatch.setattr(tpu_exec, "_selection_layout",
+    monkeypatch.setattr(scan_full, "_selection_layout",
                         lambda *a: None)
     assert "runs=selection" not in fleet.stages(sql)["reduce"][2]
     assert fleet.query(sql) == got
@@ -365,7 +366,7 @@ def test_a_program_without_the_seam_is_not_correct_at_the_frontier(
                   **fam.frontier(fleet.frontier_s(), fleet.ds))
     assert params["end_s"] % 60
     assert fleet.judge(fam, params)["ok"]
-    monkeypatch.setattr(tpu_exec, "_seam", lambda *a: None)
+    monkeypatch.setattr(scan_cache, "_seam", lambda *a: None)
     fleet.post_next()           # a new tail: its mirror is made anew
     got = fam.parse(fleet.query(fam.sql(params, fleet.ds)), fleet.ds)
     res = chk.compare(got, fam.reference(params, fleet.ds), fam.tolerance)
@@ -533,7 +534,7 @@ def test_a_counter_keeps_its_growth_across_the_seam(counters):
         assert max(got["bytes_2_6e14"]) >= 900_000
     assert metric("scan_cache_merges") == merges
     assert metric("scan_seam_pairs") - seams >= len(COUNTERS)
-    base, tail = tpu_exec.SCAN_CACHE.get_parts(
+    base, tail = scan_cache.SCAN_CACHE.get_parts(
         next(iter(fe.catalog.table("greptime", "public",
                                    "c").regions.values())))
     assert base.num_rows == HISTORY * (len(COUNTERS) + 1) - len(HOLE)
@@ -569,7 +570,7 @@ def test_the_seam_is_made_in_float64():
     """`_seam` on arrays: the first difference of a tail's series is
     `v - prev` against the base's last sample (`v` itself after a
     reset), 0 for a series the base has never seen."""
-    base = tpu_exec.MergedScan(
+    base = scan_cache.MergedScan(
         np.array([0, 0, 2, 2], np.int32), np.array([0, 10, 0, 10]),
         {"v": (np.array([2.6e14, 2.6e14 + 988.0, 5.0, 7.0]), None)},
         None, 0)
@@ -577,11 +578,11 @@ def test_the_seam_is_made_in_float64():
     v = np.array([2.6e14 + 1976.0, 2.6e14 + 2964.0, 3.0, 1.0])
     d = np.array([0.0, 988.0, 0.0, 0.0])
     pairs = metric("scan_seam_pairs")
-    tpu_exec._seam(base, "v", True, sids, v, d)
+    scan_cache._seam(base, "v", True, sids, v, d)
     assert d.tolist() == [988.0, 988.0, 0.0, 1.0]
     assert metric("scan_seam_pairs") == pairs + 2
     d = np.zeros(4)
-    tpu_exec._seam(base, "v", False, sids, v, d)
+    scan_cache._seam(base, "v", False, sids, v, d)
     assert d.tolist() == [988.0, 0.0, 0.0, -6.0]
 
 
@@ -589,8 +590,8 @@ def test_growth_folds_across_the_seam_by_run():
     """`_fold_runs`: a run both partials hold is the base's growth, the
     tail's, and the tail's first difference; a run of the tail alone
     keeps its growth."""
-    from greptimedb_tpu.query.tpu_exec import (BucketGroup, Moment, TpuPlan,
-                                                _fold_runs, _RunPartial)
+    from greptimedb_tpu.query.agg_plan import BucketGroup, Moment, TpuPlan
+    from greptimedb_tpu.query.moment_fold import _fold_runs, _RunPartial
     plan = TpuPlan.__new__(TpuPlan)
     plan.tag_groups = [object()]
     plan.bucket = BucketGroup(60_000, 0, "w")
@@ -619,11 +620,11 @@ def test_a_tails_group_axis_follows_its_base():
     axis is an eighth of its base's (a tail holds up to an eighth of its
     base's rows), whatever it holds today, and the axis kind is the
     base's."""
-    shape = tpu_exec._LaunchShape
-    assert tpu_exec._tail_groups(shape("full", 8192, "live", 1 << 20)) \
+    shape = scan_launch._LaunchShape
+    assert scan_launch._tail_groups(shape("full", 8192, "live", 1 << 20)) \
         == 1 << 17
-    assert tpu_exec._tail_groups(shape("narrow", 8, None, 0)) == 0
-    assert tpu_exec._tail_groups(None) == 0
+    assert scan_launch._tail_groups(shape("narrow", 8, None, 0)) == 0
+    assert scan_launch._tail_groups(None) == 0
 
 
 def test_the_live_reference_is_the_shared_grids_at_every_offset():

@@ -513,6 +513,7 @@ class TestStreamedColdSelect:
 
     def test_streamed_matches_resident(self, fe):
         from greptimedb_tpu.query import stream_exec, tpu_exec
+        from greptimedb_tpu.storage import scan_cache
         _mk_cpu(fe)
         table = fe.catalog.table("greptime", "public", "cpu")
         region = next(iter(table.regions.values()))
@@ -525,26 +526,27 @@ class TestStreamedColdSelect:
             inst_res = _q(fe, "cpu", 100_000, 100_000, 1000, instant=True)
             # force the cold path and evict any residency
             stream_exec.configure_streaming(threshold_rows=1)
-            tpu_exec.SCAN_CACHE._entries.clear()
+            scan_cache.SCAN_CACHE._entries.clear()
             assert tpu_exec.region_streams_cold(region)
             streamed = _q(fe, "rate(cpu[1m])", 300_000, 480_000, 60_000)
             inst_str = _q(fe, "cpu", 100_000, 100_000, 1000, instant=True)
             assert streamed == resident
             assert inst_str == inst_res
             # the cold read must not have populated the scan cache
-            assert tpu_exec.SCAN_CACHE.resident_bytes() == 0
+            assert scan_cache.SCAN_CACHE.resident_bytes() == 0
         finally:
             stream_exec.configure_streaming(threshold_rows=saved)
 
     def test_streamed_reads_only_window(self, fe):
-        from greptimedb_tpu.query import stream_exec, tpu_exec
+        from greptimedb_tpu.query import stream_exec
+        from greptimedb_tpu.storage import scan_cache
         from greptimedb_tpu.session import QueryContext
         from greptimedb_tpu.promql.parser import parse_promql
         _mk_cpu(fe)                      # 60 samples / host, 10s apart
         saved = stream_exec.stream_threshold_rows()
         try:
             stream_exec.configure_streaming(threshold_rows=1)
-            tpu_exec.SCAN_CACHE._entries.clear()
+            scan_cache.SCAN_CACHE._entries.clear()
             eng = fe.promql_engine()
             sel = parse_promql("cpu[1m]")
             selection = eng.select(sel, 100_000, 160_000, QueryContext())

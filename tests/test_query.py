@@ -16,7 +16,8 @@ from greptimedb_tpu.datatypes.schema import ColumnSchema, Schema, SemanticType
 from greptimedb_tpu.errors import TableNotFoundError, UnsupportedError
 from greptimedb_tpu.mito import MitoEngine
 from greptimedb_tpu.query import QueryEngine
-from greptimedb_tpu.query import tpu_exec
+from greptimedb_tpu.query import agg_plan, tpu_exec
+from greptimedb_tpu.storage import scan_cache
 from greptimedb_tpu.session import QueryContext
 from greptimedb_tpu.sql import parse_sql
 from greptimedb_tpu.storage.engine import EngineConfig, StorageEngine
@@ -231,7 +232,7 @@ class TestTpuPath:
         engine, table, _ = world
         a = __import__("greptimedb_tpu.query.planner",
                        fromlist=["analyze"]).analyze(parse_sql(sql))
-        plan = tpu_exec.plan_for(table, a, parse_sql(sql))
+        plan = agg_plan.plan_for(table, a, parse_sql(sql))
         assert plan is not None, f"expected TPU plan for: {sql}"
         got = run(engine, sql)
         want = self._oracle(engine, sql, monkeypatch)
@@ -267,7 +268,7 @@ class TestTpuPath:
             stmt = parse_sql(sql)
             a = __import__("greptimedb_tpu.query.planner",
                            fromlist=["analyze"]).analyze(stmt)
-            assert tpu_exec.plan_for(table, a, stmt) is None, sql
+            assert agg_plan.plan_for(table, a, stmt) is None, sql
 
     def test_plan_accepts_expression_args(self, world):
         """ISSUE 14: arithmetic agg arguments plan as virtual expression
@@ -280,7 +281,7 @@ class TestTpuPath:
             stmt = parse_sql(sql)
             a = __import__("greptimedb_tpu.query.planner",
                            fromlist=["analyze"]).analyze(stmt)
-            plan = tpu_exec.plan_for(table, a, stmt)
+            plan = agg_plan.plan_for(table, a, stmt)
             assert plan is not None and plan.field_exprs, sql
 
 
@@ -466,13 +467,13 @@ class TestIncrementalScanCache:
         t.insert({"host": ["a", "c"], "ts": [3, 4], "cpu": [3.0, 4.0]})
         got = run(engine, "SELECT host, sum(cpu) AS s FROM inc "
                           "GROUP BY host").batches[0].to_pylist()
-        cache = tpu_exec.SCAN_CACHE
-        tpu_exec.SCAN_CACHE = tpu_exec._ScanCache()   # force full rebuild
+        cache = scan_cache.SCAN_CACHE
+        scan_cache.SCAN_CACHE = scan_cache._ScanCache()   # force full rebuild
         try:
             want = run(engine, "SELECT host, sum(cpu) AS s FROM inc "
                                "GROUP BY host").batches[0].to_pylist()
         finally:
-            tpu_exec.SCAN_CACHE = cache
+            scan_cache.SCAN_CACHE = cache
         key = lambda r: r["host"]
         assert sorted(got, key=key) == sorted(want, key=key)
         storage.close()
@@ -533,7 +534,7 @@ def test_incremental_cache_randomized_oracle(tmp_path):
     ])
     storage = StorageEngine(EngineConfig(data_home=str(tmp_path)))
     r = storage.create_region("rnd", schema)
-    cache = tpu_exec._ScanCache()
+    cache = scan_cache._ScanCache()
     for round_ in range(12):
         n = int(rng.integers(1, 60))
         hosts = [f"h{int(h)}" for h in rng.integers(0, 5, n)]
@@ -551,7 +552,7 @@ def test_incremental_cache_randomized_oracle(tmp_path):
         if rng.random() < 0.4:
             r.flush()
         got = cache.get(r)                        # incremental path
-        want = tpu_exec._ScanCache().get(r)       # fresh full rebuild
+        want = scan_cache._ScanCache().get(r)       # fresh full rebuild
         assert got.num_rows == want.num_rows, f"round {round_}"
         assert np.array_equal(got.series_ids, want.series_ids)
         assert np.array_equal(got.ts, want.ts)

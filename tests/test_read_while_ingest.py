@@ -1,5 +1,5 @@
 """A dashboard that refreshes while the fleet writes (ISSUE 37): the scan
-cache is refreshed by what was written (`tpu_exec._ScanCache`: an immutable
+cache is refreshed by what was written (`scan_cache._ScanCache`: an immutable
 base with its mirrors and programs, and a tail of the rows written since),
 and a statement after a write answers as a from-scratch reference does.
 
@@ -36,7 +36,9 @@ from greptimedb_tpu.datanode.instance import (  # noqa: E402
 from greptimedb_tpu.frontend.instance import FrontendInstance  # noqa: E402
 from greptimedb_tpu.ops.kernels import (  # noqa: E402
     _sorted_grouped_aggregate_pre)
-from greptimedb_tpu.query import scan_narrow, tpu_exec  # noqa: E402
+from greptimedb_tpu.query import (  # noqa: E402
+    moment_fold, scan_narrow, tpu_exec)
+from greptimedb_tpu.storage import scan_cache  # noqa: E402
 
 HOSTS, TICKS, TICK_MS = 12, 480, 10_000
 T0 = 1_700_000_040_000 + 3_000              # 3 s past a whole minute
@@ -296,7 +298,7 @@ def warm(db: Db):
     """What a server's warm statements do: the scan caches of both
     tables built, every statement kind sent once, before any write."""
     check_all(db, TICKS)
-    assert tpu_exec.SCAN_CACHE.get_parts(db.region())[1] is None
+    assert scan_cache.SCAN_CACHE.get_parts(db.region())[1] is None
 
 
 # ---------------------------------------------------------------------------
@@ -336,7 +338,7 @@ def through_an_sst(db):
 
 
 def past_capacity(db):
-    ticks = tpu_exec.tail_capacity(HOSTS * TICKS) // HOSTS + 8
+    ticks = scan_cache.tail_capacity(HOSTS * TICKS) // HOSTS + 8
     for at in range(TICKS, TICKS + ticks, 100):
         db.put([(h, k) for k in range(at, min(at + 100, TICKS + ticks))
                 for h in range(HOSTS)])
@@ -396,14 +398,14 @@ CASES = [
 @pytest.mark.parametrize("case,how", CASES, ids=[c.__name__ for c, _ in CASES])
 def test_a_statement_after_a_write_answers_the_reference(db, case, how):
     warm(db)
-    base = tpu_exec.SCAN_CACHE.get_parts(db.region())[0]
+    base = scan_cache.SCAN_CACHE.get_parts(db.region())[0]
     merges, misses = metric("scan_cache_merges"), metric("scan_cache_miss")
     end_tick = case(db)
     check_sql(db, FULL)
-    now_base, tail = tpu_exec.SCAN_CACHE.get_parts(db.region())
+    now_base, tail = scan_cache.SCAN_CACHE.get_parts(db.region())
     if how == "tail":
         assert now_base is base and tail is not None and tail.pinned
-        assert tail.num_rows == tpu_exec.tail_capacity(base.num_rows)
+        assert tail.num_rows == scan_cache.tail_capacity(base.num_rows)
         assert metric("scan_cache_merges") == merges
     elif how == "merge":
         assert now_base is not base and tail is None
@@ -487,7 +489,7 @@ def test_closed_history_is_not_refreshed_until_a_row_lands_in_it(db):
     assert "cache=hit" in db.stages(closed)["scan_prep"][1]
     check_closed()
     assert metric("scan_cache_incremental") == refreshes
-    assert tpu_exec.SCAN_CACHE.get_parts(db.region())[1].valid_rows == 48
+    assert scan_cache.SCAN_CACHE.get_parts(db.region())[1].valid_rows == 48
     older_rows(db)              # ticks 4..9 of h05: inside the range
     assert "cache=incremental" in db.stages(closed)["scan_prep"][1]
     check_closed()
@@ -495,7 +497,7 @@ def test_closed_history_is_not_refreshed_until_a_row_lands_in_it(db):
 
 
 def _partial(keys, moments, rowcount):
-    return tpu_exec._RunPartial(
+    return moment_fold._RunPartial(
         np.array([k[0] for k in keys], dtype=np.int32),
         np.array([k[1] for k in keys], dtype=np.int64),
         [np.array(m) for m in moments], np.array(rowcount), None)
@@ -505,7 +507,7 @@ def test_fold_runs_is_finalizes_fold_of_two_partials():
     """Runs (series, bucket) that base and tail both hold fold as
     `_finalize` folds two rows of one group; the others pass through."""
     from greptimedb_tpu.query.ir import plan_from_specs
-    from greptimedb_tpu.query.tpu_exec import BucketGroup
+    from greptimedb_tpu.query.agg_plan import BucketGroup
     from greptimedb_tpu.datatypes import ColumnSchema, Schema, SemanticType
     from greptimedb_tpu.datatypes import data_type as dt
     schema = Schema([
@@ -538,7 +540,7 @@ def test_fold_runs_is_finalizes_fold_of_two_partials():
         sum=[4.0, 9.0, 3.0], count=[1, 1, 1], min=[4.0, 9.0, 3.0],
         max=[4.0, 9.0, 3.0], first=[4.0, 9.0, 3.0], min_ts=[400, 410, 420],
         last=[4.0, 9.0, 3.0], max_ts=[400, 410, 420])
-    out = tpu_exec._fold_runs(base, tail, plan)
+    out = moment_fold._fold_runs(base, tail, plan)
     assert list(zip(out.sids.tolist(), out.buckets.tolist())) == \
         [(0, 5), (0, 6), (2, 6), (1, 6)]
     got = {op: m.tolist() for (op, _), m in zip(ops, out.moments)}
@@ -556,10 +558,10 @@ def test_fold_runs_is_finalizes_fold_of_two_partials():
     base.series_dict = tail.series_dict = out.series_dict = \
         types.SimpleNamespace(tag_id_column=lambda sids, i: (
             sids, ["a", "b", "c"]))
-    folded = tpu_exec._finalize(tpu_exec._partial_frame(out, plan), plan)
-    both = tpu_exec._finalize(pd.concat(
-        [tpu_exec._partial_frame(base, plan),
-         tpu_exec._partial_frame(tail, plan)], ignore_index=True), plan)
+    folded = moment_fold._finalize(moment_fold._partial_frame(out, plan), plan)
+    both = moment_fold._finalize(pd.concat(
+        [moment_fold._partial_frame(base, plan),
+         moment_fold._partial_frame(tail, plan)], ignore_index=True), plan)
     key = list(folded.columns[:2])
     pd.testing.assert_frame_equal(
         folded.sort_values(key).reset_index(drop=True),
@@ -573,14 +575,14 @@ def test_fold_runs_is_finalizes_fold_of_two_partials():
 def test_a_refresh_leaves_the_base_and_uploads_the_tail_alone(db):
     warm(db)
     region = db.region()
-    base = tpu_exec.SCAN_CACHE.get_parts(region)[0]
+    base = scan_cache.SCAN_CACHE.get_parts(region)[0]
     mirrors = {k: id(v) for k, v in base.device.items()
                if k.startswith(("f:", "v:", "__ts", "__all_valid"))}
     arrays = (id(base.series_ids), id(base.ts),
               {n: id(v) for n, (v, _) in base.fields.items()})
     launched = set(base.launched)
     assert mirrors and launched
-    capacity = tpu_exec.tail_capacity(base.num_rows)
+    capacity = scan_cache.tail_capacity(base.num_rows)
     # on the device a row of `cpu` holds: ts 4 B, usage and idle 4 B each,
     # idle's validity, the pad mask and the all-valid mask 1 B each
     row_bytes = 4 + 4 + 4 + 1 + 1 + 1
@@ -598,7 +600,7 @@ def test_a_refresh_leaves_the_base_and_uploads_the_tail_alone(db):
         # the tail goes up whole, at its capacity, whatever the delta:
         # at most 2 x capacity x the bytes of a row, nothing of the base
         assert 0 < uploaded <= 2 * capacity * row_bytes, uploaded
-    now, tail = tpu_exec.SCAN_CACHE.get_parts(region)
+    now, tail = scan_cache.SCAN_CACHE.get_parts(region)
     assert now is base and tail.valid_rows == 6 * HOSTS
     assert {k: id(v) for k, v in base.device.items() if k in mirrors} == \
         mirrors
@@ -656,9 +658,9 @@ def test_other_callers_receive_one_sorted_scan(db):
     warm(db)
     appended(db)
     region = db.region()
-    assert tpu_exec.SCAN_CACHE.get_parts(region)[1] is not None
-    scan = tpu_exec.SCAN_CACHE.get(region)
-    assert tpu_exec.SCAN_CACHE.get_parts(region) == (scan, None)
+    assert scan_cache.SCAN_CACHE.get_parts(region)[1] is not None
+    scan = scan_cache.SCAN_CACHE.get(region)
+    assert scan_cache.SCAN_CACHE.get_parts(region) == (scan, None)
     rows = db.rows()
     assert scan.num_rows == len(rows) and scan.valid_rows is None
     order = np.lexsort((scan.ts, scan.series_ids))
@@ -715,11 +717,11 @@ def test_writes_of_any_size_after_the_warm_statements_compile_nothing(big):
     compiled = (_sorted_grouped_aggregate_pre._cache_size(),
                 scan_narrow._narrow_reduce._cache_size())
     region = next(iter(table.regions.values()))
-    base = tpu_exec.SCAN_CACHE.get_parts(region)[0]
+    base = scan_cache.SCAN_CACHE.get_parts(region)[0]
     # one executable a statement shape, compiled and not run: a table
     # nobody writes holds nothing of a tail's size on the device
     import jax
-    capacity = tpu_exec.tail_capacity(base.num_rows)
+    capacity = scan_cache.tail_capacity(base.num_rows)
     assert len(base.tail_programs) == 2
     assert not [a for a in jax.live_arrays() if a.shape[:1] == (capacity,)]
     tick, written = BIG_TICKS, {}
@@ -742,7 +744,7 @@ def test_writes_of_any_size_after_the_warm_statements_compile_nothing(big):
         assert (_sorted_grouped_aggregate_pre._cache_size(),
                 scan_narrow._narrow_reduce._cache_size()) == compiled, \
             f"a write of {ticks} ticks x {hosts} hosts met a new program"
-    now, tail = tpu_exec.SCAN_CACHE.get_parts(region)
+    now, tail = scan_cache.SCAN_CACHE.get_parts(region)
     assert now is base and tail.valid_rows == 400 * 6 + 7 + 7 * 150
 
 
@@ -884,7 +886,7 @@ def test_a_statement_on_an_empty_table_then_a_bulk_load(tmp_path):
                    for b in fe.do_query(count)[-1].batches) == 0
         table = fe.catalog.table("greptime", "public", "m")
         region = next(iter(table.regions.values()))
-        assert tpu_exec.SCAN_CACHE.cached(region)
+        assert scan_cache.SCAN_CACHE.cached(region)
         hosts, ticks = 300, 40
         loaded = fe.handle_bulk_load("m", {
             "host": np.repeat([f"h{h:03d}" for h in range(hosts)], ticks),
@@ -902,7 +904,7 @@ def test_a_statement_on_an_empty_table_then_a_bulk_load(tmp_path):
         assert metric("scan_cache_miss") == before["scan_cache_miss"] + 1
         assert {n: metric(n) for n in before if n != "scan_cache_miss"} == \
             {n: v for n, v in before.items() if n != "scan_cache_miss"}
-        base, tail = tpu_exec.SCAN_CACHE.get_parts(region)
+        base, tail = scan_cache.SCAN_CACHE.get_parts(region)
         assert (base.num_rows, tail) == (hosts * ticks, None)
     finally:
         fe.do_query("SET tpu_dispatch_min_rows = 131072")
@@ -918,9 +920,9 @@ def _rows(keys, seq, values, deleted=None, block=True):
     stamps = np.array([k[1] for k in keys], dtype=np.int64)
     vals = np.array(values, dtype=np.float64)
     blk = vals[:, None].copy() if block else None
-    fields = tpu_exec._block_fields(["v"], blk) if block \
+    fields = scan_cache._block_fields(["v"], blk) if block \
         else {"v": (vals, None)}
-    return tpu_exec._Rows(
+    return scan_cache._Rows(
         sids, stamps, np.full(len(keys), seq, np.int64), fields,
         None if deleted is None else np.array(deleted, dtype=bool), blk)
 
@@ -933,13 +935,13 @@ def test_merge_rows_places_replaces_and_removes(block):
                 [10, 20, 30, 40, 50, 60],
                 deleted=[False, False, False, False, True, False],
                 block=block)
-    out = tpu_exec._merge_rows(old, new)
+    out = scan_cache._merge_rows(old, new)
     assert list(zip(out.sids.tolist(), out.ts.tolist())) == \
         [(0, 10), (0, 15), (0, 20), (1, 7), (2, 1), (2, 5), (3, 0)]
     assert out.fields["v"][0].tolist() == [1, 10, 20, 30, 40, 3, 60]
     assert out.seq.tolist() == [1, 2, 2, 2, 2, 1, 2]
     assert out.deleted is None and (out.block is not None) == block
-    kept = tpu_exec._merge_rows(old, new, drop_deleted=False)
+    kept = scan_cache._merge_rows(old, new, drop_deleted=False)
     assert len(kept) == 8 and kept.deleted.tolist() == \
         [False] * 6 + [True, False]
 
@@ -948,8 +950,8 @@ def test_merge_rows_appends_without_a_search_over_times(monkeypatch):
     """What ticks give: every new row comes after its series' last."""
     old = _rows([(0, 10), (0, 20), (1, 10), (1, 20)], 1, [1, 2, 3, 4])
     new = _rows([(0, 30), (0, 40), (1, 30), (2, 30)], 2, [5, 6, 7, 8])
-    monkeypatch.setattr(scan_narrow, "_lower_bound", None)  # not reached
-    out = tpu_exec._merge_rows(old, new)
+    monkeypatch.setattr(scan_cache, "_lower_bound", None)  # not reached
+    out = scan_cache._merge_rows(old, new)
     assert out.fields["v"][0].tolist() == [1, 2, 5, 6, 3, 4, 7, 8]
     assert out.block.shape == (8, 1)
 

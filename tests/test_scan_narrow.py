@@ -20,7 +20,9 @@ import pytest
 from greptimedb_tpu.common.telemetry import registry_snapshot
 from greptimedb_tpu.datanode.instance import DatanodeInstance, DatanodeOptions
 from greptimedb_tpu.frontend.instance import FrontendInstance
-from greptimedb_tpu.query import scan_narrow, tpu_exec
+from greptimedb_tpu.query import (agg_plan, moment_fold, scan_full,
+                                  scan_launch, scan_narrow, tpu_exec)
+from greptimedb_tpu.storage import scan_cache
 
 HOSTS, TICKS, TICK_MS = 24, 800, 10_000
 T0 = 1_700_000_040_000                      # a whole minute
@@ -351,9 +353,9 @@ def test_narrow_builds_nothing_of_the_tables_length(db, monkeypatch):
     array the narrow path makes is as long as the table."""
     def never(*a, **k):
         raise AssertionError("the narrow path swept the table")
-    monkeypatch.setattr(tpu_exec, "_scan_row_mask", never)
-    monkeypatch.setattr(tpu_exec, "_scan_runs", never)
-    monkeypatch.setattr(tpu_exec, "_launch_scan_kernel", never)
+    monkeypatch.setattr(scan_full, "_scan_row_mask", never)
+    monkeypatch.setattr(scan_full, "_scan_runs", never)
+    monkeypatch.setattr(scan_full, "_launch_scan_kernel", never)
     seen = []
     real = scan_narrow.launch
 
@@ -381,7 +383,7 @@ def test_lower_bound_is_searchsorted_per_range():
     for value in (-1, 0, 37, 99, 100):
         want = [a + np.searchsorted(ts[a:b], value, side="left")
                 for a, b in zip(lo, hi)]
-        assert list(scan_narrow._lower_bound(ts, lo, hi, value)) == want
+        assert list(scan_cache._lower_bound(ts, lo, hi, value)) == want
 
 
 def test_many_runs_take_the_high_cardinality_kernels(db, monkeypatch):
@@ -391,10 +393,10 @@ def test_many_runs_take_the_high_cardinality_kernels(db, monkeypatch):
     from greptimedb_tpu.storage.series import SeriesDict
     hosts, ticks = 160, 1000
     captured = []
-    real = tpu_exec.plan_for
+    real = agg_plan.plan_for
     picked = list(range(3, 80, 5))
     with monkeypatch.context() as m:
-        m.setattr(tpu_exec, "plan_for", lambda t, a, q: captured.append(
+        m.setattr(agg_plan, "plan_for", lambda t, a, q: captured.append(
             (real(t, a, q), t.schema)) or captured[-1][0])
         db.sql(f"SELECT host, date_bin(INTERVAL '10 second', ts) AS b, "
                f"{EXACT} FROM cpu WHERE host IN ({in_list(picked)}) "
@@ -405,7 +407,7 @@ def test_many_runs_take_the_high_cardinality_kernels(db, monkeypatch):
                     [f"r{h % 3}" for h in range(hosts)]])
     rng = np.random.default_rng(11)
     n = hosts * ticks
-    scan = tpu_exec.MergedScan(
+    scan = scan_cache.MergedScan(
         np.repeat(np.arange(hosts, dtype=np.int32), ticks),
         np.tile(T0 + np.arange(ticks, dtype=np.int64) * TICK_MS, hosts),
         {"usage": (rng.random(n) * 100, None),
@@ -581,10 +583,10 @@ def test_the_live_axis_builds_nothing_of_the_tables_run_count(db,
 
     def never(*a, **k):
         raise AssertionError("the live axis cut the table's runs again")
-    monkeypatch.setattr(tpu_exec, "_segment_layout", never)
+    monkeypatch.setattr(scan_launch, "_segment_layout", never)
     seen = {}
     real_layout, real_collect = scan_narrow.live_layout, \
-        tpu_exec._collect_moment_frame
+        moment_fold._collect_moment_frame
 
     def layout(run_starts, run_ends, lo, hi, n, *pinned):
         out = real_layout(run_starts, run_ends, lo, hi, n, *pinned)
@@ -598,7 +600,7 @@ def test_the_live_axis_builds_nothing_of_the_tables_run_count(db,
                            {len(r) for r in res_np})
         return real_collect(launched, plan, counts, res_np)
     monkeypatch.setattr(scan_narrow, "live_layout", layout)
-    monkeypatch.setattr(tpu_exec, "_collect_moment_frame", collect)
+    monkeypatch.setattr(moment_fold, "_collect_moment_frame", collect)
     before = axis_counter("live")
     detail = db.stages(sql)["reduce"]
     assert axis_counter("live") == before + 1

@@ -24,7 +24,7 @@ from greptimedb_tpu.errors import (
     InvalidArgumentsError, SketchCodecError, UnsupportedError)
 from greptimedb_tpu.frontend.distributed import DistInstance
 from greptimedb_tpu.meta import MemKv, MetaClient, MetaSrv, Peer
-from greptimedb_tpu.query import sketches, tpu_exec
+from greptimedb_tpu.query import agg_plan, moment_fold, sketches
 from greptimedb_tpu.query.sketches import (
     EXACT_SET_LIMIT, DistinctSketch, HyperLogLog, TDigest, decode_sketch,
     encode_sketch, hash64)
@@ -36,7 +36,7 @@ def _clean_knobs():
     failpoint.reset()
     yield
     failpoint.reset()
-    tpu_exec.configure_partial_pushdown(enabled=True)
+    agg_plan.configure_partial_pushdown(enabled=True)
     sketches.configure(exact_distinct=False, error_target=0.01)
 
 
@@ -389,16 +389,16 @@ class TestDegrade:
 
     def test_truncated_frame_in_finalize_is_typed(self):
         import pandas as pd
-        plan = tpu_exec.TpuPlan(
+        plan = agg_plan.TpuPlan(
             tag_groups=[], bucket=None,
-            moments=[tpu_exec.Moment("distinct", "a", "__m0")],
+            moments=[agg_plan.Moment("distinct", "a", "__m0")],
             finals=[("__agg0", "count_distinct", ["__m0"])],
             time_lo=None, time_hi=None, tag_predicates=[],
             field_filters=[])
         good = encode_sketch(DistinctSketch.from_values(np.array([1.0])))
         df = pd.DataFrame({"__m0": [good[:-2]], "__rowcount": [1]})
         with pytest.raises(SketchCodecError):
-            tpu_exec._finalize(df, plan)
+            moment_fold._finalize(df, plan)
 
 
 class TestCostDispatch:

@@ -375,7 +375,7 @@ class TestSqlDifferential:
         fe.do_query("ADMIN FLUSH TABLE d", ctx)
 
     def test_on_off_answers_identical(self, frontend):
-        from greptimedb_tpu.query import tpu_exec
+        from greptimedb_tpu.storage import scan_cache
         from greptimedb_tpu.session import QueryContext
         ctx = QueryContext()
         self._setup(frontend, ctx)
@@ -385,7 +385,7 @@ class TestSqlDifferential:
                 answers = {}
                 for on in (1, 0):
                     frontend.do_query(f"SET sst_index = {on}", ctx)
-                    tpu_exec.SCAN_CACHE._entries.clear()
+                    scan_cache.SCAN_CACHE._entries.clear()
                     answers[on] = _rows(frontend.do_query(q, ctx)[-1])
                 assert answers[1] == answers[0], q
         finally:
@@ -399,6 +399,7 @@ class TestSqlDifferential:
         the stream path itself (not the indexed-point route that would
         otherwise win) consumes the sid set."""
         from greptimedb_tpu.query import stream_exec, tpu_exec
+        from greptimedb_tpu.storage import scan_cache
         from greptimedb_tpu.session import QueryContext
         ctx = QueryContext()
         self._setup(frontend, ctx)
@@ -412,7 +413,7 @@ class TestSqlDifferential:
                 answers = {}
                 for on in (1, 0):
                     frontend.do_query(f"SET sst_index = {on}", ctx)
-                    tpu_exec.SCAN_CACHE._entries.clear()
+                    scan_cache.SCAN_CACHE._entries.clear()
                     answers[on] = _rows(frontend.do_query(q, ctx)[-1])
                 assert answers[1] == answers[0], q
         finally:
@@ -439,7 +440,8 @@ class TestSqlDifferential:
     def test_promql_selector_differential(self, frontend):
         """The PromQL cold selector path resolves equality matchers to
         sid sets; answers must match the index-off run."""
-        from greptimedb_tpu.query import stream_exec, tpu_exec
+        from greptimedb_tpu.query import stream_exec
+        from greptimedb_tpu.storage import scan_cache
         from greptimedb_tpu.session import QueryContext
         ctx = QueryContext()
         self._setup(frontend, ctx)
@@ -449,7 +451,7 @@ class TestSqlDifferential:
             answers = {}
             for on in (1, 0):
                 frontend.do_query(f"SET sst_index = {on}", ctx)
-                tpu_exec.SCAN_CACHE._entries.clear()
+                scan_cache.SCAN_CACHE._entries.clear()
                 out = frontend.do_query(
                     "TQL EVAL (0, 30, '5s') d{host=\"h2\"}", ctx)[-1]
                 answers[on] = _rows(out)

@@ -19,6 +19,7 @@ from greptimedb_tpu.datatypes.schema import ColumnSchema, Schema, SemanticType
 from greptimedb_tpu.mito import MitoEngine
 from greptimedb_tpu.query import QueryEngine
 from greptimedb_tpu.query import stream_exec, tpu_exec
+from greptimedb_tpu.storage import scan_cache
 from greptimedb_tpu.session import QueryContext
 from greptimedb_tpu.sql import parse_sql
 from greptimedb_tpu.storage.engine import EngineConfig, StorageEngine
@@ -266,7 +267,7 @@ class TestStreamedMatchesCached:
             rows_of(engine, "SELECT host, avg(cpu) FROM m GROUP BY host")
             assert len(calls) > 3, "expected multiple slices"
             # the huge region never entered the scan cache
-            assert region.uid not in tpu_exec.SCAN_CACHE._entries
+            assert region.uid not in scan_cache.SCAN_CACHE._entries
         finally:
             storage.close()
 
@@ -283,7 +284,7 @@ class TestStreamedMatchesCached:
                                 [1 << 62])
             est = stream_exec.region_estimated_bytes(region)
             assert est > 0
-            monkeypatch.setattr(tpu_exec.SCAN_CACHE, "budget_bytes", est)
+            monkeypatch.setattr(scan_cache.SCAN_CACHE, "budget_bytes", est)
             called = []
             orig = stream_exec.stream_region_moment_frames
 
@@ -294,7 +295,7 @@ class TestStreamedMatchesCached:
                                 "stream_region_moment_frames", spy)
             rows_of(engine, "SELECT host, avg(cpu) FROM m GROUP BY host")
             assert called, "wide region must stream, not cache"
-            assert region.uid not in tpu_exec.SCAN_CACHE._entries
+            assert region.uid not in scan_cache.SCAN_CACHE._entries
         finally:
             storage.close()
 
@@ -338,7 +339,7 @@ class TestScanCacheBudget:
                     "cpu": np.full(n, float(i)).tolist()})
             r.write(wb)
             regions.append(r)
-        cache = tpu_exec._ScanCache(capacity=100)
+        cache = scan_cache._ScanCache(capacity=100)
         one = cache.get(regions[0]).nbytes
         cache.configure(budget_bytes=int(one * 2.5))
         for r in regions:

@@ -1,5 +1,5 @@
 """A statement's time window reaches the scan kernel as two scalars
-(ISSUE 43, `query/tpu_exec.py:_device_window`,
+(ISSUE 43, `query/scan_launch.py:_device_window`,
 `ops/kernels.py:_sorted_grouped_aggregate_pre`): the host makes no row
 mask of the table's length for a time predicate and uploads none.
 
@@ -24,7 +24,9 @@ from greptimedb_tpu.ops.kernels import (_SEG_HIGH_CARD_THRESHOLD,
                                         distinct_arrays, moment_results,
                                         open_window, seg_len_bucket,
                                         shape_bucket)
-from greptimedb_tpu.query import scan_narrow, tpu_exec
+from greptimedb_tpu.query import (agg_plan, scan_full, scan_launch,
+                                  scan_narrow, tpu_exec)
+from greptimedb_tpu.storage import scan_cache
 from test_kernels import block_edge_lens
 
 HOSTS, TICKS, TICK_MS = 40, 600, 10_000
@@ -146,9 +148,9 @@ class Plans:
 
     def of(self, sql: str):
         captured = []
-        real = tpu_exec.plan_for
+        real = agg_plan.plan_for
         with pytest.MonkeyPatch.context() as m:
-            m.setattr(tpu_exec, "plan_for", lambda t, a, q: captured.append(
+            m.setattr(agg_plan, "plan_for", lambda t, a, q: captured.append(
                 real(t, a, q)) or captured[-1])
             self.fe.do_query(sql)
         assert captured[-1] is not None, sql
@@ -176,7 +178,7 @@ def scan(plans):
     holds NULLs."""
     rng = np.random.default_rng(43)
     n = HOSTS * TICKS
-    return tpu_exec.MergedScan(
+    return scan_cache.MergedScan(
         np.repeat(np.arange(HOSTS, dtype=np.int32), TICKS),
         np.tile(T0 + np.arange(TICKS, dtype=np.int64) * TICK_MS, HOSTS),
         {"usage": (np.round(rng.random(n) * 100, 3), None),
@@ -188,11 +190,11 @@ def scan(plans):
 def host_masked(monkeypatch):
     """A context in which a launch is the parent's: the window ANDed into
     the row mask on the host, the program handed the open bounds."""
-    real = tpu_exec._scan_row_mask
+    real = scan_full._scan_row_mask
 
     def row_mask(scan, schema, plan, sel=None):
         mask = real(scan, schema, plan, sel)
-        if mask is tpu_exec._NO_ROWS:
+        if mask is scan_full._NO_ROWS:
             return mask
         if mask is None:
             mask = np.zeros(scan.num_rows, dtype=bool)
@@ -201,11 +203,11 @@ def host_masked(monkeypatch):
             mask &= scan.ts >= plan.time_lo
         if plan.time_hi is not None:
             mask &= scan.ts < plan.time_hi
-        return mask if mask.any() else tpu_exec._NO_ROWS
+        return mask if mask.any() else scan_full._NO_ROWS
 
     with monkeypatch.context() as m:
-        m.setattr(tpu_exec, "_scan_row_mask", row_mask)
-        m.setattr(tpu_exec, "_device_window",
+        m.setattr(scan_full, "_scan_row_mask", row_mask)
+        m.setattr(scan_launch, "_device_window",
                   lambda plan, scan: open_window(np.int32))
         yield
 
@@ -280,7 +282,7 @@ def test_a_full_launch_equals_the_host_masked_launch(plans, scan, case,
         HI if f"< {HI}" in where else None)
     monkeypatch.setattr(scan_narrow, "_NARROW_MAX_SHARE", 10**12)
     uploads = []
-    monkeypatch.setattr(tpu_exec.MergedScan, "upload", lambda self, arr:
+    monkeypatch.setattr(scan_cache.MergedScan, "upload", lambda self, arr:
                         uploads.append(arr.dtype) or np.asarray(arr))
     before = masks_made()
     got = tpu_exec._moment_frame_for_scan(scan, plans.schema, plan)
@@ -313,7 +315,7 @@ SPAN = 2**31 - 1        # the last relative time `device_ts` admits
 def wide(plans):
     """One series whose rows reach the end of the int32 coordinates."""
     ts = T0 + np.array([0, 5, 1000, SPAN], dtype=np.int64)
-    return tpu_exec.MergedScan(
+    return scan_cache.MergedScan(
         np.zeros(4, dtype=np.int32), ts,
         {"usage": (np.array([1.0, 2.0, 3.0, 4.0]), None),
          "idle": (np.zeros(4), None)},
@@ -348,8 +350,8 @@ def test_the_edges_of_the_span_and_of_the_coordinates(plans, wide, edge,
                     + (f" WHERE {where}" if where else ""))
     assert (plan.time_lo, plan.time_hi) == (lo, hi)
     calls = []
-    real = tpu_exec._run_program
-    monkeypatch.setattr(tpu_exec, "_run_program", lambda *a, **k:
+    real = scan_launch._run_program
+    monkeypatch.setattr(scan_launch, "_run_program", lambda *a, **k:
                         calls.append(1) or real(*a, **k))
     got = tpu_exec._moment_frame_for_scan(wide, plans.schema, plan)
     assert len(calls) == launches
@@ -361,7 +363,7 @@ def test_the_edges_of_the_span_and_of_the_coordinates(plans, wide, edge,
                        & (wide.ts < (hi if hi is not None else 2**62))]
         assert len(kept) == rows
     if launches:
-        w_lo, w_hi = tpu_exec._device_window(plan, wide)
+        w_lo, w_hi = scan_launch._device_window(plan, wide)
         assert (w_lo.shape, w_lo.dtype, w_hi.shape, w_hi.dtype) == \
             ((), np.int32, (), np.int32)
 
@@ -374,11 +376,11 @@ def test_a_tails_padding_inside_the_window_counts_no_row(plans, scan):
     ts = np.tile(T0 + (TICKS + np.arange(ticks, dtype=np.int64)) * TICK_MS,
                  HOSTS)
     k = len(ts)
-    tail = tpu_exec._make_tail(tpu_exec._Rows(
+    tail = scan_cache._make_tail(scan_cache._Rows(
         hosts, ts, np.zeros(k, np.int64),
         {"usage": (np.arange(k, dtype=np.float64), None),
          "idle": (np.ones(k), None)}), scan)
-    assert tail.num_rows == tpu_exec.tail_capacity(scan.num_rows) > k
+    assert tail.num_rows == scan_cache.tail_capacity(scan.num_rows) > k
     assert tail.ts[-1] == ts[-1] and tail.valid_rows == k
     lo = T0 + (TICKS + 1) * TICK_MS
     plan = plans.of(f"SELECT host, count(usage), max(usage) FROM cpu "
@@ -447,7 +449,7 @@ def test_two_windows_of_one_shape_are_one_program_and_a_tails_is_warmed(big):
         assert answer(sql(60, 400)).c.sum() == BIG_HOSTS * (BIG_TICKS - 60)
     compiled = _sorted_grouped_aggregate_pre._cache_size()
     region = next(iter(table.regions.values()))
-    base = tpu_exec.SCAN_CACHE.get_parts(region)[0]
+    base = scan_cache.SCAN_CACHE.get_parts(region)[0]
     assert len(base.tail_programs) == 1
     before = masks_made()
     for lo, hi in ((0, 120), (61, 400), (137, 139), (300, 10**6)):
@@ -473,5 +475,5 @@ def test_two_windows_of_one_shape_are_one_program_and_a_tails_is_warmed(big):
     detail = reduce_detail(sql(100, 400))
     assert "mask=none" in detail and "tail_mask=none" in detail
     assert len(ran) == 4 and len(base.tail_programs) == 1
-    assert tpu_exec.SCAN_CACHE.get_parts(region)[0] is base
+    assert scan_cache.SCAN_CACHE.get_parts(region)[0] is base
     assert _sorted_grouped_aggregate_pre._cache_size() == compiled

@@ -436,7 +436,7 @@ def test_the_dashboards_stack_is_fused_on_a_v5e(one_chip):
 def test_a_46m_row_tables_run_labels_are_one_pass_on_a_v5e(one_chip):
     """`scan_narrow.run_labels` at `prom-node-1k-2h`'s 46.08M rows: what a
     statement off every laid-out grid launches ahead of the scan kernel
-    (`tpu_exec._selection_layout`). One fused pass: nothing gathered,
+    (`scan_full._selection_layout`). One fused pass: nothing gathered,
     nothing kept but the labels."""
     from greptimedb_tpu.query import scan_narrow
     n, i32 = 46_080_000, jnp.int32
